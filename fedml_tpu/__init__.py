@@ -22,8 +22,4 @@ Layer map (mirrors SURVEY.md §1, rebuilt TPU-first):
                     genuinely remote cross-silo participants
 """
 
-from fedml_tpu import compat as _compat  # noqa: F401  (patches lagging jax
-#                                          APIs — jax.shard_map/lax.pcast —
-#                                          before any engine module loads)
-
 __version__ = "0.1.0"

@@ -49,7 +49,10 @@ class FedAvgEngine:
         self.cfg = cfg
         # opt-in fused aggregation kernel (fedml_tpu/ops); the default XLA
         # tree-mean is already fused well — the kernel wins when the whole
-        # stack is flattened anyway (robust pipeline) or on very many leaves
+        # stack is flattened anyway (robust pipeline) or on very many leaves.
+        # It flattens the cohort to one f32 [C, N] matrix (+ a pad copy):
+        # at the ResNet-18 row (N = 11.2 M) C = 8-10 fits one v5e chip and
+        # C = 128 does not (ops/aggregate.py "Size limit")
         self.pallas_agg = pallas_agg
         self.donate = donate
         self.sampler = ClientSampler.for_data(data, cfg)
@@ -151,8 +154,8 @@ class FedAvgEngine:
         # observability (fedml_tpu/obs; all no-ops unless --obs_dir):
         # each round gets a span + an optional deadline watchdog (a
         # flight-recorder dump fires if the round overruns
-        # cfg.round_deadline_s — the artifact tools/isolate_hang.py
-        # collects); an unhandled error dumps the ring before re-raising
+        # cfg.round_deadline_s); an unhandled error dumps the ring
+        # before re-raising
         deadline_s = getattr(cfg, "round_deadline_s", None)
         engine_name = type(self).__name__
         try:

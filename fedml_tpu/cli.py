@@ -1339,6 +1339,8 @@ def main(argv: Optional[list[str]] = None) -> int:
         level=logging.INFO,
         format="%(asctime)s %(name)s %(levelname)s %(message)s")
     from fedml_tpu.parallel.multihost import MultihostContext
+    from fedml_tpu.utils import compile_cache
+    compile_cache.configure()
     mh_ctx = MultihostContext.from_env()
     if args.multihost_procs is not None and mh_ctx is None:
         # self-spawn harness: re-exec this exact command N times wired
@@ -1393,7 +1395,7 @@ def main(argv: Optional[list[str]] = None) -> int:
             obs_dir = os.path.join(obs_dir, sub)
         obs.configure(obs_dir)
     else:
-        obs.configure_from_env()     # FEDML_OBS_DIR (tools/isolate_hang)
+        obs.configure_from_env()     # FEDML_OBS_DIR
     if args.obs_http_port is not None:
         port = obs.serve_http(args.obs_http_port).port
         logging.getLogger(__name__).info(

@@ -82,6 +82,11 @@ def build_client_shards(x: np.ndarray, y: np.ndarray,
     # pad_to_batches' zero padding
     gx[~mask] = 0
     gy[~mask] = 0
+    if gy.dtype == np.int64:
+        # without x64 the device holds int32 either way: cast ONCE here,
+        # so every upload ships (and the H2D accounting counts) the
+        # bytes the program uses, not twice as many
+        gy = gy.astype(np.int32)
     rs = lambda a: a.reshape((n_clients, B, batch_size) + a.shape[2:])
     return {"x": rs(gx), "y": rs(gy),
             "mask": rs(mask.astype(np.float32))}

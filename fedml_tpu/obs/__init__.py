@@ -119,8 +119,8 @@ def configure(directory: str, *, flight_capacity: int = 4096,
 
 
 def configure_from_env() -> bool:
-    """Enable from FEDML_OBS_DIR when set (bench.py / tools / child
-    processes of tools/isolate_hang.py).  No-op if already enabled."""
+    """Enable from FEDML_OBS_DIR when set (bench.py / tools / spawned
+    worker processes).  No-op if already enabled."""
     d = os.environ.get(ENV_VAR)
     if d and not enabled():
         configure(d)

@@ -1,12 +1,11 @@
 """Flight recorder — a fixed-size ring of recent span/metric events that
 dumps to disk when something goes wrong, so stalls are diagnosable from
-artifacts instead of reruns (tools/isolate_hang.py's whole reason to
-exist).
+artifacts instead of reruns.
 
 Triggers (wired in fedml_tpu/obs/__init__.py and the engine run loop):
 
-  * SIGUSR1 — an operator (or tools/isolate_hang.py watching a stuck
-    child) pokes the process; the handler dumps the ring plus every
+  * SIGUSR1 — an operator (or a parent watching a stuck child) pokes
+    the process; the handler dumps the ring plus every
     thread's current Python stack.  Python-level hangs (a recv loop
     parked on a queue, a prefetch join) show up directly; a process
     wedged inside a C call dumps as soon as the interpreter resumes.

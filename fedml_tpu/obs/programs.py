@@ -34,8 +34,9 @@ PERF.md stage table).  This registry makes them STANDING artifacts:
 ``report(since=snapshot())`` is the standing replacement for the
 manual profile session: per-family dispatch counts, wall p50/p95,
 compile seconds, flops/bytes per dispatch, and MFU against
-``peak_flops()`` (FEDML_PEAK_FLOPS env override; a documented
-order-of-magnitude CPU heuristic otherwise) — bench.py's schema-v11
+``peak_flops()`` (the published per-chip peak of the attached
+``device_kind``; a documented order-of-magnitude heuristic on the CPU
+backend) — bench.py's schema-v11
 ``programs`` block and PERF.md's "Performance observatory" table both
 read it.
 """
@@ -49,7 +50,6 @@ from typing import Any, Optional
 from fedml_tpu.obs.metrics import quantile_from_cumulative
 
 ENV_CENSUS = "FEDML_OBS_CENSUS"
-ENV_PEAK_FLOPS = "FEDML_PEAK_FLOPS"
 
 _lock = threading.Lock()
 _families: dict[str, "ProgramFamily"] = {}
@@ -195,26 +195,33 @@ def load_census(report: Any) -> int:
     return n
 
 
-def peak_flops() -> Optional[float]:
-    """Peak-FLOP/s denominator for MFU.  FEDML_PEAK_FLOPS overrides
-    (the chip-attached runs set the real per-chip number); otherwise a
-    documented order-of-magnitude CPU heuristic — cores x 3.2 GHz x 16
-    f32 FLOP/cycle (one AVX2 FMA port's worth) — good enough to rank
-    families and watch trends on the 2-core CI box, NOT a calibrated
-    utilization claim (PERF.md says so next to the table)."""
-    env = os.environ.get(ENV_PEAK_FLOPS)
-    if env:
-        try:
-            return float(env)
-        except ValueError:
-            pass
+# Published per-chip peaks, keyed by `jax.devices()[0].device_kind`.
+# v5e: 197 TFLOP/s bf16 (Google Cloud documentation, "TPU v5e").  A
+# device that is not in the table is an error, not a default.
+PEAK_FLOPS_BY_DEVICE_KIND = {
+    "TPU v5 lite": 197e12,
+}
+
+
+def peak_flops() -> float:
+    """Peak-FLOP/s denominator for MFU, from the device JAX reports.
+    On an accelerator: the published per-chip peak of its
+    `device_kind` (ValueError for an unknown kind — no silent
+    default).  On the CPU backend: a documented
+    order-of-magnitude heuristic — cores x 3.2 GHz x 16 f32 FLOP/cycle
+    (one AVX2 FMA port's worth) — good enough to rank families and
+    watch trends on a CI box, NOT a calibrated utilization claim."""
+    import jax
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
+        return float(os.cpu_count() or 1) * 3.2e9 * 16
     try:
-        import jax
-        if jax.default_backend() != "cpu":
-            return None              # no honest default for unknown chips
-    except Exception:
-        return None
-    return float(os.cpu_count() or 1) * 3.2e9 * 16
+        return PEAK_FLOPS_BY_DEVICE_KIND[dev.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peak FLOP/s for device_kind "
+            f"{dev.device_kind!r}: add it (with its source) to "
+            f"obs/programs.py PEAK_FLOPS_BY_DEVICE_KIND") from None
 
 
 # -- the dispatch wrapper ----------------------------------------------------
@@ -335,7 +342,7 @@ def report(since: Optional[dict] = None, *,
          "total": {...}}            # the whole-run row
 
     MFU = flops_total / (window_s x peak_flops) — null without census
-    numbers or a peak estimate.  `publish_gauges` mirrors the rows into
+    numbers.  `publish_gauges` mirrors the rows into
     ``program_mfu{family}`` / ``program_bytes_moved_total{family}``
     gauges (the "live MFU accounting" surface)."""
     from fedml_tpu import obs

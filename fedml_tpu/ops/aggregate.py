@@ -17,7 +17,20 @@ through):
 Layout: client pytrees are flattened to one [C, N] matrix (N padded to
 the 128-lane tile), so every leaf rides the same kernel and the tiling is
 always aligned.  On non-TPU backends the kernels run in pallas interpret
-mode (tests), selected automatically.
+mode (tests), selected automatically and counted in
+`ops_kernel_path_total{op="aggregate", path=...}`.
+
+Size limit (one v5e chip, 16 GB HBM): the fused ops materialize the
+whole cohort as ONE f32 [C, N] matrix next to the stacked input —
+`flatten_stacked_tree`'s concat (fused with the pad to a TILE multiple)
+and a relayout copy of the big leaves.  At the ResNet-18 row
+(N = 11,173,962; 44.7 MB per client per copy) C = 8 and C = 10 compile
+for the chip; at C = 128 the compiler refuses with RESOURCE_EXHAUSTED
+(16.01 G of 15.75 G HBM: 5.37 G of arguments + a 5.33 G [128, N] concat
++ a 5.31 G leaf copy; tests/test_tpu_compile.py pins the refusal) — a
+size limit of this layout, not a kernel fault.  Cohorts that large
+aggregate through the mesh engines' chunked Σw·v carry
+(parallel/engine.py), which never builds [C, N].
 """
 from __future__ import annotations
 
@@ -26,21 +39,25 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:                                   # pltpu import fails on cpu-only jax
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-    _SMEM = pltpu.SMEM
-except Exception:                      # pragma: no cover
-    pltpu = None
-    _VMEM = _SMEM = None
+from fedml_tpu import obs
 
 Pytree = Any
 TILE = 512                             # lanes per grid step (4×128)
+_VMEM = pltpu.VMEM
+_SMEM = pltpu.SMEM
 
 
 def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+    """Compiled (Mosaic) on a TPU backend, pallas interpret mode
+    elsewhere — the CPU tests' path.  The choice is counted at trace
+    time (`ops_kernel_path_total{op="aggregate"}`), so a run that
+    reports "pallas" can show which one it got."""
+    interpret = jax.default_backend() != "tpu"
+    obs.counter("ops_kernel_path_total", op="aggregate",
+                path="interpret" if interpret else "pallas").inc()
+    return interpret
 
 
 # ---------------------------------------------------------------------------
@@ -91,10 +108,17 @@ def unflatten_to_tree(vec: jax.Array, spec) -> Pytree:
 # kernel 1: weighted mean
 # ---------------------------------------------------------------------------
 
+# The MXU's default f32 matmul is ONE bf16 pass: measured on the v5e it
+# cost the weighted mean 5.6e-3 absolute against the f32 reference (PR 21
+# chip run) — model weights aggregated at 8 bits of mantissa.  HIGHEST
+# keeps the reduction at f32 accuracy; the op is HBM-bound either way.
+_F32_DOT = dict(preferred_element_type=jnp.float32,
+                precision=jax.lax.Precision.HIGHEST)
+
+
 def _wmean_kernel(w_ref, x_ref, inv_ref, o_ref):
     # [1,C] @ [C,T] on the MXU, scaled by 1/Σw from SMEM
-    o_ref[:] = jnp.dot(w_ref[:], x_ref[:],
-                       preferred_element_type=jnp.float32) * inv_ref[0, 0]
+    o_ref[:] = jnp.dot(w_ref[:], x_ref[:], **_F32_DOT) * inv_ref[0, 0]
 
 
 def _wmean_flat(flat: jax.Array, w: jax.Array, interpret: bool) -> jax.Array:
@@ -144,8 +168,7 @@ def _sqnorm_kernel(x_ref, g_ref, o_ref):
 def _clip_agg_kernel(cf_ref, x_ref, g_ref, o_ref):
     # out = g + Σ_c cf_c·(x_c − g):   cf already folds ŵ_c·min(1, τ/‖d_c‖)
     d = x_ref[:] - g_ref[:]
-    o_ref[:] = g_ref[:] + jnp.dot(cf_ref[:], d,
-                                  preferred_element_type=jnp.float32)
+    o_ref[:] = g_ref[:] + jnp.dot(cf_ref[:], d, **_F32_DOT)
 
 
 def robust_weighted_mean_pallas(stacked: Pytree, weights: jax.Array,

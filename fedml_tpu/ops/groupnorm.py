@@ -31,25 +31,24 @@ mask passes amortize.
 from __future__ import annotations
 
 import functools
+import logging
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:                      # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from fedml_tpu import obs
+
+log = logging.getLogger(__name__)
 
 BLOCK_N = 8      # sublane granularity: blocks must be multiples of 8
 FTILE = 8192     # in-kernel chunk (VMEM temporaries stay ~1 MB)
+_VMEM = pltpu.VMEM
 
 
-def _use_pallas(shape, num_groups) -> bool:
-    if jax.default_backend() != "tpu":
-        return False
+def _kernel_supports(shape, num_groups) -> bool:
+    """The fused kernels' layout requirement (module docstring)."""
     if len(shape) < 2:
         return False
     feat = 1
@@ -62,6 +61,24 @@ def _use_pallas(shape, num_groups) -> bool:
         return True
     # chunked path needs C-aligned full tiles
     return feat % FTILE == 0 and FTILE % C == 0
+
+
+def _use_pallas(shape, num_groups) -> bool:
+    """Fused kernels on a TPU backend for shapes they support; the jnp
+    reference otherwise.  Decided (and counted, in
+    `ops_kernel_path_total{op="group_norm"}`) at trace time; on a TPU
+    backend a rejected shape is also logged by name (once per trace), so
+    a run that asked for the fused op cannot silently become the
+    reference."""
+    on_tpu = jax.default_backend() == "tpu"
+    fused = on_tpu and _kernel_supports(shape, num_groups)
+    obs.counter("ops_kernel_path_total", op="group_norm",
+                path="pallas" if fused else "reference").inc()
+    if on_tpu and not fused:
+        log.warning("group_norm: shape %s (groups=%d) does not fit the "
+                    "fused kernels' layout; using the jnp reference",
+                    tuple(shape), num_groups)
+    return fused
 
 
 # ---------------------------------------------------------------------------

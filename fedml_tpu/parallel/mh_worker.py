@@ -76,23 +76,18 @@ DEFAULTS = {
 
 def _setup_jax(cfg: dict) -> None:
     """Platform/device-count/compile-cache config — BEFORE any jax
-    backend init (the init_multihost contract)."""
+    backend init (the init_multihost contract).  The multi-process tier
+    is host-level and CPU-only today: spawn_cluster_report hands every
+    rank JAX_PLATFORMS=cpu explicitly (a parent may hold the chip); the
+    setdefault only covers a worker started by hand."""
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     if "host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
         os.environ["XLA_FLAGS"] = (
             os.environ.get("XLA_FLAGS", "")
             + f" --xla_force_host_platform_device_count="
               f"{cfg['local_devices']}")
-    import jax
-    if os.environ.get("JAX_PLATFORMS") == "cpu":
-        jax.config.update("jax_platforms", "cpu")
-    cache = os.path.expanduser("~/.cache/fedml_tpu_jax_tests")
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          0.5)
-    except Exception:
-        pass
+    from fedml_tpu.utils import compile_cache
+    compile_cache.configure(min_compile_time_secs=0.5)
 
 
 def build_case(cfg: dict):

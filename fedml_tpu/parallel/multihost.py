@@ -23,10 +23,7 @@ aggregation (arXiv:2007.13518):
 * `HostChannel` — the DCN tier executed for real: a tiny TCP
   coordinator (rank 0) carrying the P-sized flat f32 carry between
   hosts.  On the CPU dev box this stands in for gloo/DCN; it needs NO
-  backend collective support, which is what makes the runtime runnable
-  on jaxlib builds whose CPU backend lacks cross-process computations
-  (the 0.4.x line — see tests/test_multihost_spmd.py's version gate on
-  the in-program gloo path).  Every wait is BOUNDED: a dead or hung
+  backend collective support.  Every wait is BOUNDED: a dead or hung
   rank raises `DeadRankError` NAMING the rank instead of hanging the
   cluster.
 * `MultihostRunner` — the two-level round loop: intra-host psum over
@@ -116,35 +113,24 @@ def init_multihost(coordinator_address: Optional[str] = None,
     required=True (the CLI's --multihost sets it) — a failure raises:
     silently training independent single-host replicas would corrupt the
     run.  Replaces the reference's mpirun/hostfile bootstrap."""
-    try:
-        if jax.distributed.is_initialized():
-            return
-    except AttributeError:              # older jax: no is_initialized
-        pass
+    if jax.distributed.is_initialized():
+        return
     explicit = (required or coordinator_address is not None
                 or num_processes is not None or process_id is not None)
     try:
         # CPU cross-process collectives need a transport; without one the
-        # global mesh forms but the first psum fails.  Current jaxlib
-        # defaults the option to "gloo" (test_multihost_spmd runs over
-        # it); this fallback covers builds whose default is unset/"none".
-        # It must happen BEFORE initialize, and without probing the
-        # platform — that would initialize the backend, which
-        # jax.distributed.initialize forbids (see module docstring) — so
-        # the option is set whenever it is not already configured (it
-        # only affects the cpu backend; TPU pods use ICI/DCN natively).
-        # getattr's default covers the older-jaxlib option-absent case
-        # (cur = "absent" skips the update); a FAILING update on a jaxlib
-        # that HAS the option is a real configuration error and must not
-        # be swallowed — deferring it to the first cross-process psum
-        # yields a much worse message
-        cur = getattr(jax.config,
-                      "jax_cpu_collectives_implementation", "absent")
-        if cur in (None, "", "none"):
-            # unset/disabled only (this jaxlib's default is already
-            # "gloo"): an operator's explicit transport choice (env
-            # JAX_CPU_COLLECTIVES_IMPLEMENTATION=mpi or a prior
-            # config.update) must win
+        # global mesh forms but the first psum fails.  jaxlib defaults
+        # the option to "gloo" (test_multihost_spmd runs over it); only
+        # an unset/disabled value is repaired here, so an operator's
+        # explicit transport choice (env
+        # JAX_CPU_COLLECTIVES_IMPLEMENTATION=mpi or a prior
+        # config.update) wins.  It must happen BEFORE initialize, and
+        # without probing the platform — that would initialize the
+        # backend, which jax.distributed.initialize forbids (see module
+        # docstring); the option only affects the cpu backend (TPU pods
+        # use ICI/DCN natively).
+        if jax.config.jax_cpu_collectives_implementation in (
+                None, "", "none"):
             jax.config.update("jax_cpu_collectives_implementation",
                               "gloo")
         jax.distributed.initialize(coordinator_address=coordinator_address,
@@ -336,7 +322,10 @@ def spawn_cluster_report(cmd: list[str], procs: int, *,
                          "cluster kills the survivors the rejoiner "
                          "would rejoin)")
     coord = f"{coordinator_host}:{free_port()}"
-    base_env = {**os.environ, **(env or {}),
+    # N ranks on ONE host can never share its chip(s) — and the parent
+    # may hold them — so every rank is CPU unless the caller's `env`
+    # says otherwise: explicit here, not left to a child's setdefault
+    base_env = {**os.environ, "JAX_PLATFORMS": "cpu", **(env or {}),
                 ENV_WORLD: str(procs), ENV_COORD: coord}
     base_env.pop("FEDML_MH_REJOIN", None)
     if jax_distributed:

@@ -3,15 +3,7 @@ both the worker processes (multihost_worker.py) and the single-process
 oracle (test_multihost_spmd.py) build EXACTLY these engines, so any
 digest difference is attributable to the process boundary, not the
 workload."""
-import os
-
 import numpy as np
-
-# ONE persistent-compile-cache location for the whole test universe —
-# conftest.py (the pytest process) and the multihost workers (fresh
-# subprocesses) must point at the SAME dir or the workers recompile
-# every round program every run
-JAX_TEST_CACHE_DIR = os.path.expanduser("~/.cache/fedml_tpu_jax_tests")
 
 
 def _case_data_cfg(comm_round: int):

@@ -26,11 +26,11 @@ os.environ["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={ndev}"
 
 import jax  # noqa: E402
 
-jax.config.update("jax_platforms", "cpu")
-from tests.multihost_case import JAX_TEST_CACHE_DIR  # noqa: E402
+# same persistent compile cache as conftest.py — the workers are fresh
+# processes and would otherwise recompile every round program every run
+from fedml_tpu.utils import compile_cache  # noqa: E402
 
-jax.config.update("jax_compilation_cache_dir", JAX_TEST_CACHE_DIR)
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+compile_cache.configure(min_compile_time_secs=0.5)
 
 from fedml_tpu.parallel.multihost import init_multihost  # noqa: E402
 

@@ -1,8 +1,9 @@
 """Fused GroupNorm: value and gradient parity with flax nn.GroupNorm (the
 spec), on the reference path (the test platform is CPU, where _use_pallas
-is False).  The pallas TPU path shares the custom-VJP plumbing but its
-kernels only compile on hardware — run `python tools/tpu_smoke.py` on a
-TPU host to check pallas-vs-reference parity there."""
+is False).  The pallas TPU path shares the custom-VJP plumbing; its
+kernels are compiled for the chip (without one) at the ResNet-18 stage
+shapes by tests/test_tpu_compile.py, and run against the reference on
+the chip by `python chip_smoke.py` phase (c)."""
 import flax.linen as nn
 import jax
 import jax.numpy as jnp

@@ -28,25 +28,8 @@ import sys
 import threading
 import time
 
-import jax
 import numpy as np
 import pytest
-
-# The gloo-backed CPU cross-process collectives the GLOBAL-MESH tests
-# run over landed after jaxlib 0.4: on the 0.4.x CI image every
-# cross-process device_put dies in the runtime with "Multiprocess
-# computations aren't implemented on the CPU backend" — a backend
-# capability gap, not a framework bug (the same programs run the
-# single-process 8-device oracle in multihost_case.py).  Those tests
-# skip, like the chip-gated ones.  The ISSUE-13 TWO-LEVEL runtime tests
-# below do NOT skip: their cross-process tier is the HostChannel (host
-# sockets), which needs no backend collective support — that is the
-# point of the design.
-gloo_gate = pytest.mark.skipif(
-    jax.__version_info__ < (0, 5),
-    reason="jaxlib < 0.5: multiprocess computations not implemented on "
-           "the CPU backend (cross-process gloo collectives landed "
-           "later)")
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 WORKER = os.path.join(REPO, "tests", "multihost_worker.py")
@@ -183,12 +166,10 @@ def _check_against_oracle(workers, silos: int):
     assert w0["ba"] == pytest.approx(ba, abs=1e-6)
 
 
-@gloo_gate
 def test_two_process_mesh_matches_single_process():
     _check_against_oracle(_run_cluster(nprocs=2, ndev=4), silos=2)
 
 
-@gloo_gate
 def test_multihost_checkpoint_resume(tmp_path):
     """save → kill → resume across a 2-process cluster (VERDICT r4 #5):
     cluster A runs rounds 0-1 of 4 with per-round orbax checkpointing
@@ -212,7 +193,6 @@ def test_multihost_checkpoint_resume(tmp_path):
         assert float(res.group(1)) == float(full.group(1))
 
 
-@gloo_gate
 def test_four_process_mesh_matches_single_process():
     _check_against_oracle(_run_cluster(nprocs=4, ndev=2), silos=4)
 

@@ -214,8 +214,8 @@ def test_flight_deadline_cancelled_when_round_finishes(clean_obs,
 
 
 def test_flight_dump_on_sigusr1(clean_obs, tmp_path):
-    """kill -USR1 <pid> (what tools/isolate_hang.py --timeout sends to a
-    stuck stage) produces a dump with the recent event ring."""
+    """kill -USR1 <pid> (what an operator sends to a stuck run)
+    produces a dump with the recent event ring."""
     obs.configure(str(tmp_path))          # installs the handler
     with obs.span("round.blockstream", round=7):
         pass

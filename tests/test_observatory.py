@@ -447,7 +447,7 @@ def test_bench_diff_gates_and_noise_bands(tmp_path):
 
 
 def test_bench_diff_handles_schema_range_and_wrappers(tmp_path):
-    """v4-v11 bench lines and BENCH_r*.json driver wrappers normalize;
+    """v4-v11 bench lines and driver wrappers ({"parsed": line}) normalize;
     fields absent on one side report `missing`, never a regression."""
     bd = _load_bench_diff()
     v4 = {"schema_version": 4, "mode": "async", "value": 2.0,

@@ -86,3 +86,19 @@ def test_engine_pallas_agg_matches_default():
     vb, _, _ = e2.round_fn(v0, e2.server_init(v0), cohort, r)
     for a, b in zip(jax.tree.leaves(va), jax.tree.leaves(vb)):
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+
+
+def test_kernel_path_choice_is_counted():
+    """The implicit interpret/reference choice off-TPU is observable:
+    ops_kernel_path_total{op, path} ticks at trace time."""
+    from fedml_tpu import obs
+    from fedml_tpu.ops.groupnorm import group_norm
+    agg = obs.counter("ops_kernel_path_total", op="aggregate",
+                      path="interpret")
+    gn = obs.counter("ops_kernel_path_total", op="group_norm",
+                     path="reference")
+    a0, g0 = agg.value, gn.value
+    weighted_mean_pallas(random_stack(jax.random.PRNGKey(4)),
+                         jnp.ones(5))              # interpret=None: default
+    group_norm(jnp.ones((8, 4, 4, 16)), jnp.ones(16), jnp.zeros(16), 8)
+    assert agg.value == a0 + 1 and gn.value == g0 + 1

@@ -1,0 +1,204 @@
+"""Compile the main path's kernels for the REAL chip, without one.
+
+The TPU compiler is installed in the CPU-only sandbox and compiles for a
+chip that is described, not attached (`topologies.get_topology_desc`,
+the `on-chip-measurement` guide §2).  Interpret mode cannot show what
+Mosaic refuses — a slice not aligned to the tiling, too much VMEM, a
+program that does not fit 16 GB of HBM — so the pallas kernels of
+`chip_smoke.py` phase (c) are compiled here at their real widths, and
+`-m slow` adds the (32,32,32,64) GroupNorm pair, the whole headline
+round program on one and on four described chips, and the documented
+C = 128 size limit of the fused robust aggregation.
+
+A compile that passes is not a chip run: nothing executes, so these
+tests say nothing about results or times.  Skipped where the topology
+cannot be described.
+"""
+import os
+import sys
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")   # else it logs under /tmp
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.experimental import topologies
+from jax.experimental.compilation_cache import compilation_cache
+from jax.sharding import SingleDeviceSharding
+
+from fedml_tpu.ops import aggregate, groupnorm
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+RESNET18_N = 11_173_962                         # ResNet-18-GN, 10 classes
+N_PADDED = RESNET18_N + (-RESNET18_N) % aggregate.TILE
+
+
+@pytest.fixture(scope="module")
+def topo():
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e topology here: {e!r}")
+    # a described-chip compile is written to the persistent cache but
+    # cannot be read back without a chip (the next run would warn and
+    # recompile): keep these compiles out of it
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+def _compile(topo, fn, *shapes):
+    """jit(fn) lowered from shape structs placed on the first described
+    chip, then compiled by the chip's compiler."""
+    chip = SingleDeviceSharding(topo.devices[0])
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in shapes]
+    return jax.jit(fn).lower(*args).compile()
+
+
+def _assert_kernels(compiled, n: int):
+    assert compiled.as_text().count("tpu_custom_call") >= n
+
+
+# -- aggregation: what FedAvgEngine(pallas_agg=True) / FedAvgRobustEngine
+#    see for an 8- or 10-client cohort of ResNet-18 rows -------------------
+
+@pytest.mark.parametrize("C", [8, 10])
+def test_wmean_flat_compiles(topo, C):
+    c = _compile(topo, lambda f, w: aggregate._wmean_flat(f, w, False),
+                 ((C, N_PADDED), jnp.float32), ((C,), jnp.float32))
+    _assert_kernels(c, 1)
+
+
+@pytest.mark.parametrize("C", [8, 10])
+def test_robust_passes_compile(topo, C):
+    """Both robust passes (per-client ‖x−g‖², then the clipped
+    reduction) on a single-leaf tree already at the padded width."""
+    def f(flat, w, g):
+        return aggregate.robust_weighted_mean_pallas(
+            {"w": flat}, w, {"w": g}, 5.0, interpret=False)
+    c = _compile(topo, f, ((C, N_PADDED), jnp.float32),
+                 ((C,), jnp.float32), ((N_PADDED,), jnp.float32))
+    _assert_kernels(c, 2)
+
+
+# -- fused GroupNorm at the ResNet-18 CIFAR stage shapes -------------------
+
+GN_FAST = [(32, 8, 8, 256), (32, 4, 4, 512)]
+GN_SLOW = [(32, 32, 32, 64)]
+G = 8              # group_norm's default (the model's own GroupNorm uses 2)
+
+
+def _gn_fwd_case(topo, shape, dtype):
+    assert groupnorm._kernel_supports(shape, G)
+    Cc = shape[-1]
+    c = _compile(
+        topo, lambda x, g, b: groupnorm._pallas_fwd(x, g, b, G, 1e-5),
+        (shape, dtype), ((Cc,), jnp.float32), ((Cc,), jnp.float32))
+    _assert_kernels(c, 1)
+
+
+def _gn_dx_case(topo, shape, dtype):
+    Cc, N = shape[-1], shape[0]
+    c = _compile(
+        topo,
+        lambda x, dy, g, m, r: groupnorm._pallas_dx(x, dy, g, m, r, G, 1e-5),
+        (shape, dtype), (shape, dtype), ((Cc,), jnp.float32),
+        ((N, G), jnp.float32), ((N, G), jnp.float32))
+    _assert_kernels(c, 1)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("shape", GN_FAST, ids=str)
+def test_groupnorm_fwd_compiles(topo, shape, dtype):
+    _gn_fwd_case(topo, shape, dtype)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("shape", GN_FAST, ids=str)
+def test_groupnorm_dx_compiles(topo, shape, dtype):
+    _gn_dx_case(topo, shape, dtype)
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("case", [_gn_fwd_case, _gn_dx_case],
+                         ids=["fwd", "dx"])
+@pytest.mark.parametrize("shape", GN_SLOW, ids=str)
+def test_groupnorm_large_stage_compiles(topo, shape, case):
+    case(topo, shape, jnp.float32)
+
+
+# -- the documented size limit --------------------------------------------
+
+@pytest.mark.slow
+def test_robust_c128_exceeds_one_chip(topo):
+    """C = 128 ResNet-18 rows do not fit one v5e chip through the fused
+    robust op (ops/aggregate.py "Size limit"): the compiler refuses."""
+    params = {"a": ((3, 3, 64, 64), jnp.float32),
+              "b": ((RESNET18_N - 3 * 3 * 64 * 64,), jnp.float32)}
+    chip = SingleDeviceSharding(topo.devices[0])
+    stacked = {k: jax.ShapeDtypeStruct((128,) + s, d, sharding=chip)
+               for k, (s, d) in params.items()}
+    g = {k: jax.ShapeDtypeStruct(s, d, sharding=chip)
+         for k, (s, d) in params.items()}
+    w = jax.ShapeDtypeStruct((128,), jnp.float32, sharding=chip)
+    f = jax.jit(lambda s, w, g: aggregate.robust_weighted_mean_pallas(
+        s, w, g, 5.0, interpret=False))
+    with pytest.raises(Exception, match="RESOURCE_EXHAUSTED"):
+        f.lower(stacked, w, g).compile()
+
+
+# -- the whole headline round program --------------------------------------
+
+def _headline_round(topo, n_devices: int):
+    """bench.py's headline engine over a mesh of described chips, its
+    streaming round lowered from shape structs (128 clients x 13 batches
+    x 32, ResNet-18-GN, bf16 compute, chunk 2, unroll 8)."""
+    sys.path.insert(0, REPO)
+    import bench
+    from fedml_tpu.parallel.mesh import (client_sharding, make_mesh,
+                                         replicated_sharding,
+                                         stack_leaf_sharding)
+    spc = bench.SAMPLES_PER_CLIENT
+    rs = np.random.RandomState(0)
+    # two host clients fix the per-client shapes; the cohort axis of the
+    # lowered program comes from the shape structs below
+    x = rs.rand(2 * spc, 32, 32, 3).astype(np.float32)
+    y = rs.randint(0, 10, 2 * spc).astype(np.int64)
+    cfg, data, trainer = bench.build_headline(x, y, n_clients=2)
+    mesh = make_mesh(devices=topo.devices[:n_devices])
+    engine = bench.headline_engine(cfg, data, trainer, mesh=mesh)
+    host = engine._cast_stack_x(dict(data.client_shards))
+    cohort = {
+        k: jax.ShapeDtypeStruct((bench.N_CLIENTS,) + v.shape[1:], v.dtype,
+                                sharding=stack_leaf_sharding(mesh, v))
+        for k, v in host.items()}
+    rep = replicated_sharding(mesh)
+    variables = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rep),
+        jax.eval_shape(engine.init_variables))
+    weights = jax.ShapeDtypeStruct((bench.N_CLIENTS,), jnp.float32,
+                                   sharding=client_sharding(mesh))
+    rng = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=rep)
+    return engine.round_fn_streaming.lower(
+        variables, (), cohort, weights, rng).compile()
+
+
+@pytest.mark.slow
+def test_headline_round_compiles_for_one_chip(topo):
+    mem = _headline_round(topo, 1).memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+             + mem.generated_code_size_in_bytes)
+    assert total < 15.75 * 2 ** 30, mem
+
+
+@pytest.mark.slow
+def test_headline_round_compiles_for_four_chips(topo):
+    compiled = _headline_round(topo, 4)
+    assert "all-reduce" in compiled.as_text()

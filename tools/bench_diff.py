@@ -1,21 +1,19 @@
 """Cross-run bench regression differ (ISSUE 12).
 
-Five BENCH_r*.json records and seven bench modes exist; until now a
-regression was caught by a human re-reading PERF.md.  This tool
-compares two bench JSON documents (or a directory trajectory) per mode
-with EXPLICIT noise bands — the measured run-to-run spreads from the
+A regression used to be caught by a human re-reading PERF.md.  This
+tool compares two bench JSON documents per mode with EXPLICIT noise
+bands — the measured run-to-run spreads from the
 CHANGES/PERF history are encoded here once, not rediscovered per
 review — and emits named regression/improvement verdicts:
 
     python tools/bench_diff.py OLD.json NEW.json
     python tools/bench_diff.py benchmarks/bench_baseline_2core.json NEW.json
-    python tools/bench_diff.py --dir .          # BENCH_r*.json trajectory
     python tools/bench_diff.py OLD NEW --json out.json
 
 Accepted input shapes (schema v4-v17, normalized by `prune()`):
 
   * a raw bench.py JSON line (any --mode);
-  * a driver record wrapping one under "parsed" (BENCH_r*.json);
+  * a driver record wrapping one under "parsed";
   * a pruned baseline snapshot {"kind": "bench_baseline",
     "modes": {mode: fields}} — benchmarks/bench_baseline_2core.json is
     the committed anchor (see its "calibration" note for the
@@ -29,7 +27,8 @@ synthetically degraded document.
 
 Noise-band sources (don't tighten without re-measuring):
 
-  * sync rounds/sec: chip run-to-run 0.544-0.549 (~1%, BENCH_r04/r05);
+  * sync rounds/sec: chip run-to-run 0.544-0.549 (~1%; builder session
+    on one v5e, 2026-07/08, older than PR 1);
     10% band absorbs box-load spread while catching the 20%+ drops
     that have historically meant a real regression;
   * ingest/chaos/connections committed-updates/sec: the in-process
@@ -83,9 +82,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
-import glob
 import json
-import os
 import sys
 from typing import Optional
 
@@ -117,7 +114,7 @@ def load_doc(path: str) -> dict:
             raise SystemExit(f"bench_diff: {path} holds no JSON document")
     if isinstance(doc, dict) and "parsed" in doc and isinstance(
             doc["parsed"], dict):
-        doc = doc["parsed"]          # BENCH_r*.json driver wrapper
+        doc = doc["parsed"]          # driver wrapper
     return doc
 
 
@@ -153,7 +150,8 @@ def prune(doc: dict) -> dict:
     mode = doc.get("mode", "sync")
     out: dict = {}
     if doc.get("error"):
-        # chip-unavailable marker rows never fold into trends
+        # an error row (older records marked a missing chip this way;
+        # bench.py now exits non-zero instead) never folds into trends
         return {mode: {"error": doc["error"]}}
     f: dict = {}
     if mode == "sync":
@@ -755,23 +753,6 @@ def run_diff(old_path: str, new_path: str) -> tuple[list[dict], int]:
     return rows, rc
 
 
-def run_trajectory(directory: str) -> tuple[list[dict], int]:
-    paths = sorted(glob.glob(os.path.join(directory, "BENCH_r*.json")))
-    if len(paths) < 2:
-        raise SystemExit(
-            f"bench_diff: --dir needs >= 2 BENCH_r*.json under "
-            f"{directory}, found {len(paths)}")
-    rows, rc = [], 0
-    for a, b in zip(paths, paths[1:]):
-        step_rows, step_rc = run_diff(a, b)
-        tag = f"{os.path.basename(a)} -> {os.path.basename(b)}"
-        for r in step_rows:
-            r["step"] = tag
-        rows.extend(step_rows)
-        rc = max(rc, step_rc)
-    return rows, rc
-
-
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
         description=__doc__.splitlines()[0],
@@ -779,21 +760,14 @@ def main(argv=None) -> int:
     ap.add_argument("old", nargs="?",
                     help="older bench JSON / baseline snapshot")
     ap.add_argument("new", nargs="?", help="newer bench JSON")
-    ap.add_argument("--dir", default=None,
-                    help="diff the BENCH_r*.json trajectory in this "
-                         "directory (consecutive pairs) instead of two "
-                         "files")
     ap.add_argument("--json", default=None,
                     help="also write the verdict rows as JSON here")
     args = ap.parse_args(argv)
     try:
-        if args.dir:
-            rows, rc = run_trajectory(args.dir)
-        else:
-            if not args.old or not args.new:
-                ap.print_usage(sys.stderr)
-                return 2
-            rows, rc = run_diff(args.old, args.new)
+        if not args.old or not args.new:
+            ap.print_usage(sys.stderr)
+            return 2
+        rows, rc = run_diff(args.old, args.new)
     except (OSError, ValueError) as e:
         print(f"bench_diff: {e}", file=sys.stderr)
         return 2

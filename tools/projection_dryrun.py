@@ -58,8 +58,10 @@ def _child(n_devices: int, batch_axis: int) -> None:
     from fedml_tpu.core.trainer import ClientTrainer
     from fedml_tpu.parallel import MeshFedAvgEngine
     from fedml_tpu.parallel.mesh import make_mesh, make_mesh_batch
+    from fedml_tpu.utils import compile_cache
     from fedml_tpu.utils.config import FedConfig
 
+    compile_cache.configure()
     assert len(jax.devices()) == n_devices, jax.devices()
     if batch_axis > 1:
         mesh = make_mesh_batch(n_devices // batch_axis, batch_axis)

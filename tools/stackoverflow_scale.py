@@ -10,7 +10,7 @@ round program is the same jitted streaming program the 96-client CI test
 pins.  Numbers land in SCALING.md.
 
 Usage: python tools/stackoverflow_scale.py [n_clients] [rounds]
-(defaults: the full 342,477 / 5).  PLATFORM=tpu runs on the chip;
+(defaults: the full 342,477 / 5).  JAX_PLATFORMS=tpu runs on the chip;
 default is CPU so the demo is about HOST scale, not device speed.
 """
 from __future__ import annotations
@@ -20,13 +20,10 @@ import resource
 import sys
 import time
 
-if os.environ.get("PLATFORM", "cpu") != "tpu":
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+# host-side scale demo: CPU unless JAX_PLATFORMS says otherwise
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import jax
-
-if os.environ.get("PLATFORM", "cpu") != "tpu":
-    jax.config.update("jax_platforms", "cpu")
 
 import numpy as np
 
@@ -35,6 +32,7 @@ from fedml_tpu.data.loaders import load_data
 from fedml_tpu.models import create_model
 from fedml_tpu.parallel import MeshFedAvgEngine
 from fedml_tpu.parallel.mesh import make_mesh
+from fedml_tpu.utils import compile_cache
 from fedml_tpu.utils.config import FedConfig
 
 
@@ -43,6 +41,7 @@ def rss_gb() -> float:
 
 
 def main(n_clients: int = 342_477, rounds: int = 5) -> None:
+    compile_cache.configure()
     t0 = time.time()
     # synthetic_scale=0: sc() floors at 2 samples/client — the point is
     # the CLIENT COUNT (index maps, stacked arrays, cohort gather), the
