@@ -100,9 +100,10 @@ ESTIMATED_REFERENCE_ROUNDS_PER_SEC = 2.0
 #     slo_clean_breaches verdict) while chaos/storm arms breach BY
 #     DESIGN with named attribution — and + "programs" block
 #     (fedml_tpu/obs/programs.py): the per-jit-program-family profile
-#     ({"window_s", "peak_flops", "families": [{family, stage,
+#     ({"window_s", "families": [{family, stage,
 #     dispatches, dispatch_wall_s, dispatch_p50/p95_s, flops/bytes per
-#     dispatch, mfu}], "total"}), the PERF.md stage table as a standing
+#     dispatch}], "total"}; PR 23 took "mfu" and "peak_flops" out: census
+#     FLOPs over HOST dispatch wall), the PERF.md stage table as a standing
 #     artifact; v10 readers that ignore unknown keys keep working
 # v12: + "multihost" block (`python bench.py --mode multihost`,
 #     ISSUE 13 — fedml_tpu/parallel/multihost.py): the weak-scaling

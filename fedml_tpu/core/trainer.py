@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import optax
 
 from fedml_tpu import obs
+from fedml_tpu.obs import scopes
 from fedml_tpu.core.pytree import (tree_merge_counts, tree_select,
                                    tree_vary_noop)
 
@@ -236,6 +237,7 @@ class ClientTrainer:
             if jnp.issubdtype(a.dtype, jnp.floating) else a, tree)
 
     # -- loss ---------------------------------------------------------------
+    @jax.named_scope(scopes.FED_FORWARD)
     def _loss(self, params, rest, batch, rng, global_params=None):
         """Masters (params/opt state/stats) stay float32; when train_dtype
         is bfloat16 the forward/backward compute runs through bf16 casts —
@@ -324,7 +326,6 @@ class ClientTrainer:
             loss = self._revary(jax.lax.psum(loss, self.batch_axes))
             new_rest = self._revary(jax.lax.pmean(new_rest, self.batch_axes))
             n_valid = self._revary(jax.lax.psum(n_valid, self.batch_axes))
-        updates, opt_state = self.tx.update(grads, state.opt_state, params)
         # empty-batch guard: for params, scaling the UPDATES by the has-data
         # flag is exactly equivalent to a post-hoc select (additive updates;
         # u*0 leaves params bitwise unchanged) but fuses into apply_updates
@@ -333,18 +334,23 @@ class ClientTrainer:
         # select (core/pytree.py:tree_select).  Under batch_axes the guard
         # keys on the GLOBAL count — a shard whose slice is all padding must
         # still apply the other shards' gradient contribution.
-        has_data = n_valid > 0
-        g = has_data.astype(jnp.float32)
-        new_params = optax.apply_updates(
-            params, jax.tree.map(lambda u: u * g.astype(u.dtype), updates))
-        keep = functools.partial(tree_select, has_data)
-        kept_opt = keep(opt_state, state.opt_state)
-        if self.has_schedule:
-            # padded batches still advance the schedule's step count so
-            # ragged clients share one LR trajectory (tree_merge_counts)
-            kept_opt = tree_merge_counts(kept_opt, opt_state)
+        with jax.named_scope(scopes.FED_OPTIMIZER):
+            updates, opt_state = self.tx.update(grads, state.opt_state,
+                                                params)
+            has_data = n_valid > 0
+            g = has_data.astype(jnp.float32)
+            new_params = optax.apply_updates(
+                params,
+                jax.tree.map(lambda u: u * g.astype(u.dtype), updates))
+            keep = functools.partial(tree_select, has_data)
+            kept_opt = keep(opt_state, state.opt_state)
+            if self.has_schedule:
+                # padded batches still advance the schedule's step count so
+                # ragged clients share one LR trajectory (tree_merge_counts)
+                kept_opt = tree_merge_counts(kept_opt, opt_state)
+            kept_rest = keep(new_rest, rest)
         return TrainState(
-            variables={"params": new_params, **keep(new_rest, rest)},
+            variables={"params": new_params, **kept_rest},
             opt_state=kept_opt,
             rng=rng), jnp.where(has_data, loss, 0.0)
 

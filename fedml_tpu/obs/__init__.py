@@ -15,9 +15,16 @@ Two tiers, by cost:
   (or a test poking `obs.registry()`) sees history, not a cold start.
 * **Tracing/flight-recording is opt-in** via `configure(obs_dir)` (the
   CLI's `--obs_dir`, or the FEDML_OBS_DIR env var for bench/tools).
-  Until then `span()` returns a shared stateless no-op and nothing is
-  buffered — the disabled fast path in the engine hot loop is a flag
-  check and a constant return.
+  Until then nothing is buffered.
+* **`span()` always annotates.**  Every span enters a
+  `jax.profiler.TraceAnnotation` of the same name and attributes: with
+  a profiler session active (`jax.profiler.start_trace`, the CLI's
+  `--profile_dir`, the benchmark's `--trace 1`) it lands in `/host:CPU`
+  of the same `.xplane.pb` as the device ops, on the profiler's clock,
+  from whichever thread opened it; with no session the annotation is a
+  flag check and records nothing.  The `SpanTracer` keeps its own
+  `perf_counter` epoch (obs/tracer.py) — the device trace never sees
+  that clock, which is why the annotation exists.
 
 `configure()` also installs the SIGUSR1 flight-dump handler (main
 thread only) and an atexit export, so any obs-enabled run leaves a
@@ -37,10 +44,12 @@ import threading
 import time
 from typing import Iterator, Optional
 
+from jax.profiler import TraceAnnotation
+
 from fedml_tpu.obs.flight import FlightRecorder, thread_stacks
 from fedml_tpu.obs.metrics import (Counter, Gauge, Histogram,
                                    MetricsRegistry)
-from fedml_tpu.obs.tracer import NOOP_SPAN, SpanTracer
+from fedml_tpu.obs.tracer import SpanTracer
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "SpanTracer",
@@ -156,11 +165,20 @@ def reset() -> None:
 # -- tracing -----------------------------------------------------------------
 
 def span(name: str, **attrs):
-    """Nestable wall-clock span; the no-op singleton when disabled."""
+    """Nestable wall-clock span: always a profiler annotation (recorded
+    only while a profiler session is active), and a `SpanTracer` event
+    too once `configure()` ran."""
+    ann = TraceAnnotation(name, **attrs)
     t = _tracer
     if t is None:
-        return NOOP_SPAN
-    return t.span(name, **attrs)
+        return ann
+    return _both(ann, t.span(name, **attrs))
+
+
+@contextlib.contextmanager
+def _both(annotation, tracer_span) -> Iterator[None]:
+    with annotation, tracer_span:
+        yield
 
 
 def instant(name: str, **attrs) -> None:

@@ -26,27 +26,33 @@ PERF.md stage table).  This registry makes them STANDING artifacts:
   reading ``compiled.cost_analysis()``; default OFF so the hot paths
   and tier-1 pay nothing) or from a ``tools/hlo_copy_audit.py --out``
   artifact (``load_census()``), giving per-family and whole-run
-  MFU/bytes-moved gauges;
+  FLOP/bytes-moved totals (no utilization: dispatch wall is HOST time
+  of an asynchronous enqueue, so the "MFU" this module once derived
+  from it measured nothing — the device trace's ``round_roofline`` is
+  that number, PERF.md §3);
+* ``scope_map()`` names the scopes of the compiled program: HLO
+  instruction name -> ``fed_*`` scope label (obs/scopes.py), which is
+  what lets a reader split a device trace whose events carry bare HLO
+  names by layer;
 * every family maps to a canonical timeline stage
   (obs/timeline.py PROGRAM_FAMILY_STAGES), so the profile table groups
   into the same taxonomy as the round critical path.
 
 ``report(since=snapshot())`` is the standing replacement for the
 manual profile session: per-family dispatch counts, wall p50/p95,
-compile seconds, flops/bytes per dispatch, and MFU against
-``peak_flops()`` (the published per-chip peak of the attached
-``device_kind``; a documented order-of-magnitude heuristic on the CPU
-backend) — bench.py's schema-v11
+compile seconds and flops/bytes per dispatch — bench.py's schema-v11
 ``programs`` block and PERF.md's "Performance observatory" table both
 read it.
 """
 from __future__ import annotations
 
 import os
+import re
 import threading
 import time
 from typing import Any, Optional
 
+from fedml_tpu.obs import scopes
 from fedml_tpu.obs.metrics import quantile_from_cumulative
 
 ENV_CENSUS = "FEDML_OBS_CENSUS"
@@ -204,7 +210,7 @@ PEAK_FLOPS_BY_DEVICE_KIND = {
 
 
 def peak_flops() -> float:
-    """Peak-FLOP/s denominator for MFU, from the device JAX reports.
+    """Peak FLOP/s of the device JAX reports.
     On an accelerator: the published per-chip peak of its
     `device_kind` (ValueError for an unknown kind — no silent
     default).  On the CPU backend: a documented
@@ -228,18 +234,22 @@ def peak_flops() -> float:
 
 class InstrumentedProgram:
     """Transparent wrapper around one jitted program: counts + times
-    each dispatch, marks the thread's current family for compile
-    attribution, and (census mode) runs a one-time AOT cost analysis.
-    `lower` and every other attribute delegate to the wrapped jit, so
-    AOT consumers (hlo_copy_audit's ``fn.lower(*args).compile()``) see
-    the real thing."""
+    each dispatch (and opens the ``program.dispatch`` span around it),
+    marks the thread's current family for compile attribution, and
+    (census mode) runs a one-time AOT cost analysis.  `lower` and every
+    other attribute delegate to the wrapped jit, so AOT consumers
+    (hlo_copy_audit's ``fn.lower(*args).compile()``) see the real
+    thing."""
 
-    __slots__ = ("_fn", "_family", "_census_tried")
+    __slots__ = ("_fn", "_family", "_census_tried", "_signature",
+                 "_scope_map")
 
     def __init__(self, fn, family: ProgramFamily):
         self._fn = fn
         self._family = family
         self._census_tried = False
+        self._signature = None      # abstract (args, kwargs), 1st dispatch
+        self._scope_map = None
 
     @property
     def inner(self):
@@ -254,15 +264,52 @@ class InstrumentedProgram:
         if (not self._census_tried and fam.flops_per_dispatch is None
                 and census_enabled()):
             self._try_census(args, kwargs)
+        if self._signature is None:
+            self._signature = _abstract_signature(args, kwargs)
         prev = getattr(_tls, "family", None)
         _tls.family = fam.name
+        from fedml_tpu import obs
         t0 = time.perf_counter()
         try:
-            return self._fn(*args, **kwargs)
+            with obs.span(scopes.SPAN_DISPATCH, family=fam.name,
+                          n=int(fam._handles()[0].value)):
+                return self._fn(*args, **kwargs)
         finally:
             dt = time.perf_counter() - t0
             _tls.family = prev
             fam.observe_dispatch(dt)
+
+    def scope_map(self) -> Optional[dict]:
+        """{HLO instruction name -> scope label} of the compiled program
+        (labels: obs/scopes.py LABELS; the labelling rule:
+        ``scope_map_of_hlo_text``), or None before the first dispatch /
+        for a callable that cannot be lowered.
+
+        Nothing is computed until this is called: the first dispatch
+        only remembered the abstract signature (shape, dtype, sharding);
+        here it is lowered and compiled again (the persistent compile
+        cache has the executable) and the optimized module's text is
+        walked once.  The cache keys on the module WITHOUT its metadata,
+        so the executable it returns may carry another build's names (the
+        same program before it had scopes): where the text lacks a scope
+        that the lowering has, it is compiled once more past the cache.
+        Instruction names do not depend on metadata, so either text names
+        the ops of the executable that ran.  Every instruction of every
+        computation is listed (fused bodies too); a device trace names
+        only the ones that ran as ops of their own."""
+        if self._scope_map is None:
+            if self._signature is None or not hasattr(self._fn, "lower"):
+                return None
+            args, kwargs = self._signature
+            lowered = self._fn.lower(*args, **kwargs)
+            text = lowered.compile().as_text()
+            missing = [s for s in scopes.LABEL_OF_SCOPE if s not in text]
+            if missing:
+                traced = lowered.as_text(debug_info=True)
+                if any(s in traced for s in missing):
+                    text = _compile_past_the_cache(lowered).as_text()
+            self._scope_map = scope_map_of_hlo_text(text)
+        return self._scope_map
 
     def _try_census(self, args, kwargs) -> None:
         """One-time AOT lower+compile with the live call's args (shapes
@@ -292,6 +339,114 @@ class InstrumentedProgram:
     def __repr__(self):
         return (f"InstrumentedProgram({self._family.name}, "
                 f"{self._fn!r})")
+
+
+def _compile_past_the_cache(lowered):
+    """Compile with the persistent cache off, so the executable's
+    metadata is this lowering's own; nothing is written to the cache.
+    A `Lowered` keeps the executable it was first given unless compiler
+    options are passed: the one passed here is XLA's default."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        return lowered.compile(
+            compiler_options={"xla_dump_hlo_as_text": False})
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was_on)
+        compilation_cache.reset_cache()
+
+
+def _abstract_signature(args, kwargs):
+    """The call's arguments as `jax.ShapeDtypeStruct`s — what
+    ``lower()`` needs to rebuild the same program without holding a
+    (donated) buffer.  An uncommitted array keeps no sharding, as jit
+    treats it; non-array leaves pass through."""
+    import jax
+
+    def abstract(a):
+        if not isinstance(a, jax.Array):
+            return a
+        sharding = a.sharding if getattr(a, "committed", True) else None
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding)
+
+    return jax.tree.map(abstract, (args, kwargs))
+
+
+# an HLO module's text: computations `[ENTRY ]%name (...) -> ... {` at
+# column 0, their instructions `  [ROOT ]%name = ...` indented
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(")
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = ")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_OPERAND = re.compile(r"%([\w.\-]+)")
+_LOOP_COMPUTATION = re.compile(r"(?:body|condition)=%?([\w.\-]+)")
+
+
+def scope_map_of_hlo_text(text: str) -> dict:
+    """{instruction name -> scope label} from an optimized HLO module's
+    text (see InstrumentedProgram.scope_map).
+
+    An instruction whose ``op_name`` is a traced op's name stack
+    (``jit(...)/...``) is labelled by its innermost ``fed_*`` component
+    (``scopes.label_of``).  A fusion carries the ``op_name`` of its root:
+    that is the granularity — a fusion that merged ops of two scopes is
+    booked whole to its root's.  One without (no metadata, or
+    only an argument's name) was put there by the compiler — an async
+    copy-start/-done or slice into faster memory, a relayout copy of an
+    argument, the tuple plumbing of a while: it takes the label of the
+    nearest labelled instruction that consumes it — the layer it moves
+    data for — else of the nearest that produces its operands, else of
+    the ``while`` whose body it sits in (a carry that is only copied
+    through), else ``unscoped``."""
+    own, operands, users, comp_of, loop_of = {}, {}, {}, {}, {}
+    comp = None
+    for line in text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if not m:
+            h = _COMPUTATION.match(line)
+            comp = h.group(1) if h else comp
+            continue
+        name = m.group(1)
+        op = _OP_NAME.search(line, m.end())
+        own[name] = (scopes.label_of(op.group(1))
+                     if op and op.group(1).startswith("jit(") else None)
+        operands[name] = _OPERAND.findall(line, m.end())
+        comp_of[name] = comp
+        if " while(" in line:
+            for c in _LOOP_COMPUTATION.findall(line, m.end()):
+                loop_of[c] = name
+    for name, ops in operands.items():
+        # computations named by calls=/body= are not instructions
+        operands[name] = ops = [o for o in ops if o in own]
+        for o in ops:
+            users.setdefault(o, []).append(name)
+
+    def nearest(name, edges):
+        seen, frontier = {name}, [name]
+        while frontier:
+            nxt = []
+            for n in frontier:
+                for e in edges.get(n, ()):
+                    if own[e] is not None:
+                        return own[e]
+                    if e not in seen:
+                        seen.add(e)
+                        nxt.append(e)
+            frontier = nxt
+        return None
+
+    labels = {name: (label if label is not None else
+                     nearest(name, users) or nearest(name, operands))
+              for name, label in own.items()}
+
+    def resolved(name):
+        while name is not None and labels[name] is None:
+            name = loop_of.get(comp_of[name])
+        return scopes.UNSCOPED if name is None else labels[name]
+
+    return {name: resolved(name) for name in labels}
 
 
 def instrument(family: str, fn) -> InstrumentedProgram:
@@ -326,29 +481,24 @@ def snapshot() -> dict:
 
 
 def report(since: Optional[dict] = None, *,
-           peak: Optional[float] = None,
            publish_gauges: bool = True) -> dict:
     """Per-family profile over the window since `since` (a snapshot();
     None = since process start / family registration).  Returns
 
-        {"window_s", "peak_flops", "families": [
+        {"window_s", "families": [
             {family, stage, dispatches, dispatch_wall_s,
              dispatch_p50_s, dispatch_p95_s, compile_seconds,
              flops_per_dispatch, bytes_per_dispatch, flops_total,
-             bytes_total, mfu}, ...],
+             bytes_total}, ...],
          "processes": [...],        # per-process breakdown rows from a
                                     # multihost run's origin-labeled
                                     # merged series (ISSUE 13)
          "total": {...}}            # the whole-run row
 
-    MFU = flops_total / (window_s x peak_flops) — null without census
-    numbers.  `publish_gauges` mirrors the rows into
-    ``program_mfu{family}`` / ``program_bytes_moved_total{family}``
-    gauges (the "live MFU accounting" surface)."""
+    flops/bytes are null without census numbers.  `publish_gauges`
+    mirrors the rows' bytes into ``program_bytes_moved_total{family}``."""
     from fedml_tpu import obs
     reg = obs.registry()
-    if peak is None:
-        peak = peak_flops()
     t0 = (since or {}).get("t")
     window_s = (time.perf_counter() - t0) if t0 is not None else None
     prev = (since or {}).get("families", {})
@@ -367,10 +517,6 @@ def report(since: Optional[dict] = None, *,
                        if fam.flops_per_dispatch is not None else None)
         bytes_total = (fam.bytes_per_dispatch * dispatches
                        if fam.bytes_per_dispatch is not None else None)
-        mfu = None
-        if (flops_total is not None and peak and window_s
-                and window_s > 0):
-            mfu = flops_total / (window_s * peak)
         # windowed like everything else in the row: compiles BEFORE the
         # snapshot (the cold-start storm) must not re-report in later
         # windows' recompile attribution
@@ -390,36 +536,25 @@ def report(since: Optional[dict] = None, *,
             "bytes_per_dispatch": fam.bytes_per_dispatch,
             "flops_total": flops_total,
             "bytes_total": bytes_total,
-            "mfu": (round(mfu, 6) if mfu is not None else None),
             "census_source": fam.census_source,
         })
-        if publish_gauges:
-            if mfu is not None:
-                obs.gauge("program_mfu", family=name).set(mfu)
-            if bytes_total is not None:
-                obs.gauge("program_bytes_moved_total",
-                          family=name).set(bytes_total)
+        if publish_gauges and bytes_total is not None:
+            obs.gauge("program_bytes_moved_total",
+                      family=name).set(bytes_total)
     total_flops = [r["flops_total"] for r in rows
                    if r["flops_total"] is not None]
     total_bytes = [r["bytes_total"] for r in rows
                    if r["bytes_total"] is not None]
-    total_mfu = None
-    if total_flops and peak and window_s and window_s > 0:
-        total_mfu = sum(total_flops) / (window_s * peak)
     total = {
         "dispatches": sum(r["dispatches"] for r in rows),
         "dispatch_wall_s": round(sum(r["dispatch_wall_s"]
                                      for r in rows), 6),
         "flops_total": sum(total_flops) if total_flops else None,
         "bytes_total": sum(total_bytes) if total_bytes else None,
-        "mfu": (round(total_mfu, 6) if total_mfu is not None else None),
     }
-    if publish_gauges and total_mfu is not None:
-        obs.gauge("program_mfu", family="_total").set(total_mfu)
     return {
         "window_s": (round(window_s, 3) if window_s is not None
                      else None),
-        "peak_flops": peak,
         "families": rows,
         "processes": _per_process_rows(reg),
         "total": total,
@@ -470,17 +605,15 @@ def _per_process_rows(reg) -> list:
 def format_table(rep: dict) -> str:
     """Human-readable per-family table (PERF.md's standing artifact)."""
     lines = [f"{'family':<24}{'stage':<8}{'disp':>8}{'wall s':>10}"
-             f"{'p95 ms':>9}{'GFLOP/disp':>12}{'MFU':>8}"]
+             f"{'p95 ms':>9}{'GFLOP/disp':>12}"]
     for r in rep["families"]:
         gf = (f"{r['flops_per_dispatch'] / 1e9:.3f}"
               if r["flops_per_dispatch"] is not None else "-")
-        mfu = f"{r['mfu']:.2%}" if r["mfu"] is not None else "-"
         lines.append(
             f"{r['family']:<24}{r['stage']:<8}{r['dispatches']:>8}"
             f"{r['dispatch_wall_s']:>10.3f}"
-            f"{r['dispatch_p95_s'] * 1e3:>9.2f}{gf:>12}{mfu:>8}")
+            f"{r['dispatch_p95_s'] * 1e3:>9.2f}{gf:>12}")
     t = rep["total"]
-    mfu = f"{t['mfu']:.2%}" if t["mfu"] is not None else "-"
     lines.append(f"{'TOTAL':<24}{'':<8}{t['dispatches']:>8}"
-                 f"{t['dispatch_wall_s']:>10.3f}{'':>9}{'':>12}{mfu:>8}")
+                 f"{t['dispatch_wall_s']:>10.3f}")
     return "\n".join(lines)

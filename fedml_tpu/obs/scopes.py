@@ -1,0 +1,75 @@
+"""The names the round program carries into its HLO, and the program spans
+of the hot path — one module, so the program that opens them and the
+readers that look for them cannot drift apart.
+
+**Scopes** are ``jax.named_scope`` names.  They change the ``op_name``
+metadata of the ops traced beneath them and nothing else; the optimized
+HLO is the same but for metadata (tests/test_hlo_copy_audit.py pins the
+copy census of the touched families exactly).  ``label_of`` turns one
+``op_name`` into the label the benchmark splits device time by:
+
+    fed_take            MeshFedAvgEngine._mesh_round     take
+    fed_local_train     the chunk scan of per-client     local_other
+                        training and its plumbing        (what no inner
+                        (chunked_weighted_train)         scope claims)
+    fed_forward         ClientTrainer._loss              forward; under
+                        value_and_grad the backward ops read
+                        transpose(jvp(fed_forward))   -> backward
+    fed_optimizer       ClientTrainer.train_step         optimizer
+    fed_aggregate       Σ w·v fold, psums, weighted mean aggregate
+    fed_server_update   engine.server_update             server_update
+
+An op under several scopes belongs to the innermost one (a forward op
+is inside fed_local_train too); an op under none is ``unscoped``.
+
+**Spans** are ``obs.span`` names: host intervals that land in the
+``SpanTracer`` when ``obs.configure()`` ran and, always, in the
+profiler's own trace (``/host:CPU`` of the ``.xplane.pb``) when a
+profiler session is active.
+"""
+from __future__ import annotations
+
+import re
+
+FED_TAKE = "fed_take"
+FED_LOCAL_TRAIN = "fed_local_train"
+FED_FORWARD = "fed_forward"
+FED_OPTIMIZER = "fed_optimizer"
+FED_AGGREGATE = "fed_aggregate"
+FED_SERVER_UPDATE = "fed_server_update"
+
+UNSCOPED = "unscoped"
+BACKWARD = "backward"
+LABEL_OF_SCOPE = {
+    FED_TAKE: "take",
+    FED_LOCAL_TRAIN: "local_other",
+    FED_FORWARD: "forward",
+    FED_OPTIMIZER: "optimizer",
+    FED_AGGREGATE: "aggregate",
+    FED_SERVER_UPDATE: "server_update",
+}
+LABELS = tuple(LABEL_OF_SCOPE.values()) + (BACKWARD, UNSCOPED)
+
+SPAN_SAMPLE = "round.sample"
+SPAN_ARGS_PUT = "round.args_put"
+SPAN_DISPATCH = "program.dispatch"
+SPAN_GATHER = "h2d.gather"
+SPAN_PUT = "h2d.put"
+SPAN_WAIT = "h2d.wait"
+SPANS = (SPAN_SAMPLE, SPAN_ARGS_PUT, SPAN_DISPATCH, SPAN_GATHER, SPAN_PUT,
+         SPAN_WAIT)
+
+_SCOPE = re.compile("|".join(sorted(LABEL_OF_SCOPE, key=len, reverse=True)))
+
+
+def label_of(op_name: str) -> str:
+    """The label of one HLO instruction from its ``op_name``: the
+    innermost ``fed_*`` component of the "/"-separated name stack
+    (``fed_forward`` wrapped in ``transpose(`` is the backward pass)."""
+    for part in reversed(op_name.split("/")):
+        m = _SCOPE.search(part)
+        if m:
+            if m.group() == FED_FORWARD and "transpose(" in part:
+                return BACKWARD
+            return LABEL_OF_SCOPE[m.group()]
+    return UNSCOPED

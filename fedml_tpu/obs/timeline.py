@@ -57,7 +57,10 @@ SPAN_STAGES = {
     "round.block_step": "train",
     "round.chunked": "train",
     "h2d.upload_block": "h2d",
+    "h2d.upload_cohort": "h2d",     # the span the streaming round opens
     "h2d.upload": "h2d",
+    "h2d.gather": "h2d",
+    "h2d.put": "h2d",
     "async.eval": "eval",
     "eval": "eval",
     "checkpoint": "checkpoint",

@@ -20,8 +20,8 @@ event is appended to a side file as it is recorded, up to a byte cap
 (`spill_limit_bytes`), after which truncation is counted instead of
 silently eating disk — ring (tail) + spill (head) together lose nothing
 until the cap.  When observability is disabled the tracer is never
-constructed at all — `obs.span()` returns a shared no-op (see
-fedml_tpu/obs/__init__.py).
+constructed at all — `obs.span()` then enters only its profiler
+annotation (see fedml_tpu/obs/__init__.py).
 
 Cross-process federation (ISSUE 7): `export_jsonl` leads with one
 `__meta__` line (pid, epoch_unix, drop/spill accounting) so
@@ -215,20 +215,3 @@ class SpanTracer:
             if self._spill_f is not None:
                 self._spill_f.close()
                 self._spill_f = None
-
-
-class _NoopSpan:
-    """Shared no-op context manager — the disabled-by-default fast path.
-    Stateless, so one instance serves every call site and nesting level
-    concurrently; entering costs two trivial method calls."""
-
-    __slots__ = ()
-
-    def __enter__(self) -> "_NoopSpan":
-        return self
-
-    def __exit__(self, *exc) -> Optional[bool]:
-        return None
-
-
-NOOP_SPAN = _NoopSpan()

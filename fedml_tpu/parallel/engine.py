@@ -27,6 +27,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from fedml_tpu import obs
 from fedml_tpu.obs import programs as obs_programs
+from fedml_tpu.obs import scopes
 from fedml_tpu.algorithms.fedavg import FedAvgEngine
 from fedml_tpu.algorithms.fedopt import make_server_optimizer
 from fedml_tpu.core import robust as robust_ops
@@ -222,7 +223,6 @@ def chunked_weighted_train(trainer, variables, cohort, weights, rngs,
     sizes.
     """
     from fedml_tpu.ops.aggregate import flatten_stacked_tree
-    cohort, weights, rngs = pad_and_chunk(cohort, weights, rngs, chunk_cap)
     global_params = variables["params"] if trainer.prox_mu > 0 else None
 
     def one(shard, crng):
@@ -236,27 +236,36 @@ def chunked_weighted_train(trainer, variables, cohort, weights, rngs,
         if restore_x is not None:      # flat_stack: image shape back,
             cs = restore_x(cs)         # O(chunk) per trip
         vs, losses = jax.vmap(one)(cs, cr)
-        if client_transform is not None:
-            vs = jax.vmap(client_transform,
-                          in_axes=(0, 0, None))(vs, cw, variables)
-        # Σ w·v per leaf, folded into the ONE-vector f32 carry: a pytree
-        # carry gets per-leaf relayout copies every scan trip (the
-        # round-2b copy category — see flatten_carry_f32)
-        num_flat = num_flat + flatten_carry_f32(
-            weighted_sum_tree(cw, vs))[0]
-        ys = (flatten_stacked_tree(vs["params"])[0]
-              if emit_flat_params else None)
-        return (num_flat, den + jnp.sum(cw),
-                lsum + jnp.sum(losses * cw)), ys
+        with jax.named_scope(scopes.FED_AGGREGATE):
+            if client_transform is not None:
+                vs = jax.vmap(client_transform,
+                              in_axes=(0, 0, None))(vs, cw, variables)
+            # Σ w·v per leaf, folded into the ONE-vector f32 carry: a
+            # pytree carry gets per-leaf relayout copies every scan trip
+            # (the round-2b copy category — see flatten_carry_f32)
+            num_flat = num_flat + flatten_carry_f32(
+                weighted_sum_tree(cw, vs))[0]
+            ys = (flatten_stacked_tree(vs["params"])[0]
+                  if emit_flat_params else None)
+            return (num_flat, den + jnp.sum(cw),
+                    lsum + jnp.sum(losses * cw)), ys
 
-    zeros = jax.tree.map(lambda a: jnp.zeros(a.shape, jnp.float32),
-                         variables)
-    zeros_flat, num_spec = flatten_carry_f32(zeros)
-    zeros_flat = pvary_tree(zeros_flat, vary_axes)
-    zf = pvary_tree(jnp.float32(0), vary_axes)
-    (num_flat, den, lsum), flats = jax.lax.scan(
-        chunk_body, (zeros_flat, zf, zf), (cohort, weights, rngs))
-    num = unflatten_carry_f32(num_flat, num_spec)
+    with jax.named_scope(scopes.FED_AGGREGATE):
+        zeros = jax.tree.map(lambda a: jnp.zeros(a.shape, jnp.float32),
+                             variables)
+        zeros_flat, num_spec = flatten_carry_f32(zeros)
+        zeros_flat = pvary_tree(zeros_flat, vary_axes)
+        zf = pvary_tree(jnp.float32(0), vary_axes)
+    # fed_local_train spans the chunk scan with its plumbing (chunking,
+    # the while itself, the flat_stack restore, the per-client training);
+    # the aggregation fold inside the body belongs to its own, inner scope
+    with jax.named_scope(scopes.FED_LOCAL_TRAIN):
+        cohort, weights, rngs = pad_and_chunk(cohort, weights, rngs,
+                                              chunk_cap)
+        (num_flat, den, lsum), flats = jax.lax.scan(
+            chunk_body, (zeros_flat, zf, zf), (cohort, weights, rngs))
+    with jax.named_scope(scopes.FED_AGGREGATE):
+        num = unflatten_carry_f32(num_flat, num_spec)
     if emit_flat_params:
         return num, den, lsum, flats
     return num, den, lsum
@@ -633,15 +642,17 @@ class MeshFedAvgEngine(FedAvgEngine):
         axes = self.mesh.axis_names
         # the global model arrives replicated; per-client training makes
         # it shard-varying, so cast up-front for the vma type system
-        variables = pvary_tree(variables, axes)
-        local_vars = cast_local(variables, self.local_dtype)
+        with jax.named_scope(scopes.FED_LOCAL_TRAIN):
+            variables = pvary_tree(variables, axes)
+            local_vars = cast_local(variables, self.local_dtype)
         num, den, lsum = chunked_weighted_train(
             self.trainer, local_vars, cohort, weights, client_rngs,
             self.cfg.epochs, vary_axes=axes, chunk_cap=self.chunk,
             client_transform=self.client_transform,
             restore_x=self._restore_chunk_x)
-        return (jax.lax.psum(num, axes), jax.lax.psum(den, axes),
-                jax.lax.psum(lsum, axes))
+        with jax.named_scope(scopes.FED_AGGREGATE):
+            return (jax.lax.psum(num, axes), jax.lax.psum(den, axes),
+                    jax.lax.psum(lsum, axes))
 
     def _zero_sums(self, variables):
         """Zero accumulators matching _shard_sums' output structure (the
@@ -662,9 +673,9 @@ class MeshFedAvgEngine(FedAvgEngine):
     def _shard_body(self, variables, cohort, weights, client_rngs):
         """Whole-cohort round body: the two-collective FedAvg aggregation
         (SURVEY.md §5) — sums then the weighted mean."""
-        return self._finalize_from_sums(
-            variables,
-            self._shard_sums(variables, cohort, weights, client_rngs))
+        sums = self._shard_sums(variables, cohort, weights, client_rngs)
+        with jax.named_scope(scopes.FED_AGGREGATE):
+            return self._finalize_from_sums(variables, sums)
 
     def _train_and_update(self, variables, server_state, cohort, weights,
                           rng):
@@ -682,18 +693,20 @@ class MeshFedAvgEngine(FedAvgEngine):
             self._shard_body, mesh=mesh,
             in_specs=(P(), cohort_specs, csh, csh), out_specs=(P(), P()))(
                 variables, cohort, weights, client_rngs)
-        new_variables, server_state = self.server_update(
-            avg, variables, server_state, agg_rng)
+        with jax.named_scope(scopes.FED_SERVER_UPDATE):
+            new_variables, server_state = self.server_update(
+                avg, variables, server_state, agg_rng)
         return new_variables, server_state, {"train_loss": train_loss}
 
     def _mesh_round(self, variables, server_state, stack, stack_w, ids,
                     wmask, rng):
         # cohort gather: device-side take along the sharded client axis; XLA
         # lowers the cross-shard gather to ICI collectives.
-        cohort = {k: jax.lax.with_sharding_constraint(
-            jnp.take(v, ids, axis=0), stack_leaf_sharding(self.mesh, v))
-            for k, v in stack.items()}
-        weights = jnp.take(stack_w, ids) * wmask
+        with jax.named_scope(scopes.FED_TAKE):
+            cohort = {k: jax.lax.with_sharding_constraint(
+                jnp.take(v, ids, axis=0), stack_leaf_sharding(self.mesh, v))
+                for k, v in stack.items()}
+            weights = jnp.take(stack_w, ids) * wmask
         return self._train_and_update(variables, server_state, cohort,
                                       weights, rng)
 
@@ -771,10 +784,11 @@ class MeshFedAvgEngine(FedAvgEngine):
         device; the block cohort is a device-side take by LOCAL index.
         Gather values are bitwise the host-gather's, so both residency
         modes feed the identical partial math."""
-        cohort = {k: jax.lax.with_sharding_constraint(
-            jnp.take(v, ids, axis=0), stack_leaf_sharding(self.mesh, v))
-            for k, v in stack.items()}
-        weights = jnp.take(stack_w, ids) * wmask
+        with jax.named_scope(scopes.FED_TAKE):
+            cohort = {k: jax.lax.with_sharding_constraint(
+                jnp.take(v, ids, axis=0), stack_leaf_sharding(self.mesh, v))
+                for k, v in stack.items()}
+            weights = jnp.take(stack_w, ids) * wmask
         return self._twolevel_partial_body(variables, cohort, weights,
                                            rngs)
 
@@ -785,45 +799,61 @@ class MeshFedAvgEngine(FedAvgEngine):
         server update — run identically on every host (audited as the
         `twolevel_commit` hlo family: 0 copy ops, donation
         complete)."""
-        sums = unflatten_carry_f32(flat_sums, self._zero_sums(variables))
-        avg, loss = self._finalize_from_sums(variables, sums)
-        new_variables, server_state = self.server_update(
-            avg, variables, server_state, agg_rng)
+        with jax.named_scope(scopes.FED_AGGREGATE):
+            sums = unflatten_carry_f32(flat_sums,
+                                       self._zero_sums(variables))
+            avg, loss = self._finalize_from_sums(variables, sums)
+        with jax.named_scope(scopes.FED_SERVER_UPDATE):
+            new_variables, server_state = self.server_update(
+                avg, variables, server_state, agg_rng)
         return new_variables, server_state, {"train_loss": loss}
 
-    def _host_gather_upload(self, ids) -> dict:
+    @staticmethod
+    def _round_attr(round_idx) -> dict:
+        """The `round` identifier of an upload's spans (none where the
+        caller gathers outside a round: bench.py, the tools)."""
+        return {} if round_idx is None else {"round": int(round_idx)}
+
+    def _host_gather_upload(self, ids, round_idx=None) -> dict:
         """THE host-gather upload pipeline (shared by stream_cohort and
         _upload_block so the two streaming granularities can never
         diverge): slice the host arrays (the uint8 view when the stack
         is quantized — compressed bytes are what cross H2D), apply
         stack_dtype/flat_stack (_cast_stack_x), async device_put with
         per-leaf sharding.  Every byte handed to device_put lands in
-        the engine_h2d_bytes_total accounting."""
-        host = self._cast_stack_x(
-            {k: np.take(np.asarray(v), ids, axis=0)
-             for k, v in self._host_shards().items()})
+        the engine_h2d_bytes_total accounting.  The h2d.put span is the
+        host's enqueue and staging, not the transfer."""
+        span_attrs = self._round_attr(round_idx)
+        with obs.span(scopes.SPAN_GATHER, **span_attrs):
+            host = self._cast_stack_x(
+                {k: np.take(np.asarray(v), ids, axis=0)
+                 for k, v in self._host_shards().items()})
         self.transfer_stats.add_h2d_bytes(
             sum(v.nbytes for v in host.values()))
-        return {k: jax.device_put(v, stack_leaf_sharding(self.mesh, v))
-                for k, v in host.items()}
+        with obs.span(scopes.SPAN_PUT, **span_attrs):
+            return {k: jax.device_put(v, stack_leaf_sharding(self.mesh, v))
+                    for k, v in host.items()}
 
     def stream_cohort(self, round_idx: int):
         """Host-side cohort gather for the streaming path: the same padded
         sampling as the resident path, but slicing the HOST arrays and
         uploading only the cohort (chunk-multiple padding happens inside
         chunked_weighted_train)."""
-        return self._stream_gather(*self._sample_padded_np(round_idx))
+        return self._stream_gather(*self._sample_padded_np(round_idx),
+                                   round_idx)
 
-    def _stream_gather(self, ids, wmask):
+    def _stream_gather(self, ids, wmask, round_idx=None):
         """The upload half of stream_cohort, split from the sampling:
         this part is what runs on the prefetch thread (_round_args) —
         the SAMPLER must stay on the caller thread because it reseeds
         the process-global numpy RNG (core/sampling.py), which a
         background thread would race.  The wall lands in transfer_stats
-        from whichever thread runs it."""
-        with obs.span("h2d.upload_cohort", clients=len(ids)), \
+        from whichever thread runs it; `round_idx` (the round the cohort
+        is FOR) rides its spans."""
+        with obs.span("h2d.upload_cohort", clients=len(ids),
+                      **self._round_attr(round_idx)), \
                 self.transfer_stats.uploading():
-            cohort = self._host_gather_upload(ids)
+            cohort = self._host_gather_upload(ids, round_idx)
             w = np.take(np.asarray(self.data.client_num_samples,
                                    np.float32), ids) * wmask
             self.transfer_stats.add_h2d_bytes(w.nbytes)
@@ -841,15 +871,18 @@ class MeshFedAvgEngine(FedAvgEngine):
             self._shard_sums, mesh=self.mesh,
             in_specs=(P(), specs, csh, csh), out_specs=P())(
                 variables, block, weights, rngs)
-        return jax.tree.map(lambda a, b: a + b, sums, bsums)
+        with jax.named_scope(scopes.FED_AGGREGATE):
+            return jax.tree.map(lambda a, b: a + b, sums, bsums)
 
     def _block_finalize_impl(self, variables, server_state, sums, agg_rng):
-        avg, loss = self._finalize_from_sums(variables, sums)
-        new_variables, server_state = self.server_update(
-            avg, variables, server_state, agg_rng)
+        with jax.named_scope(scopes.FED_AGGREGATE):
+            avg, loss = self._finalize_from_sums(variables, sums)
+        with jax.named_scope(scopes.FED_SERVER_UPDATE):
+            new_variables, server_state = self.server_update(
+                avg, variables, server_state, agg_rng)
         return new_variables, server_state, {"train_loss": loss}
 
-    def _upload_block(self, ids_blk, w_blk, rngs_blk):
+    def _upload_block(self, ids_blk, w_blk, rngs_blk, round_idx=None):
         """Host-gather + async device_put of one client block (the
         double-buffer unit), via the shared _host_gather_upload pipeline.
         Runs on the prefetch thread when the pipeline is on; the wall
@@ -857,9 +890,10 @@ class MeshFedAvgEngine(FedAvgEngine):
         whichever thread uploads, so on the pipelined path it lands on
         the worker's trace row, interleaved with the round loop's
         block_step spans — the overlap is visible directly."""
-        with obs.span("h2d.upload_block", clients=len(ids_blk)), \
+        with obs.span("h2d.upload_block", clients=len(ids_blk),
+                      **self._round_attr(round_idx)), \
                 self.transfer_stats.uploading():
-            block = self._host_gather_upload(ids_blk)
+            block = self._host_gather_upload(ids_blk, round_idx)
             self.transfer_stats.add_h2d_bytes(
                 np.asarray(w_blk).nbytes + np.asarray(rngs_blk).nbytes)
             weights = jax.device_put(w_blk, client_sharding(self.mesh))
@@ -878,7 +912,7 @@ class MeshFedAvgEngine(FedAvgEngine):
         spans = [(s, s + B) for s in range(0, len(ids), B)]
         return ids, wmask, spans
 
-    def _block_fetcher(self, ids, w_all, crngs, spans):
+    def _block_fetcher(self, ids, w_all, crngs, spans, round_idx=None):
         """Block iterator for the streamed rounds: the background
         double-buffered upload pipeline (prefetch.py), or the strictly
         synchronous inline path under prefetch=False (--no_prefetch).
@@ -887,10 +921,12 @@ class MeshFedAvgEngine(FedAvgEngine):
         undelivered buffers."""
         def produce(span):
             s, e = span
-            return self._upload_block(ids[s:e], w_all[s:e], crngs[s:e])
+            return self._upload_block(ids[s:e], w_all[s:e], crngs[s:e],
+                                      round_idx)
 
         cls = Prefetcher if self.prefetch else InlineFetcher
-        return cls(produce, spans, stats=self.transfer_stats)
+        return cls(produce, spans, stats=self.transfer_stats,
+                   wait_attrs=self._round_attr(round_idx))
 
     def _round_blockstream(self, variables, server_state, round_idx, rng):
         """Block-streamed round: `stream_block`-client blocks cross
@@ -927,7 +963,8 @@ class MeshFedAvgEngine(FedAvgEngine):
                           clients=len(ids), blocks=len(spans)):
                 sums = jax.device_put(self._zero_sums(variables),
                                       replicated_sharding(self.mesh))
-                with self._block_fetcher(ids, w_all, crngs, spans) as fetch:
+                with self._block_fetcher(ids, w_all, crngs, spans,
+                                         round_idx) as fetch:
                     for i, _ in enumerate(spans):
                         args = fetch.get()
                         # dispatch wall only (the jit call is async);
@@ -955,11 +992,14 @@ class MeshFedAvgEngine(FedAvgEngine):
         """Sample the round's cohort and pad to a mesh-size multiple
         (pad_ids — the one padding policy shared by the resident,
         streaming, and GAN mesh paths)."""
-        return pad_ids(self.sampler.sample(round_idx), self.n_shards)
+        with obs.span(scopes.SPAN_SAMPLE, round=int(round_idx)):
+            return pad_ids(self.sampler.sample(round_idx), self.n_shards)
 
     def sample_padded(self, round_idx: int):
         ids, wmask = self._sample_padded_np(round_idx)
-        return jnp.asarray(ids), jnp.asarray(wmask)
+        # the resident round's only per-round host→device put
+        with obs.span(scopes.SPAN_ARGS_PUT, round=int(round_idx)):
+            return jnp.asarray(ids), jnp.asarray(wmask)
 
     def _prepare_server_state(self, server_state):
         # via host: a checkpoint-restored state arrives COMMITTED to one
@@ -1037,19 +1077,20 @@ class MeshFedAvgEngine(FedAvgEngine):
                 else:
                     args = pre[1]
             else:
-                with self.transfer_stats.waiting():   # unhidden gather
-                    args = self.stream_cohort(round_idx)
+                with self.transfer_stats.waiting(round=int(round_idx)):
+                    args = self.stream_cohort(round_idx)  # unhidden gather
             limit = getattr(self, "_rounds_limit", None)
             if limit is None or round_idx + 1 < limit:
                 nxt = round_idx + 1
                 if self.prefetch:
                     nxt_ids, nxt_wmask = self._sample_padded_np(nxt)
                     self._prefetched = (
-                        nxt, AsyncValue(self._stream_gather, nxt_ids,
-                                        nxt_wmask,
-                                        stats=self.transfer_stats))
+                        nxt, AsyncValue(
+                            self._stream_gather, nxt_ids, nxt_wmask, nxt,
+                            stats=self.transfer_stats,
+                            wait_attrs={"round": nxt}))
                 else:
-                    with self.transfer_stats.waiting():
+                    with self.transfer_stats.waiting(round=nxt):
                         self._prefetched = (nxt, self.stream_cohort(nxt))
             else:
                 self._prefetched = None
@@ -1122,12 +1163,11 @@ class MeshFedNovaEngine(MeshFedAvgEngine):
         whole-cohort shard body AND the block-streamed round drive it
         through the shared _finalize_from_sums."""
         axes = self.mesh.axis_names
-        variables = pvary_tree(variables, axes)
-        local_vars = cast_local(variables, self.local_dtype)
+        with jax.named_scope(scopes.FED_LOCAL_TRAIN):
+            variables = pvary_tree(variables, axes)
+            local_vars = cast_local(variables, self.local_dtype)
         epochs = self.cfg.epochs
         trainer = self.trainer
-        ch_cohort, ch_w, ch_r = pad_and_chunk(
-            cohort, weights, client_rngs, self.chunk)
 
         from fedml_tpu.algorithms.fednova import fednova_tau
 
@@ -1143,38 +1183,48 @@ class MeshFedNovaEngine(MeshFedAvgEngine):
             cs, cw, cr = xs
             cs = self._restore_chunk_x(cs)      # flat_stack (engine.py)
             vs, losses, taus = jax.vmap(one)(cs, cr)
-            v_params, v_rest = self._split(vs)
-            # params: Σ w·(g − v)/τ  (zero-weight pad lanes contribute 0)
-            # — folded into flat f32 carries like chunked_weighted_train
-            # (flatten_carry_f32: one 1-D buffer per carry, no per-leaf
-            # relayout copies across scan trips)
-            coef = cw / jnp.maximum(taus, 1.0)
-            d_chunk = jax.tree.map(
-                lambda g, v: jnp.einsum(
-                    "k,k...->...", coef,
-                    g[None].astype(jnp.float32) - v.astype(jnp.float32)),
-                g_params, v_params)
-            dflat = dflat + flatten_carry_f32(d_chunk)[0]
-            # stats collections: plain weighted mean, like FedAvg
-            rflat = rflat + flatten_carry_f32(
-                weighted_sum_tree(cw, v_rest))[0]
-            return (dflat, rflat, den + jnp.sum(cw),
-                    tsum + jnp.sum(cw * taus),
-                    lsum + jnp.sum(losses * cw)), None
+            with jax.named_scope(scopes.FED_AGGREGATE):
+                v_params, v_rest = self._split(vs)
+                # params: Σ w·(g − v)/τ  (zero-weight pad lanes contribute
+                # 0) — folded into flat f32 carries like
+                # chunked_weighted_train (flatten_carry_f32: one 1-D
+                # buffer per carry, no per-leaf relayout copies across
+                # scan trips)
+                coef = cw / jnp.maximum(taus, 1.0)
+                d_chunk = jax.tree.map(
+                    lambda g, v: jnp.einsum(
+                        "k,k...->...", coef,
+                        g[None].astype(jnp.float32)
+                        - v.astype(jnp.float32)),
+                    g_params, v_params)
+                dflat = dflat + flatten_carry_f32(d_chunk)[0]
+                # stats collections: plain weighted mean, like FedAvg
+                rflat = rflat + flatten_carry_f32(
+                    weighted_sum_tree(cw, v_rest))[0]
+                return (dflat, rflat, den + jnp.sum(cw),
+                        tsum + jnp.sum(cw * taus),
+                        lsum + jnp.sum(losses * cw)), None
 
-        zp, zr = self._split(jax.tree.map(
-            lambda a: jnp.zeros(a.shape, jnp.float32), variables))
-        zpf, d_spec = flatten_carry_f32(zp)
-        zrf, r_spec = flatten_carry_f32(zr)
-        zpf, zrf = pvary_tree(zpf, axes), pvary_tree(zrf, axes)
-        zf = pvary_tree(jnp.float32(0), axes)
-        (dflat, rflat, den, tsum, lsum), _ = jax.lax.scan(
-            chunk_body, (zpf, zrf, zf, zf, zf), (ch_cohort, ch_w, ch_r))
-        dsum = unflatten_carry_f32(dflat, d_spec)
-        rest_num = unflatten_carry_f32(rflat, r_spec)
-        return (jax.lax.psum(dsum, axes), jax.lax.psum(rest_num, axes),
-                jax.lax.psum(den, axes), jax.lax.psum(tsum, axes),
-                jax.lax.psum(lsum, axes))
+        with jax.named_scope(scopes.FED_AGGREGATE):
+            zp, zr = self._split(jax.tree.map(
+                lambda a: jnp.zeros(a.shape, jnp.float32), variables))
+            zpf, d_spec = flatten_carry_f32(zp)
+            zrf, r_spec = flatten_carry_f32(zr)
+            zpf, zrf = pvary_tree(zpf, axes), pvary_tree(zrf, axes)
+            zf = pvary_tree(jnp.float32(0), axes)
+        # scopes as in chunked_weighted_train: fed_local_train spans the
+        # chunk scan, the fold inside the body is fed_aggregate's
+        with jax.named_scope(scopes.FED_LOCAL_TRAIN):
+            ch_cohort, ch_w, ch_r = pad_and_chunk(
+                cohort, weights, client_rngs, self.chunk)
+            (dflat, rflat, den, tsum, lsum), _ = jax.lax.scan(
+                chunk_body, (zpf, zrf, zf, zf, zf), (ch_cohort, ch_w, ch_r))
+        with jax.named_scope(scopes.FED_AGGREGATE):
+            dsum = unflatten_carry_f32(dflat, d_spec)
+            rest_num = unflatten_carry_f32(rflat, r_spec)
+            return (jax.lax.psum(dsum, axes), jax.lax.psum(rest_num, axes),
+                    jax.lax.psum(den, axes), jax.lax.psum(tsum, axes),
+                    jax.lax.psum(lsum, axes))
 
     def _zero_sums(self, variables):
         zp, zr = self._split(jax.tree.map(

@@ -65,13 +65,15 @@ class Prefetcher:
 
     def __init__(self, produce: Callable[[Any], Any], items: Sequence,
                  depth: int = 2, stats: Optional[TransferOverlapStats] = None,
-                 name: str = "h2d-prefetch"):
+                 name: str = "h2d-prefetch",
+                 wait_attrs: Optional[dict] = None):
         if depth < 2:
             raise ValueError(f"depth must be >= 2 (double buffer), got "
                              f"{depth}")
         self._produce = produce
         self._items = list(items)
         self._stats = stats
+        self._wait_attrs = wait_attrs or {}     # ride the h2d.wait spans
         self._q: queue.Queue = queue.Queue()
         # permits = how far the producer may run ahead of the consumer;
         # acquired before each produce, released on each get
@@ -104,8 +106,8 @@ class Prefetcher:
     def get(self):
         """Next result, blocking until the worker has produced it (the
         block recorded as wait_wall in `stats`)."""
-        wait = (self._stats.waiting() if self._stats is not None
-                else contextlib.nullcontext())
+        wait = (self._stats.waiting(**self._wait_attrs)
+                if self._stats is not None else contextlib.nullcontext())
         with wait:
             while True:
                 try:
@@ -167,15 +169,17 @@ class InlineFetcher:
 
     def __init__(self, produce: Callable[[Any], Any], items: Sequence,
                  depth: int = 2, stats: Optional[TransferOverlapStats] = None,
-                 name: str = "h2d-inline"):
+                 name: str = "h2d-inline",
+                 wait_attrs: Optional[dict] = None):
         self._produce = produce
         self._it = iter(list(items))
         self._stats = stats
+        self._wait_attrs = wait_attrs or {}
 
     def get(self):
         item = next(self._it)
-        wait = (self._stats.waiting() if self._stats is not None
-                else contextlib.nullcontext())
+        wait = (self._stats.waiting(**self._wait_attrs)
+                if self._stats is not None else contextlib.nullcontext())
         with wait:
             return self._produce(item)
 
@@ -197,10 +201,12 @@ class AsyncValue:
 
     def __init__(self, fn: Callable, *args,
                  stats: Optional[TransferOverlapStats] = None,
-                 name: str = "h2d-prefetch-round"):
+                 name: str = "h2d-prefetch-round",
+                 wait_attrs: Optional[dict] = None):
         self._out = None
         self._err: Optional[BaseException] = None
         self._stats = stats
+        self._wait_attrs = wait_attrs or {}
 
         def work():
             try:
@@ -213,7 +219,7 @@ class AsyncValue:
 
     def result(self):
         if self._thread.is_alive() and self._stats is not None:
-            with self._stats.waiting():
+            with self._stats.waiting(**self._wait_attrs):
                 self._thread.join()
         else:
             self._thread.join()

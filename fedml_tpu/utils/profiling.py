@@ -18,6 +18,7 @@ from typing import Iterator, Optional
 import jax
 
 from fedml_tpu import obs
+from fedml_tpu.obs import scopes
 
 
 @contextlib.contextmanager
@@ -75,11 +76,15 @@ class TransferOverlapStats:
 
     Producers — whichever thread runs the host gather + cast +
     `jax.device_put` — time each upload with `uploading()`; the round
-    loop times its blocking prefetch waits with `waiting()` and
-    brackets each round with `round_start()`/`round_end()`.  Per round
-    (and cumulatively since `reset()`):
+    loop times its blocking prefetch waits with `waiting()` (which also
+    opens the `h2d.wait` program span) and brackets each round with
+    `round_start()`/`round_end()`.  Per round (and cumulatively since
+    `reset()`):
 
-        upload_wall_s     Σ wall of upload calls, any thread
+        upload_wall_s     Σ wall of upload calls, any thread: HOST time
+                          of gather + cast + the device_put ENQUEUE (the
+                          put is asynchronous) — not transfer time; the
+                          h2d.gather / h2d.put spans split it
         wait_wall_s       wall the round loop spent blocked on uploads
         round_wall_s      wall of the whole round
         compute_wall_s    round_wall_s − wait_wall_s (dispatch + device)
@@ -163,10 +168,11 @@ class TransferOverlapStats:
             self._h_upload.observe(dt)
 
     @contextlib.contextmanager
-    def waiting(self) -> Iterator[None]:
+    def waiting(self, **span_attrs) -> Iterator[None]:
         t0 = time.perf_counter()
         try:
-            yield
+            with obs.span(scopes.SPAN_WAIT, **span_attrs):
+                yield
         finally:
             dt = time.perf_counter() - t0
             with self._lock:

@@ -176,13 +176,16 @@ def test_tracer_ring_bound_counts_drops():
     assert tr.events()[-1]["name"] == "s24"            # newest retained
 
 
-def test_span_disabled_is_noop_singleton(clean_obs):
-    s1, s2 = obs.span("a", x=1), obs.span("b")
-    assert s1 is s2                       # shared stateless no-op
+def test_span_disabled_records_nothing(clean_obs):
+    """With no obs.configure() and no profiler session a span is only a
+    profiler annotation that nobody listens to: no tracer exists, nothing
+    is buffered, any attribute value is accepted."""
+    s1, s2 = obs.span("a", x=1, what={"k": None}), obs.span("b")
     with s1:
         with s2:
             pass
     assert obs.tracer() is None and not obs.enabled()
+    assert obs.rollup()["spans_recorded"] == 0
 
 
 # -- flight recorder ---------------------------------------------------------
@@ -262,9 +265,10 @@ def _assert_trees_bitwise(a, b):
 
 
 def test_blockstream_bitwise_obs_on_vs_off(clean_obs, tmp_path):
-    """Acceptance pin: the block-stream round under --obs_dir produces
-    BITWISE the variables of the obs-disabled run (spans/counters are
-    pure host bookkeeping), and the enabled run exports a loadable
+    """Acceptance pin: the block-stream round under --obs_dir, and under
+    a profiler session, produces BITWISE the variables of the run with
+    neither (spans/counters are pure host bookkeeping), the profiler's
+    trace holds the program spans, and the enabled run exports a loadable
     Chrome trace whose upload spans sit on the prefetch worker's tid,
     plus a Prometheus snapshot carrying the engine walls."""
     from fedml_tpu.parallel import MeshFedAvgEngine
@@ -275,6 +279,35 @@ def test_blockstream_bitwise_obs_on_vs_off(clean_obs, tmp_path):
                            donate=False, stream_block=8)
     v0 = ref.init_variables()
     v_off = ref.run(variables=jax.tree.map(jnp.copy, v0), rounds=2)
+
+    # a profiler session alone (no obs.configure()): the same spans land in
+    # /host:CPU of the profiler's trace, each with the round it belongs to,
+    # the uploads on the prefetch worker's line — and the bits do not move
+    prof = MeshFedAvgEngine(trainer, data, cfg, mesh=make_mesh(8),
+                            donate=False, stream_block=8)
+    jax.profiler.start_trace(str(tmp_path / "prof"))
+    try:
+        v_prof = jax.block_until_ready(
+            prof.run(variables=jax.tree.map(jnp.copy, v0), rounds=2))
+    finally:
+        jax.profiler.stop_trace()
+    _assert_trees_bitwise(v_off, v_prof)
+    assert obs.tracer() is None
+    xplane = glob.glob(str(tmp_path / "prof" / "**" / "*.xplane.pb"),
+                       recursive=True)[0]
+    host = next(p for p in jax.profiler.ProfileData.from_file(xplane).planes
+                if p.name == "/host:CPU")
+    seen = {}
+    for line in host.lines:
+        for e in line.events:
+            if e.name.startswith(("h2d.", "round.")):
+                seen.setdefault(e.name, []).append(dict(e.stats))
+    assert {"round.blockstream", "round.block_step", "round.sample",
+            "h2d.upload_block", "h2d.gather", "h2d.put",
+            "h2d.wait"} <= set(seen)
+    for name in ("h2d.upload_block", "h2d.gather", "h2d.put", "h2d.wait",
+                 "round.sample", "round.blockstream"):
+        assert {st["round"] for st in seen[name]} == {0, 1}, name
 
     obs.configure(str(tmp_path), install_signal=False)
     eng = MeshFedAvgEngine(trainer, data, cfg, mesh=make_mesh(8),
@@ -295,10 +328,10 @@ def test_blockstream_bitwise_obs_on_vs_off(clean_obs, tmp_path):
     prom = open(paths["prometheus"]).read()
     assert "engine_round_wall_seconds_count" in prom
     assert "engine_upload_wall_seconds_total" in prom
-    # metrics are always-on: BOTH runs' rounds landed in the registry
+    # metrics are always-on: ALL THREE runs' rounds landed in the registry
     line = next(ln for ln in prom.splitlines()
                 if ln.startswith("engine_rounds_total"))
-    assert float(line.split()[-1]) == 4.0, line
+    assert float(line.split()[-1]) == 6.0, line
 
 
 def test_messaging_comm_counters_per_backend(clean_obs, tmp_path):

@@ -486,7 +486,7 @@ def test_bench_json_schema_v11_carries_slo_and_programs_blocks():
     prog = open(os.path.join(os.path.dirname(__file__), "..",
                              "fedml_tpu", "obs", "programs.py")).read()
     for field in ("dispatch_wall_s", "dispatch_p95_s",
-                  "flops_per_dispatch", '"mfu"'):
+                  "flops_per_dispatch"):
         assert field in prog, (
             f"programs.report lost {field!r} — bench.py's v11 programs "
             "block reads it")
