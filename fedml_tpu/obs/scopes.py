@@ -8,7 +8,7 @@ HLO is the same but for metadata (tests/test_hlo_copy_audit.py pins the
 copy census of the touched families exactly).  ``label_of`` turns one
 ``op_name`` into the label the benchmark splits device time by:
 
-    fed_take            MeshFedAvgEngine._mesh_round     take
+    fed_take            parallel/engine.py::take_cohort  take
     fed_local_train     the chunk scan of per-client     local_other
                         training and its plumbing        (what no inner
                         (chunked_weighted_train)         scope claims)

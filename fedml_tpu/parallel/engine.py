@@ -34,7 +34,8 @@ from fedml_tpu.core import robust as robust_ops
 from fedml_tpu.core.trainer import ClientTrainer
 from fedml_tpu.data.federated import FederatedData
 from fedml_tpu.parallel.mesh import (BATCH_AXIS, client_axes,
-                                     client_sharding, make_mesh, pvary_tree,
+                                     client_shard_count, client_sharding,
+                                     make_mesh, pvary_tree,
                                      replicated_sharding, shard_stack,
                                      stack_leaf_sharding, stack_leaf_spec)
 from fedml_tpu.parallel.prefetch import (AsyncValue, InlineFetcher,
@@ -124,6 +125,44 @@ def pad_ids(ids: np.ndarray, n_shards: int):
                             np.zeros(pad, np.float32)])
     ids = np.concatenate([ids, np.zeros(pad, ids.dtype)])
     return ids, wmask
+
+
+def take_cohort(mesh: Mesh, stack: dict, stack_w, ids, wmask):
+    """THE resident cohort take (the `fed_take` scope, the benchmark's
+    `take_ms`): the cohort {x,y,mask}[K,B,bs,...] and its weights [K]
+    out of the device-resident client stack, by the padded ids of
+    `pad_ids`.  Two bodies, chosen by whether the client axis is
+    partitioned over the mesh:
+
+    one shard — a `dynamic_index_in_dim` per cohort slot (K is static),
+      stacked.  The program then reads K clients.  A gather here costs
+      a pass over the WHOLE stack on the TPU: its lowering pulls the
+      trainer's narrowing convert of the cohort above the gather, onto
+      the gather's operand, and relays the stack's layout to clients-
+      major first (PERF.md §6 d: 19 of 71 ms a round at 10 of 4,000).
+      The slices must stay unrolled here, outside every loop: from a
+      scan or a `lax.map` the compiler hoists that convert back out.
+    several shards — `jnp.take` along the sharded client axis, which XLA
+      lowers to a cross-shard gather with ICI collectives.
+
+    ids are always in range (the sampler's, padded with client 0 at
+    weight 0), so both bodies return the same values bit for bit."""
+    if client_shard_count(mesh) == 1:
+        slots = [ids[i] for i in range(ids.shape[0])]
+
+        def take(v):
+            return jnp.stack([
+                jax.lax.dynamic_index_in_dim(v, i, 0, keepdims=False)
+                for i in slots])
+    else:
+        def take(v):
+            return jnp.take(v, ids, axis=0)
+    with jax.named_scope(scopes.FED_TAKE):
+        cohort = {k: jax.lax.with_sharding_constraint(
+            take(v), stack_leaf_sharding(mesh, v))
+            for k, v in stack.items()}
+        weights = jnp.take(stack_w, ids) * wmask
+    return cohort, weights
 
 
 def pad_and_chunk(cohort, weights, rngs, chunk_cap: int):
@@ -379,8 +418,7 @@ class MeshFedAvgEngine(FedAvgEngine):
         self.client_axes = client_axes(self.mesh)
         self.batch_axes = tuple(a for a in self.mesh.axis_names
                                 if a == BATCH_AXIS)
-        self.n_shards = int(np.prod([self.mesh.shape[a]
-                                     for a in self.client_axes]))
+        self.n_shards = client_shard_count(self.mesh)
         if self.batch_axes:
             nb = self.mesh.shape[BATCH_AXIS]
             bs = int(np.shape(data.client_shards["mask"])[2])
@@ -700,13 +738,9 @@ class MeshFedAvgEngine(FedAvgEngine):
 
     def _mesh_round(self, variables, server_state, stack, stack_w, ids,
                     wmask, rng):
-        # cohort gather: device-side take along the sharded client axis; XLA
-        # lowers the cross-shard gather to ICI collectives.
-        with jax.named_scope(scopes.FED_TAKE):
-            cohort = {k: jax.lax.with_sharding_constraint(
-                jnp.take(v, ids, axis=0), stack_leaf_sharding(self.mesh, v))
-                for k, v in stack.items()}
-            weights = jnp.take(stack_w, ids) * wmask
+        # device-side cohort take: per-slot slices of the resident stack on
+        # one shard, a cross-shard gather (ICI collectives) on several
+        cohort, weights = take_cohort(self.mesh, stack, stack_w, ids, wmask)
         return self._train_and_update(variables, server_state, cohort,
                                       weights, rng)
 
@@ -784,11 +818,7 @@ class MeshFedAvgEngine(FedAvgEngine):
         device; the block cohort is a device-side take by LOCAL index.
         Gather values are bitwise the host-gather's, so both residency
         modes feed the identical partial math."""
-        with jax.named_scope(scopes.FED_TAKE):
-            cohort = {k: jax.lax.with_sharding_constraint(
-                jnp.take(v, ids, axis=0), stack_leaf_sharding(self.mesh, v))
-                for k, v in stack.items()}
-            weights = jnp.take(stack_w, ids) * wmask
+        cohort, weights = take_cohort(self.mesh, stack, stack_w, ids, wmask)
         return self._twolevel_partial_body(variables, cohort, weights,
                                            rngs)
 

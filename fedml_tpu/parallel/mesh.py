@@ -80,6 +80,11 @@ def client_axes(mesh: Mesh) -> tuple:
     return tuple(a for a in mesh.axis_names if a != BATCH_AXIS)
 
 
+def client_shard_count(mesh: Mesh) -> int:
+    """How many ways the client axis is partitioned over the mesh."""
+    return int(np.prod([mesh.shape[a] for a in client_axes(mesh)]))
+
+
 def client_sharding(mesh: Mesh) -> NamedSharding:
     """Shard a [K, ...] cohort/stack along its leading (client) axis over
     the client axes — on a silo×clients mesh clients split over both; a

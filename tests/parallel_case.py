@@ -2,6 +2,8 @@
 test_parallel_stream.py — split so pytest-xdist's per-file scheduling
 can run the resident-mesh and streaming/block-stream groups in
 parallel workers)."""
+import re
+
 from fedml_tpu.core.trainer import ClientTrainer
 from fedml_tpu.data.loaders import load_data
 from fedml_tpu.models import create_model
@@ -43,3 +45,16 @@ def run_donate_pair(make_engine, rounds=2):
     v_not = eng_n.run(variables=jax.tree.map(jnp.copy, v0), rounds=rounds)
     for a, b in zip(jax.tree.leaves(v_don), jax.tree.leaves(v_not)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+_HLO_INSTRUCTION = re.compile(
+    r"\s+(?:ROOT )?%?([\w.\-]+) = (\(.*?\)|\S+) ([\w\-]+)\((.*)")
+
+
+def hlo_instructions(text: str):
+    """(name, result type, opcode, the text from its operands on) of every
+    instruction of an optimized HLO module's text, fused bodies included."""
+    for line in text.splitlines():
+        m = _HLO_INSTRUCTION.match(line)
+        if m:
+            yield m.groups()

@@ -7,14 +7,18 @@ Mosaic refuses — a slice not aligned to the tiling, too much VMEM, a
 program that does not fit 16 GB of HBM — so the pallas kernels of
 `chip_smoke.py` phase (c) are compiled here at their real widths, and
 `-m slow` adds the (32,32,32,64) GroupNorm pair, the whole headline
-round program on one and on four described chips, and the documented
-C = 128 size limit of the fused robust aggregation.
+round program on one and on four described chips, the resident round of
+the benchmark's `xdev10of4000` cell (10 of 4,000 clients: the take reads
+the cohort, not the stack) and of `so_nwp_lstm` at its published 342,477
+clients, and the documented C = 128 size limit of the fused robust
+aggregation.
 
 A compile that passes is not a chip run: nothing executes, so these
 tests say nothing about results or times.  Skipped where the topology
 cannot be described.
 """
 import os
+import re
 import sys
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")   # else it logs under /tmp
@@ -202,3 +206,87 @@ def test_headline_round_compiles_for_one_chip(topo):
 def test_headline_round_compiles_for_four_chips(topo):
     compiled = _headline_round(topo, 4)
     assert "all-reduce" in compiled.as_text()
+
+
+# -- the resident round of a benchmark cell ---------------------------------
+
+def _bench_files(config: str, traffic: str):
+    sys.path.insert(0, REPO)
+    from fedbench.harness import manifest
+    return tuple(manifest.load_json(os.path.join(
+        manifest.BENCH_DIR, kind, name + ".json"))
+        for kind, name in (("configs", config), ("traffic", traffic)))
+
+
+def _resident_round(topo, config: dict, traffic: dict):
+    """The resident round (`MeshFedAvgEngine._mesh_round`) of a benchmark
+    configuration under a traffic mix (the two files' contents), lowered
+    from shape structs of the `[population, ...]` stack over a mesh of
+    described chips and compiled.  The host holds 4 clients a chip: the
+    program's population and cohort are the structs'."""
+    from fedbench.harness import build
+    from fedml_tpu.parallel.engine import pad_ids
+    from fedml_tpu.parallel.mesh import (client_sharding, make_mesh,
+                                         replicated_sharding,
+                                         stack_leaf_sharding)
+    n_dev, population = int(traffic["mesh_devices"]), int(traffic["population"])
+    host_traffic = dict(traffic, population=4 * n_dev)
+    data = build.make_data(host_traffic, seed=0)
+    engine = build.make_engine(config, host_traffic, data, seed=0)
+    mesh = engine.mesh = make_mesh(devices=topo.devices[:n_dev])
+    host = engine._cast_stack_x(dict(engine._host_shards()))
+    stack = {
+        k: jax.ShapeDtypeStruct((population,) + v.shape[1:], v.dtype,
+                                sharding=stack_leaf_sharding(mesh, v))
+        for k, v in host.items()}
+    rep, csh = replicated_sharding(mesh), client_sharding(mesh)
+    variables = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rep),
+        jax.eval_shape(engine.init_variables))
+    k = len(pad_ids(np.zeros(int(traffic["cohort"]), np.int32),
+                    engine.n_shards)[0])
+    return jax.jit(engine._mesh_round).lower(
+        variables, (), stack,
+        jax.ShapeDtypeStruct((population,), jnp.float32, sharding=csh),
+        jax.ShapeDtypeStruct((k,), jnp.int32, sharding=rep),
+        jax.ShapeDtypeStruct((k,), jnp.float32, sharding=rep),
+        jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=rep)).compile()
+
+
+@pytest.mark.slow
+def test_xdev_resident_round_reads_only_the_cohort(topo):
+    """The structural pin of the sliced take (`engine.take_cohort`), at
+    `resnet18gn.xdev10of4000`'s shapes: 10 of 4,000 resident clients.  A
+    gather here made the compiler convert and relay the whole 4.9 GB stack
+    every round (14 whole-stack instructions, 4.07 GB of temporaries,
+    19 of 71 ms on the chip: PERF.md §6 d)."""
+    from fedml_tpu.obs import programs
+    from parallel_case import hlo_instructions
+    compiled = _resident_round(
+        topo, *_bench_files("resnet18gn_cifar", "xdev10of4000"))
+    text = compiled.as_text()
+    smap = programs.scope_map_of_hlo_text(text)
+    stack_x, whole_stack, readers = [], [], []
+    for name, result, opcode, rest in hlo_instructions(text):
+        if re.search(r"\[4000,5,20,\d+\]", result):
+            (stack_x if opcode == "parameter" else whole_stack).append(name)
+        if re.match(r"%stack__(x|y|mask)__", rest):
+            readers.append(name)
+    assert stack_x and not whole_stack, (stack_x, whole_stack)
+    # what reads the stack is the take's, one fusion a leaf: `take_ms`
+    # (fedbench/harness/program_trace.py) sums it by its label
+    assert len(readers) == 3 and {smap[n] for n in readers} == {"take"}
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
+
+
+@pytest.mark.slow
+def test_solstm_published_population_fits_one_chip(topo):
+    """The `so_nwp_lstm` resident round at the PUBLISHED population
+    (342,477 clients, 7.2 GB of tokens) compiles for one chip.  With a
+    gather the compiler wanted a clients-major, 20 -> 128 lane-padded copy
+    of the whole token stack: 22.4 GB, RESOURCE_EXHAUSTED (PERF.md §6 d)."""
+    config, traffic = _bench_files("so_nwp_lstm", "xdev50of64k")
+    mem = _resident_round(
+        topo, config, dict(traffic, population=342_477)).memory_analysis()
+    assert mem.argument_size_in_bytes > 7e9
+    assert mem.temp_size_in_bytes < 0.5e9
