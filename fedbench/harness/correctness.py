@@ -3,7 +3,16 @@
 One float32 round of the cell's engine class on a seeded sample at the
 published widths (the first few clients and batches of the cell's own data,
 full participation) must match one FedAvg round of the configuration's plain
-reference, parameter for parameter.
+reference, parameter for parameter.  The reference is handed everything the
+program holds (``variables``) and, where the configuration's local training
+updates a subset of the weights, the subset's path prefixes
+(``check.trainable``; absent = every leaf of ``params``): it trains those and
+reads the rest in place, and every leaf of ``params`` is compared, so a leaf
+the configuration calls frozen must come back as it went in.
+
+The check runs before the cell's own engine and resident stack exist, and its
+engine and the round's outputs are dropped before the reference starts: what
+must fit the chip is the larger of the two, not their sum beside the cell.
 
 Tolerance: max |delta| <= ``check.param_tol`` x max |update|, written in the
 configuration's file with its reason, because the float32 noise floor is the
@@ -16,6 +25,8 @@ published widths (measured, PR 22: 1.8e-2 / 3.4e-2 for the ResNet cells,
 1.8e-3 for the LSTM).  The mean train loss must agree to 1e-4 relative.
 """
 from __future__ import annotations
+
+import gc
 
 import jax
 import numpy as np
@@ -45,21 +56,24 @@ def check_round(config: dict, traffic: dict, data, seed: int, sample: dict) -> d
         engine = build.make_engine(
             config, full, small, seed, train_dtype="float32", local_dtype=None)
         variables = engine._prepare_variables(build.init_variables(engine))
-        before = jax.tree.map(np.asarray, variables["params"])
+        held = jax.tree.map(np.asarray, variables)      # the round donates them
         new_vars, _, m = engine.round_fn(
             variables, engine.server_init(variables), *engine._round_args(0),
             jax.random.fold_in(jax.random.PRNGKey(seed + 1), 0))
         got = jax.tree.map(np.asarray, new_vars["params"])
         got_loss = float(m["train_loss"])
     loop.join_prefetch(engine)
-    ref = reference.resolve(config["reference"])
-    want, want_loss = reference.fedavg_round(ref, before, shards,
-                                             engine.cfg.lr, engine.cfg.epochs)
+    lr, epochs = engine.cfg.lr, engine.cfg.epochs
+    del engine, variables, new_vars, m
+    gc.collect()                    # the engine's device buffers, before the reference's
+    want, want_loss = reference.fedavg_round(
+        reference.resolve(config["reference"]), held, shards, lr, epochs,
+        config["check"].get("trainable"))
     leaves = lambda t: jax.tree.leaves(t)
     delta = max(float(np.max(np.abs(g - w)))
                 for g, w in zip(leaves(got), leaves(want)))
     update = max(float(np.max(np.abs(w - a)))
-                 for w, a in zip(leaves(want), leaves(before)))
+                 for w, a in zip(leaves(want), leaves(held["params"])))
     loss_err = abs(got_loss - want_loss) / max(abs(want_loss), 1e-12)
     tol = float(config["check"]["param_tol"])
     return {"ok": bool(delta <= tol * update and loss_err <= LOSS_TOL

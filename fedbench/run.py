@@ -2,8 +2,8 @@
 
     python3 -m fedbench.run --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
-One process, no children.  Set-up (data from the seed, engine, placement,
-reference check, warm-up), then the measured window, then one JSON line.
+One process, no children.  Set-up (data from the seed, reference check,
+engine, placement, warm-up), then the measured window, then one JSON line.
 Everything about a cell comes from files found by the names in
 BENCHMARK.json (fedbench/harness/manifest.py).
 """
@@ -54,6 +54,11 @@ def main(argv=None) -> int:
     t = lap("device_s", t)                  # the runtime's first program
     data = build.make_data(cell.traffic, args.seed)
     t = lap("data_s", t)
+    # before the cell's own state is placed: the check engine and the
+    # reference then have the chip to themselves, one after the other
+    check = correctness.check_round(cell.config, cell.traffic, data, args.seed,
+                                    knobs["check"])
+    t = lap("check_s", t)
     engine = build.make_engine(cell.config, cell.traffic, data, args.seed)
     t = lap("build_s", t)
     state = loop.State(engine, build.init_variables(engine), args.seed)
@@ -64,9 +69,6 @@ def main(argv=None) -> int:
         # would otherwise hide inside the first warm-up round
         jax.block_until_ready(engine._device_stack())
     t = lap("place_s", t)
-    check = correctness.check_round(cell.config, cell.traffic, data, args.seed,
-                                    knobs["check"])
-    t = lap("check_s", t)
     c0 = compiles.seconds
     warm = loop.run_rounds(state, knobs["in_flight_rounds"],
                            rounds=knobs["warmup_rounds"])

@@ -1,6 +1,7 @@
 """Every cell of BENCHMARK.json, and every cell held back from it, end to end
 at the tests' tiny sizes, on the CPU by explicit choice: the printed line has
-the contract's keys and, off the chip, not one device metric."""
+the contract's keys and, off the chip, not one device metric: a traced run
+prints the one count that needs no device, ``real_slot_pct``."""
 import json
 
 import pytest
@@ -26,4 +27,6 @@ def test_cell_runs_tiny_and_prints_the_contracts_line(name, chips, tmp_path):
     assert line["device"]["count"] == chips
     assert set(line["device"]) == {"platform", "kind", "count",
                                    "memory_peak_bytes"}
-    assert line["metrics"] == {}            # no device metric off the chip
+    # no device metric off the chip; the x4 tiny clients hold 6 of 2 x 4 slots
+    assert line["metrics"] == ({"real_slot_pct": {"value": 75.0, "unit": "%"}}
+                               if trace else {})

@@ -135,4 +135,4 @@ def test_a_cell_made_only_of_new_files_is_found_and_runs(tmp_path):
     # streamed path's exact byte count from the program's transfer_stats
     assert line["metrics"]["window_rounds"]["value"] == line["attempted"]
     assert line["metrics"]["h2d_MB"]["value"] > 0
-    assert set(line["metrics"]) == {"window_rounds", "h2d_MB"}
+    assert set(line["metrics"]) == {"window_rounds", "h2d_MB", "real_slot_pct"}

@@ -128,7 +128,7 @@ def test_memory_peak_is_what_the_program_holds_not_the_compilers_pool(monkeypatc
 
 def test_generators_depend_on_the_seed_only():
     from fedbench.harness import build
-    for name in ("xdev10of4000", "xdev50of64k"):
+    for name in ("xdev10of4000", "xdev50of342k"):
         traffic = tiny_doc("traffic", name)
         a, b, c = (build.make_data(traffic, s) for s in (1, 1, 2))
         for k in a.client_shards:
@@ -140,12 +140,15 @@ def test_generators_depend_on_the_seed_only():
         assert (a.client_shards["x"][m == 0] == 0).all()
 
 
-def test_reference_check_passes_in_float32_and_catches_a_bfloat16_pass(monkeypatch):
+@pytest.mark.parametrize("config_name,traffic_name", [
+    ("resnet18gn_cifar", "xdev10of4000"), ("so_nwp_lstm", "xdev50of342k")])
+def test_reference_check_passes_in_float32_and_catches_a_bfloat16_pass(
+        monkeypatch, config_name, traffic_name):
     """The tolerance in the configuration's file is tight enough that
     computing in a lower precision than float32 fails it."""
     from fedbench.harness import build, correctness
-    config = tiny_doc("configs", "resnet18gn_cifar")
-    traffic = tiny_doc("traffic", "xdev10of4000")
+    config = tiny_doc("configs", config_name)
+    traffic = tiny_doc("traffic", traffic_name)
     data = build.make_data(traffic, 4)
     sample = {"clients": 3, "batches": 2}
     good = correctness.check_round(config, traffic, data, 4, sample)
