@@ -18,6 +18,7 @@ unequal client dataset sizes become padding, not data-dependent control flow
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Any, Callable, Optional
 
@@ -284,12 +285,18 @@ class ClientTrainer:
             # the gather inside CE stays in-bounds (0*NaN would poison the
             # masked sum otherwise)
             y = jnp.where(valid, y, 0)
-        if self.loss_name == "ce":
-            loss = masked_cross_entropy(logits, y, mask)
-        elif self.loss_name == "bce":
-            loss = masked_bce(logits, y, mask)
-        else:
-            loss = masked_focal_loss(logits, y, mask)
+        # a model whose output layer has a scope of its own (a vocabulary
+        # head: obs/scopes.py) names it, and the loss on its logits is
+        # traced under that name; any other model's loss stays where it was
+        head_scope = getattr(self.model, "loss_scope", None)
+        with (jax.named_scope(head_scope) if head_scope
+              else contextlib.nullcontext()):
+            if self.loss_name == "ce":
+                loss = masked_cross_entropy(logits, y, mask)
+            elif self.loss_name == "bce":
+                loss = masked_bce(logits, y, mask)
+            else:
+                loss = masked_focal_loss(logits, y, mask)
         if self.batch_axes:
             # batch-split normalization: the masked losses divide by this
             # SHARD's valid count; rescale to S_l / C_g so the psum over

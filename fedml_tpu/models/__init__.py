@@ -39,6 +39,11 @@ def create_model(model_name: str, output_dim: int, input_dim: int | None = None,
         # (models/transformer.py) — vocab from the dataset's class count
         from fedml_tpu.models.transformer import TransformerLM
         return TransformerLM(vocab_size=output_dim, **kw)
+    if name == "looped_lm":
+        # a decoder LM whose layer stack runs several times (Ouro's
+        # family, models/looped_lm.py); every width is a keyword
+        from fedml_tpu.models.looped_lm import LoopedDecoderLM
+        return LoopedDecoderLM(vocab_size=output_dim, **kw)
     if name in ("resnet18_gn", "resnet18"):
         return ResNet18GN(num_classes=output_dim, **kw)
     if name == "resnet56":

@@ -18,9 +18,20 @@ copy census of the touched families exactly).  ``label_of`` turns one
     fed_optimizer       ClientTrainer.train_step         optimizer
     fed_aggregate       Σ w·v fold, psums, weighted mean aggregate
     fed_server_update   engine.server_update             server_update
+    fed_attention       a transformer block's attention  attention
+                        (norms, projections, rotary,
+                        scores, softmax, output)
+    fed_mlp             its gated MLP with both norms    mlp
+    fed_lm_head         the vocabulary projection and    lm_head
+                        the loss on its logits
 
 An op under several scopes belongs to the innermost one (a forward op
 is inside fed_local_train too); an op under none is ``unscoped``.
+The last three sit inside fed_forward and claim their ops forward,
+backward and rematerialised alike, so in a model that has them
+``forward`` / ``backward`` read what lies outside them (embedding,
+residual stream between blocks, the final norm); a model without them
+reads as before.
 
 **Spans** are ``obs.span`` names: host intervals that land in the
 ``SpanTracer`` when ``obs.configure()`` ran and, always, in the
@@ -37,6 +48,9 @@ FED_FORWARD = "fed_forward"
 FED_OPTIMIZER = "fed_optimizer"
 FED_AGGREGATE = "fed_aggregate"
 FED_SERVER_UPDATE = "fed_server_update"
+FED_ATTENTION = "fed_attention"
+FED_MLP = "fed_mlp"
+FED_LM_HEAD = "fed_lm_head"
 
 UNSCOPED = "unscoped"
 BACKWARD = "backward"
@@ -47,6 +61,9 @@ LABEL_OF_SCOPE = {
     FED_OPTIMIZER: "optimizer",
     FED_AGGREGATE: "aggregate",
     FED_SERVER_UPDATE: "server_update",
+    FED_ATTENTION: "attention",
+    FED_MLP: "mlp",
+    FED_LM_HEAD: "lm_head",
 }
 LABELS = tuple(LABEL_OF_SCOPE.values()) + (BACKWARD, UNSCOPED)
 

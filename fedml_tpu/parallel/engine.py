@@ -58,6 +58,12 @@ def cast_local(tree, dtype):
         if jnp.issubdtype(a.dtype, jnp.floating) else a, tree)
 
 
+# leaves of at least this many elements (16 MiB in f32; the largest conv
+# kernel of a ResNet-18 is 2.4 M) accumulate outside the packed Σ w·v
+# carry of the chunked cohort loop: chunked_weighted_train says why
+BIG_CARRY_LEAF = 1 << 22
+
+
 def weighted_acc(w):
     """Accumulator step for the chunked loops: acc + Σₖ wₖ·vₖ in f32.
     One definition so every engine's accumulation (FedAvg/Nova/robust/
@@ -269,8 +275,27 @@ def chunked_weighted_train(trainer, variables, cohort, weights, rngs,
             variables, shard, crng, epochs, global_params=global_params)
         return v, loss
 
+    # The Σ w·v carry: leaves under BIG_CARRY_LEAF elements packed into
+    # ONE f32 vector (flatten_carry_f32: a pytree carry costs them a
+    # relayout copy every trip), each leaf of at least that size an
+    # accumulator of its own in the leaf's shape.  Packing a large matrix
+    # costs what it saves the small ones: a relayout of the whole leaf
+    # to one dimension every trip, then the concatenation as a second
+    # f32 copy of the tree — at 0.5 B parameters two 2 GB temporaries
+    # and five passes over them a trip, where the in-place multiply-add
+    # is one.  A model with no such leaf (every conv kernel, the LSTM)
+    # carries the one vector and compiles to the program it had.  Either
+    # way each element sees the same adds in the same order.
+    leaves, treedef = jax.tree.flatten(variables)
+    big = [int(np.prod(a.shape)) >= BIG_CARRY_LEAF for a in leaves]
+    packed_spec = [a for a, b in zip(leaves, big) if not b]
+
+    def split(tree_leaves):
+        return ([a for a, b in zip(tree_leaves, big) if not b],
+                [a for a, b in zip(tree_leaves, big) if b])
+
     def chunk_body(carry, xs):
-        num_flat, den, lsum = carry
+        (num_flat, num_big), den, lsum = carry
         cs, cw, cr = xs
         if restore_x is not None:      # flat_stack: image shape back,
             cs = restore_x(cs)         # O(chunk) per trip
@@ -279,21 +304,18 @@ def chunked_weighted_train(trainer, variables, cohort, weights, rngs,
             if client_transform is not None:
                 vs = jax.vmap(client_transform,
                               in_axes=(0, 0, None))(vs, cw, variables)
-            # Σ w·v per leaf, folded into the ONE-vector f32 carry: a
-            # pytree carry gets per-leaf relayout copies every scan trip
-            # (the round-2b copy category — see flatten_carry_f32)
-            num_flat = num_flat + flatten_carry_f32(
-                weighted_sum_tree(cw, vs))[0]
+            packed, own = split(jax.tree.leaves(weighted_sum_tree(cw, vs)))
+            num_flat = num_flat + flatten_carry_f32(packed)[0]
+            num_big = [acc + v for acc, v in zip(num_big, own)]
             ys = (flatten_stacked_tree(vs["params"])[0]
                   if emit_flat_params else None)
-            return (num_flat, den + jnp.sum(cw),
+            return ((num_flat, num_big), den + jnp.sum(cw),
                     lsum + jnp.sum(losses * cw)), ys
 
     with jax.named_scope(scopes.FED_AGGREGATE):
-        zeros = jax.tree.map(lambda a: jnp.zeros(a.shape, jnp.float32),
-                             variables)
-        zeros_flat, num_spec = flatten_carry_f32(zeros)
-        zeros_flat = pvary_tree(zeros_flat, vary_axes)
+        packed0, own0 = split([jnp.zeros(a.shape, jnp.float32)
+                               for a in leaves])
+        zeros = pvary_tree((flatten_carry_f32(packed0)[0], own0), vary_axes)
         zf = pvary_tree(jnp.float32(0), vary_axes)
     # fed_local_train spans the chunk scan with its plumbing (chunking,
     # the while itself, the flat_stack restore, the per-client training);
@@ -301,10 +323,13 @@ def chunked_weighted_train(trainer, variables, cohort, weights, rngs,
     with jax.named_scope(scopes.FED_LOCAL_TRAIN):
         cohort, weights, rngs = pad_and_chunk(cohort, weights, rngs,
                                               chunk_cap)
-        (num_flat, den, lsum), flats = jax.lax.scan(
-            chunk_body, (zeros_flat, zf, zf), (cohort, weights, rngs))
+        ((num_flat, num_big), den, lsum), flats = jax.lax.scan(
+            chunk_body, (zeros, zf, zf), (cohort, weights, rngs))
     with jax.named_scope(scopes.FED_AGGREGATE):
-        num = unflatten_carry_f32(num_flat, num_spec)
+        packed = iter(unflatten_carry_f32(num_flat, packed_spec))
+        own = iter(num_big)
+        num = jax.tree.unflatten(
+            treedef, [next(own) if b else next(packed) for b in big])
     if emit_flat_params:
         return num, den, lsum, flats
     return num, den, lsum

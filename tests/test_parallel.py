@@ -164,6 +164,31 @@ def test_prime_cohort_chunk_padding():
                                    rtol=2e-4, atol=2e-5)
 
 
+def test_large_leaves_accumulate_outside_the_packed_carry(monkeypatch):
+    """A leaf of at least BIG_CARRY_LEAF elements keeps a Σw·v accumulator
+    of its own shape in the chunk scan, the others share the packed
+    vector: each element sees the same adds in the same order wherever
+    the line is drawn (the lr model's 7,840-element kernel on one side,
+    its 10 biases on the other; both packed; both on their own), so the
+    rounds agree to the last bits — XLA contracts the multiply-add of a
+    leaf on its own differently from the concatenated one (1e-6 of a
+    value here); a model with no large leaf compiles to the program it
+    had (tests/test_hlo_copy_audit.py pins the census)."""
+    from fedml_tpu.parallel import engine as engine_mod
+    cfg = _mnist_like_cfg(client_num_per_round=6, comm_round=2)
+    trainer, data = _setup(cfg)
+    got = []
+    for line in (engine_mod.BIG_CARRY_LEAF, 1000, 1):
+        monkeypatch.setattr(engine_mod, "BIG_CARRY_LEAF", line)
+        eng = MeshFedAvgEngine(trainer, data, cfg, mesh=make_mesh(2),
+                               donate=False, chunk=2)
+        got.append(eng.run(variables=eng.init_variables(), rounds=2))
+    for other in got[1:]:
+        for a, b in zip(jax.tree.leaves(got[0]), jax.tree.leaves(other)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-5, atol=1e-8)
+
+
 def test_chunk_size_invariance():
     """The chunked cohort scan (perf: bounds live model replicas) must not
     change results vs one full-width chunk."""

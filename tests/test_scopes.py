@@ -173,6 +173,31 @@ def test_streamed_upload_carries_the_round_it_is_for():
     ("jit(r)/fed_local_train/while", "local_other"),
     ("jit(r)/fed_server_update/add", "server_update"),
     ("jit(r)/shard_map/random_split", "unscoped"),
+    # a transformer block's scopes claim forward, backward and the
+    # rematerialised forward alike (models/looped_lm.py)
+    ("jit(r)/fed_local_train/while/body/vmap(jvp(fed_forward))/LoopedDecoderLM"
+     "/while/body/checkpoint/fed_attention/bqhd,bkhd->bhqk/dot_general",
+     "attention"),
+    ("jit(r)/fed_local_train/while/body/transpose(jvp(fed_forward))/while/body"
+     "/checkpoint/fed_attention/transpose", "attention"),
+    ("jit(r)/fed_local_train/while/body/transpose(jvp(fed_forward))/while/body"
+     "/checkpoint/rematted_computation/fed_attention/exp", "attention"),
+    ("jit(r)/fed_local_train/while/body/jvp(fed_forward)/while/body/checkpoint"
+     "/fed_mlp/jit(silu)/logistic", "mlp"),
+    ("jit(r)/fed_local_train/while/body/transpose(jvp(fed_forward))/while/body"
+     "/checkpoint/rematted_computation/fed_mlp/...a,ab->...b/dot_general",
+     "mlp"),
+    ("jit(r)/fed_local_train/while/body/transpose(jvp(fed_forward))/while/body"
+     "/checkpoint/fed_mlp/mul", "mlp"),
+    ("jit(r)/fed_local_train/while/body/jvp(fed_forward)/LoopedDecoderLM"
+     "/fed_lm_head/...a,ab->...b/dot_general", "lm_head"),
+    ("jit(r)/fed_local_train/while/body/transpose(jvp(fed_forward))"
+     "/fed_lm_head/reduce_max", "lm_head"),
+    # outside the three, the model's ops stay forward / backward
+    ("jit(r)/fed_local_train/while/body/jvp(fed_forward)/LoopedDecoderLM"
+     "/while/body/rsqrt", "forward"),
+    ("jit(r)/fed_local_train/while/body/transpose(jvp(fed_forward))"
+     "/LoopedDecoderLM/while/body/mul", "backward"),
 ])
 def test_label_is_the_innermost_scope(op_name, label):
     assert scopes.label_of(op_name) == label

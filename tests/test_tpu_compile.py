@@ -9,9 +9,10 @@ program that does not fit 16 GB of HBM — so the pallas kernels of
 `-m slow` adds the (32,32,32,64) GroupNorm pair, the whole headline
 round program on one and on four described chips, the resident round of
 the benchmark's `xdev10of4000` cell (10 of 4,000 clients: the take reads
-the cohort, not the stack) and of `so_nwp_lstm` at its published 342,477
-clients, and the documented C = 128 size limit of the fused robust
-aggregation.
+the cohort, not the stack), of `so_nwp_lstm` at its published 342,477
+clients and of `ouro_2p6b` (0.51 B parameters, with the float32 twin the
+reference check runs), and the documented C = 128 size limit of the
+fused robust aggregation.
 
 A compile that passes is not a chip run: nothing executes, so these
 tests say nothing about results or times.  Skipped where the topology
@@ -290,3 +291,39 @@ def test_solstm_published_population_fits_one_chip(topo):
         topo, config, dict(traffic, population=342_477)).memory_analysis()
     assert mem.argument_size_in_bytes > 7e9
     assert mem.temp_size_in_bytes < 0.5e9
+
+
+@pytest.mark.slow
+def test_ouro_round_and_its_float32_twin_fit_one_chip(topo, monkeypatch):
+    """`ouro2p6b.silo4of256t1024`'s resident round (0.51 B parameters trained
+    whole, bf16 compute on float32 local masters, chunk 1) and the float32
+    twin that the reference check runs (4 clients, full participation,
+    precision "highest") compile for one chip; the round's output may take the donated
+    arguments' place, so arguments + temporaries are what must fit.  The
+    Σw·v carry of a matrix this size is an accumulator in the matrix's own
+    shape: the bf16 round holds no second float32 copy of the whole tree (a
+    packed carry made two, the zeros vector and the concatenated update:
+    PERF.md §6 PR 26)."""
+    from fedbench.harness import build
+    from parallel_case import hlo_instructions
+    config, traffic = _bench_files("ouro_2p6b", "silo4of256t1024")
+    n_params = config["widths"]["parameters_at_6_layers"]
+
+    def needs(compiled):
+        mem = compiled.memory_analysis()
+        assert mem.argument_size_in_bytes > 4 * n_params       # f32 masters
+        return (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+                + mem.generated_code_size_in_bytes)
+
+    compiled = _resident_round(topo, config, traffic)
+    assert needs(compiled) < 11.5e9, compiled.memory_analysis()
+    whole_tree = [name for name, result, opcode, _ in
+                  hlo_instructions(compiled.as_text())
+                  if re.search(r"f32\[\d{9,}\]", result)]
+    assert not whole_tree, whole_tree
+    real = build.make_engine
+    monkeypatch.setattr(build, "make_engine", lambda *a, **k: real(
+        *a, **{**k, "train_dtype": "float32", "local_dtype": None}))
+    with jax.default_matmul_precision("highest"):
+        twin = _resident_round(topo, config, dict(traffic, population=4, cohort=4))
+    assert needs(twin) < 15.75 * 2 ** 30, twin.memory_analysis()
