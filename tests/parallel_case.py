@@ -29,6 +29,19 @@ def _setup(cfg, prox_mu=0.0):
     return trainer, data
 
 
+def _token_setup(dataset, model, model_kw, has_time_axis):
+    """(trainer, data, cfg) of a tiny synthetic token federation: 8 clients
+    of 2 batches of 4 sequences, 4 a round."""
+    data = load_data(dataset, client_num_in_total=8, batch_size=4,
+                     max_batches_per_client=2, seed=0, synthetic_scale=0.01)
+    cfg = FedConfig(model=model, dataset=dataset, client_num_in_total=8,
+                    client_num_per_round=4, comm_round=1, epochs=1,
+                    batch_size=4, lr=0.1, frequency_of_the_test=100)
+    trainer = ClientTrainer(create_model(model, data.class_num, **model_kw),
+                            lr=0.1, has_time_axis=has_time_axis)
+    return trainer, data, cfg
+
+
 def run_donate_pair(make_engine, rounds=2):
     """Bitwise donation-correctness pin (ISSUE 4), shared by the resident
     and streaming test files: donation is a memory optimization — the

@@ -255,3 +255,40 @@ def test_scope_map_sees_past_a_cached_executable_without_the_scopes(tmp_path):
         jax.config.update("jax_persistent_cache_min_compile_time_secs", old_min)
         compilation_cache.reset_cache()
     assert "aggregate" in labels
+
+
+def test_lstm_sequence_vjp_keeps_the_scopes():
+    """`models/rnn.py::lstm_sequence` has a hand-written VJP.  Its ops still
+    carry the trainer's scope: the reverse time loop and, outside it, the
+    three kernel-shaped products and the bias sum are `backward`; the
+    forward loop and the input projection of all steps `forward`; no traced
+    op of the model is `unscoped` (`backward_ms` / `forward_ms` read them)."""
+    from parallel_case import _token_setup, hlo_instructions
+    trainer, data, cfg = _token_setup(
+        "stackoverflow_nwp", "rnn_stackoverflow",
+        dict(embedding_dim=12, hidden_size=24), True)
+    eng = MeshFedAvgEngine(trainer, data, cfg, mesh=make_mesh(1), chunk=2)
+    eng.run(rounds=1)
+    args, kwargs = eng.round_fn._signature
+    text = eng.round_fn.lower(*args, **kwargs).compile().as_text()
+    smap = programs.scope_map_of_hlo_text(text)
+    seen = collections.Counter()
+    for inst, _, opcode, rest in hlo_instructions(text):
+        op = re.search(r'op_name="(jit\([^"]*/RNNStackOverflow/[^"]*)"', rest)
+        if not op:
+            continue
+        want = ("backward" if "transpose(jvp(fed_forward))" in op.group(1)
+                else "forward")
+        assert smap[inst] == want, (inst, op.group(1), smap[inst])
+        # where the op sits in the model; the layer's own ops sit right
+        # under the model's name (the Dense layers' under `Dense_k/`)
+        where = op.group(1).split("/RNNStackOverflow/")[1]
+        where = re.sub(r"^while/body/.*/", "while/body/", where)
+        seen[want, opcode, where] += 1
+    for want in ("forward", "backward"):
+        assert seen[want, "while", "while"] == 1                 # the loop
+        assert seen[want, "dot", "while/body/dot_general"] == 1  # h . W_h
+    # outside the loops: x . W_i for all steps; dW_i, dW_h, dx and db
+    assert seen["forward", "dot", "dot_general"] == 1
+    assert seen["backward", "dot", "dot_general"] == 3
+    assert seen["backward", "reduce", "reduce_sum"] >= 1

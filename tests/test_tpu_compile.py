@@ -10,7 +10,8 @@ program that does not fit 16 GB of HBM — so the pallas kernels of
 round program on one and on four described chips, the resident round of
 the benchmark's `xdev10of4000` cell (10 of 4,000 clients: the take reads
 the cohort, not the stack), of `so_nwp_lstm` at its published 342,477
-clients and of `ouro_2p6b` (0.51 B parameters, with the float32 twin the
+clients (and, at the cell's traffic, what the LSTM's backward time loop
+carries) and of `ouro_2p6b` (0.51 B parameters, with the float32 twin the
 reference check runs), and the documented C = 128 size limit of the
 fused robust aggregation.
 
@@ -291,6 +292,34 @@ def test_solstm_published_population_fits_one_chip(topo):
         topo, config, dict(traffic, population=342_477)).memory_analysis()
     assert mem.argument_size_in_bytes > 7e9
     assert mem.temp_size_in_bytes < 0.5e9
+
+
+@pytest.mark.slow
+def test_solstm_backward_time_loop_carries_no_kernel_gradient(topo):
+    """The structural pin of `models/rnn.py::lstm_sequence`, at
+    `solstm.xdev50of342k`'s shapes (a chunk of 2 clients, bs 16, 20 steps,
+    hidden 670): the backward time loop of the LSTM carries the state's
+    cotangents and stacks only.  Scan's own transposition carried every
+    kernel's gradient through it — four `bf16[2,670,670]` and four
+    `bf16[2,96,670]` accumulators, a rank-16 product and a read-modify-write
+    of them at each of 4,000 steps a round (`slice_add_fusion.14/.15`,
+    `convolution_convert_fusion.2`: 65 of 138 ms on the chip, PERF.md §6
+    PR 27)."""
+    text = _resident_round(
+        topo, *_bench_files("so_nwp_lstm", "xdev50of342k")).as_text()
+    loops = [line.split(" = ", 1)[1].split(" while(")[0]
+             for line in text.splitlines() if " while(" in line and re.search(
+                 r'op_name="[^"]*transpose\(jvp\(fed_forward\)\)'
+                 r'/RNNStackOverflow/while"', line)]
+    assert len(loops) == 1
+    carried = re.findall(r"(\w+)\[([\d,]*)\]", loops[0])
+    assert ("f32", "2,16,670") in carried                  # dc, dh
+    assert ("f32", "20,2,16,2680") in carried              # the stacked dgates
+    kernels = {"2,670,670", "2,96,670", "2,670,2680", "2,96,2680"}
+    assert not [c for c in carried if c[1] in kernels], carried
+    assert not [line for line in text.splitlines() if re.match(
+        r"\s*(ROOT )?%?[\w.\-]*slice_add[\w.\-]* = \w+\[2,(670|96),670\]",
+        line)]
 
 
 @pytest.mark.slow
