@@ -12,14 +12,15 @@ last line — printed only when every phase passed — is exactly
 Default (one chip), in order:
   (a) device     what JAX attached, versions, the compile cache in force.
                  Anything but a TPU ends the run here, non-zero.
-  (b) headline   the cell bench.py times — ResNet-18-GN (11.2 M params,
-                 published widths 64-128-256-512), CIFAR-10 shapes, 128
-                 clients x 390 samples, batch 32, one local epoch, bf16
-                 compute, full participation — built by bench.py's own
-                 builder over a LEARNABLE seeded stand-in (bench.py's
-                 random labels pin the loss at ln 10 and prove nothing):
-                 2 warm-up + 3 timed rounds; fails on a non-finite loss,
-                 a loss that did not fall, or variables not on the TPU.
+  (b) headline   the recipe of the benchmark's silo cell
+                 (resnet18gn.silo128of1024) — ResNet-18-GN (11.2 M
+                 params, published widths 64-128-256-512), CIFAR-10
+                 shapes, 128 clients x 390 samples, batch 32, one local
+                 epoch, bf16 compute — under full participation, over a
+                 LEARNABLE seeded stand-in (random labels would pin the
+                 loss at ln 10 and prove nothing): 2 warm-up + 3 timed
+                 rounds; fails on a non-finite loss, a loss that did not
+                 fall, or variables not on the TPU.
       oracle     the repo's own correctness oracle at this width: 8
                  clients x one full batch of 32, E = 1, f32, full
                  participation — one engine round must equal one
@@ -119,24 +120,105 @@ def _learnable_cohort(sz: Sizes, n_clients: int, spc: int, seed: int):
         n_clients * spc, (sz.image_hw, sz.image_hw), 3, 10, seed=seed)
 
 
+def build_headline(x, y, n_clients: int = Sizes.n_clients,
+                   model_name: str = Sizes.model,
+                   batch_size: int = Sizes.batch_size):
+    """The headline cell's (cfg, data, trainer) over pre-made samples
+    x [n, h, w, 3] / y [n], split evenly over `n_clients` with full
+    participation and one local epoch.  The recipe (model, batch size,
+    lr, epochs, dtypes, chunk, unroll) is the benchmark's silo cell's,
+    fedbench/configs/resnet18gn_cifar.json under
+    fedbench/traffic/silo128of1024.json, so the smoke proves the
+    program the benchmark times; tests/test_one_instrument.py holds the
+    two together field by field."""
+    import jax.numpy as jnp
+
+    from fedml_tpu.core.trainer import ClientTrainer
+    from fedml_tpu.data.federated import (FederatedData, build_client_shards,
+                                          build_eval_shard)
+    from fedml_tpu.models import create_model
+    from fedml_tpu.utils.config import FedConfig
+
+    n = len(y)
+    spc = n // n_clients
+    cfg = FedConfig(model=model_name, dataset="cifar10",
+                    client_num_in_total=n_clients,
+                    client_num_per_round=n_clients,
+                    epochs=1, batch_size=batch_size, lr=0.1,
+                    frequency_of_the_test=10_000)
+    idx = {i: np.arange(i * spc, (i + 1) * spc) for i in range(n_clients)}
+    ev = build_eval_shard(x[:batch_size], y[:batch_size], batch_size)
+    data = FederatedData(
+        train_data_num=n, test_data_num=n, train_global=ev, test_global=ev,
+        client_shards=build_client_shards(x, y, idx, batch_size),
+        client_num_samples=np.full(n_clients, spc, np.float32),
+        test_client_shards=None, class_num=10, synthetic=True)
+    model = create_model(model_name, output_dim=10)
+    # bf16 compute / f32 masters: the MXU fast path (core/trainer.py);
+    # batch_unroll=8 unrolls the 13-step batch scan (measured −2.5%:
+    # 1.806 vs 1.851 s/round, PERF.md §6 "Before PR 22")
+    trainer = ClientTrainer(model, lr=cfg.lr, train_dtype=jnp.bfloat16,
+                            batch_unroll=8)
+    return cfg, data, trainer
+
+
+def headline_engine(cfg, data, trainer, mesh=None):
+    """MeshFedAvgEngine at the committed recipe — chunk=2 + bf16 local
+    masters: the measured v5e optimum (PERF.md §6 "Before PR 22").
+    `mesh` defaults to all visible devices."""
+    import jax.numpy as jnp
+
+    from fedml_tpu.parallel import MeshFedAvgEngine
+    from fedml_tpu.parallel.mesh import make_mesh
+    return MeshFedAvgEngine(trainer, data, cfg,
+                            mesh=mesh if mesh is not None else make_mesh(),
+                            chunk=2, local_dtype=jnp.bfloat16)
+
+
+class HeadlineRun:
+    """Full participation: the cohort IS the whole client stack — upload
+    it once (`cohort`, `weights`) and drive the streaming round over it
+    (no per-round device-side gather).  Each step() runs one round and
+    returns (variables, metrics)."""
+
+    def __init__(self, engine, seed: int = 0):
+        import jax
+        self.engine = engine
+        # placed like every later round's inputs (replicated over the
+        # mesh, what run() does): fresh single-device variables would
+        # make the FIRST call a program of its own — a second ~2-minute
+        # compile and a second 80 MB cache entry of the same round
+        # (PR 21 chip runs)
+        self.variables = engine._prepare_variables(engine.init_variables())
+        self.server_state = engine.server_init(self.variables)
+        self.rng = jax.random.PRNGKey(seed)
+        self.cohort, self.weights = engine.stream_cohort(0)
+
+    def step(self):
+        import jax
+        self.rng, r = jax.random.split(self.rng)
+        self.variables, self.server_state, m = (
+            self.engine.round_fn_streaming(
+                self.variables, self.server_state, self.cohort,
+                self.weights, r))
+        return self.variables, m
+
+
 def _headline_parts(sz: Sizes, seed: int):
-    """bench.py's (cfg, data, trainer) over the learnable stand-in."""
-    import bench
+    """build_headline's (cfg, data, trainer) over the learnable stand-in."""
     x, y = _learnable_cohort(sz, sz.n_clients, sz.samples_per_client, seed)
-    return bench.build_headline(x, y, n_clients=sz.n_clients,
-                                model_name=sz.model,
-                                batch_size=sz.batch_size)
+    return build_headline(x, y, n_clients=sz.n_clients,
+                          model_name=sz.model,
+                          batch_size=sz.batch_size)
 
 
 def phase_headline(sz: Sizes, seed: int) -> None:
     import jax
 
-    import bench
     from fedml_tpu.parallel.mesh import make_mesh
-    engine = bench.headline_engine(*_headline_parts(sz, seed),
-                                   mesh=make_mesh(1))
+    engine = headline_engine(*_headline_parts(sz, seed), mesh=make_mesh(1))
     t0 = time.perf_counter()
-    run = bench.HeadlineRun(engine, seed=seed)
+    run = HeadlineRun(engine, seed=seed)
     jax.block_until_ready(run.cohort)
     upload_s = time.perf_counter() - t0
     step = run.step
@@ -189,7 +271,6 @@ def phase_oracle(sz: Sizes, seed: int) -> None:
     import jax
     import jax.numpy as jnp
 
-    import bench
     from fedml_tpu.core.trainer import ClientTrainer
     from fedml_tpu.models import create_model
     from fedml_tpu.parallel import MeshFedAvgEngine
@@ -197,8 +278,8 @@ def phase_oracle(sz: Sizes, seed: int) -> None:
 
     C, bs, lr = sz.oracle_clients, sz.batch_size, 0.1
     x, y = _learnable_cohort(sz, C, bs, seed + 1)
-    cfg, data, _ = bench.build_headline(x, y, n_clients=C,
-                                        model_name=sz.model, batch_size=bs)
+    cfg, data, _ = build_headline(x, y, n_clients=C,
+                                  model_name=sz.model, batch_size=bs)
     model = create_model(sz.model, output_dim=10)
     engine = MeshFedAvgEngine(ClientTrainer(model, lr=lr), data, cfg,
                               mesh=make_mesh(1), donate=False)
@@ -362,14 +443,13 @@ def phase_four_chip(sz: Sizes, seed: int, n_chips: int = 4) -> None:
     rounds on make_mesh(1), in this process."""
     import jax
 
-    import bench
     from fedml_tpu.parallel.mesh import make_mesh
     rounds = 1 + 2                                # 1 warm-up + 2
     parts = _headline_parts(sz, seed)
     out = {}
     for n in (n_chips, 1):
-        engine = bench.headline_engine(*parts, mesh=make_mesh(n))
-        run = bench.HeadlineRun(engine, seed=seed)
+        engine = headline_engine(*parts, mesh=make_mesh(n))
+        run = HeadlineRun(engine, seed=seed)
         rows = sorted(s.data.shape[0]
                       for s in run.cohort["x"].addressable_shards)
         losses, times = [], []

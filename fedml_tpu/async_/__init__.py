@@ -23,8 +23,8 @@ Six layers (module docstrings have the full design):
                  rejoin / crash) + the AsyncServerManager /
                  AsyncClientManager FSM pair over the comm backends,
                  with the bounded parallel-decode ingest pool
-  torture.py     concurrent-uplink ingestion torture bench
-                 (bench.py --mode ingest / profile_bench exp_INGEST)
+  torture.py     concurrent-uplink ingestion torture harness
+                 (run_ingest_torture; tests/test_async_messaging.py)
 """
 from fedml_tpu.async_.adversary import (ATTACK_MODES, AdversarySim,
                                         AttackConfig, apply_data_attack)

@@ -27,7 +27,7 @@ scheduler's arrival handler): every uplink row passes, in order,
 
 Everything numeric runs in ONE jitted program per arrival (O(P), the
 same order as the PR-6 fold itself), so the hot ingest path keeps its
-throughput — the ≥0.9x gate is priced by ``bench.py --mode attack``'s
+throughput — ``run_ingest_torture(defense=...)`` is the screen-on
 overhead arm.  Rejected rows are quarantined, never folded: counted in
 ``async_updates_quarantined_total{reason}``, timed into
 ``defense_screen_seconds``, traced as ``defense.quarantine`` instants

@@ -554,7 +554,7 @@ class AsyncFedAvgEngine(FedAvgEngine):
         return {f"p{q}": float(np.percentile(s, q)) for q in qs}
 
     def async_report(self) -> dict:
-        """Headline async numbers for bench.py / profile_bench."""
+        """Headline async numbers of a run (tests/test_async.py)."""
         occ = np.asarray(self.occupancy_at_commit or [0])
         out = {
             "committed_updates": int(self.version),

@@ -16,11 +16,9 @@ server ingests and commits, reporting
 
 Clients send PRE-ENCODED frames (encode cost would otherwise compete
 with the server for cores on small boxes), so the wall measures the
-server's ingestion pipeline alone.  `bench.py --mode ingest` wraps this
-in the A/B the acceptance gate reads: legacy (inline decode + drain
-commit, the PR-5 path) vs decode-into + streaming at pool 1/4/8;
-tools/profile_bench.py exp_INGEST queues the same sweep for chip
-windows.
+server's ingestion pipeline alone.  The arms of the ISSUE-6 A/B are
+arguments of `run_ingest_torture`: legacy (inline decode + drain
+commit, the PR-5 path) vs decode-into + streaming at pool 1/4/8.
 """
 from __future__ import annotations
 
@@ -256,7 +254,7 @@ def run_ingest_torture(*, n_clients: int = 32, backend: str = "TCP",
     transport flow control backpressures the senders — the A/B's
     queue-discipline isolation arm.
 
-    Chaos + reliability (ISSUE 8, `bench.py --mode chaos`):
+    Chaos + reliability (ISSUE 8; tests/test_chaos.py):
     `reliable=True` swaps the spam clients for window-limited FMLR
     uplink pushers (per-seq envelopes, ack-retired windows, backoff
     resend) and envelopes the server; `chaos` (a dict of
@@ -294,9 +292,8 @@ def run_ingest_torture(*, n_clients: int = 32, backend: str = "TCP",
             kw["reactor"] = False
 
     tracer = obs.tracer()
-    # trace watermark: several torture arms share one process tracer
-    # (bench --mode ingest) — this run's critical path must only see
-    # its own spans
+    # trace watermark: several torture arms may share one process
+    # tracer — this run's critical path must only see its own spans
     trace_t0 = tracer._now_us() if tracer is not None else 0.0
     hist = obs.histogram("comm_decode_seconds",
                          buckets=obs.metrics.DECODE_SECONDS_BUCKETS,
@@ -317,7 +314,7 @@ def run_ingest_torture(*, n_clients: int = 32, backend: str = "TCP",
         policy = ChaosPolicy(ChaosConfig(seed=chaos_seed, **chaos))
     # ISSUE 12: one arm = one SLO evaluation window of the default
     # serving-spine pack — primed before the server starts, judged
-    # after it quiesces, so the bench's v11 `slo` block attributes
+    # after it quiesces, so the report's `slo_arm` block attributes
     # breaches (quarantines, evictions, starved commits) per ARM
     slo_eng = obs_slo.SloEngine(obs_slo.default_slo_pack(),
                                 dump_min_interval_s=30.0)
@@ -454,9 +451,9 @@ def run_ingest_torture(*, n_clients: int = 32, backend: str = "TCP",
         # during warmup count too; the goodput ratio compares arms
         # under IDENTICAL accounting, so the window mismatch cancels)
         "reliable": bool(reliable),
-        # ISSUE-9 admission accounting: the screen-on overhead arm of
-        # `bench.py --mode attack` reads these (honest torture clients
-        # must see zero quarantines — the false-positive gate)
+        # ISSUE-9 admission accounting of the screen-on overhead arm
+        # (honest torture clients must see zero quarantines — the
+        # false-positive gate)
         "defense": defense is not None,
         "admission": (server._admission.report()
                       if server._admission is not None else None),
@@ -475,7 +472,7 @@ def run_ingest_torture(*, n_clients: int = 32, backend: str = "TCP",
                               - rob0["recv_thread_deaths"],
     }
     # the run-scoped SLO verdict (full report + the compact per-arm
-    # summary bench.py's v11 `slo` block embeds)
+    # summary)
     slo_eng.evaluate()
     report["slo"] = slo_eng.report()
     report["slo_arm"] = slo_eng.arm_summary()
@@ -486,8 +483,7 @@ def run_ingest_torture(*, n_clients: int = 32, backend: str = "TCP",
         for leaf in jax.tree.leaves(server.variables)))
     if tracer is not None:
         # commit-to-commit stage attribution (decode/fold/commit + wait
-        # on this no-training harness) — the ISSUE-7 critical path,
-        # surfaced in bench.py's schema-v6 "critical_path" block
+        # on this no-training harness) — the ISSUE-7 critical path
         from fedml_tpu.obs import timeline
         report["critical_path"] = timeline.critical_path(
             [e for e in tracer.events() if e["ts"] >= trace_t0])

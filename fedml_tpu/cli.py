@@ -231,7 +231,7 @@ def build_parser() -> argparse.ArgumentParser:
     # turnaround with a load curve — at the trough of the diurnal cycle
     # (or outside a flash crowd) the fleet answers slower, so staleness
     # and deadline behavior see production load shapes.  The standalone
-    # heavy-traffic bench is `python bench.py --mode serve`.
+    # heavy-traffic simulation is fedml_tpu.scale.serve.run_serve_sim.
     p.add_argument("--arrival_process", type=str, default="none",
                    choices=("none", "constant", "diurnal", "flash",
                             "trace"),
@@ -354,7 +354,7 @@ def build_parser() -> argparse.ArgumentParser:
                         "round's sampled cohort (cross-device scale)")
     p.add_argument("--cohort_chunk", type=int, default=None,
                    help="max client model replicas live per shard "
-                        "(default 8; tools/profile_bench.py)")
+                        "(default: engine.py default_chunk)")
     p.add_argument("--batch_unroll", type=int, default=None,
                    help="unroll factor of the local batch scan (perf "
                         "knob; 8 measured -2.5%% on the v5e bench round "

@@ -373,7 +373,7 @@ class ClientTrainer:
         scanned XLA program.  `unroll` (default: the constructor's
         batch_unroll) unrolls the batch scan — measured on v5e at the
         bench shape: neutral at chunk 8, and at the chunk-2 optimum a
-        full-shard unroll wins ~1-2% (tools/profile_bench.py L2U rows).
+        full-shard unroll wins ~1-2% (PERF.md §6 "Before PR 22").
 
         The obs span fires at TRACE time only (this function runs under
         jit): it measures how long building the local-training scan

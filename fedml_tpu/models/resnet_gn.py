@@ -22,8 +22,8 @@ class FusionBarrierGroupNorm(nn.GroupNorm):
     semantically the identity, but it stops XLA from output-fusing the
     producing convolution with the GN statistics reduces — the dominant
     cost category of the north-star bench trace (PERF.md round-2b).
-    Opt-in via ResNet18GN(norm_fusion_barrier=True) until the chip
-    measurement (tools/profile_bench.py exp G4) shows which way it cuts."""
+    Opt-in via ResNet18GN(norm_fusion_barrier=True); measured slower on
+    the v5e (PERF.md §6 "Before PR 22"), so no cell turns it on."""
 
     @nn.compact
     def __call__(self, x):

@@ -14,7 +14,7 @@ Two tiers, by cost:
   events write through unconditionally so a later `obs.configure()`
   (or a test poking `obs.registry()`) sees history, not a cold start.
 * **Tracing/flight-recording is opt-in** via `configure(obs_dir)` (the
-  CLI's `--obs_dir`, or the FEDML_OBS_DIR env var for bench/tools).
+  CLI's `--obs_dir`, or the FEDML_OBS_DIR env var for tools/workers).
   Until then nothing is buffered.
 * **`span()` always annotates.**  Every span enters a
   `jax.profiler.TraceAnnotation` of the same name and attributes: with
@@ -128,8 +128,8 @@ def configure(directory: str, *, flight_capacity: int = 4096,
 
 
 def configure_from_env() -> bool:
-    """Enable from FEDML_OBS_DIR when set (bench.py / tools / spawned
-    worker processes).  No-op if already enabled."""
+    """Enable from FEDML_OBS_DIR when set (tools / spawned worker
+    processes).  No-op if already enabled."""
     d = os.environ.get(ENV_VAR)
     if d and not enabled():
         configure(d)
@@ -392,8 +392,8 @@ def _atexit_export() -> None:                # pragma: no cover - exit path
 
 
 def rollup() -> dict:
-    """Small summary for embedding in bench JSON lines: where the
-    artifacts are plus the headline counters."""
+    """Small summary for embedding in a report: where the artifacts
+    are plus the headline counters."""
     t = _tracer
     from fedml_tpu.obs import programs, slo
     eng = slo.active()

@@ -40,9 +40,7 @@ PERF.md stage table).  This registry makes them STANDING artifacts:
 
 ``report(since=snapshot())`` is the standing replacement for the
 manual profile session: per-family dispatch counts, wall p50/p95,
-compile seconds and flops/bytes per dispatch — bench.py's schema-v11
-``programs`` block and PERF.md's "Performance observatory" table both
-read it.
+compile seconds and flops/bytes per dispatch.
 """
 from __future__ import annotations
 

@@ -30,9 +30,8 @@ The default pack (:func:`default_slo_pack`) encodes the serving spine's
 health contract — committed-updates/sec floor, admission-latency p95,
 reactor loop-lag p95, zero quarantines/evictions/sheds, zero
 recv-thread deaths — with targets green on the clean ingest/connection
-bench arms and breached by the chaos/storm arms (the ISSUE-12
-acceptance shape; bench.py's schema-v11 ``slo`` block records the
-per-arm verdicts).
+torture arms and breached by the chaos/storm arms (the ISSUE-12
+acceptance shape; tests/test_observatory.py).
 """
 from __future__ import annotations
 
@@ -363,7 +362,7 @@ class SloEngine:
     def report(self) -> dict:
         """JSON-able verdict: per-spec status/value/target/breaches +
         the pack rollup (`healthy`, `breaches`, `breached` names) —
-        the /slo endpoint's body and the bench v11 `slo` arms' source."""
+        the /slo endpoint's body and the source of `arm_summary`."""
         with self._lock:
             slos = []
             for s in self.specs:
@@ -391,7 +390,7 @@ class SloEngine:
             }
 
     def arm_summary(self) -> dict:
-        """Compact per-bench-arm verdict (the v11 `slo` block rows)."""
+        """Compact verdict of one arm (one evaluation scope)."""
         r = self.report()
         return {"breaches": r["breaches"], "breached": r["breached"],
                 "healthy": r["healthy"]}

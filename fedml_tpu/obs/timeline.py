@@ -31,9 +31,8 @@ streams every layer already emits:
   the stage with the largest mean share is the named bottleneck.
 
 `tools/trace_timeline.py` is the CLI; `critical_path()` also runs
-in-process on a live tracer's events (bench.py's schema-v6
-`critical_path` block, the torture report, AsyncFedAvgEngine
-.timeline_report()).
+in-process on a live tracer's events (the torture report,
+AsyncFedAvgEngine.timeline_report()).
 """
 from __future__ import annotations
 

@@ -195,7 +195,7 @@ def pad_and_chunk(cohort, weights, rngs, chunk_cap: int):
 
 
 def default_chunk(local_dtype) -> int:
-    """Measured v5e chunk optima (tools/profile_bench.py, PERF.md): the
+    """Measured v5e chunk optima (PERF.md §6 "Before PR 22"): the
     L-curve bottoms at 2 with bf16 local masters (1.851 s/round vs 2.080
     at 4, 1.920 at 1); with f32 masters the F-curve bottoms at 8."""
     return 2 if local_dtype == jnp.bfloat16 else 8
@@ -341,7 +341,7 @@ class MeshFedAvgEngine(FedAvgEngine):
     `chunk` caps how many client model replicas are live at once on each
     shard: the per-shard cohort is processed as a lax.scan over groups of
     `chunk` vmapped clients, weighted-sums accumulated in the scan carry.
-    Measured on a v5e chip (tools/profile_bench.py): 128 concurrent
+    Measured on a v5e chip (PERF.md §6 "Before PR 22"): 128 concurrent
     ResNet-18 replicas run 3.72 s/round; chunked at 8 the same round is
     2.31 s — the full-width vmap blows the HBM working set.
 
@@ -359,7 +359,7 @@ class MeshFedAvgEngine(FedAvgEngine):
     psum in f32, and the global model stays f32 across rounds (the server
     average's small increments need the f32 grid; the 13 local steps at
     lr≫ulp do not).  Measured on v5e: 2.310 → 2.080 s/round at chunk 4
-    (tools/profile_bench.py L4 vs F8).
+    (bf16 masters at chunk 4 against f32 masters at chunk 8).
 
     A mesh with a "batch" axis (make_mesh_batch) additionally splits each
     client's per-step batch over that axis — per-client SAMPLE parallelism
@@ -395,8 +395,8 @@ class MeshFedAvgEngine(FedAvgEngine):
         # programs, same inputs — pinned by tests/test_prefetch.py).
         self.prefetch = prefetch
         # upload/compute overlap accounting, always on (two perf_counter
-        # calls per event); bench.py and tools/profile_bench.py surface
-        # overlap_fraction from here (PERF.md §"Prefetch pipeline")
+        # calls per event): overlap_fraction and the H2D byte counts
+        # (tests/test_prefetch.py, tests/test_parallel_stream.py)
         self.transfer_stats = TransferOverlapStats()
         # flat_stack stores image cohorts as [C, B, bs, h*w*c] on device
         # and restores [h, w, c] per chunk INSIDE the scan: XLA assigns
@@ -498,8 +498,8 @@ class MeshFedAvgEngine(FedAvgEngine):
                     donate_argnums=(0, 1) if donate else ()))
         # streaming variant: the gather happened on host; cohort arrives
         # pre-sharded [K, ...] with K = padded cohort size.  This public
-        # entry donates variables/server_state ONLY — bench.py and the
-        # convergence tools upload one cohort and replay it for every
+        # entry donates variables/server_state ONLY — chip_smoke.py and
+        # the convergence tools upload one cohort and replay it for every
         # round, so the cohort args must survive the call.
         self.round_fn_streaming = obs_programs.instrument(
             self.program_family,
@@ -866,7 +866,7 @@ class MeshFedAvgEngine(FedAvgEngine):
     @staticmethod
     def _round_attr(round_idx) -> dict:
         """The `round` identifier of an upload's spans (none where the
-        caller gathers outside a round: bench.py, the tools)."""
+        caller gathers outside a round: chip_smoke.py, the tools)."""
         return {} if round_idx is None else {"round": int(round_idx)}
 
     def _host_gather_upload(self, ids, round_idx=None) -> dict:
@@ -1038,8 +1038,8 @@ class MeshFedAvgEngine(FedAvgEngine):
     # built and CUT after chip measurement: at ms-scale rounds (LR/MNIST,
     # 1000 clients, 10/round — the regime where amortizing per-round
     # dispatch should pay if it ever does) the jitted per-round loop ran
-    # 2.56 ms/round vs 23.8 ms/round scanned (tools/profile_bench.py
-    # exp_SCAN, v5e, 2026-07-31; PERF.md).  The in-scan cohort gather +
+    # 2.56 ms/round vs 23.8 ms/round scanned (v5e, 2026-07-31;
+    # PERF.md §6 "Before PR 22").  The in-scan cohort gather +
     # shard_map compile far worse than the host-dispatched round program,
     # and per-round dispatch is not a bottleneck at any measured scale.
     # -- driver loop ----------------------------------------------------------

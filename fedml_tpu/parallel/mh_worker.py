@@ -10,9 +10,8 @@ mode(s), and prints ONE JSON line per rank:
     {"rank", "world", "n_blocks", "digests": {mode: md5},
      "rounds_per_sec", "carry_allreduce_bytes_per_round", ...}
 
-Used by bench.py --mode multihost (the weak-scaling sweep) and
-tests/test_multihost_spmd.py (the 2-vs-1-process bitwise pin, the
-crash-of-one-rank naming case).  Not a test file itself.
+Used by tests/test_multihost_spmd.py (the 2-vs-1-process bitwise pin,
+the crash-of-one-rank naming case).  Not a test file itself.
 
 Config keys (all optional; defaults in DEFAULTS):
     clients, spc, dim, classes, k_per_round, n_blocks, rounds, warmup,

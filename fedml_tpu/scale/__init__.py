@@ -2,8 +2,8 @@
 
 Sharded O(1)-per-round client registry, streaming cohort samplers over
 its eligibility mask, on-demand client-shard stores, trace-driven
-arrival processes, and the virtual-time serve simulation behind
-`bench.py --mode serve`.
+arrival processes, and the virtual-time serve simulation
+(`serve.run_serve_sim`).
 """
 from fedml_tpu.scale.arrivals import (ARRIVAL_MODES, ArrivalConfig,
                                       ArrivalProcess, ConstantArrivals,

@@ -35,8 +35,9 @@ Two invariants, both pinned by tests/test_cluster_serve.py:
   * cross-rank digest equality holds with live ingest, because every
     rank folds the identical exchanged payload bytes in item order.
 
-`bench.py --mode cluster` (schema v16) drives this with a multi-target
-connswarm fleet striped across the host endpoints.
+`python -m fedml_tpu.cli --cluster_serve` is the entry point; a
+multi-target connswarm fleet (comm/connswarm.py) striped across the
+host endpoints is the load.
 """
 from __future__ import annotations
 
@@ -141,6 +142,16 @@ class ClusterServeManager(AsyncServerManager):
         self._hosted: tuple = ()
         self._rr = 0
         self.misrouted = 0
+        # this host's own lane exists BEFORE the transport opens: the
+        # base constructor listens, and installs the frame sink, long
+        # before it returns, and a peer that dials the moment the port
+        # answers has its first uplinks decoded while this constructor
+        # is still running — with no hosted lane they were counted
+        # `misrouted` and dropped (seen under load: a loaded host lost
+        # the first 1-16 rows of a run, tests/test_cluster_serve.py)
+        self.buffer_k = int(buffer_k)
+        self._window_cv: Optional[threading.Condition] = None
+        self._adopt_locked(self.cluster_rank, 0)
         template = {"w": np.zeros((row_dim,), np.float32)}
         super().__init__(
             template, 1 << 62, buffer_k, 0, n_connections + 1, "TCP",
@@ -154,7 +165,6 @@ class ClusterServeManager(AsyncServerManager):
         # driver waits on it holding the SAME manager lock the insert
         # path times into async_lock_wait_seconds
         self._window_cv = threading.Condition(self._lock)
-        self._adopt_locked(self.cluster_rank, 0)
         # satellite (ISSUE 18): registry/lane pressure reaches the
         # reactor's door — before this only decode-pool depth and RSS
         # fed the gate, so a lane-bound host kept accepting uplinks it
@@ -258,7 +268,9 @@ class ClusterServeManager(AsyncServerManager):
             else:
                 self._admit_locked(lane, sparse if sparse is not None
                                    else row, weight, staleness, sender)
-            if lane.full():
+            if lane.full() and self._window_cv is not None:
+                # (None only while the constructor runs: no driver can
+                # be waiting on the barrier yet)
                 self._window_cv.notify_all()
         finally:
             self._lock.release()

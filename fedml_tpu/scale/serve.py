@@ -1,5 +1,5 @@
 """Virtual-time serve simulation — the million-client heavy-traffic
-bench behind `bench.py --mode serve`.
+harness (`run_serve_sim`; tests/test_scale.py).
 
 What it measures: the SERVER's cross-device round hot path at
 production populations — cohort sampling over the sharded registry,

@@ -42,8 +42,8 @@ from an honest one at ingest.  The only per-update enforcement that
 survives is the norm bound built into quantization itself:
 ``mpc.quantize`` raises on any row whose fixed-point magnitude exceeds
 the field's signed half-range, so a boosted model-replacement larger
-than ±(p−1)/(2·scale) cannot even be encoded.  ``bench.py --mode
-secure`` measures exactly this (the masked × byzantine arm).
+than ±(p−1)/(2·scale) cannot even be encoded
+(tests/test_secagg.py holds the refusal by name).
 
 Arithmetic bounds (ENFORCED at quantization, see mpc.quantize and
 client_row): every per-client word and the K-client field SUM must

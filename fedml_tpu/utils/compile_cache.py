@@ -6,7 +6,7 @@ variable itself — this module then sets no directory in code);
 otherwise the cache lives at `<checkout>/.jax_cache`, a fixed path next
 to the package.  Never a temp name, a pid or a timestamp.
 
-Callers: `fedml_tpu.cli.main`, `bench.py`, `chip_smoke.py`,
+Callers: `fedml_tpu.cli.main`, `chip_smoke.py`,
 `parallel/mh_worker.py`, the jax-running scripts under `tools/`, and
 `tests/conftest.py` plus the multihost test workers.
 """

@@ -46,7 +46,7 @@ class FedConfig:
     # fedprox
     prox_mu: float = 0.0
     # unroll factor of the local batch scan (perf knob; 8 measured -2.5%
-    # on the v5e bench round at chunk 2 — PERF.md L2U rows)
+    # on the v5e silo round at chunk 2 — PERF.md §6 "Before PR 22")
     batch_unroll: int = 1
     # robust aggregation
     norm_bound: float = 5.0

@@ -126,8 +126,8 @@ class TransferOverlapStats:
         # byte accounting (transfer-compression layer): every host
         # buffer the engine hands to device_put counts here, so the
         # stack-dtype tiers (f32/bf16/uint8) are comparable as BYTES,
-        # not just walls — bench.py surfaces h2d_bytes_per_round from
-        # this, and the registry counter is the Prometheus view
+        # not just walls — round_records() carries h2d_bytes per round,
+        # and the registry counter is the Prometheus view
         self._m_h2d_bytes = obs.counter("engine_h2d_bytes_total")
         self.reset()
 

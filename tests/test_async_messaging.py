@@ -245,7 +245,7 @@ def test_ingest_torture_smoke_streaming():
 
 def test_ingest_torture_smoke_legacy_arm():
     """The A/B's legacy arm (inline decode + drained O(K·P) commit)
-    still runs green — bench.py --mode ingest needs both arms."""
+    still runs green — the ingest A/B needs both arms."""
     from fedml_tpu.async_ import run_ingest_torture
     r = run_ingest_torture(**_torture_kw(ingest_pool=0, decode_into=False,
                                          streaming=False))

@@ -1,8 +1,8 @@
 """Bring-up contracts (ISSUE 21): the one placeable compile cache, the
-bench's no-fallback device check, and the published-peaks table."""
-import json
+published-peaks table and the headline stepper's one compile.  The
+no-fallback device check is held for chip_smoke.py by
+tests/test_chip_smoke.py and for the benchmark by tests/fedbench/."""
 import os
-import subprocess
 import sys
 
 import jax
@@ -58,38 +58,6 @@ def test_spawned_ranks_get_an_explicit_cpu_platform(monkeypatch):
     assert [o.strip() for o in outs] == ["cpu", "cpu"]
 
 
-# -- bench.py: no device => no number --------------------------------------
-
-_BENCH_ON_A_FORCED_CPU = (
-    "import jax, runpy, sys; jax.config.update('jax_platforms', 'cpu'); "
-    "sys.argv = ['bench.py']; runpy.run_path('bench.py', run_name='__main__')")
-
-
-def test_bench_without_a_tpu_exits_nonzero_and_prints_no_metric():
-    """A non-TPU platform the user did not ask for by JAX_PLATFORMS=cpu
-    (here forced through the config, so the test means the same on any
-    machine) is refused: rc != 0 and not one JSON line on stdout."""
-    env = {k: v for k, v in os.environ.items() if k != "JAX_PLATFORMS"}
-    r = subprocess.run([sys.executable, "-c", _BENCH_ON_A_FORCED_CPU],
-                       cwd=REPO, env=env, capture_output=True, text=True,
-                       timeout=300)
-    assert r.returncode != 0
-    assert r.stdout.strip() == ""
-    assert "no TPU attached" in r.stderr
-
-
-def test_bench_device_doc_names_the_device(monkeypatch):
-    sys.path.insert(0, REPO)
-    import bench
-    monkeypatch.setenv("JAX_PLATFORMS", "cpu")       # the explicit opt-in
-    doc = bench.require_device()
-    assert doc == {"platform": "cpu", "device_kind": "cpu",
-                   "device_count": len(jax.devices())}
-    stamped = bench._stamp({})
-    assert {"platform", "device_kind", "device_count"} <= set(stamped)
-    json.dumps(stamped)
-
-
 # -- published peaks -------------------------------------------------------
 
 class _FakeDevice:
@@ -124,14 +92,14 @@ def test_headline_run_compiles_the_round_once():
     round's inputs, so three rounds are ONE compiled program."""
     import numpy as np
     sys.path.insert(0, REPO)
-    import bench
+    import chip_smoke
     rs = np.random.RandomState(0)
     x = rs.rand(4 * 16, 8, 8, 3).astype(np.float32)
     y = rs.randint(0, 10, 4 * 16)
-    engine = bench.headline_engine(
-        *bench.build_headline(x, y, n_clients=4, model_name="lr",
-                              batch_size=8))
-    run = bench.HeadlineRun(engine)
+    engine = chip_smoke.headline_engine(
+        *chip_smoke.build_headline(x, y, n_clients=4, model_name="lr",
+                                   batch_size=8))
+    run = chip_smoke.HeadlineRun(engine)
     for _ in range(3):
         variables, _ = run.step()
     jax.block_until_ready(variables)

@@ -182,6 +182,44 @@ def test_overload_gate_reads_lane_saturation():
         mgr.finish()
 
 
+def test_uplink_during_construction_is_admitted(monkeypatch):
+    """The host's own lane exists before its transport can deliver.  The
+    base constructor listens and installs the frame sink long before
+    ClusterServeManager.__init__ returns, so a peer that dials the
+    moment the port answers has rows on the insert path while the
+    constructor still runs.  Here the first row arrives AT the moment
+    the sink goes in: it is folded into the host's lane, not counted
+    `misrouted` and dropped (what made
+    test_world1_socket_path_matches_synthetic_digest lose its first rows
+    on a loaded machine)."""
+    from fedml_tpu.comm.base import BaseCommManager
+    install = BaseCommManager.set_frame_sink
+    arrived = []
+
+    def install_then_deliver(self, sink):
+        install(self, sink)
+        mgr = sink.__self__
+        assert mgr.hosted_items() == (0,), (
+            "the transport delivers before the host has a lane")
+        mgr._ingest_row(3, np.ones((8,), np.float32), 1.0, 0)
+        arrived.append(mgr)
+
+    monkeypatch.setattr(BaseCommManager, "set_frame_sink",
+                        install_then_deliver)
+    mgr = ClusterServeManager(8, population=16, buffer_k=2, port=free_port(),
+                              n_connections=4, ingest_pool=1)
+    try:
+        assert arrived == [mgr]
+        assert mgr.misrouted == 0
+        assert mgr._lanes[0].admitted == 1
+        # and the row is in the window the first commit closes
+        mgr._ingest_row(4, np.ones((8,), np.float32), 1.0, 0)
+        assert mgr.wait_window(5.0) is True
+        assert mgr.take_partials()[0][2] == 2
+    finally:
+        mgr.finish()
+
+
 def test_connswarm_multi_target_striping():
     """Satellite: the subprocess fleet config grows a multi-target
     list — sender i dials targets[(i-1) % N], stats carry a per_target

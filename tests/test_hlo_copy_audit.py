@@ -87,6 +87,23 @@ def test_ceilings_artifact_shape(ceilings):
     assert set(ceilings["families"]) == set(hlo_copy_audit.ALL_FAMILIES)
 
 
+def test_copy_audit_ceilings_artifact_exists(ceilings):
+    """ISSUE 4: the copy-regression gate needs its pinned artifacts —
+    the per-family ceilings (with a machine-readable calibration env)
+    and the committed pre-PR baseline the FedAvg reduction is asserted
+    against.  Losing either silently disarms the gate."""
+    assert ceilings["families"], "ceilings artifact carries no families"
+    for fam, pins in ceilings["families"].items():
+        assert pins["copy_bytes_ceiling"] >= 0, fam
+    for key in ("jax", "jaxlib", "date"):
+        assert key in ceilings["calibration"], (
+            f"ceilings calibration env lost {key!r} (the recalibrate "
+            "protocol needs it to name version skew)")
+    with open(BASELINE_PATH) as f:
+        base = json.load(f)
+    assert "fedavg_resident" in base["families"]
+
+
 def test_copy_bytes_under_ceilings(audit, ceilings):
     cal = ceilings["calibration"]
     over = []

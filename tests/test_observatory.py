@@ -1,7 +1,6 @@
 """Performance-observatory tests (ISSUE 12): the SLO engine
 (fedml_tpu/obs/slo.py), the per-program-family profile registry
-(fedml_tpu/obs/programs.py), the httpd endpoint semantics, and the
-cross-run bench differ (tools/bench_diff.py).
+(fedml_tpu/obs/programs.py) and the httpd endpoint semantics.
 
 Pinned invariants:
 
@@ -10,22 +9,17 @@ Pinned invariants:
   attribution, breaches increment slo_breaches_total{slo} and fire ONE
   throttled flight dump;
 * the default serving-spine pack is green on a clean ingest arm and
-  counts >= 1 breach on a chaos arm (the bench v11 acceptance shape);
+  counts >= 1 breach on a chaos arm (the ISSUE-12 acceptance shape);
 * instrumented programs count dispatches + dispatch walls per family,
   attribute backend compiles to the registering family (fallback
   `unattributed`), join the HLO flop/byte census into MFU, and NEVER
   change results (the jit passes through untouched — `lower` included,
-  so the hlo audit keeps working);
-* bench_diff reports zero regressions against itself, names mode +
-  field + delta vs noise band for a synthetic 20% degradation, and
-  exits nonzero from the CLI.
+  so the hlo audit keeps working).
 """
 import glob
 import json
 import os
 import signal
-import subprocess
-import sys
 import time
 
 import numpy as np
@@ -33,10 +27,6 @@ import pytest
 
 from fedml_tpu import obs
 from fedml_tpu.obs import programs, slo
-
-REPO = os.path.join(os.path.dirname(__file__), "..")
-BENCH_DIFF = os.path.join(REPO, "tools", "bench_diff.py")
-BASELINE = os.path.join(REPO, "benchmarks", "bench_baseline_2core.json")
 
 
 @pytest.fixture
@@ -180,13 +170,13 @@ def test_slo_background_evaluator_installs_and_stops(clean_obs):
     assert eng.report()["windows_evaluated"] >= 2
 
 
-# -- default pack vs real bench arms -----------------------------------------
+# -- default pack vs real ingest arms ----------------------------------------
 
 def test_default_pack_green_on_clean_breach_on_chaos(clean_obs):
     """The ISSUE-12 acceptance shape at test scale: one clean INPROC
     ingest arm evaluates green, one corrupt-chaos arm counts >= 1
-    breach with named attribution (the same per-arm windows bench.py's
-    v11 `slo` block records)."""
+    breach with named attribution (one SLO window per arm, the
+    `slo_arm` block of the torture report)."""
     from fedml_tpu.async_.torture import run_ingest_torture
     clean = run_ingest_torture(
         n_clients=3, backend="INPROC", p=4096, buffer_k=4, commits=5,
@@ -370,103 +360,6 @@ def test_engine_round_dispatches_profiled(clean_obs):
     row = next(r for r in rep["families"]
                if r["family"] == "fedavg_resident")
     assert row["dispatches"] == 1
-
-
-# -- bench_diff --------------------------------------------------------------
-
-def _load_bench_diff():
-    import importlib.util
-    spec_ = importlib.util.spec_from_file_location("_bench_diff_under_test",
-                                                   BENCH_DIFF)
-    bd = importlib.util.module_from_spec(spec_)
-    sys.modules[spec_.name] = bd
-    spec_.loader.exec_module(bd)
-    return bd
-
-
-def _degraded_baseline(tmp_path, mode: str, field: str, factor: float):
-    doc = json.load(open(BASELINE))
-    doc["modes"][mode][field] = round(doc["modes"][mode][field] * factor,
-                                      6)
-    p = tmp_path / "degraded.json"
-    p.write_text(json.dumps(doc))
-    return str(p)
-
-
-def test_bench_diff_self_compare_is_clean():
-    bd = _load_bench_diff()
-    rows, rc = bd.run_diff(BASELINE, BASELINE)
-    assert rc == 0
-    assert all(r["status"] != "regressed" for r in rows)
-    # every baseline mode produced comparable fields
-    modes = {r["mode"] for r in rows}
-    assert {"sync", "ingest", "chaos", "attack", "serve",
-            "connections"} <= modes
-
-
-def test_bench_diff_names_synthetic_regression(tmp_path):
-    """Degrade one headline field 20% -> the verdict names mode +
-    field + delta vs the noise band, and the CLI exits nonzero (the
-    ISSUE-12 acceptance wording)."""
-    degraded = _degraded_baseline(tmp_path, "attack", "defended_acc",
-                                  0.8)
-    r = subprocess.run(
-        [sys.executable, BENCH_DIFF, BASELINE, degraded],
-        capture_output=True, text=True)
-    assert r.returncode == 1, r.stdout + r.stderr
-    line = next(l for l in r.stdout.splitlines()
-                if l.startswith("regressed"))
-    assert "attack" in line and "defended_acc" in line
-    assert "noise band" in line and "-20" in line
-    # improvements are reported but never fatal
-    improved = _degraded_baseline(tmp_path, "sync", "rounds_per_sec",
-                                  1.5)
-    r = subprocess.run(
-        [sys.executable, BENCH_DIFF, BASELINE, improved],
-        capture_output=True, text=True)
-    assert r.returncode == 0
-    assert "improved" in r.stdout
-
-
-def test_bench_diff_gates_and_noise_bands(tmp_path):
-    """A 20% drop INSIDE a wide GIL-noise band is ok (the encoded
-    0.75-2.7x spread), while crossing an absolute gate regresses even
-    within-band."""
-    bd = _load_bench_diff()
-    inside = _degraded_baseline(tmp_path, "ingest",
-                                "best_updates_per_sec", 0.8)
-    rows, rc = bd.run_diff(BASELINE, inside)
-    assert rc == 0, "20% inside the 65% GIL-noise band must not page"
-    gated = _degraded_baseline(tmp_path, "chaos", "goodput_vs_clean",
-                               0.4)                      # 0.33 < gate 0.5
-    rows, rc = bd.run_diff(BASELINE, gated)
-    assert rc == 1
-    row = next(r for r in rows if r["status"] == "regressed")
-    assert row["field"] == "goodput_vs_clean"
-    assert "gate" in row["detail"]
-
-
-def test_bench_diff_handles_schema_range_and_wrappers(tmp_path):
-    """v4-v11 bench lines and driver wrappers ({"parsed": line}) normalize;
-    fields absent on one side report `missing`, never a regression."""
-    bd = _load_bench_diff()
-    v4 = {"schema_version": 4, "mode": "async", "value": 2.0,
-          "async": {"staleness_p95": 3.0}}
-    v11 = {"schema_version": 11, "mode": "async", "value": 2.1,
-           "async": {"staleness_p95": 3.0,
-                     "buffer_occupancy_mean": 6.5},
-           "slo": {"pack": "serving_spine_default",
-                   "arms": {"run": {"breaches": 0}}}}
-    a = tmp_path / "a.json"
-    b = tmp_path / "b.json"
-    a.write_text(json.dumps({"parsed": v4}))     # driver wrapper shape
-    b.write_text(json.dumps(v11))
-    rows, rc = bd.run_diff(str(a), str(b))
-    assert rc == 0
-    by_field = {r["field"]: r for r in rows}
-    assert by_field["commits_per_sec"]["status"] in ("ok", "improved")
-    assert by_field["buffer_occupancy_mean"]["status"] == "missing"
-    assert by_field["slo_clean_breaches"]["status"] == "missing"
 
 
 # -- overhead gate -----------------------------------------------------------

@@ -379,14 +379,15 @@ def test_blockstream_device_memory_is_o_block():
 def test_donate_bitwise_streaming():
     """The run-loop streaming variant donates the per-round cohort
     (engine._round_fn_streaming_consume); the public replay entry must
-    stay un-donated so bench.py-style cohort reuse survives."""
+    stay un-donated so a caller that uploads one cohort and replays it
+    (chip_smoke.py::HeadlineRun) survives."""
     cfg = _mnist_like_cfg(client_num_per_round=12, comm_round=2)
     trainer, data = _setup(cfg)
     run_donate_pair(lambda donate: MeshFedAvgEngine(
         trainer, data, cfg, mesh=make_mesh(8), donate=donate,
         streaming=True))
     # replay safety: round_fn_streaming does NOT donate the cohort — the
-    # same uploaded cohort must survive two calls (bench.py's pattern)
+    # same uploaded cohort must survive two calls (HeadlineRun's pattern)
     eng = MeshFedAvgEngine(trainer, data, cfg, mesh=make_mesh(8),
                            donate=True, streaming=True)
     v = eng._prepare_variables(eng.init_variables())
@@ -440,7 +441,7 @@ def test_blockstream_uint8_h2d_byte_reduction():
                                rounds=1)
         bytes_per[tag] = eng.transfer_stats.h2d_bytes
         assert bytes_per[tag] > 0
-        # per-round records carry the byte accounting (bench.py schema)
+        # per-round records carry the byte accounting
         assert eng.transfer_stats.rounds[0]["h2d_bytes"] > 0
     assert bytes_per["f32"] / bytes_per["u8"] >= 3.5, bytes_per
     assert bytes_per["bf16"] / bytes_per["u8"] >= 1.9, bytes_per

@@ -863,9 +863,9 @@ def test_attack_defense_grid_over_tcp():
     # extra row+g passes show up fully (paired-median 0.73x at the
     # canonical 32-client point, per-call 2.05x fused vs 0.5x e2e for
     # the rejected unfused design — PERF.md "Adversarial robustness");
-    # the ISSUE-9 >=0.9x target is the chip gate, priced by
-    # profile_bench exp_ATTACK where the fold dispatches to the
-    # accelerator and the screen rides its pass.
+    # the ISSUE-9 >=0.9x target is for a host where the fold
+    # dispatches to the accelerator and the screen rides its pass
+    # (never measured on a chip).
     off = run_ingest_torture(n_clients=16, backend="TCP", buffer_k=8,
                              commits=12, warmup_commits=2, ingest_pool=4,
                              base_port=53700)

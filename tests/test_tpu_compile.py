@@ -163,33 +163,34 @@ def test_robust_c128_exceeds_one_chip(topo):
 # -- the whole headline round program --------------------------------------
 
 def _headline_round(topo, n_devices: int):
-    """bench.py's headline engine over a mesh of described chips, its
-    streaming round lowered from shape structs (128 clients x 13 batches
-    x 32, ResNet-18-GN, bf16 compute, chunk 2, unroll 8)."""
+    """chip_smoke.py's headline engine over a mesh of described chips,
+    its streaming round lowered from shape structs (128 clients x 13
+    batches x 32, ResNet-18-GN, bf16 compute, chunk 2, unroll 8)."""
     sys.path.insert(0, REPO)
-    import bench
+    import chip_smoke
+    sz = chip_smoke.Sizes()
     from fedml_tpu.parallel.mesh import (client_sharding, make_mesh,
                                          replicated_sharding,
                                          stack_leaf_sharding)
-    spc = bench.SAMPLES_PER_CLIENT
+    spc = sz.samples_per_client
     rs = np.random.RandomState(0)
     # two host clients fix the per-client shapes; the cohort axis of the
     # lowered program comes from the shape structs below
     x = rs.rand(2 * spc, 32, 32, 3).astype(np.float32)
     y = rs.randint(0, 10, 2 * spc).astype(np.int64)
-    cfg, data, trainer = bench.build_headline(x, y, n_clients=2)
+    cfg, data, trainer = chip_smoke.build_headline(x, y, n_clients=2)
     mesh = make_mesh(devices=topo.devices[:n_devices])
-    engine = bench.headline_engine(cfg, data, trainer, mesh=mesh)
+    engine = chip_smoke.headline_engine(cfg, data, trainer, mesh=mesh)
     host = engine._cast_stack_x(dict(data.client_shards))
     cohort = {
-        k: jax.ShapeDtypeStruct((bench.N_CLIENTS,) + v.shape[1:], v.dtype,
+        k: jax.ShapeDtypeStruct((sz.n_clients,) + v.shape[1:], v.dtype,
                                 sharding=stack_leaf_sharding(mesh, v))
         for k, v in host.items()}
     rep = replicated_sharding(mesh)
     variables = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rep),
         jax.eval_shape(engine.init_variables))
-    weights = jax.ShapeDtypeStruct((bench.N_CLIENTS,), jnp.float32,
+    weights = jax.ShapeDtypeStruct((sz.n_clients,), jnp.float32,
                                    sharding=client_sharding(mesh))
     rng = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=rep)
     return engine.round_fn_streaming.lower(

@@ -1,14 +1,14 @@
-"""Chip-measured convergence at the committed bench recipe (VERDICT r3
+"""Chip-measured convergence at the committed silo recipe (VERDICT r3
 next-#2).
 
-bench.py times the committed recipe (MeshFedAvgEngine, chunk 2, bf16
-local masters, batch_unroll 8, bf16 compute) on random labels — correct
-for timing, evidence-free for training quality; the recipe's numerics
-were pinned only by CPU closeness tests.  This script runs the EXACT
-bench code path on the real chip over a LEARNABLE synthetic CIFAR
-stand-in (class templates + noise, data/synthetic.py) — bench-scale
-cohort (128 clients x 390 samples, full participation, streaming) —
-for a few hundred rounds, recording the held-out accuracy curve.
+The benchmark times the committed recipe (MeshFedAvgEngine, chunk 2,
+bf16 local masters, batch_unroll 8, bf16 compute) for a 20 s window —
+evidence of speed, not of training quality; the recipe's numerics were
+pinned only by CPU closeness tests.  This script runs the same recipe
+on the real chip over a LEARNABLE synthetic CIFAR stand-in (class
+templates + noise, data/synthetic.py) — the silo cohort (128 clients x
+390 samples, full participation, streaming) — for a few hundred
+rounds, recording the held-out accuracy curve.
 
 The endpoint is pinned in PERF.md; tests/test_quality_regression.py
 pins the same recipe's CPU behavior.  Usage:
@@ -72,8 +72,9 @@ def main() -> None:
                     epochs=1, batch_size=BS, lr=0.1,
                     frequency_of_the_test=10_000)
     model = create_model("resnet18_gn", output_dim=10)
-    # the committed bench recipe, exactly (bench.py): bf16 compute,
-    # unroll 8, chunk 2, bf16 local masters, bf16 cohort storage
+    # the committed silo recipe (chip_smoke.py::build_headline):
+    # bf16 compute, unroll 8, chunk 2, bf16 local masters, bf16
+    # cohort storage
     trainer = ClientTrainer(model, lr=cfg.lr, train_dtype=jnp.bfloat16,
                             batch_unroll=8)
     engine = MeshFedAvgEngine(trainer, data, cfg, mesh=make_mesh(), chunk=2,

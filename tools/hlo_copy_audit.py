@@ -7,8 +7,7 @@ aliasing, donation/input-output aliasing), so the OPTIMIZED HLO of the
 same round program compiled on the virtual-CPU mesh is a faithful
 STRUCTURAL proxy for the chip: a carry-layout or donation regression
 shows up here as new `copy`/`copy-start` instructions and bytes, without
-needing a chip.  (Wall-clock is still priced on chip —
-tools/profile_bench.py exp_DN128 is the donate on/off A/B.)
+needing a chip.  (Wall-clock is still priced on the chip.)
 
 For every engine family this tool compiles the family's jitted round
 program(s) with the family's real argument placement (sharded stacks,
