@@ -140,9 +140,31 @@ def tree_select(pred, new: Pytree, old: Pytree) -> Pytree:
     return jax.tree.map(lambda n, o: jnp.where(pred, n, o), new, old)
 
 
+def _map_schedule_counts(fn, state: Pytree, *others: Pytree) -> Pytree:
+    """`state` with every SCHEDULE step count — the ``count`` field of
+    optax ``ScaleByScheduleState`` NamedTuples — replaced by
+    `fn(count, *the same count of each of others)`; everything else,
+    other counts included, as it was."""
+    if hasattr(state, "_fields"):          # optax states are NamedTuples
+        schedule = type(state).__name__ == "ScaleByScheduleState"
+
+        def field(f):
+            args = (getattr(state, f), *(getattr(o, f) for o in others))
+            if f == "count" and schedule:
+                return fn(*args)
+            return _map_schedule_counts(fn, *args)
+        return type(state)(**{f: field(f) for f in state._fields})
+    if isinstance(state, (list, tuple)):
+        return type(state)(_map_schedule_counts(fn, *group)
+                           for group in zip(state, *others))
+    if isinstance(state, dict):
+        return {k: _map_schedule_counts(fn, v, *(o[k] for o in others))
+                for k, v in state.items()}
+    return state
+
+
 def tree_merge_counts(kept: Pytree, advanced: Pytree) -> Pytree:
-    """Return `kept` with every SCHEDULE step count (the ``count`` field
-    of optax ``ScaleByScheduleState`` NamedTuples) taken from `advanced`.
+    """Return `kept` with every SCHEDULE step count taken from `advanced`.
 
     The empty-batch guard freezes optimizer state via tree_select, which
     also freezes the schedule step count — so padded-lane clients would
@@ -153,20 +175,16 @@ def tree_merge_counts(kept: Pytree, advanced: Pytree) -> Pytree:
     total_steps to the padded batch count).  Other counts — notably
     ScaleByAdamState.count, whose bias correction must agree with the
     frozen mu/nu moments — and momentum / moment buffers stay frozen."""
-    if hasattr(kept, "_fields"):          # optax states are NamedTuples
-        schedule = type(kept).__name__ == "ScaleByScheduleState"
-        return type(kept)(**{
-            f: (getattr(advanced, f) if f == "count" and schedule
-                else tree_merge_counts(getattr(kept, f),
-                                       getattr(advanced, f)))
-            for f in kept._fields})
-    if isinstance(kept, (list, tuple)):
-        return type(kept)(tree_merge_counts(k, a)
-                          for k, a in zip(kept, advanced))
-    if isinstance(kept, dict):
-        return {k: tree_merge_counts(v, advanced[k])
-                for k, v in kept.items()}
-    return kept
+    return _map_schedule_counts(lambda _, count: count, kept, advanced)
+
+
+def tree_advance_counts(state: Pytree, steps) -> Pytree:
+    """Return `state` with every SCHEDULE step count (the counts
+    tree_merge_counts merges) advanced by `steps` more elapsed local
+    steps: what the padded batches a bounded batch loop never visits
+    (ClientTrainer.local_train) would have added one at a time."""
+    return _map_schedule_counts(
+        lambda count: count + jnp.asarray(steps, count.dtype), state)
 
 
 def tree_vary_noop(tree: Pytree, shard) -> Pytree:

@@ -29,8 +29,8 @@ import optax
 
 from fedml_tpu import obs
 from fedml_tpu.obs import scopes
-from fedml_tpu.core.pytree import (tree_merge_counts, tree_select,
-                                   tree_vary_noop)
+from fedml_tpu.core.pytree import (tree_advance_counts, tree_merge_counts,
+                                   tree_select, tree_vary_noop)
 
 Pytree = Any
 
@@ -364,7 +364,7 @@ class ClientTrainer:
     # -- local training: epochs x batches under lax.scan --------------------
     def local_train(self, variables: Pytree, shard, rng: jax.Array,
                     epochs: int, global_params=None,
-                    unroll: Optional[int] = None):
+                    unroll: Optional[int] = None, batch_bound=None):
         """Run E local epochs of SGD over one client's padded shard.
 
         shard: {"x": [B, bs, ...], "y": [B, bs, ...], "mask": [B, bs]}
@@ -374,6 +374,21 @@ class ClientTrainer:
         batch_unroll) unrolls the batch scan — measured on v5e at the
         bench shape: neutral at chunk 8, and at the chunk-2 optimum a
         full-shard unroll wins ~1-2% (PERF.md §6 "Before PR 22").
+
+        `batch_bound` (a traced int32 scalar, or None) ends each epoch's
+        batch loop after that many batches: the caller promises that
+        every batch from `batch_bound` on is all padding, so the steps
+        left out are the ones the empty-batch guard turns into no-ops.
+        The loop is then a `fori_loop` that reads batch i by a dynamic
+        index, and the trained weights are bitwise those of the scan over
+        all B batches: what the skipped steps still did there — split
+        the carried rng, advance a schedule's step count — is made up
+        after the loop.  Under `vmap` pass ONE bound for all lanes (not
+        a mapped axis: a per-lane bound makes the loop's predicate a
+        vector and every step a select of the whole TrainState); under
+        `batch_axes` it must agree across the batch shards, whose psums
+        meet inside the step (chunked_weighted_train gives both).
+        Without it the code is the scan it was, `unroll` included.
 
         The obs span fires at TRACE time only (this function runs under
         jit): it measures how long building the local-training scan
@@ -410,6 +425,32 @@ class ClientTrainer:
                     cnt = self._revary(jax.lax.psum(cnt, self.batch_axes))
                 return state, (loss, cnt)
 
+            def bounded_epoch_body(state, _):
+                def trip(i, carry):
+                    state, loss_sum, count = carry
+                    batch = jax.tree.map(
+                        lambda a: jax.lax.dynamic_index_in_dim(
+                            a, i, 0, keepdims=False), shard)
+                    state, (loss, cnt) = batch_body(state, batch)
+                    return state, loss_sum + loss * cnt, count + cnt
+
+                # the sums start with the type a trip gives them (shard-
+                # varying under shard_map, like the optimizer state above)
+                zero = jnp.sum(shard["mask"][:0].astype(jnp.float32))
+                state, loss_sum, count = jax.lax.fori_loop(
+                    0, batch_bound, trip, (state, zero, zero))
+                skipped = shard["mask"].shape[0] - batch_bound
+                if epochs > 1:
+                    # the next epoch starts from the rng the full scan
+                    # would hand it: one split a skipped batch
+                    state = state.replace(rng=jax.lax.fori_loop(
+                        0, skipped, lambda _, r: jax.random.split(r)[0],
+                        state.rng))
+                if self.has_schedule:
+                    state = state.replace(opt_state=tree_advance_counts(
+                        state.opt_state, skipped))
+                return state, loss_sum / jnp.maximum(count, 1.0)
+
             def epoch_body(state, _):
                 state, (losses, counts) = jax.lax.scan(
                     batch_body, state, shard, unroll=unroll)
@@ -417,8 +458,9 @@ class ClientTrainer:
                 return state, jnp.sum(losses * counts) / jnp.maximum(
                     jnp.sum(counts), 1.0)
 
-            state, epoch_losses = jax.lax.scan(epoch_body, state, None,
-                                               length=epochs)
+            state, epoch_losses = jax.lax.scan(
+                epoch_body if batch_bound is None else bounded_epoch_body,
+                state, None, length=epochs)
             n = jnp.sum(shard["mask"])
             if self.batch_axes:   # client's TOTAL sample count (agg weight)
                 n = self._revary(jax.lax.psum(n, self.batch_axes))

@@ -11,7 +11,11 @@ copy census of the touched families exactly).  ``label_of`` turns one
     fed_take            parallel/engine.py::take_cohort  take
     fed_local_train     the chunk scan of per-client     local_other
                         training and its plumbing        (what no inner
-                        (chunked_weighted_train)         scope claims)
+                        (chunked_weighted_train; for a   scope claims)
+                        ragged population also the
+                        cohort's ordering by batch
+                        trips, each chunk's bound and
+                        the batch loop that runs to it)
     fed_forward         ClientTrainer._loss              forward; under
                         value_and_grad the backward ops read
                         transpose(jvp(fed_forward))   -> backward

@@ -171,17 +171,26 @@ def take_cohort(mesh: Mesh, stack: dict, stack_w, ids, wmask):
     return cohort, weights
 
 
-def pad_and_chunk(cohort, weights, rngs, chunk_cap: int):
+def chunk_shape(k_local: int, chunk_cap: int):
     """Balanced chunk sizing shared by every chunked cohort loop: same
     number of scan trips as ceil(k/cap) but lanes spread evenly (k=12,
-    cap=8 gives 2x6 not 2x8); non-multiple cohorts are padded in-program
-    with zero-weight lanes (static shapes; the empty-batch guard makes
-    them numeric no-ops).  Returns (cohort, weights, rngs) reshaped to
-    [n_chunks, chunk, ...]."""
-    k_local = weights.shape[0]
+    cap=8 gives 2x6 not 2x8).  Returns (chunk, pad): lanes a chunk and
+    the zero-weight lanes that fill the last one."""
     n_trips = -(-k_local // min(chunk_cap, k_local))
     chunk = -(-k_local // n_trips)
-    pad = (-k_local) % chunk
+    return chunk, (-k_local) % chunk
+
+
+def pad_and_chunk(cohort, weights, rngs, chunk_cap: int):
+    """Chunk a shard-local cohort by `chunk_shape`; non-multiple cohorts
+    are padded in-program with zero-weight lanes (static shapes; the
+    empty-batch guard makes them numeric no-ops, and a bounded batch loop
+    — batch_trips — never visits their all-padding batches).  Lanes keep
+    the order they arrive in: chunked_weighted_train orders a ragged
+    cohort before it calls this.  Returns (cohort, weights, rngs)
+    reshaped to [n_chunks, chunk, ...]."""
+    k_local = weights.shape[0]
+    chunk, pad = chunk_shape(k_local, chunk_cap)
     if pad:
         cohort = jax.tree.map(
             lambda a: jnp.concatenate(
@@ -192,6 +201,44 @@ def pad_and_chunk(cohort, weights, rngs, chunk_cap: int):
     n_chunks = (k_local + pad) // chunk
     resh = lambda a: a.reshape((n_chunks, chunk) + a.shape[1:])
     return jax.tree.map(resh, cohort), resh(weights), resh(rngs)
+
+
+def batch_trips(mask):
+    """Batch-loop trips each client needs: 1 + the index of its last
+    batch that holds a real sample (0 for a client with none) — NOT the
+    number of such batches, real batches need not form a prefix.
+    mask [..., B, bs] -> int32 [...]; numpy in, numpy out (the engine's
+    host-side count), a jax array or tracer in, jax out (the program)."""
+    xp = jnp if isinstance(mask, jax.Array) else np
+    nth = xp.arange(1, mask.shape[-2] + 1, dtype=xp.int32)
+    return ((mask > 0).any(axis=-1) * nth).max(axis=-1)
+
+
+def population_trips(data):
+    """(trips [C], ragged) of a resident population, read off its masks:
+    each client's batch_trips, and whether any client leaves batches of
+    the stack empty — what decides, at an engine's construction, between
+    the bounded batch loop and the static one (chunked_weighted_train,
+    `ragged_batches`).  A fact of the data, not an option."""
+    mask = np.asarray(data.client_shards["mask"])
+    trips = batch_trips(mask)
+    return trips, bool((trips < mask.shape[1]).any())
+
+
+def order_by_trips(trips, chunk_cap: int):
+    """THE ordering and the trip bounds of a ragged shard-local cohort,
+    for the program and for the host's count of it alike (numpy or jax,
+    as batch_trips): `order` sorts the lanes by `trips` [k], descending
+    and stable, and `bounds` [n_chunks] is the longest client of each
+    chunk once `pad_and_chunk` cuts the ordered lanes (its zero-weight
+    fill counts 0).  Ordered, a round's chunks run Σ bounds batch trips,
+    near the fewest any grouping of these clients allows; as sampled, a
+    long client drags a short neighbour's lane through its padding."""
+    xp = jnp if isinstance(trips, jax.Array) else np
+    chunk, pad = chunk_shape(trips.shape[0], chunk_cap)
+    order = xp.argsort(-trips, stable=True)
+    lanes = xp.concatenate([trips[order], xp.zeros((pad,), trips.dtype)])
+    return order, lanes.reshape(-1, chunk).max(axis=1)
 
 
 def default_chunk(local_dtype) -> int:
@@ -247,7 +294,7 @@ def chunked_weighted_train(trainer, variables, cohort, weights, rngs,
                            epochs, vary_axes, chunk_cap: int = 8,
                            client_transform=None,
                            emit_flat_params: bool = False,
-                           restore_x=None):
+                           restore_x=None, ragged_batches: bool = False):
     """Train a shard-local cohort as a lax.scan over chunks of at most
     `chunk_cap` vmapped clients, accumulating Σ w·v / Σ w / Σ w·loss in the
     carry — the HBM-bounded inner loop shared by the flat and hierarchical
@@ -260,19 +307,37 @@ def chunked_weighted_train(trainer, variables, cohort, weights, rngs,
     With `emit_flat_params` the scan ALSO emits each client's trained
     params flattened to an f32 row (ops/aggregate tile padding), returned
     as a fourth value [n_chunks, chunk, P] — the order-statistic robust
-    defenses consume this (any chunk-pad lanes sit at the flattened tail).
+    defenses consume this; rows are in the cohort's order as it arrived
+    (any chunk-pad lanes sit at the flattened tail).
 
     A cohort whose size is not a chunk multiple is padded IN-PROGRAM with
     zero-weight lanes (pad_and_chunk), so chunk stays at the cap instead
     of degenerating to small divisors for awkward (e.g. prime) cohort
     sizes.
+
+    `ragged_batches` says that the population leaves batches of the
+    stack empty (what the caller's engine saw in its resident masks: a
+    fact of the data, not a preference).  The cohort is then ordered by
+    the batch trips each client needs (batch_trips, from its mask, in-
+    program; a zero-weight lane needs none) before it is chunked —
+    cohort, weights and rngs permuted together, so a client trains on
+    its own batches with its own rng whatever lane it lands in — and
+    each chunk's batch loop stops at the chunk's own longest client
+    (order_by_trips; ClientTrainer.local_train's `batch_bound`, one
+    scalar for the vmapped lanes, the pmax over `batch_axes` where the
+    batch is split).  Every step left out was a numeric no-op, so each
+    client's trained weights are bitwise the static loop's; Σ w·v folds
+    the clients in another order and agrees to float32 rounding.  With
+    every client filling all its batches the flag is False and the
+    program is the static one: no sort, no bound, the `unroll` kept.
     """
     from fedml_tpu.ops.aggregate import flatten_stacked_tree
     global_params = variables["params"] if trainer.prox_mu > 0 else None
 
-    def one(shard, crng):
+    def one(shard, crng, bound):
         v, loss, _n = trainer.local_train(
-            variables, shard, crng, epochs, global_params=global_params)
+            variables, shard, crng, epochs, global_params=global_params,
+            batch_bound=bound)
         return v, loss
 
     # The Σ w·v carry: leaves under BIG_CARRY_LEAF elements packed into
@@ -296,10 +361,10 @@ def chunked_weighted_train(trainer, variables, cohort, weights, rngs,
 
     def chunk_body(carry, xs):
         (num_flat, num_big), den, lsum = carry
-        cs, cw, cr = xs
+        cs, cw, cr, bound = xs
         if restore_x is not None:      # flat_stack: image shape back,
             cs = restore_x(cs)         # O(chunk) per trip
-        vs, losses = jax.vmap(one)(cs, cr)
+        vs, losses = jax.vmap(one, in_axes=(0, 0, None))(cs, cr, bound)
         with jax.named_scope(scopes.FED_AGGREGATE):
             if client_transform is not None:
                 vs = jax.vmap(client_transform,
@@ -321,16 +386,28 @@ def chunked_weighted_train(trainer, variables, cohort, weights, rngs,
     # the while itself, the flat_stack restore, the per-client training);
     # the aggregation fold inside the body belongs to its own, inner scope
     with jax.named_scope(scopes.FED_LOCAL_TRAIN):
+        k_local, order, bounds = weights.shape[0], None, None
+        if ragged_batches:
+            trips = batch_trips(cohort["mask"])
+            if trainer.batch_axes:     # a batch shard sees bs/n samples
+                trips = jax.lax.pmax(trips, trainer.batch_axes)
+            order, bounds = order_by_trips(
+                jnp.where(weights > 0, trips, 0), chunk_cap)
+            cohort, weights, rngs = jax.tree.map(
+                lambda a: a[order], (cohort, weights, rngs))
         cohort, weights, rngs = pad_and_chunk(cohort, weights, rngs,
                                               chunk_cap)
         ((num_flat, num_big), den, lsum), flats = jax.lax.scan(
-            chunk_body, (zeros, zf, zf), (cohort, weights, rngs))
+            chunk_body, (zeros, zf, zf), (cohort, weights, rngs, bounds))
     with jax.named_scope(scopes.FED_AGGREGATE):
         packed = iter(unflatten_carry_f32(num_flat, packed_spec))
         own = iter(num_big)
         num = jax.tree.unflatten(
             treedef, [next(own) if b else next(packed) for b in big])
     if emit_flat_params:
+        if order is not None:          # rows back where the cohort had them
+            rows = flats.reshape(-1, flats.shape[-1])
+            flats = rows.at[order].set(rows[:k_local]).reshape(flats.shape)
         return num, den, lsum, flats
     return num, den, lsum
 
@@ -360,6 +437,15 @@ class MeshFedAvgEngine(FedAvgEngine):
     average's small increments need the f32 grid; the 13 local steps at
     lr≫ulp do not).  Measured on v5e: 2.310 → 2.080 s/round at chunk 4
     (bf16 masters at chunk 4 against f32 masters at chunk 8).
+
+    A population that leaves batches of the client stack empty (clients
+    of unequal size: the engine reads it off the resident masks at
+    construction) gets its cohort ordered by the batch trips each client
+    needs, and each chunk's batch loop ends at the chunk's own longest
+    client instead of the stack's cap (chunked_weighted_train,
+    `ragged_batches`): the steps left out were numeric no-ops.  An
+    equal-sized population compiles to the static loop.  Measured on
+    v5e: PERF.md §6 PR 29.
 
     A mesh with a "batch" axis (make_mesh_batch) additionally splits each
     client's per-step batch over that axis — per-client SAMPLE parallelism
@@ -479,6 +565,13 @@ class MeshFedAvgEngine(FedAvgEngine):
         if self._stack_u8:
             self._prepare_uint8_stack(data)
         super().__init__(trainer, data, cfg, donate=donate)
+        # a client whose last real batch comes before the stack's last
+        # leaves trips that train nothing: where any client does, the
+        # round program orders its cohort and bounds each chunk's batch
+        # loop; where none does (equal-sized clients) it is the static
+        # program (population_trips)
+        self._client_trips, ragged = population_trips(data)
+        self._ragged_batches = self._bounds_batch_loop and ragged
         self._stack = None           # sharded client stack, uploaded lazily
         self._stack_weights = None
         # stack/stack_w are explicit (pre-sharded) args, not closed-over
@@ -548,6 +641,9 @@ class MeshFedAvgEngine(FedAvgEngine):
     # profile rows and compile attribution name the right family in the
     # hlo_copy_audit taxonomy
     _family_stem = "fedavg"
+    # an engine whose chunk body is its own (FedNova's) runs the static
+    # batch loop whatever the population
+    _bounds_batch_loop = True
 
     def _program_family_name(self, streaming: bool,
                              stream_block) -> str:
@@ -712,7 +808,8 @@ class MeshFedAvgEngine(FedAvgEngine):
             self.trainer, local_vars, cohort, weights, client_rngs,
             self.cfg.epochs, vary_axes=axes, chunk_cap=self.chunk,
             client_transform=self.client_transform,
-            restore_x=self._restore_chunk_x)
+            restore_x=self._restore_chunk_x,
+            ragged_batches=self._ragged_batches)
         with jax.named_scope(scopes.FED_AGGREGATE):
             return (jax.lax.psum(num, axes), jax.lax.psum(den, axes),
                     jax.lax.psum(lsum, axes))
@@ -909,10 +1006,10 @@ class MeshFedAvgEngine(FedAvgEngine):
                       **self._round_attr(round_idx)), \
                 self.transfer_stats.uploading():
             cohort = self._host_gather_upload(ids, round_idx)
-            w = np.take(np.asarray(self.data.client_num_samples,
-                                   np.float32), ids) * wmask
+            w = self._lane_weights(ids, wmask)
             self.transfer_stats.add_h2d_bytes(w.nbytes)
             weights = jax.device_put(w, client_sharding(self.mesh))
+        self._count_batch_trips(ids, w)
         return cohort, weights
 
     # -- block-streamed round (stream_block) ---------------------------------
@@ -953,6 +1050,7 @@ class MeshFedAvgEngine(FedAvgEngine):
                 np.asarray(w_blk).nbytes + np.asarray(rngs_blk).nbytes)
             weights = jax.device_put(w_blk, client_sharding(self.mesh))
             rngs = jax.device_put(rngs_blk, client_sharding(self.mesh))
+        self._count_batch_trips(ids_blk, w_blk)
         return block, weights, rngs
 
     def _pad_to_block(self, ids, wmask):
@@ -1008,8 +1106,7 @@ class MeshFedAvgEngine(FedAvgEngine):
         below by upload bandwidth."""
         ids, wmask = self._sample_padded_np(round_idx)
         ids, wmask, spans = self._pad_to_block(ids, wmask)
-        w_all = (np.take(np.asarray(self.data.client_num_samples,
-                                    np.float32), ids) * wmask)
+        w_all = self._lane_weights(ids, wmask)
         rng, agg_rng = jax.random.split(rng)
         crngs = np.asarray(jax.random.split(rng, len(ids)))
         self.transfer_stats.round_start()
@@ -1050,8 +1147,37 @@ class MeshFedAvgEngine(FedAvgEngine):
         with obs.span(scopes.SPAN_SAMPLE, round=int(round_idx)):
             return pad_ids(self.sampler.sample(round_idx), self.n_shards)
 
+    def _lane_weights(self, ids, wmask) -> np.ndarray:
+        """The aggregation weights of a round's lanes, as the resident
+        take computes them in-program: sample counts, 0 on pad lanes."""
+        return np.take(np.asarray(self.data.client_num_samples,
+                                  np.float32), ids) * wmask
+
+    def _count_batch_trips(self, ids, live) -> None:
+        """Host-side count of the batch trips ONE round program runs on
+        these lanes (mesh-padded ids; `live` > 0 where a lane holds a
+        client — its wmask or its weight, a client of no samples needs no
+        trip either way), beside what the static loop would run:
+        `order_by_trips` on the host-known trips of the sampled ids, a
+        client shard at a time as the program chunks them — the same
+        helper on the same numbers as the program's, so no device sync
+        (microseconds; tests pin it to the in-program bounds)."""
+        n_batches = np.shape(self.data.client_shards["mask"])[1]
+        k_local = len(ids) // self.n_shards
+        chunk, pad = chunk_shape(k_local, self.chunk)
+        static = self.n_shards * ((k_local + pad) // chunk) * n_batches
+        ran = static
+        if self._ragged_batches:
+            trips = np.where(np.asarray(live) > 0,
+                             self._client_trips[np.asarray(ids)], 0)
+            ran = sum(int(order_by_trips(t, self.chunk)[1].sum())
+                      for t in trips.reshape(self.n_shards, k_local))
+        self.transfer_stats.add_batch_trips(self.cfg.epochs * ran,
+                                            self.cfg.epochs * static)
+
     def sample_padded(self, round_idx: int):
         ids, wmask = self._sample_padded_np(round_idx)
+        self._count_batch_trips(ids, wmask)
         # the resident round's only per-round host→device put
         with obs.span(scopes.SPAN_ARGS_PUT, round=int(round_idx)):
             return jnp.asarray(ids), jnp.asarray(wmask)
@@ -1207,6 +1333,7 @@ class MeshFedNovaEngine(MeshFedAvgEngine):
     state is one weighted τ accumulator in the chunk-scan carry."""
 
     _family_stem = "fednova"
+    _bounds_batch_loop = False     # _shard_sums below: the static loop
 
     @staticmethod
     def _split(v):
@@ -1437,7 +1564,8 @@ class MeshRobustEngine(MeshFedAvgEngine):
         num, den, lsum, flats = chunked_weighted_train(
             self.trainer, local_vars, cohort, weights, client_rngs,
             self.cfg.epochs, vary_axes=axes, chunk_cap=self.chunk,
-            emit_flat_params=True, restore_x=self._restore_chunk_x)
+            emit_flat_params=True, restore_x=self._restore_chunk_x,
+            ragged_batches=self._ragged_batches)
         rest_num = {k: v for k, v in num.items() if k != "params"}
         # [n_chunks, chunk, P] -> this shard's clients; drop the in-chunk
         # pad lanes (they sit at the STATIC tail of the local stack)
@@ -1513,7 +1641,8 @@ class MeshRobustEngine(MeshFedAvgEngine):
             num, den, lsum, flats = chunked_weighted_train(
                 self.trainer, local_vars, cohort, w, r, self.cfg.epochs,
                 vary_axes=axes, chunk_cap=self.chunk,
-                emit_flat_params=True, restore_x=self._restore_chunk_x)
+                emit_flat_params=True, restore_x=self._restore_chunk_x,
+                ragged_batches=self._ragged_batches)
             flats = flats.reshape(-1, flats.shape[-1])[:w.shape[0]]
             rest = {k: x for k, x in num.items() if k != "params"}
             return (jax.lax.psum(rest, axes), jax.lax.psum(den, axes),
@@ -1599,8 +1728,7 @@ class MeshRobustEngine(MeshFedAvgEngine):
         ids, wmask = self._sample_padded_np(round_idx)
         assert wmask.all(), "order statistics cannot ignore padded lanes"
         K = len(ids)
-        w_all = np.take(np.asarray(self.data.client_num_samples,
-                                   np.float32), ids) * wmask
+        w_all = self._lane_weights(ids, wmask)
         rng, agg_rng = jax.random.split(rng)
         crngs = np.asarray(jax.random.split(rng, K))
         self.transfer_stats.round_start()

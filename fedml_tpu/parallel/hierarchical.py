@@ -31,8 +31,8 @@ from fedml_tpu.algorithms.fedavg import FedAvgEngine
 from fedml_tpu.core.trainer import ClientTrainer
 from fedml_tpu.data.federated import FederatedData
 from fedml_tpu.parallel.engine import (cast_local, chunked_weighted_train,
-                                       flatten_stack_x, restore_chunk_x,
-                                       default_chunk)
+                                       flatten_stack_x, population_trips,
+                                       restore_chunk_x, default_chunk)
 from fedml_tpu.parallel.mesh import (CLIENT_AXIS, SILO_AXIS, make_mesh_2d,
                                      pvary_tree)
 from fedml_tpu.utils.config import FedConfig
@@ -73,6 +73,9 @@ class MeshHierarchicalEngine(FedAvgEngine):
         assert C % self.n_silos == 0, (
             f"{C} clients cannot split into {self.n_silos} silos")
         self.clients_per_silo = C // self.n_silos
+        # a population that leaves batches of the stack empty gets the
+        # ordered cohort and the bounded batch loop (engine.py)
+        self._ragged_batches = population_trips(data)[1]
         self._stack = None
         self._stack_w = None
         from fedml_tpu.obs import programs as obs_programs
@@ -165,7 +168,8 @@ class MeshHierarchicalEngine(FedAvgEngine):
                     vary_axes=(SILO_AXIS, CLIENT_AXIS),
                     chunk_cap=self.chunk,
                     restore_x=lambda cs: restore_chunk_x(
-                        self._x_image_shape, cs))
+                        self._x_image_shape, cs),
+                    ragged_batches=self._ragged_batches)
                 num = jax.lax.psum(num, CLIENT_AXIS)        # ICI tier
                 den = jax.lax.psum(den, CLIENT_AXIS)
                 silo_vars = jax.tree.map(

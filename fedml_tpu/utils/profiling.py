@@ -129,6 +129,14 @@ class TransferOverlapStats:
         # not just walls — round_records() carries h2d_bytes per round,
         # and the registry counter is the Prometheus view
         self._m_h2d_bytes = obs.counter("engine_h2d_bytes_total")
+        # batch-loop trips of the round programs dispatched since
+        # reset(), and the trips a loop over every batch of the stack
+        # would have run: their ratio is how far the bounded batch loop
+        # of a ragged cohort engages (parallel/engine.py order_by_trips;
+        # the benchmark's batch_trips_pct)
+        self._m_batch_trips = obs.counter("engine_batch_trips_total")
+        self._m_batch_trips_static = obs.counter(
+            "engine_batch_trips_static_total")
         self.reset()
 
     def reset(self) -> None:
@@ -136,6 +144,8 @@ class TransferOverlapStats:
             self._upload_wall = 0.0
             self._wait_wall = 0.0
             self._h2d_bytes = 0
+            self.batch_trips = 0
+            self.batch_trips_static = 0
             self._round_t0: Optional[float] = None
             self._snap = (0.0, 0.0, 0)
             self.rounds: list[dict] = []
@@ -147,6 +157,15 @@ class TransferOverlapStats:
         with self._lock:
             self._h2d_bytes += n
         self._m_h2d_bytes.inc(n)
+
+    def add_batch_trips(self, ran: int, static: int) -> None:
+        """Record one round program's batch-loop trips (the engine's
+        host-side count, made where the round's ids are known)."""
+        with self._lock:
+            self.batch_trips += ran
+            self.batch_trips_static += static
+        self._m_batch_trips.inc(ran)
+        self._m_batch_trips_static.inc(static)
 
     @property
     def h2d_bytes(self) -> int:

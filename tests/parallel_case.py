@@ -71,3 +71,15 @@ def hlo_instructions(text: str):
         m = _HLO_INSTRUCTION.match(line)
         if m:
             yield m.groups()
+
+
+def jaxpr_eqns(jaxpr):
+    """Every equation of a jaxpr, sub-jaxprs (scan, while, shard_map,
+    pjit, custom_vjp bodies) included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from jaxpr_eqns(sub)

@@ -11,7 +11,7 @@ round program on one and on four described chips, the resident round of
 the benchmark's `xdev10of4000` cell (10 of 4,000 clients: the take reads
 the cohort, not the stack), of `so_nwp_lstm` at its published 342,477
 clients (and, at the cell's traffic, what the LSTM's backward time loop
-carries) and of `ouro_2p6b` (0.51 B parameters, with the float32 twin the
+carries and where its batch loop ends) and of `ouro_2p6b` (0.51 B parameters, with the float32 twin the
 reference check runs), and the documented C = 128 size limit of the
 fused robust aggregation.
 
@@ -321,6 +321,41 @@ def test_solstm_backward_time_loop_carries_no_kernel_gradient(topo):
     assert not [line for line in text.splitlines() if re.match(
         r"\s*(ROOT )?%?[\w.\-]*slice_add[\w.\-]* = \w+\[2,(670|96),670\]",
         line)]
+
+
+@pytest.mark.slow
+def test_solstm_batch_loop_runs_to_one_scalar_bound_a_chunk(topo):
+    """The structural pin of the bounded batch loop (`core/trainer.py::
+    local_train`, `batch_bound`; ISSUE 29), at `solstm.xdev50of342k`'s
+    shapes: the population is ragged, so the chunk scan carries one trip
+    bound a chunk (`s32[25]`) and holds ONE batch `while`, whose condition
+    compares the trip index with a scalar taken from its own carry — the
+    chunk's bound, not a constant 8 and not a vector over the two lanes —
+    and no `select` anywhere picks between two copies of a lane's
+    kernels (what a per-lane bound under `vmap` becomes: a select of the
+    whole train state at every step)."""
+    from parallel_case import hlo_instructions
+    text = _resident_round(
+        topo, *_bench_files("so_nwp_lstm", "xdev50of342k")).as_text()
+    whiles = [line for line in text.splitlines() if " while(" in line]
+    chunk_scan = [w for w in whiles
+                  if 'op_name="jit(_mesh_round)/fed_local_train/while"' in w]
+    batch_loop = [w for w in whiles if re.search(
+        r'op_name="[^"]*/fed_local_train/while/body/closed_call/vmap\(\)'
+        r'/while/body/closed_call/while"', w)]
+    assert len(chunk_scan) == 1 and len(batch_loop) == 1, whiles
+    assert re.search(r"s32\[25\]", chunk_scan[0].split(" while(")[0])
+    cond = re.search(r"condition=%([\w.\-]+)", batch_loop[0]).group(1)
+    body = text.split(f"\n%{cond} (", 1)[1].split("\n}\n", 1)[0]
+    root = next(line for line in body.splitlines() if "ROOT " in line)
+    assert re.search(r"pred\[\]\S* compare\(%get-tuple-element[\w.]*, "
+                     r"%get-tuple-element[\w.]*\), direction=LT", root), root
+    kernels = {"2,670,670", "2,96,670", "2,670,2680", "2,96,2680",
+               "2,10004,96", "2,96,10004", "2,670,96"}
+    selects = [(name, result) for name, result, opcode, _ in
+               hlo_instructions(text) if opcode == "select"
+               and re.match(r"\w+\[([\d,]*)\]", result).group(1) in kernels]
+    assert not selects, selects
 
 
 @pytest.mark.slow
