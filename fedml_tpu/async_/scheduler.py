@@ -331,10 +331,7 @@ class AsyncFedAvgEngine(FedAvgEngine):
             slots = self.concurrency - reg.count_in_flight
             if slots <= 0 or reg.count_free == 0:
                 return
-            # sample_fast: the non-mutating bitwise twin of the
-            # reference draw (core/sampling.py, ISSUE 10) — same
-            # cohorts, no global-RNG reseed per wave
-            draw = self.sampler.sample_fast(wave_idx)
+            draw = self.sampler.sample(wave_idx)
             ids = draw[reg.status_of(draw) == _reg.FREE][:slots]
             if ids.size == 0:   # the draw missed every free client:
                 ids = reg.free_ids(slots)     # take the pool directly

@@ -60,7 +60,8 @@ def _to_numpy(tree: Pytree) -> Pytree:
 class FedAvgAggregator:
     """Server-side round state (FedAVGAggregator.py:24-108): receive slots,
     all-received barrier, sample-weighted average, deterministic per-round
-    client sampling (np.random.seed(round_idx), :90-98).
+    client sampling (:90-98: the reference's draw for round_idx, from a
+    private generator — core/sampling.py).
 
     `secure` (ISSUE 20) swaps the plaintext slots for the secure data
     plane's SecureAggregator: uploads arrive as masked field rows and

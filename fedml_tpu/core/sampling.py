@@ -5,16 +5,38 @@ Reproduces the reference's sampling semantics exactly
 fedml_api/distributed/fedavg/FedAVGAggregator.py:90-98):
 ``np.random.seed(round_idx); np.random.choice(range(N), k, replace=False)``
 — so runs are comparable round-for-round with the reference, and the
-equivalence oracle (BASELINE.md) stays valid.  A JAX-native sampler is also
-provided for fully-jitted round loops.
+equivalence oracle (BASELINE.md) stays valid.  The semantics are the
+reference's, the generator is private: the same cohorts from a legacy
+`RandomState` of this module's own, without the reference's re-seed of
+the process-global numpy RNG and without its Python `range(N)`.  A
+JAX-native sampler is also provided for fully-jitted round loops.
 """
 from __future__ import annotations
 
+import threading
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+
+_generators = threading.local()
+
+
+def _thread_generator() -> np.random.RandomState:
+    """This thread's legacy generator, which `ClientSampler.sample`
+    re-seeds before every draw.  Held, because building a RandomState
+    costs ten times a small population's draw (0.15 ms on the
+    builder's CPU) for a state that `seed(r)` overwrites whole; per
+    thread, because the server paths draw from real threads
+    (comm/fedavg_messaging.py) and a shared generator could be
+    re-seeded between another thread's seed and its choice.  It carries
+    nothing from one draw to the next."""
+    rs = getattr(_generators, "rs", None)
+    if rs is None:
+        rs = _generators.rs = np.random.RandomState(0)
+    return rs
 
 
 class ClientSampler:
@@ -41,37 +63,30 @@ class ClientSampler:
                 n_total, cfg.client_num_in_total, n_total)
         return cls(n_total, cfg.client_num_per_round)
 
-    def sample(self, round_idx: int) -> np.ndarray:
+    def sample(self, round_idx: int,
+               k: Optional[int] = None) -> np.ndarray:
+        """The reference's draw for `round_idx`, bit for bit, from a
+        private generator.  `np.random.seed(r); np.random.choice(
+        range(N), k, replace=False)` delegates to the global legacy
+        RandomState, so a private legacy `RandomState` seeded with `r`
+        walks the identical Mersenne-Twister stream, and `choice` on
+        the INTEGER N indexes the same permutation the range-array path
+        takes (pinned against the two lines in tests/test_scale.py).
+        It must stay the legacy RandomState: `default_rng` walks
+        another stream.  Still one O(N) numpy permutation a draw, but
+        ndarray scratch (2.7 MB of int64 at N = 342,477), not N boxed
+        Python ints, and the global numpy RNG is left alone: nothing
+        else in the process loses its state, and any thread may draw.
+        `k` overrides the cohort size (the streaming sampler's
+        variable-width draws)."""
+        k = self.client_num_per_round if k is None else int(k)
         # >= (not ==): per_round beyond the population is full
         # participation too, and must agree with sample_jax's branch so
         # cohort ordering (and thus rng-lane pairing) matches
-        if self.client_num_per_round >= self.client_num_in_total:
-            return np.arange(self.client_num_in_total, dtype=np.int64)
-        num = min(self.client_num_per_round, self.client_num_in_total)
-        np.random.seed(round_idx)  # deterministic, matches reference
-        return np.asarray(
-            np.random.choice(range(self.client_num_in_total), num, replace=False),
-            dtype=np.int64,
-        )
-
-    def sample_fast(self, round_idx: int,
-                    k: Optional[int] = None) -> np.ndarray:
-        """BITWISE-equal twin of `sample` that neither reseeds the
-        GLOBAL numpy RNG nor builds a Python `range(N)` list — the
-        cross-device fast path (ISSUE 10): `np.random.seed(r)` +
-        `np.random.choice(range(N), ...)` delegates to a global legacy
-        RandomState, so a PRIVATE `RandomState(r)` walks the identical
-        Mersenne-Twister stream (and `choice(N, ...)` indexes the same
-        permutation the range-array path takes) — cross-pinned against
-        the oracle in tests/test_scale.py.  Per draw this is still an
-        O(N) numpy permutation internally, but transient ndarray scratch
-        instead of an O(N) boxed-int list, and concurrency-safe: nothing
-        else sharing the process loses its RNG state.  `k` overrides the
-        cohort size (the streaming sampler's variable-width draws)."""
-        k = self.client_num_per_round if k is None else int(k)
         if k >= self.client_num_in_total:
             return np.arange(self.client_num_in_total, dtype=np.int64)
-        rs = np.random.RandomState(round_idx)
+        rs = _thread_generator()
+        rs.seed(round_idx)
         return np.asarray(
             rs.choice(self.client_num_in_total, k, replace=False),
             dtype=np.int64,

@@ -2,8 +2,9 @@
 
 Parity: fedml_api/data_preprocessing/MNIST/mnist_mobile_preprocessor.py —
 pre-computes, for each of `client_num_per_round` devices, the client ids it
-will play across `comm_round` rounds (the SAME deterministic
-np.random.seed(round_idx) sampler as training) and writes per-device LEAF
+will play across `comm_round` rounds (the SAME deterministic sampler as
+training: the reference's draw for round_idx, from a private generator —
+core/sampling.py) and writes per-device LEAF
 JSONs: `<out>/<device>/train/train.json` and `<out>/<device>/test/test.json`
 with `users` / `num_samples` / `user_data` restricted to those clients.
 The mobile runtime then ships one small JSON per device instead of the full

@@ -996,12 +996,11 @@ class MeshFedAvgEngine(FedAvgEngine):
 
     def _stream_gather(self, ids, wmask, round_idx=None):
         """The upload half of stream_cohort, split from the sampling:
-        this part is what runs on the prefetch thread (_round_args) —
-        the SAMPLER must stay on the caller thread because it reseeds
-        the process-global numpy RNG (core/sampling.py), which a
-        background thread would race.  The wall lands in transfer_stats
-        from whichever thread runs it; `round_idx` (the round the cohort
-        is FOR) rides its spans."""
+        this part is what runs on the prefetch thread (_round_args);
+        the sampler's draw stays on the caller thread (milliseconds at
+        most, and it decides what the thread is asked to gather).  The
+        wall lands in transfer_stats from whichever thread runs it;
+        `round_idx` (the round the cohort is FOR) rides its spans."""
         with obs.span("h2d.upload_cohort", clients=len(ids),
                       **self._round_attr(round_idx)), \
                 self.transfer_stats.uploading():
@@ -1215,9 +1214,8 @@ class MeshFedAvgEngine(FedAvgEngine):
             # cast + device_put (_stream_gather) runs on a background
             # thread (AsyncValue) while round r computes — the HOST side
             # of the upload no longer serializes with the round loop.
-            # SAMPLING stays on THIS thread either way: the sampler
-            # reseeds the process-global numpy RNG, which a background
-            # thread would race (and the knob must not change cohorts).
+            # SAMPLING stays on THIS thread either way (the knob must
+            # not change cohorts).
             # With prefetch=False the gather runs inline here, the old
             # synchronous path, recorded as consumer wait (unhidden).
             # Two cohorts live on device, bounded.  The base run()

@@ -113,8 +113,9 @@ class MeshHierarchicalEngine(FedAvgEngine):
     # -- sampling: per-silo cohort ids for every inner round ----------------
     def sample_inner_rounds(self, global_round: int):
         """ids[g_round, silo, K_pad] (silo-local indices) + wmask like it.
-        Reference seed discipline: np.random.seed(round) per sampling call
-        (group.py / fedavg_api.py:83-91)."""
+        Reference seed discipline (group.py / fedavg_api.py:83-91): the
+        reference's draw per sampling call, from a private generator
+        seeded with the call's round."""
         K = min(self.cfg.client_num_per_round, self.clients_per_silo)
         Kp = K + ((-K) % self.per_silo_shards)
         G = self.group_comm_round

@@ -1,10 +1,9 @@
 """Streaming cohort sampling over the sharded client registry.
 
 `ClientSampler` (core/sampling.py) draws uniform cohorts by permuting
-the whole population — exact reference semantics, O(N) per draw, and
-(before PR 10) it reseeded the GLOBAL numpy RNG and built a Python
-`range(N)` list.  At a million clients the server needs cohort draws
-that (a) never materialize the population, (b) respect an eligibility
+the whole population — exact reference semantics, O(N) per draw.  At a
+million clients the server needs cohort draws that (a) never
+materialize the population, (b) respect an eligibility
 mask from the registry (banned/dead/crashed/in-flight clients are not
 candidates; repeat-quarantined clients auto-BAN past the registry's
 `quarantine_ban_threshold` — below it a quarantined sender returns to
@@ -16,8 +15,8 @@ differ).
 
 Three modes:
 
-    uniform     the degenerate anchor: ClientSampler.sample_fast (the
-                non-mutating exact twin of the reference draw) filtered
+    uniform     the degenerate anchor: ClientSampler.sample (the
+                reference's draw, from a private generator) filtered
                 by eligibility — with every client eligible this
                 reproduces the existing ClientSampler cohorts BITWISE,
                 which is what pins the new spine to the old sampler.
@@ -98,7 +97,7 @@ class StreamingCohortSampler:
             self._note_scratch(out)
             return out
         if self.mode == "uniform":
-            draw = self._uniform.sample_fast(round_idx, k=k)
+            draw = self._uniform.sample(round_idx, k=k)
             keep = reg.eligible(draw)
             out = draw[keep][:k]
             if out.size < k:

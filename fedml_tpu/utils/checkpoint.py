@@ -4,7 +4,8 @@ The reference has essentially no FL-state checkpointing (SURVEY.md §5:
 FedGKT saves a server .pth.tar, DARTS saves genotypes, nothing resumes a
 round).  Here any engine's (variables, server_state, round_idx) checkpoints
 atomically every N rounds and training resumes exactly — the deterministic
-per-round client sampler (np.random.seed(round_idx)) makes a resumed run
+per-round client sampler (the reference's draw for round_idx, from a
+private generator: core/sampling.py) makes a resumed run
 bitwise-identical to an uninterrupted one.
 """
 from __future__ import annotations

@@ -4,9 +4,10 @@ Anchors, in order of importance:
 
 * Degenerate sampling pin: the streaming cohort sampler in uniform mode
   with a fully-eligible registry reproduces the existing ClientSampler
-  cohorts BITWISE, and ClientSampler.sample_fast is the bitwise
-  non-mutating twin of the reference `sample` — the new spine is
-  anchored to the old sampler, not merely plausible.
+  cohorts BITWISE, and ClientSampler.sample is the reference's two
+  lines bit for bit, from a private generator that leaves the global
+  numpy RNG alone — the new spine is anchored to the old sampler, not
+  merely plausible.
 * Statistical pins: reservoir and stratified draws are chi-square
   uniform at a fixed seed, deterministic per seed, two seeds differ
   (the chaos/adversary seeded-stream convention).
@@ -37,38 +38,130 @@ from fedml_tpu.scale import registry as R
 from parallel_case import _mnist_like_cfg, _setup
 
 
-# -- ClientSampler fast path (satellite) -------------------------------------
+# -- ClientSampler: the reference's draw from a private generator ------------
 
-def test_sample_fast_bitwise_matches_reference_oracle():
-    """The non-mutating fast path IS the reference draw: np.random.seed
-    + global choice(range(N)) delegates to a global RandomState, so a
-    private RandomState(round) walks the identical stream — cross-
-    pinned bitwise over populations and rounds, including the
-    full-participation branch."""
-    for n, k in ((100, 10), (1000, 16), (4096, 128), (8, 16)):
-        s = ClientSampler(n, k)
-        for r in (0, 1, 7, 12345):
-            np.testing.assert_array_equal(s.sample(r), s.sample_fast(r))
+def _reference_draw(n, k, r):
+    """The reference's two lines (FedAVGAggregator.client_sampling),
+    written out: the oracle `ClientSampler.sample` is held to."""
+    np.random.seed(r)
+    return np.random.choice(range(n), k, replace=False)
 
 
-def test_sample_fast_does_not_mutate_global_rng():
+@pytest.mark.parametrize("n,k", [
+    (100, 10), (1000, 16),
+    # the five benchmark cells' populations and cohorts
+    (256, 4), (1024, 128), (4000, 10), (4096, 128), (342_477, 50)])
+def test_sample_bitwise_matches_reference_oracle(n, k):
+    """A private legacy RandomState seeded with the round walks the
+    stream np.random.seed + the global choice(range(N)) walk: the same
+    cohort bit for bit, over populations and rounds."""
+    s = ClientSampler(n, k)
+    for r in (0, 1, 7, 12345):
+        got = s.sample(r)
+        assert got.dtype == np.int64
+        np.testing.assert_array_equal(got, _reference_draw(n, k, r))
+
+
+@pytest.mark.parametrize("n,k", [(8, 16), (16, 16)])
+def test_sample_full_participation_is_arange(n, k):
+    """k >= N draws nothing: every client, in id order (the reference's
+    own full-participation branch)."""
+    got = ClientSampler(n, k).sample(5)
+    assert got.dtype == np.int64
+    np.testing.assert_array_equal(got, np.arange(n))
+
+
+def test_sample_does_not_mutate_global_rng():
     np.random.seed(4242)
     before = np.random.get_state()
-    ClientSampler(10_000, 64).sample_fast(7)
+    ClientSampler(10_000, 64).sample(7)
     after = np.random.get_state()
     assert before[0] == after[0]
     np.testing.assert_array_equal(before[1], after[1])
     assert before[2:] == after[2:]
-    # ...while the reference path famously does mutate
-    ClientSampler(10_000, 64).sample(7)
+    # ...while the reference's two lines famously do
+    _reference_draw(10_000, 64, 7)
     assert not np.array_equal(before[1], np.random.get_state()[1])
 
 
-def test_sample_fast_k_override():
+def test_sample_k_override():
     s = ClientSampler(1000, 16)
-    a = s.sample_fast(3, k=5)
+    a = s.sample(3, k=5)
     assert a.shape == (5,) and len(np.unique(a)) == 5
-    np.testing.assert_array_equal(s.sample_fast(3, k=16), s.sample(3))
+    np.testing.assert_array_equal(a, _reference_draw(1000, 5, 3))
+    np.testing.assert_array_equal(s.sample(3, k=16), s.sample(3))
+    np.testing.assert_array_equal(s.sample(3, k=1000), np.arange(1000))
+
+
+def test_sample_builds_no_python_range():
+    """Structural, not a timing: at the StackOverflow population one
+    draw's scratch is the int64 permutation (2.7 MB); the reference's
+    range(N) is ~12 MB of boxed ints on top of it."""
+    import tracemalloc
+    s = ClientSampler(342_477, 50)
+    s.sample(0)     # this thread's generator exists before the count
+    tracemalloc.start()
+    try:
+        s.sample(1)
+        _, peak = tracemalloc.get_traced_memory()
+        tracemalloc.reset_peak()
+        _reference_draw(342_477, 50, 1)
+        _, ref_peak = tracemalloc.get_traced_memory()
+    finally:
+        tracemalloc.stop()
+    assert peak < 8e6, peak
+    assert ref_peak > 8e6, ref_peak   # the bound tells the two apart
+
+
+def test_engine_sample_padded_np_is_the_oracle_padded():
+    """Every benchmark cell's round draws through this: the oracle's
+    ids, padded to a mesh multiple with zero-weight lanes."""
+    from fedml_tpu.parallel.engine import MeshFedAvgEngine
+    from fedml_tpu.parallel.mesh import make_mesh
+    cfg = _mnist_like_cfg(client_num_in_total=16, client_num_per_round=5)
+    trainer, data = _setup(cfg)
+    eng = MeshFedAvgEngine(trainer, data, cfg, mesh=make_mesh(2),
+                           donate=False)
+    assert type(eng.sampler) is ClientSampler
+    for r in (0, 3, 11):
+        ids, wmask = eng._sample_padded_np(r)
+        np.testing.assert_array_equal(ids[:5], _reference_draw(16, 5, r))
+        np.testing.assert_array_equal(ids[5:], [0])
+        np.testing.assert_array_equal(wmask, [1, 1, 1, 1, 1, 0])
+
+
+def test_sample_threads_draw_their_own_rounds():
+    """One sampler, more threads than cores, each drawing its own
+    rounds with the switch interval shortened: a generator shared
+    between them would be re-seeded between one thread's seed and its
+    choice, and a cohort would be another round's."""
+    import sys
+    import threading
+    s = ClientSampler(5000, 32)
+    n_threads, draws = 2 * (os.cpu_count() or 4), 60
+    want = {r: _reference_draw(5000, 32, r)
+            for r in range(n_threads * draws)}
+    wrong, start = [], threading.Barrier(n_threads)
+
+    def worker(t):
+        start.wait(timeout=60)
+        for r in range(t * draws, (t + 1) * draws):
+            if not np.array_equal(s.sample(r), want[r]):
+                wrong.append(r)
+
+    threads = [threading.Thread(target=worker, args=(t,))
+               for t in range(n_threads)]
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(timeout=120)
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(th.is_alive() for th in threads)
+    assert wrong == []
 
 
 # -- registry ----------------------------------------------------------------
