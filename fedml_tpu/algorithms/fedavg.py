@@ -94,14 +94,21 @@ class FedAvgEngine:
         client_rngs = jax.random.split(rng, K)
         global_params = variables["params"] if self.trainer.prox_mu > 0 else None
 
+        # a model that freezes part of its parameters (ClientTrainer.
+        # split_frozen) trains, stacks and averages the rest; the frozen
+        # leaves are read un-mapped and come back as they went in
+        trained_of = self.trainer.trained_variables
+
         def one_client(shard, crng):
-            return self.trainer.local_train(
+            v, loss, n = self.trainer.local_train(
                 variables, shard, crng, self.cfg.epochs,
                 global_params=global_params)
+            return trained_of(v), loss, n
 
         stacked_vars, losses, ns = jax.vmap(one_client)(cohort, client_rngs)
         new_variables, server_state = self.aggregate(
-            stacked_vars, ns, variables, server_state, agg_rng)
+            stacked_vars, ns, trained_of(variables), server_state, agg_rng)
+        new_variables = self.trainer.with_frozen(new_variables, variables)
         train_loss = jnp.sum(losses * ns) / jnp.sum(ns)
         return new_variables, server_state, {"train_loss": train_loss}
 

@@ -19,6 +19,7 @@ unequal client dataset sizes become padding, not data-dependent control flow
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import functools
 from typing import Any, Callable, Optional
 
@@ -40,12 +41,24 @@ class TrainState:
     variables: Pytree          # {"params": ..., ["batch_stats": ...]}
     opt_state: Pytree
     rng: jax.Array
+    # running sums of the model's counters over the steps so far
+    # (ClientTrainer.counters); empty for a model that counts nothing
+    counts: Pytree = dataclasses.field(default_factory=dict)
 
 
 def _split_variables(variables):
     params = variables["params"]
     rest = {k: v for k, v in variables.items() if k != "params"}
     return params, rest
+
+
+def merge_params(trained, frozen):
+    """The whole parameter tree from its two parts (split_frozen): nested
+    dicts that share no leaf."""
+    out = dict(frozen)
+    for k, v in trained.items():
+        out[k] = merge_params(v, out[k]) if k in out else v
+    return out
 
 
 def make_lr_schedule(mode: str, base_lr: float, total_steps: int,
@@ -231,6 +244,60 @@ class ClientTrainer:
     def init_opt(self, variables: Pytree) -> Pytree:
         return self.tx.init(variables["params"])
 
+    # -- trained and frozen leaves -------------------------------------------
+    def split_frozen(self, params):
+        """(trained, frozen): the leaves local training updates and the
+        leaves it only reads, as two trees that `merge_params` joins.  A
+        model names what it trains by path prefixes under ``params``
+        (``trainable``, e.g. ``("lora",)``: models/lfm2_moe.py); a model
+        that names nothing trains every leaf, and its tree comes back as
+        it is, beside an empty one.  Frozen leaves are not cast, not
+        differentiated, not stepped, carry no optimizer state and are not
+        in the TrainState the batch loop carries; they are read where
+        they were put, by every client of a vmapped chunk alike."""
+        prefixes = getattr(self.model, "trainable", None)
+        if prefixes is None:
+            return params, {}
+
+        def part(tree, path, want):
+            out = {}
+            for k, v in tree.items():
+                name = f"{path}/{k}" if path else k
+                named = any(name == p or name.startswith(p + "/")
+                            for p in prefixes)
+                if named or not isinstance(v, dict):
+                    if named == want:
+                        out[k] = v
+                else:
+                    sub = part(v, name, want)
+                    if sub:
+                        out[k] = sub
+            return out
+
+        return part(params, "", True), part(params, "", False)
+
+    def trained_variables(self, variables):
+        """``variables`` without the frozen leaves of ``params``: what the
+        round trains, folds and averages (the whole of it for a model
+        that freezes nothing)."""
+        trained, frozen = self.split_frozen(variables["params"])
+        return {**variables, "params": trained} if frozen else variables
+
+    def with_frozen(self, trained_variables, variables):
+        """`trained_variables` put back beside the frozen leaves of
+        ``variables``, which come back as the objects they went in as."""
+        _, frozen = self.split_frozen(variables["params"])
+        if not frozen:
+            return trained_variables
+        return {**trained_variables, "params": merge_params(
+            trained_variables["params"], frozen)}
+
+    @property
+    def counters(self) -> dict:
+        """{name: shape} of what the model counts in a forward pass
+        (obs/scopes.py COUNTERS); empty for a model that counts nothing."""
+        return dict(getattr(self.model, "counters", None) or {})
+
     # -- mixed precision ----------------------------------------------------
     def _cast_floats(self, tree, dtype):
         return jax.tree.map(
@@ -239,10 +306,12 @@ class ClientTrainer:
 
     # -- loss ---------------------------------------------------------------
     @jax.named_scope(scopes.FED_FORWARD)
-    def _loss(self, params, rest, batch, rng, global_params=None):
+    def _loss(self, params, rest, batch, rng, global_params=None, frozen=None):
         """Masters (params/opt state/stats) stay float32; when train_dtype
         is bfloat16 the forward/backward compute runs through bf16 casts —
-        the MXU recipe: bf16 matmuls, f32 accumulation and update."""
+        the MXU recipe: bf16 matmuls, f32 accumulation and update.
+        ``frozen`` (split_frozen) joins the parameters as it is stored.
+        Returns (loss, (new stats collections, the step's counters))."""
         x, y, mask = batch["x"], batch["y"], batch["mask"]
         if self.batch_axes:
             # decorrelate the sample-wise randomness (augment offsets,
@@ -259,16 +328,24 @@ class ClientTrainer:
         rngs = {"dropout": rng}
         half = self.train_dtype != jnp.float32
         apply_params = self._cast_floats(params, self.train_dtype) if half else params
+        if frozen:
+            apply_params = merge_params(apply_params, frozen)
         # stats collections (BatchNorm running mean/var) are NOT cast: the
         # EMA must accumulate on the f32 master or sub-0.4%-ulp increments
         # vanish on the bf16 grid near convergence
         apply_rest = rest
         if half and jnp.issubdtype(x.dtype, jnp.floating):
             x = x.astype(self.train_dtype)
-        if apply_rest:
+        counts = {}
+        mutable = list(apply_rest.keys()) + (
+            [scopes.COUNTERS] if self.counters else [])
+        if mutable:
             logits, new_rest = self.model.apply(
                 {"params": apply_params, **apply_rest}, x, train=True,
-                mutable=list(apply_rest.keys()), rngs=rngs)
+                mutable=mutable, rngs=rngs)
+            if self.counters:
+                new_rest = dict(new_rest)
+                counts = new_rest.pop(scopes.COUNTERS)
         else:
             logits = self.model.apply({"params": apply_params}, x, train=True,
                                       rngs=rngs)
@@ -315,20 +392,26 @@ class ClientTrainer:
                 prox = prox / self._revary(
                     jax.lax.psum(jnp.float32(1), self.batch_axes))
             loss = loss + prox
-        return loss, new_rest
+        return loss, (new_rest, counts)
 
     # -- one SGD step -------------------------------------------------------
-    def train_step(self, state: TrainState, batch, global_params=None) -> tuple[TrainState, jax.Array]:
+    def train_step(self, state: TrainState, batch, global_params=None,
+                   frozen=None):
+        """One step on ``state.variables`` (the trained leaves; ``frozen``
+        beside them); the step's counters join ``state.counts`` unless
+        the batch holds no real sample."""
         params, rest = _split_variables(state.variables)
         rng, step_rng = jax.random.split(state.rng)
-        (loss, new_rest), grads = jax.value_and_grad(self._loss, has_aux=True)(
-            params, rest, batch, step_rng, global_params)
+        (loss, (new_rest, counts)), grads = jax.value_and_grad(
+            self._loss, has_aux=True)(
+            params, rest, batch, step_rng, global_params, frozen)
         n_valid = jnp.sum(batch["mask"])
         if self.batch_axes:
             # the full-batch gradient: each shard computed S_l/C_g-normalized
             # grads over its sample slice; one psum per step completes them.
             # Every batch shard then applies the IDENTICAL update, keeping
             # the per-client weights replicated along the batch axes.
+            counts = self._revary(jax.lax.psum(counts, self.batch_axes))
             grads = self._revary(jax.lax.psum(grads, self.batch_axes))
             loss = self._revary(jax.lax.psum(loss, self.batch_axes))
             new_rest = self._revary(jax.lax.pmean(new_rest, self.batch_axes))
@@ -359,16 +442,36 @@ class ClientTrainer:
         return TrainState(
             variables={"params": new_params, **kept_rest},
             opt_state=kept_opt,
-            rng=rng), jnp.where(has_data, loss, 0.0)
+            rng=rng,
+            counts=jax.tree.map(lambda a, c: a + c * g, state.counts, counts)
+            ), jnp.where(has_data, loss, 0.0)
 
     # -- local training: epochs x batches under lax.scan --------------------
     def local_train(self, variables: Pytree, shard, rng: jax.Array,
                     epochs: int, global_params=None,
                     unroll: Optional[int] = None, batch_bound=None):
+        """`local_train_counted` without the counters: (new_variables,
+        mean_loss, n_samples)."""
+        return self.local_train_counted(
+            variables, shard, rng, epochs, global_params=global_params,
+            unroll=unroll, batch_bound=batch_bound)[:3]
+
+    def local_train_counted(self, variables: Pytree, shard, rng: jax.Array,
+                            epochs: int, global_params=None,
+                            unroll: Optional[int] = None, batch_bound=None):
         """Run E local epochs of SGD over one client's padded shard.
 
+        Returns (new_variables, mean_loss, n_samples, counters): the last
+        holds the model's counters summed over this client's real steps
+        ({} for a model that counts nothing).  Where the model freezes
+        part of its parameters (`split_frozen`) the TrainState, the
+        gradient and the optimizer hold the trained leaves alone, and
+        ``new_variables`` holds the frozen ones as they were handed in —
+        under `vmap` they stay un-mapped until the caller returns them,
+        so a vmapped caller returns `trained_variables(new_variables)`.
+
         shard: {"x": [B, bs, ...], "y": [B, bs, ...], "mask": [B, bs]}
-        Returns (new_variables, mean_loss, n_samples). This is the reference's
+        This is the reference's
         client hot loop (my_model_trainer_classification.py:19-53) as a single
         scanned XLA program.  `unroll` (default: the constructor's
         batch_unroll) unrolls the batch scan — measured on v5e at the
@@ -397,6 +500,11 @@ class ClientTrainer:
         compiled program (results stay bitwise obs-on/off).
         """
         unroll = self.batch_unroll if unroll is None else unroll
+        trained, frozen = self.split_frozen(variables["params"])
+        if frozen:
+            variables = {**variables, "params": trained}
+            if global_params is not None:
+                global_params = self.split_frozen(global_params)[0]
         with obs.span("trace.local_train", epochs=epochs, unroll=unroll):
             # tree_vary_noop: align the fresh (replicated-typed) optimizer
             # state with the varying type it takes after step 1 under
@@ -405,6 +513,10 @@ class ClientTrainer:
                 variables=variables,
                 opt_state=tree_vary_noop(self.init_opt(variables), shard),
                 rng=rng)
+            if self.counters:
+                state = state.replace(counts=tree_vary_noop(
+                    {name: jnp.zeros(shape, jnp.float32)
+                     for name, shape in self.counters.items()}, shard))
             # NOTE on the carry layout (PR-4 copy audit): packing this
             # TrainState carry's float leaves into per-dtype flat
             # vectors (the engine.py flatten_carry_f32 treatment) was
@@ -419,7 +531,8 @@ class ClientTrainer:
             # only removes copies.
 
             def batch_body(state, batch):
-                state, loss = self.train_step(state, batch, global_params)
+                state, loss = self.train_step(state, batch, global_params,
+                                              frozen)
                 cnt = jnp.sum(batch["mask"])
                 if self.batch_axes:   # loss is global; weight it globally
                     cnt = self._revary(jax.lax.psum(cnt, self.batch_axes))
@@ -464,7 +577,11 @@ class ClientTrainer:
             n = jnp.sum(shard["mask"])
             if self.batch_axes:   # client's TOTAL sample count (agg weight)
                 n = self._revary(jax.lax.psum(n, self.batch_axes))
-            return state.variables, jnp.mean(epoch_losses), n
+            new_variables = state.variables
+            if frozen:
+                new_variables = {**new_variables, "params": merge_params(
+                    new_variables["params"], frozen)}
+            return new_variables, jnp.mean(epoch_losses), n, state.counts
 
     # -- eval ---------------------------------------------------------------
     def eval_step(self, variables: Pytree, batch):

@@ -44,6 +44,14 @@ def create_model(model_name: str, output_dim: int, input_dim: int | None = None,
         # family, models/looped_lm.py); every width is a keyword
         from fedml_tpu.models.looped_lm import LoopedDecoderLM
         return LoopedDecoderLM(vocab_size=output_dim, **kw)
+    if name == "lfm2_moe":
+        # gated short convolutions + grouped-query attention + sparse
+        # experts, adapters over a frozen base (LFM2-MoE's family,
+        # models/lfm2_moe.py); every width is a keyword, and a pattern
+        # that arrives as a JSON list becomes the tuple a Module hashes
+        from fedml_tpu.models.lfm2_moe import Lfm2MoeLM
+        kw = {k: tuple(v) if isinstance(v, list) else v for k, v in kw.items()}
+        return Lfm2MoeLM(vocab_size=output_dim, **kw)
     if name in ("resnet18_gn", "resnet18"):
         return ResNet18GN(num_classes=output_dim, **kw)
     if name == "resnet56":

@@ -1,0 +1,409 @@
+"""LFM2-MoE — a decoder LM of gated short convolutions, grouped-query
+attention and sparse experts, with low-rank adapters over a frozen base.
+
+The family of LiquidAI's LFM2-24B-A2B (``model_type`` "lfm2_moe"): most
+layers mix tokens with a gated depthwise convolution three tokens wide,
+one layer in four with grouped-query attention; the first
+``num_dense_layers`` layers carry a dense gated MLP, every later one a
+router over ``n_experts`` gated MLPs of which a token visits
+``experts_per_token``.  Every width, the layer pattern, the dense-layer
+count, the layers and the experts held here and the adapter rank are
+constructor arguments; a benchmark configuration carries a published
+model's.
+
+    RMSNorm_w(x) = x * rsqrt(mean(x^2) + eps) * w
+    layer l:  a = RMSNorm_op(h)
+      conv:   [B, C, X] = split3(a W_in)                  (no bias)
+              u = B * X;  v_t = sum_j k_j * u_{t-(K-1)+j}  depthwise, causal,
+              o = (C * v) W_out                            u_{<0} = 0
+      attn:   q = a W_q [H x hd];  k, v = a W_k, a W_v [H_kv x hd]
+              q, k <- RMSNorm over each head (q_norm, k_norm); rotary
+              (rotate-half) on q, k
+              o = softmax_causal(q k^T / sqrt(hd)) v W_o   kv head g serves
+                                                           heads g R .. g R + R - 1
+      h = h + o;  f = RMSNorm_ffn(h)
+      dense (l < num_dense_layers):  m = (silu(f W1) * f W3) W2
+      experts: s = sigmoid(f W_r);  sel = top_k(s + b)     b selects only
+               g = s[sel] / (sum s[sel] + 1e-6) * routed_scaling_factor
+               m = sum_{e in sel, e held} g_e (silu(f W1_e) * f W3_e) W2_e
+      h = h + m
+    model:    h = E[x];  layers;  logits = RMSNorm_out(h) E^T   (tied head)
+    adapter:  y = x W + (alpha / r) (x A) B   on W_in, W_out, W_q, W_k, W_v,
+              W_o of every held layer;  A ~ N(0, 1 / d_in), B = 0
+
+``__call__`` returns float32 logits [B, T, vocab] — the trainer's contract.
+
+How it is built for a chip:
+
+* **The base is frozen and stored in ``base_dtype`` (bfloat16)**; only the
+  adapters train (``trainable``: the trainer reads it as it reads
+  ``loss_scope`` — core/trainer.py).  The compute dtype is the adapters'
+  (the trainer casts what it trains to its ``train_dtype``); a base leaf is
+  cast where it is used, one layer at a time, so nothing ever holds the
+  base in float32.
+* **The expert layer is dropless and grouped**: the ``k x tokens`` slots are
+  sorted by expert, the three products run as ``jax.lax.ragged_dot`` over
+  the experts held (XLA:TPU's grouped-product kernel), and the result is
+  un-sorted and combined.  No capacity, no dropped slot, no loop over
+  masks.  ``held_experts`` is a range of expert ids: routing is over all
+  ``n_experts``, and the part of ``m`` the held experts give is what goes
+  on (a slot of an absent expert adds nothing).
+* **A chunk of clients shares one read of the experts**: under the engine's
+  ``vmap`` over clients the product merges the clients' tokens before it
+  sorts them (``custom_vmap``), instead of running once per client; its
+  backward pass is written out (``custom_vjp``) so that it does the same.
+* every layer is a ``jax.checkpoint`` that saves its input only, as in
+  models/looped_lm.py; the layers are unrolled (each has its own leaves).
+* the router's decisions are counted: tokens routed to every (expert layer,
+  expert) of a step, sown as ``counters/moe_expert_tokens`` where the
+  caller asks for that collection (obs/scopes.py).
+"""
+from __future__ import annotations
+
+import functools
+from typing import Any, Optional
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+from jax.custom_batching import custom_vmap
+
+from fedml_tpu.models.looped_lm import (_dot, apply_rotary, rms_norm,
+                                        rotary_tables)
+from fedml_tpu.obs import scopes
+
+def _adapted(x, lp, ad, name, scale):
+    """x W + scale (x A) B in x's dtype; W cast where it is used."""
+    dt = x.dtype
+    y = _dot(x, lp[name].astype(dt))
+    z = _dot(_dot(x, ad[name + "_a"]).astype(dt), ad[name + "_b"])
+    return (y + scale * z).astype(dt)
+
+
+def short_conv(h, lp, ad, scale, eps):
+    """The gated short convolution on h [B, T, d]."""
+    T = h.shape[1]
+    a = rms_norm(h, lp["op_norm"], eps)
+    b, c, x = jnp.split(_adapted(a, lp, ad, "in_proj", scale), 3, axis=-1)
+    kernel = lp["conv_kernel"].astype(jnp.float32)              # [K, d]
+    K = kernel.shape[0]
+    u = jnp.pad((b * x).astype(jnp.float32), ((0, 0), (K - 1, 0), (0, 0)))
+    v = sum(kernel[j] * u[:, j:j + T] for j in range(K)).astype(h.dtype)
+    return _adapted(c * v, lp, ad, "out_proj", scale)
+
+
+def gqa_attention(h, lp, ad, scale, eps, cos, sin, n_heads, n_kv_heads):
+    """Grouped-query attention with per-head q/k norms on h [B, T, d]."""
+    B, T, _ = h.shape
+    dt = h.dtype
+    a = rms_norm(h, lp["op_norm"], eps)
+    heads = lambda name, n: _adapted(a, lp, ad, name, scale).reshape(B, T, n, -1)
+    q, k, v = heads("wq", n_heads), heads("wk", n_kv_heads), heads("wv", n_kv_heads)
+    q = apply_rotary(rms_norm(q, lp["q_norm"], eps), cos, sin)
+    k = apply_rotary(rms_norm(k, lp["k_norm"], eps), cos, sin)
+    q = q.reshape(B, T, n_kv_heads, n_heads // n_kv_heads, -1)
+    s = jnp.einsum("btgrd,bsgd->bgrts", q, k,
+                   preferred_element_type=jnp.float32) * (q.shape[-1] ** -0.5)
+    causal = jnp.tril(jnp.ones((T, T), bool))
+    s = jnp.where(causal, s, jnp.finfo(jnp.float32).min)
+    w = jax.nn.softmax(s, axis=-1).astype(dt)
+    o = jnp.einsum("bgrts,bsgd->btgrd", w, v,
+                   preferred_element_type=jnp.float32).astype(dt)
+    return _adapted(o.reshape(B, T, -1), lp, ad, "wo", scale)
+
+
+def gated_mlp(f, w1, w3, w2):
+    dt = f.dtype
+    g = jax.nn.silu(_dot(f, w1.astype(dt))) * _dot(f, w3.astype(dt))
+    return _dot(g.astype(dt), w2.astype(dt)).astype(dt)
+
+
+def route(f, router, bias, k: int, scaling: float):
+    """(sel [N, k] expert ids, gate [N, k] float32) for tokens f [N, d]:
+    sigmoid scores, the bias in the selection only, the selected scores
+    normalised to sum to ``scaling``."""
+    s = jax.nn.sigmoid(_dot(f, router.astype(f.dtype)))
+    _, sel = jax.lax.top_k(s + bias.astype(jnp.float32), k)
+    g = jnp.take_along_axis(s, sel, axis=-1)
+    return sel, g / (jnp.sum(g, axis=-1, keepdims=True) + 1e-6) * scaling
+
+
+def _slots(sel, first: int, n_held: int):
+    """The k x N token slots in the order the grouped product wants them:
+    (order [S] — slot ids, those of held experts first, by expert;
+    sizes [n_held] — slots of each held expert; valid [S] — sorted slot
+    belongs to a held expert)."""
+    key = sel.reshape(-1) - first
+    key = jnp.where((key >= 0) & (key < n_held), key, n_held)
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.bincount(key, length=n_held + 1)[:n_held].astype(jnp.int32)
+    return order, sizes, key[order] < n_held
+
+
+def _unsort(rows, order, k: int):
+    """Sorted slot rows [S, ...] back to [N, k, ...]."""
+    inverse = jnp.zeros_like(order).at[order].set(
+        jnp.arange(order.shape[0], dtype=order.dtype))
+    return rows[inverse].reshape((-1, k) + rows.shape[1:])
+
+
+def _grouped(x, w, sizes):
+    """x [S, a] . w_e [a, b] for the rows of each expert e: float32 [S, b]."""
+    return jax.lax.ragged_dot(x, w.astype(x.dtype), sizes,
+                              preferred_element_type=jnp.float32)
+
+
+def _grouped_t(x, w, sizes):
+    """x [S, b] . w_e^T for w [G, a, b]: float32 [S, a] — the backward
+    product.  XLA:TPU's grouped product wants the contracted dimension
+    second, so the compiler copies the experts to that layout; the weights
+    are invariant in the round's loops, so it makes the copy once a round,
+    outside them, and a second copy of every expert layer lives through
+    the round (PERF.md §6 PR 34: what bounds the layers one chip holds)."""
+    return _grouped(x, jnp.swapaxes(w, 1, 2), sizes)
+
+
+def _merged(fn, n_mapped: int):
+    """``fn`` whose first ``n_mapped`` arguments (and every result) lead
+    with rows, as a ``custom_vmap``: mapped over clients, the clients' rows
+    are merged and ``fn`` runs once, on weights that are not mapped."""
+    fn = custom_vmap(fn)
+
+    @fn.def_vmap
+    def rule(axis_size, in_batched, *args):
+        assert not any(in_batched[n_mapped:]), "expert weights are not mapped"
+        rows = [a if b else jnp.broadcast_to(a, (axis_size,) + a.shape)
+                for a, b in zip(args[:n_mapped], in_batched)]
+        flat = [a.reshape((-1,) + a.shape[2:]) for a in rows]
+        outs = fn(*flat, *args[n_mapped:])
+        return (tuple(o.reshape((axis_size, -1) + o.shape[1:]) for o in outs),
+                (True,) * len(outs))
+
+    return fn
+
+
+@functools.lru_cache(maxsize=None)
+def expert_product(first: int, n_held: int):
+    """``m = product(f, sel, gate, w1, w3, w2)``: the held experts' share
+    of an expert layer's output for tokens f [N, d] routed to ``sel`` with
+    weights ``gate`` ([N, k]); w1, w3 [n_held, d, width], w2 [n_held, width,
+    d].  Differentiable in f and gate; the weights are read, not trained.
+    Sorted-slot residuals (both pre-activations, the order) cross from
+    the forward to the backward pass in the merged layout of `_merged`."""
+
+    def forward(f, sel, gate, w1, w3, w2):
+        k = sel.shape[-1]
+        with jax.named_scope(scopes.FED_MOE_ROUTER):
+            order, sizes, valid = _slots(sel, first, n_held)
+            xs = f[order // k]
+        with jax.named_scope(scopes.FED_MOE_EXPERTS):
+            a1, a3 = _grouped(xs, w1, sizes), _grouped(xs, w3, sizes)
+            y = _grouped((jax.nn.silu(a1) * a3).astype(f.dtype), w2, sizes)
+        with jax.named_scope(scopes.FED_MOE_ROUTER):
+            y = jnp.where(valid[:, None], y, 0.0)
+            m = jnp.sum(_unsort(y, order, k) * gate[..., None], axis=1)
+        # residual rows: one per token, so that a merge splits them evenly
+        res = lambda a: a.reshape((f.shape[0], -1))
+        return m.astype(f.dtype), res(a1), res(a3), res(order)
+
+    def backward(f, sel, gate, a1, a3, order, dm, w1, w3, w2):
+        k, dt = sel.shape[-1], f.dtype
+        a1, a3 = (a.reshape((order.size, -1)) for a in (a1, a3))
+        order = order.reshape(-1)
+        with jax.named_scope(scopes.FED_MOE_ROUTER):
+            _, sizes, valid = _slots(sel, first, n_held)
+            dms = dm[order // k].astype(dt)
+            gs = gate.reshape(-1)[order][:, None]
+        with jax.named_scope(scopes.FED_MOE_EXPERTS):
+            back = lambda d, w: jnp.where(
+                valid[:, None], _grouped_t(d.astype(dt), w, sizes), 0.0)
+            u = back(dms, w2)
+            sig = jax.nn.sigmoid(a1)
+            act = a1 * sig
+            dgate = jnp.sum((act * a3).astype(dt).astype(jnp.float32) * u, axis=-1)
+            dh = gs * u
+            dxs = (back(dh * a3 * sig * (1.0 + a1 * (1.0 - sig)), w1)
+                   + back(dh * act, w3))
+        with jax.named_scope(scopes.FED_MOE_ROUTER):
+            df = jnp.sum(_unsort(dxs, order, k), axis=1).astype(dt)
+            return df, _unsort(dgate, order, k)
+
+    forward_m, backward_m = _merged(forward, 3), _merged(backward, 7)
+
+    @jax.custom_vjp
+    def product(f, sel, gate, w1, w3, w2):
+        return forward_m(f, sel, gate, w1, w3, w2)[0]
+
+    def fwd(f, sel, gate, w1, w3, w2):
+        m, a1, a3, order = forward_m(f, sel, gate, w1, w3, w2)
+        return m, (f, sel, gate, a1, a3, order, w1, w3, w2)
+
+    def bwd(res, dm):
+        f, sel, gate, a1, a3, order, w1, w3, w2 = res
+        df, dgate = backward_m(f, sel, gate, a1, a3, order, dm, w1, w3, w2)
+        return df, None, dgate.astype(gate.dtype), None, None, None
+
+    product.defvjp(fwd, bwd)
+    return product
+
+
+def moe_layer(f, lp, k: int, scaling: float, held=None):
+    """(m, tokens routed to every expert [n_experts]) of one expert layer
+    for f [..., d]; ``lp``: router, expert_bias and the experts HELD
+    (``held`` = (first, past-last) expert id; None = all)."""
+    n_experts = lp["router"].shape[-1]
+    first, last = held if held is not None else (0, n_experts)
+    rows = f.reshape((-1, f.shape[-1]))
+    with jax.named_scope(scopes.FED_MOE_ROUTER):
+        sel, gate = route(rows, lp["router"], lp["expert_bias"], k, scaling)
+        counts = jnp.bincount(sel.reshape(-1), length=n_experts)
+    m = expert_product(first, last - first)(
+        rows, sel, gate, lp["w1"], lp["w3"], lp["w2"])
+    return m.reshape(f.shape), counts.astype(jnp.float32)
+
+
+class _Leaves(nn.Module):
+    """A named group of parameters: ((name, shape, init, dtype), ...)."""
+    specs: tuple
+
+    @nn.compact
+    def __call__(self):
+        return {name: self.param(name, init, shape, dtype)
+                for name, shape, init, dtype in self.specs}
+
+
+class _Groups(nn.Module):
+    """Named groups of parameters under one name of their own (the
+    adapters: ``lora/layer_<i>/<matrix>_a``)."""
+    specs: tuple
+
+    @nn.compact
+    def __call__(self):
+        return {name: _Leaves(s, name=name)() for name, s in self.specs}
+
+
+class Lfm2MoeLM(nn.Module):
+    """tokens [B, T] int -> float32 logits [B, T, vocab]."""
+    vocab_size: int
+    d_model: int = 64
+    n_heads: int = 4
+    n_kv_heads: int = 2
+    head_dim: int = 16
+    d_ff: int = 96                       # the dense layers' MLP width
+    d_expert: int = 32
+    n_experts: int = 8
+    experts_per_token: int = 2
+    layer_types: tuple = ("conv", "conv", "full_attention", "conv")
+    num_dense_layers: int = 1
+    layers: Optional[tuple] = None       # ids of the layers held; None = all
+    held_experts: Optional[tuple] = None  # (first, past-last); None = all
+    conv_kernel: int = 3
+    rope_theta: float = 1e6
+    norm_eps: float = 1e-5
+    routed_scaling_factor: float = 1.0
+    lora_rank: int = 4
+    lora_alpha: float = 8.0
+    init_std: float = 0.02
+    base_dtype: Any = jnp.bfloat16
+
+    # what local training updates, as path prefixes under ``params``;
+    # every other leaf is frozen (core/trainer.py reads both names)
+    trainable = ("lora",)
+    loss_scope = scopes.FED_LM_HEAD
+
+    @property
+    def held_layers(self) -> tuple:
+        return (tuple(range(len(self.layer_types))) if self.layers is None
+                else tuple(self.layers))
+
+    @property
+    def expert_layers(self) -> tuple:
+        return tuple(i for i in self.held_layers if i >= self.num_dense_layers)
+
+    @property
+    def counters(self) -> dict:
+        return {scopes.MOE_EXPERT_TOKENS: (len(self.expert_layers),
+                                           self.n_experts)}
+
+    def _specs(self, i: int):
+        """(base, adapter) leaf specs of layer i."""
+        d, hd, bt = self.d_model, self.head_dim, self.base_dtype
+        normal, ones = nn.initializers.normal(self.init_std), nn.initializers.ones
+        mats = ({"in_proj": (d, 3 * d), "out_proj": (d, d)}
+                if self.layer_types[i] == "conv" else
+                {"wq": (d, self.n_heads * hd), "wk": (d, self.n_kv_heads * hd),
+                 "wv": (d, self.n_kv_heads * hd), "wo": (self.n_heads * hd, d)})
+        base = [(n, s, normal, bt) for n, s in mats.items()]
+        base += [("op_norm", (d,), ones, bt), ("ffn_norm", (d,), ones, bt)]
+        if self.layer_types[i] == "conv":
+            base.append(("conv_kernel", (self.conv_kernel, d), normal, bt))
+        else:
+            base += [("q_norm", (hd,), ones, bt), ("k_norm", (hd,), ones, bt)]
+        if i < self.num_dense_layers:
+            base += [("w1", (d, self.d_ff), normal, bt),
+                     ("w3", (d, self.d_ff), normal, bt),
+                     ("w2", (self.d_ff, d), normal, bt)]
+        else:
+            first, last = self.held_experts or (0, self.n_experts)
+            e, w = last - first, self.d_expert
+            base += [("router", (d, self.n_experts), normal, bt),
+                     ("expert_bias", (self.n_experts,), nn.initializers.zeros, bt),
+                     ("w1", (e, d, w), normal, bt), ("w3", (e, d, w), normal, bt),
+                     ("w2", (e, w, d), normal, bt)]
+        r = self.lora_rank
+        adapters = []
+        for n, (d_in, d_out) in mats.items():
+            adapters += [
+                (n + "_a", (d_in, r), nn.initializers.normal(d_in ** -0.5), jnp.float32),
+                (n + "_b", (r, d_out), nn.initializers.zeros, jnp.float32)]
+        return tuple(base), tuple(adapters)
+
+    def _layer(self, i: int, h, lp, ad, cos, sin):
+        eps, scale = self.norm_eps, self.lora_alpha / self.lora_rank
+        if self.layer_types[i] == "conv":
+            with jax.named_scope(scopes.FED_SHORT_CONV):
+                h = h + short_conv(h, lp, ad, scale, eps)
+        else:
+            with jax.named_scope(scopes.FED_ATTENTION):
+                h = h + gqa_attention(h, lp, ad, scale, eps, cos, sin,
+                                      self.n_heads, self.n_kv_heads)
+        if i < self.num_dense_layers:
+            with jax.named_scope(scopes.FED_MLP):
+                f = rms_norm(h, lp["ffn_norm"], eps)
+                return h + gated_mlp(f, lp["w1"], lp["w3"], lp["w2"]), None
+        with jax.named_scope(scopes.FED_MOE_ROUTER):
+            f = rms_norm(h, lp["ffn_norm"], eps)
+        m, counts = moe_layer(f, lp, self.experts_per_token,
+                              self.routed_scaling_factor, self.held_experts)
+        return h + m, counts
+
+    @nn.compact
+    def __call__(self, x, train: bool = False):
+        normal = nn.initializers.normal(self.init_std)
+        embed = self.param("embed", normal, (self.vocab_size, self.d_model),
+                           self.base_dtype)
+        out_norm = self.param("out_norm", nn.initializers.ones,
+                              (self.d_model,), self.base_dtype)
+        specs = {i: self._specs(i) for i in self.held_layers}
+        base = {i: _Leaves(specs[i][0], name=f"layer_{i}")()
+                for i in self.held_layers}
+        lora = _Groups(tuple((f"layer_{i}", specs[i][1])
+                             for i in self.held_layers), name="lora")()
+        dt = jax.tree.leaves(lora)[0].dtype          # the adapters': compute
+        cos, sin = rotary_tables(x.shape[-1], self.head_dim, self.rope_theta)
+        h = embed[x.astype(jnp.int32)].astype(dt)
+        counts = []
+        for i in self.held_layers:
+            layer = jax.checkpoint(functools.partial(self._layer, i))
+            h, c = layer(h, base[i], lora[f"layer_{i}"], cos, sin)
+            if c is not None:
+                counts.append(c)
+        if (counts and not self.is_initializing()
+                and self.is_mutable_collection(scopes.COUNTERS)):
+            self.sow(scopes.COUNTERS, scopes.MOE_EXPERT_TOKENS,
+                     jnp.stack(counts), init_fn=lambda: 0.0,
+                     reduce_fn=lambda a, b: a + b)
+        with jax.named_scope(scopes.FED_LM_HEAD):
+            s = rms_norm(h, out_norm, self.norm_eps)
+            return jnp.einsum("...d,vd->...v", s, embed.astype(dt),
+                              preferred_element_type=jnp.float32)

@@ -240,11 +240,14 @@ class InstrumentedProgram:
     thing."""
 
     __slots__ = ("_fn", "_family", "_census_tried", "_signature",
-                 "_scope_map")
+                 "_scope_map", "_on_result")
 
-    def __init__(self, fn, family: ProgramFamily):
+    def __init__(self, fn, family: ProgramFamily, on_result=None):
         self._fn = fn
         self._family = family
+        # called with what a dispatch returned, before the caller sees it
+        # (the engine keeps the round's counters: no device sync here)
+        self._on_result = on_result
         self._census_tried = False
         self._signature = None      # abstract (args, kwargs), 1st dispatch
         self._scope_map = None
@@ -271,7 +274,10 @@ class InstrumentedProgram:
         try:
             with obs.span(scopes.SPAN_DISPATCH, family=fam.name,
                           n=int(fam._handles()[0].value)):
-                return self._fn(*args, **kwargs)
+                out = self._fn(*args, **kwargs)
+            if self._on_result is not None:
+                self._on_result(out)
+            return out
         finally:
             dt = time.perf_counter() - t0
             _tls.family = prev
@@ -447,13 +453,13 @@ def scope_map_of_hlo_text(text: str) -> dict:
     return {name: resolved(name) for name in labels}
 
 
-def instrument(family: str, fn) -> InstrumentedProgram:
+def instrument(family: str, fn, on_result=None) -> InstrumentedProgram:
     """Wrap one jitted program under `family`.  Idempotent-ish: an
     already-instrumented fn is re-tagged, not double-wrapped (double
     timing would inflate the family's dispatch walls)."""
     if isinstance(fn, InstrumentedProgram):
         fn = fn.inner
-    return InstrumentedProgram(fn, register(family))
+    return InstrumentedProgram(fn, register(family), on_result)
 
 
 # -- windowed reporting ------------------------------------------------------

@@ -28,10 +28,21 @@ copy census of the touched families exactly).  ``label_of`` turns one
     fed_mlp             its gated MLP with both norms    mlp
     fed_lm_head         the vocabulary projection and    lm_head
                         the loss on its logits
+    fed_short_conv      a gated short-convolution mixer  short_conv
+                        (operator norm, in_proj, both
+                        gates, the depthwise causal
+                        convolution, out_proj, adapters)
+    fed_moe_router      an expert layer's routing: its   moe_router
+                        norm, scores, bias, top-k, the
+                        gate's normalisation, the sort
+                        of token slots by expert and
+                        the un-sort / combine
+    fed_moe_experts     the grouped products over the    moe_experts
+                        experts held, and their gate
 
 An op under several scopes belongs to the innermost one (a forward op
 is inside fed_local_train too); an op under none is ``unscoped``.
-The last three sit inside fed_forward and claim their ops forward,
+The last six sit inside fed_forward and claim their ops forward,
 backward and rematerialised alike, so in a model that has them
 ``forward`` / ``backward`` read what lies outside them (embedding,
 residual stream between blocks, the final norm); a model without them
@@ -55,6 +66,9 @@ FED_SERVER_UPDATE = "fed_server_update"
 FED_ATTENTION = "fed_attention"
 FED_MLP = "fed_mlp"
 FED_LM_HEAD = "fed_lm_head"
+FED_SHORT_CONV = "fed_short_conv"
+FED_MOE_ROUTER = "fed_moe_router"
+FED_MOE_EXPERTS = "fed_moe_experts"
 
 UNSCOPED = "unscoped"
 BACKWARD = "backward"
@@ -68,8 +82,22 @@ LABEL_OF_SCOPE = {
     FED_ATTENTION: "attention",
     FED_MLP: "mlp",
     FED_LM_HEAD: "lm_head",
+    FED_SHORT_CONV: "short_conv",
+    FED_MOE_ROUTER: "moe_router",
+    FED_MOE_EXPERTS: "moe_experts",
 }
 LABELS = tuple(LABEL_OF_SCOPE.values()) + (BACKWARD, UNSCOPED)
+
+# **Counters**: a model may count what its forward pass did in a step
+# (``self.sow(COUNTERS, name, value)``, shapes declared in its ``counters``
+# attribute); the trainer sums them over a client's real steps, the engine
+# over the round's clients, and the round program returns them in its
+# metrics under their names (core/trainer.py, parallel/engine.py)
+COUNTERS = "counters"
+MOE_EXPERT_TOKENS = "moe_expert_tokens"    # [expert layers, experts]
+# the obs counter that takes a program counter's total when it is read
+# (utils/profiling.py::TransferOverlapStats.program_counters)
+METRIC_OF_COUNTER = {MOE_EXPERT_TOKENS: "moe_expert_tokens_total"}
 
 SPAN_SAMPLE = "round.sample"
 SPAN_ARGS_PUT = "round.args_put"

@@ -302,7 +302,19 @@ def chunked_weighted_train(trainer, variables, cohort, weights, rngs,
 
     `variables` must already carry the vma types of `vary_axes` (pvary'd by
     the caller); the f32 accumulators are pvary'd here to match.  Returns
-    (num_tree_f32, den, loss_sum) — the caller applies its own psum tier(s).
+    (num_tree_f32, den, loss_sum, counters) — the caller applies its own
+    psum tier(s).
+
+    Where the trainer's model freezes part of its parameters
+    (ClientTrainer.split_frozen) only the trained leaves are per-client
+    state: `variables` is closed over by the vmapped client function, so
+    the frozen leaves enter a chunk un-mapped and every client of it reads
+    the same buffers; each client's working copy, the Σ w·v carry and
+    `num_tree_f32` hold the trained leaves alone (the structure of
+    `trainer.trained_variables(variables)`).  The last value returned is
+    always what the model counted in its forward passes
+    (ClientTrainer.counters), summed over the cohort's real steps: {} for
+    a model that counts nothing.
 
     With `emit_flat_params` the scan ALSO emits each client's trained
     params flattened to an f32 row (ops/aggregate tile padding), returned
@@ -335,10 +347,10 @@ def chunked_weighted_train(trainer, variables, cohort, weights, rngs,
     global_params = variables["params"] if trainer.prox_mu > 0 else None
 
     def one(shard, crng, bound):
-        v, loss, _n = trainer.local_train(
+        v, loss, _n, counts = trainer.local_train_counted(
             variables, shard, crng, epochs, global_params=global_params,
             batch_bound=bound)
-        return v, loss
+        return trainer.trained_variables(v), loss, counts
 
     # The Σ w·v carry: leaves under BIG_CARRY_LEAF elements packed into
     # ONE f32 vector (flatten_carry_f32: a pytree carry costs them a
@@ -351,7 +363,7 @@ def chunked_weighted_train(trainer, variables, cohort, weights, rngs,
     # is one.  A model with no such leaf (every conv kernel, the LSTM)
     # carries the one vector and compiles to the program it had.  Either
     # way each element sees the same adds in the same order.
-    leaves, treedef = jax.tree.flatten(variables)
+    leaves, treedef = jax.tree.flatten(trainer.trained_variables(variables))
     big = [int(np.prod(a.shape)) >= BIG_CARRY_LEAF for a in leaves]
     packed_spec = [a for a, b in zip(leaves, big) if not b]
 
@@ -360,28 +372,31 @@ def chunked_weighted_train(trainer, variables, cohort, weights, rngs,
                 [a for a, b in zip(tree_leaves, big) if b])
 
     def chunk_body(carry, xs):
-        (num_flat, num_big), den, lsum = carry
+        (num_flat, num_big), den, lsum, csum = carry
         cs, cw, cr, bound = xs
         if restore_x is not None:      # flat_stack: image shape back,
             cs = restore_x(cs)         # O(chunk) per trip
-        vs, losses = jax.vmap(one, in_axes=(0, 0, None))(cs, cr, bound)
+        vs, losses, counts = jax.vmap(one, in_axes=(0, 0, None))(cs, cr, bound)
+        csum = jax.tree.map(lambda a, c: a + jnp.sum(c, axis=0), csum, counts)
         with jax.named_scope(scopes.FED_AGGREGATE):
             if client_transform is not None:
-                vs = jax.vmap(client_transform,
-                              in_axes=(0, 0, None))(vs, cw, variables)
+                vs = jax.vmap(client_transform, in_axes=(0, 0, None))(
+                    vs, cw, trainer.trained_variables(variables))
             packed, own = split(jax.tree.leaves(weighted_sum_tree(cw, vs)))
             num_flat = num_flat + flatten_carry_f32(packed)[0]
             num_big = [acc + v for acc, v in zip(num_big, own)]
             ys = (flatten_stacked_tree(vs["params"])[0]
                   if emit_flat_params else None)
             return ((num_flat, num_big), den + jnp.sum(cw),
-                    lsum + jnp.sum(losses * cw)), ys
+                    lsum + jnp.sum(losses * cw), csum), ys
 
     with jax.named_scope(scopes.FED_AGGREGATE):
         packed0, own0 = split([jnp.zeros(a.shape, jnp.float32)
                                for a in leaves])
         zeros = pvary_tree((flatten_carry_f32(packed0)[0], own0), vary_axes)
         zf = pvary_tree(jnp.float32(0), vary_axes)
+    zc = pvary_tree({name: jnp.zeros(shape, jnp.float32)
+                     for name, shape in trainer.counters.items()}, vary_axes)
     # fed_local_train spans the chunk scan with its plumbing (chunking,
     # the while itself, the flat_stack restore, the per-client training);
     # the aggregation fold inside the body belongs to its own, inner scope
@@ -397,8 +412,8 @@ def chunked_weighted_train(trainer, variables, cohort, weights, rngs,
                 lambda a: a[order], (cohort, weights, rngs))
         cohort, weights, rngs = pad_and_chunk(cohort, weights, rngs,
                                               chunk_cap)
-        ((num_flat, num_big), den, lsum), flats = jax.lax.scan(
-            chunk_body, (zeros, zf, zf), (cohort, weights, rngs, bounds))
+        ((num_flat, num_big), den, lsum, csum), flats = jax.lax.scan(
+            chunk_body, (zeros, zf, zf, zc), (cohort, weights, rngs, bounds))
     with jax.named_scope(scopes.FED_AGGREGATE):
         packed = iter(unflatten_carry_f32(num_flat, packed_spec))
         own = iter(num_big)
@@ -408,8 +423,8 @@ def chunked_weighted_train(trainer, variables, cohort, weights, rngs,
         if order is not None:          # rows back where the cohort had them
             rows = flats.reshape(-1, flats.shape[-1])
             flats = rows.at[order].set(rows[:k_local]).reshape(flats.shape)
-        return num, den, lsum, flats
-    return num, den, lsum
+        return num, den, lsum, flats, csum
+    return num, den, lsum, csum
 
 
 class MeshFedAvgEngine(FedAvgEngine):
@@ -588,7 +603,8 @@ class MeshFedAvgEngine(FedAvgEngine):
         self.round_fn = obs_programs.instrument(
             self.program_family,
             jax.jit(self._mesh_round,
-                    donate_argnums=(0, 1) if donate else ()))
+                    donate_argnums=(0, 1) if donate else ()),
+            on_result=self._keep_counters)
         # streaming variant: the gather happened on host; cohort arrives
         # pre-sharded [K, ...] with K = padded cohort size.  This public
         # entry donates variables/server_state ONLY — chip_smoke.py and
@@ -607,7 +623,8 @@ class MeshFedAvgEngine(FedAvgEngine):
         self._round_fn_streaming_consume = obs_programs.instrument(
             self.program_family,
             jax.jit(self._mesh_round_streaming,
-                    donate_argnums=(0, 1, 2, 3) if donate else ()))
+                    donate_argnums=(0, 1, 2, 3) if donate else ()),
+            on_result=self._keep_counters)
         if streaming:
             self.round_fn = self._round_fn_streaming_consume
         if self.stream_block is not None:
@@ -652,6 +669,16 @@ class MeshFedAvgEngine(FedAvgEngine):
         if streaming:
             return f"{self._family_stem}_streaming"
         return f"{self._family_stem}_resident"
+
+    def _keep_counters(self, result) -> None:
+        """What the round's model counted (ClientTrainer.counters; the
+        round program returns the sums in its metrics) goes to
+        `transfer_stats`, as device arrays: read when somebody asks."""
+        names = self.trainer.counters
+        if names:
+            self.transfer_stats.add_program_counters(
+                {name: result[2][name] for name in names
+                 if name in result[2]})
 
     # -- hooks ---------------------------------------------------------------
     def client_transform(self, client_variables: Pytree, weight: jax.Array,
@@ -797,38 +824,59 @@ class MeshFedAvgEngine(FedAvgEngine):
         tier over the mesh: returns the REPLICATED (Σ w·v, Σ w, Σ w·loss)
         — the linear core shared by the whole-cohort round (_shard_body)
         and the block-streamed round (_round_blockstream), which
-        accumulates these sums across blocks before dividing."""
+        accumulates these sums across blocks before dividing.  Σ w·v
+        covers the leaves the round trains (all of them, unless the
+        model freezes some); the fourth value is what the model counted
+        (ClientTrainer.counters; {} for most)."""
         axes = self.mesh.axis_names
         # the global model arrives replicated; per-client training makes
         # it shard-varying, so cast up-front for the vma type system
         with jax.named_scope(scopes.FED_LOCAL_TRAIN):
             variables = pvary_tree(variables, axes)
-            local_vars = cast_local(variables, self.local_dtype)
-        num, den, lsum = chunked_weighted_train(
+            # frozen leaves stay in the dtype they are stored in
+            local_vars = self.trainer.with_frozen(
+                cast_local(self.trainer.trained_variables(variables),
+                           self.local_dtype), variables)
+        sums = chunked_weighted_train(
             self.trainer, local_vars, cohort, weights, client_rngs,
             self.cfg.epochs, vary_axes=axes, chunk_cap=self.chunk,
             client_transform=self.client_transform,
             restore_x=self._restore_chunk_x,
             ragged_batches=self._ragged_batches)
         with jax.named_scope(scopes.FED_AGGREGATE):
-            return (jax.lax.psum(num, axes), jax.lax.psum(den, axes),
-                    jax.lax.psum(lsum, axes))
+            return tuple(jax.lax.psum(s, axes) for s in sums)
 
     def _zero_sums(self, variables):
         """Zero accumulators matching _shard_sums' output structure (the
         block-streamed round's carry; engines with extra linear sums —
         FedNova's tau — override the triple together)."""
         return (jax.tree.map(lambda a: jnp.zeros(a.shape, jnp.float32),
-                             variables), jnp.float32(0), jnp.float32(0))
+                             self.trainer.trained_variables(variables)),
+                jnp.float32(0), jnp.float32(0),
+                {name: jnp.zeros(shape, jnp.float32)
+                 for name, shape in self.trainer.counters.items()})
 
     def _finalize_from_sums(self, variables, sums):
-        """(aggregated model, mean loss) from the accumulated linear sums
-        — pure math, shared verbatim by the whole-cohort shard body and
-        the block-streamed finalize."""
-        num, den, lsum = sums
+        """(aggregated model, mean loss, counters) from the accumulated
+        linear sums — pure math, shared verbatim by the whole-cohort shard
+        body and the block-streamed finalize.  The mean covers the leaves
+        the round trains; the caller puts it back beside the frozen ones
+        (`_install`)."""
+        num, den, lsum, counters = sums
         avg = jax.tree.map(
-            lambda s, ref: (s / den).astype(ref.dtype), num, variables)
-        return avg, lsum / den
+            lambda s, ref: (s / den).astype(ref.dtype), num,
+            self.trainer.trained_variables(variables))
+        return avg, lsum / den, counters
+
+    def _install(self, avg, variables, server_state, agg_rng):
+        """The round's tail, at the top level of its jitted program: the
+        aggregate beside the frozen leaves of `variables` — the program's
+        own donated arguments, which the output then aliases: they come
+        back in the buffers they came in — then the server update."""
+        with jax.named_scope(scopes.FED_SERVER_UPDATE):
+            return self.server_update(
+                self.trainer.with_frozen(avg, variables), variables,
+                server_state, agg_rng)
 
     def _shard_body(self, variables, cohort, weights, client_rngs):
         """Whole-cohort round body: the two-collective FedAvg aggregation
@@ -849,14 +897,14 @@ class MeshFedAvgEngine(FedAvgEngine):
                         for k, v in cohort.items()}
         rng, agg_rng = jax.random.split(rng)
         client_rngs = jax.random.split(rng, weights.shape[0])
-        avg, train_loss = jax.shard_map(
+        avg, train_loss, counters = jax.shard_map(
             self._shard_body, mesh=mesh,
-            in_specs=(P(), cohort_specs, csh, csh), out_specs=(P(), P()))(
+            in_specs=(P(), cohort_specs, csh, csh), out_specs=P())(
                 variables, cohort, weights, client_rngs)
-        with jax.named_scope(scopes.FED_SERVER_UPDATE):
-            new_variables, server_state = self.server_update(
-                avg, variables, server_state, agg_rng)
-        return new_variables, server_state, {"train_loss": train_loss}
+        new_variables, server_state = self._install(
+            avg, variables, server_state, agg_rng)
+        return new_variables, server_state, {"train_loss": train_loss,
+                                             **counters}
 
     def _mesh_round(self, variables, server_state, stack, stack_w, ids,
                     wmask, rng):
@@ -954,11 +1002,11 @@ class MeshFedAvgEngine(FedAvgEngine):
         with jax.named_scope(scopes.FED_AGGREGATE):
             sums = unflatten_carry_f32(flat_sums,
                                        self._zero_sums(variables))
-            avg, loss = self._finalize_from_sums(variables, sums)
-        with jax.named_scope(scopes.FED_SERVER_UPDATE):
-            new_variables, server_state = self.server_update(
-                avg, variables, server_state, agg_rng)
-        return new_variables, server_state, {"train_loss": loss}
+            avg, loss, counters = self._finalize_from_sums(variables, sums)
+        new_variables, server_state = self._install(
+            avg, variables, server_state, agg_rng)
+        return new_variables, server_state, {"train_loss": loss,
+                                             **counters}
 
     @staticmethod
     def _round_attr(round_idx) -> dict:
@@ -1027,11 +1075,11 @@ class MeshFedAvgEngine(FedAvgEngine):
 
     def _block_finalize_impl(self, variables, server_state, sums, agg_rng):
         with jax.named_scope(scopes.FED_AGGREGATE):
-            avg, loss = self._finalize_from_sums(variables, sums)
-        with jax.named_scope(scopes.FED_SERVER_UPDATE):
-            new_variables, server_state = self.server_update(
-                avg, variables, server_state, agg_rng)
-        return new_variables, server_state, {"train_loss": loss}
+            avg, loss, counters = self._finalize_from_sums(variables, sums)
+        new_variables, server_state = self._install(
+            avg, variables, server_state, agg_rng)
+        return new_variables, server_state, {"train_loss": loss,
+                                             **counters}
 
     def _upload_block(self, ids_blk, w_blk, rngs_blk, round_idx=None):
         """Host-gather + async device_put of one client block (the
@@ -1421,7 +1469,7 @@ class MeshFedNovaEngine(MeshFedAvgEngine):
         new = {"params": new_params,
                **jax.tree.map(lambda s, ref: (s / den).astype(ref.dtype),
                               rest_num, grest)}
-        return new, lsum / den
+        return new, lsum / den, {}
 
 
 class MeshRobustEngine(MeshFedAvgEngine):
@@ -1559,7 +1607,7 @@ class MeshRobustEngine(MeshFedAvgEngine):
         # the shared chunked loop, additionally emitting each client's
         # flattened trained params (prox term etc. included — one code
         # path with the norm_clip/FedAvg engines)
-        num, den, lsum, flats = chunked_weighted_train(
+        num, den, lsum, flats, _counters = chunked_weighted_train(
             self.trainer, local_vars, cohort, weights, client_rngs,
             self.cfg.epochs, vary_axes=axes, chunk_cap=self.chunk,
             emit_flat_params=True, restore_x=self._restore_chunk_x,
@@ -1603,7 +1651,7 @@ class MeshRobustEngine(MeshFedAvgEngine):
                **jax.tree.map(lambda s, ref: (s / den).astype(ref.dtype),
                               rest_num, grest)}
         loss = jax.lax.psum(lsum, axes) / den
-        return new, loss
+        return new, loss, {}
 
     # -- block-streamed order statistics (VERDICT r4 #3) ---------------------
     # The linear engines stream CLIENT-major: blocks of clients cross
@@ -1636,7 +1684,7 @@ class MeshRobustEngine(MeshFedAvgEngine):
         def body(variables, cohort, w, r):
             v = pvary_tree(variables, axes)
             local_vars = cast_local(v, self.local_dtype)
-            num, den, lsum, flats = chunked_weighted_train(
+            num, den, lsum, flats, _counters = chunked_weighted_train(
                 self.trainer, local_vars, cohort, w, r, self.cfg.epochs,
                 vary_axes=axes, chunk_cap=self.chunk,
                 emit_flat_params=True, restore_x=self._restore_chunk_x,

@@ -164,7 +164,7 @@ class MeshHierarchicalEngine(FedAvgEngine):
                 local_vars = cast_local(vars_g, self.local_dtype)
                 # chunked inner loop (same HBM-bounding scan as the flat
                 # engine, parallel/engine.py::chunked_weighted_train)
-                num, den, lsum = chunked_weighted_train(
+                num, den, lsum, _counters = chunked_weighted_train(
                     trainer, local_vars, cohort, weights, crngs, epochs,
                     vary_axes=(SILO_AXIS, CLIENT_AXIS),
                     chunk_cap=self.chunk,

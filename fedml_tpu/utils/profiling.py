@@ -16,6 +16,7 @@ from collections import defaultdict
 from typing import Iterator, Optional
 
 import jax
+import numpy as np
 
 from fedml_tpu import obs
 from fedml_tpu.obs import scopes
@@ -137,6 +138,12 @@ class TransferOverlapStats:
         self._m_batch_trips = obs.counter("engine_batch_trips_total")
         self._m_batch_trips_static = obs.counter(
             "engine_batch_trips_static_total")
+        # what the round programs' models counted (obs/scopes.py COUNTERS):
+        # the programs' own device arrays, kept as they come and summed
+        # when somebody reads them
+        self._m_program_counters = {
+            name: obs.counter(metric)
+            for name, metric in scopes.METRIC_OF_COUNTER.items()}
         self.reset()
 
     def reset(self) -> None:
@@ -146,6 +153,8 @@ class TransferOverlapStats:
             self._h2d_bytes = 0
             self.batch_trips = 0
             self.batch_trips_static = 0
+            self._counted: dict = {}          # name -> summed numpy array
+            self._uncounted: list = []        # (name, device array)
             self._round_t0: Optional[float] = None
             self._snap = (0.0, 0.0, 0)
             self.rounds: list[dict] = []
@@ -166,6 +175,38 @@ class TransferOverlapStats:
             self.batch_trips_static += static
         self._m_batch_trips.inc(ran)
         self._m_batch_trips_static.inc(static)
+
+    def add_program_counters(self, counters: dict) -> None:
+        """Keep one round program's counters (its metrics' device
+        arrays).  Nothing is waited for here: once the backlog is long,
+        the arrays of rounds that have finished are summed and let go,
+        those of rounds still in flight stay."""
+        with self._lock:
+            self._uncounted.extend(counters.items())
+            backlog = len(self._uncounted) > 256
+        if backlog:
+            self._sum_counters(finished_only=True)
+
+    def program_counters(self) -> dict:
+        """{name: numpy array} summed over the round programs dispatched
+        since reset(); waits for those still running."""
+        self._sum_counters(finished_only=False)
+        with self._lock:
+            return dict(self._counted)
+
+    def _sum_counters(self, finished_only: bool) -> None:
+        with self._lock:
+            fresh, kept = [], []
+            for entry in self._uncounted:
+                running = finished_only and not entry[1].is_ready()
+                (kept if running else fresh).append(entry)
+            self._uncounted = kept
+        for name, value in fresh:
+            value = np.asarray(value, np.float64)
+            with self._lock:
+                self._counted[name] = self._counted.get(name, 0.0) + value
+            if name in self._m_program_counters:
+                self._m_program_counters[name].inc(float(value.sum()))
 
     @property
     def h2d_bytes(self) -> int:

@@ -193,6 +193,21 @@ def test_streamed_upload_carries_the_round_it_is_for():
      "/fed_lm_head/...a,ab->...b/dot_general", "lm_head"),
     ("jit(r)/fed_local_train/while/body/transpose(jvp(fed_forward))"
      "/fed_lm_head/reduce_max", "lm_head"),
+    # a gated short convolution, an expert layer's routing and its grouped
+    # products (models/lfm2_moe.py): forward, rematerialised and - the
+    # product's backward pass is written out - transposed alike
+    ("jit(r)/fed_local_train/while/body/vmap(jvp(fed_forward))/Lfm2MoeLM"
+     "/checkpoint/fed_short_conv/...a,ab->...b/dot_general", "short_conv"),
+    ("jit(r)/fed_local_train/while/body/transpose(jvp(fed_forward))/Lfm2MoeLM"
+     "/checkpoint/rematted_computation/fed_short_conv/mul", "short_conv"),
+    ("jit(r)/fed_local_train/while/body/jvp(fed_forward)/Lfm2MoeLM/checkpoint"
+     "/fed_moe_router/top_k", "moe_router"),
+    ("jit(r)/fed_local_train/while/body/transpose(jvp(fed_forward))/Lfm2MoeLM"
+     "/checkpoint/rematted_computation/fed_moe_router/sort", "moe_router"),
+    ("jit(r)/fed_local_train/while/body/jvp(fed_forward)/Lfm2MoeLM/checkpoint"
+     "/fed_moe_experts/ragged_dot_general", "moe_experts"),
+    ("jit(r)/fed_local_train/while/body/transpose(jvp(fed_forward))/Lfm2MoeLM"
+     "/checkpoint/fed_moe_experts/transpose", "moe_experts"),
     # outside the three, the model's ops stay forward / backward
     ("jit(r)/fed_local_train/while/body/jvp(fed_forward)/LoopedDecoderLM"
      "/while/body/rsqrt", "forward"),

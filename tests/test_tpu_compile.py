@@ -392,3 +392,75 @@ def test_ouro_round_and_its_float32_twin_fit_one_chip(topo, monkeypatch):
     with jax.default_matmul_precision("highest"):
         twin = _resident_round(topo, config, dict(traffic, population=4, cohort=4))
     assert needs(twin) < 15.75 * 2 ** 30, twin.memory_analysis()
+
+
+@pytest.mark.slow
+def test_lfm2_adapter_round_and_its_float32_twin_fit_one_chip(topo, monkeypatch):
+    """`lfm2moe24b.lora4of256t2048`'s resident round (a 5.4 GB frozen bfloat16
+    base under 999,424 adapter parameters, chunk from the file) and the
+    float32 twin that the reference check runs (4 clients, full
+    participation, precision "highest"), compiled as the engine dispatches
+    them - variables donated, so the frozen leaves' buffers are the output's -
+    fit one chip: the test that sizes the cut (two periods of the layer
+    pattern need 19.9 GB: fedbench/configs/lfm2_24b_a2b.json, "cut").  The
+    base is read as it is stored: the bfloat16 round holds no float32 buffer
+    of a frozen matrix's shape, and no buffer of one behind a client axis;
+    what it folds and averages is the adapters."""
+    from fedbench.harness import build
+    from fedml_tpu.parallel.engine import flatten_carry_f32
+    from fedml_tpu.parallel.mesh import (client_sharding, make_mesh,
+                                         replicated_sharding,
+                                         stack_leaf_sharding)
+    config, traffic = _bench_files("lfm2_24b_a2b", "lora4of256t2048")
+
+    def dispatched(traffic, **engine_kw):
+        population, k = int(traffic["population"]), int(traffic["cohort"])
+        host = dict(traffic, population=4)
+        data = build.make_data(host, seed=0)
+        engine = build.make_engine(config, host, data, seed=0, **engine_kw)
+        mesh = engine.mesh = make_mesh(devices=topo.devices[:1])
+        rep, csh = replicated_sharding(mesh), client_sharding(mesh)
+        stack = {
+            name: jax.ShapeDtypeStruct((population,) + v.shape[1:], v.dtype,
+                                       sharding=stack_leaf_sharding(mesh, v))
+            for name, v in engine._cast_stack_x(dict(engine._host_shards())).items()}
+        variables = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rep),
+            jax.eval_shape(engine.init_variables))
+        compiled = engine.round_fn.inner.lower(
+            variables, (), stack,
+            jax.ShapeDtypeStruct((population,), jnp.float32, sharding=csh),
+            jax.ShapeDtypeStruct((k,), jnp.int32, sharding=rep),
+            jax.ShapeDtypeStruct((k,), jnp.float32, sharding=rep),
+            jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=rep)).compile()
+        return engine, variables, compiled
+
+    def needs(compiled):
+        mem = compiled.memory_analysis()
+        # the frozen leaves come back in the buffers they came in
+        assert mem.alias_size_in_bytes > 2 * config["widths"]["parameters_held"] - 1e6
+        return (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+                + mem.generated_code_size_in_bytes)
+
+    engine, variables, compiled = dispatched(traffic)
+    assert needs(compiled) < 13.5e9, compiled.memory_analysis()
+    trained = engine.trainer.trained_variables(variables)
+    n_trained = sum(int(np.prod(a.shape)) for a in jax.tree.leaves(trained))
+    assert n_trained == config["widths"]["parameters_trained"]
+    assert flatten_carry_f32(engine._zero_sums(variables)[0])[0].shape == (n_trained,)
+    frozen = engine.trainer.split_frozen(variables["params"])[1]
+    text = compiled.as_text()
+    # the expert stacks and the embedding, 97 % of the base: shapes no
+    # activation shares (T = hidden = 2048 here, so [2048, x] says nothing)
+    shapes = {a.shape for a in jax.tree.leaves(frozen) if len(a.shape) == 3}
+    shapes.add(frozen["embed"].shape)
+    assert len(shapes) == 3, shapes
+    for shape in shapes:
+        dims = ",".join(map(str, shape))
+        assert not re.search(rf"f32\[{dims}\]", text), shape
+        assert not re.search(rf"bf16\[\d+,{dims}\]", text), shape
+    assert text.count("ragged-dot") > 0            # XLA:TPU's grouped product
+    with jax.default_matmul_precision("highest"):
+        _, _, twin = dispatched(dict(traffic, population=4, cohort=4),
+                                train_dtype="float32", local_dtype=None)
+    assert needs(twin) < 15.75 * 2 ** 30, twin.memory_analysis()
