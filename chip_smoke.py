@@ -29,7 +29,9 @@ Default (one chip), in order:
                  engine (tests/test_fedavg.py is the CPU twin).
   (c) kernels    the pallas kernels COMPILED by Mosaic (not interpreted)
                  at the ResNet-18 row, each against its jax.numpy
-                 reference.
+                 reference; the fused causal attention at both
+                 language-model cells' shapes, output and gradients in
+                 bfloat16, against a float32 oracle beside the plain path.
   (d) cli        fedml_tpu.cli.main([...]) in-process: argument parsing
                  -> engine -> history.jsonl on the device.
 
@@ -65,6 +67,9 @@ class Sizes:
     oracle_clients: int = 8
     agg_clients: int = 8
     gn_shapes: tuple = ((32, 32, 32, 64), (32, 4, 4, 512))
+    # (B, T, H, H_kv, head size): a step of ouro2p6b.silo4of256t1024 and a
+    # chunk's step of lfm2moe24b.lora4of256t2048
+    attn_shapes: tuple = ((2, 1024, 16, 16, 128), (4, 2048, 32, 8, 64))
     platform: str = "tpu"        # where every result must live
 
 
@@ -407,6 +412,52 @@ def phase_kernels(sz: Sizes, seed: int) -> None:
                  grad_tolerance="rel 5e-3" if shift else "abs 5e-2")
             assert kernel == compiled, f"group_norm {shape}: {kernel}"
             assert fwd < 1e-3 and grads_ok, (shape, shift, fwd, grad_abs)
+
+    # fused causal attention (ops/attention.py) at the two language-model
+    # cells' shapes, bfloat16: output and the three gradients of the fused
+    # path and of the plain path, each against the plain path in float32 at
+    # matmul precision "highest".  The kernels may be no farther (relative
+    # l2 distance; the largest single error, one rounding of the result on
+    # either path, is printed beside it) from that oracle than 1.5 x the
+    # plain bfloat16 path is: they differ in where they round (p is
+    # normalised after the p.v product, dp stays float32).
+    from fedml_tpu.ops import attention
+    for B, T, H, H_kv, hd in sz.attn_shapes:
+        keys = jax.random.split(jax.random.PRNGKey(seed), 4)
+        q32, k32, v32, w = (
+            jax.random.normal(key, (B, T, n, hd), jnp.float32)
+            for key, n in zip(keys, (H, H_kv, H_kv, H)))
+
+        def both(fn, dtype):
+            def loss(q, k, v):
+                o = fn(q, k, v)
+                return jnp.sum(o.astype(jnp.float32) * w), o
+            grads, o = jax.jit(jax.grad(loss, (0, 1, 2), has_aux=True))(
+                q32.astype(dtype), k32.astype(dtype), v32.astype(dtype))
+            return [a.astype(jnp.float32) for a in (o,) + grads]
+
+        with jax.default_matmul_precision("highest"):
+            oracle = both(attention._plain, jnp.float32)
+        l2, worst = {}, {}
+        for name, fn in (("fused", attention.causal_attention),
+                         ("plain", attention._plain)):
+            got = both(fn, jnp.bfloat16)
+            l2[name] = [float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+                        for a, b in zip(got, oracle)]
+            worst[name] = [float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
+                           for a, b in zip(got, oracle)]
+        kernel = _lowered_has_kernel(
+            jax.grad(lambda q, k, v: jnp.sum(
+                attention.causal_attention(q, k, v).astype(jnp.float32)),
+                (0, 1, 2)),
+            *(a.astype(jnp.bfloat16) for a in (q32, k32, v32)))
+        emit("kernel", op="causal_attention", shape=[B, T, H, H_kv, hd],
+             dtype="bfloat16", compiled=kernel,
+             fused_l2_o_dq_dk_dv=l2["fused"], plain_l2_o_dq_dk_dv=l2["plain"],
+             fused_max_o_dq_dk_dv=worst["fused"],
+             plain_max_o_dq_dk_dv=worst["plain"], tolerance="l2 1.5 x plain")
+        assert kernel == compiled, f"causal_attention: kernel path = {kernel}"
+        assert all(f <= 1.5 * p_ for f, p_ in zip(l2["fused"], l2["plain"])), l2
 
 
 # -- (d) -------------------------------------------------------------------

@@ -54,6 +54,12 @@ How it is built for a chip:
   backward pass is written out (``custom_vjp``) so that it does the same.
 * every layer is a ``jax.checkpoint`` that saves its input only, as in
   models/looped_lm.py; the layers are unrolled (each has its own leaves).
+* the attention core of a ``full_attention`` layer is
+  ``ops/attention.py::causal_attention``, grouped (query head h reads
+  key/value head ``h // (H / H_kv)``): fused kernels in a program lowered
+  for a TPU, where the [B, H, T, T] float32 scores never reach HBM; the
+  einsum, mask, softmax, einsum that used to stand here anywhere else.
+  The per-head norms, rotary and the adapted projections stay here.
 * the router's decisions are counted: tokens routed to every (expert layer,
   expert) of a step, sown as ``counters/moe_expert_tokens`` where the
   caller asks for that collection (obs/scopes.py).
@@ -71,6 +77,7 @@ from jax.custom_batching import custom_vmap
 from fedml_tpu.models.looped_lm import (_dot, apply_rotary, rms_norm,
                                         rotary_tables)
 from fedml_tpu.obs import scopes
+from fedml_tpu.ops.attention import causal_attention
 
 def _adapted(x, lp, ad, name, scale):
     """x W + scale (x A) B in x's dtype; W cast where it is used."""
@@ -95,20 +102,12 @@ def short_conv(h, lp, ad, scale, eps):
 def gqa_attention(h, lp, ad, scale, eps, cos, sin, n_heads, n_kv_heads):
     """Grouped-query attention with per-head q/k norms on h [B, T, d]."""
     B, T, _ = h.shape
-    dt = h.dtype
     a = rms_norm(h, lp["op_norm"], eps)
     heads = lambda name, n: _adapted(a, lp, ad, name, scale).reshape(B, T, n, -1)
     q, k, v = heads("wq", n_heads), heads("wk", n_kv_heads), heads("wv", n_kv_heads)
     q = apply_rotary(rms_norm(q, lp["q_norm"], eps), cos, sin)
     k = apply_rotary(rms_norm(k, lp["k_norm"], eps), cos, sin)
-    q = q.reshape(B, T, n_kv_heads, n_heads // n_kv_heads, -1)
-    s = jnp.einsum("btgrd,bsgd->bgrts", q, k,
-                   preferred_element_type=jnp.float32) * (q.shape[-1] ** -0.5)
-    causal = jnp.tril(jnp.ones((T, T), bool))
-    s = jnp.where(causal, s, jnp.finfo(jnp.float32).min)
-    w = jax.nn.softmax(s, axis=-1).astype(dt)
-    o = jnp.einsum("bgrts,bsgd->btgrd", w, v,
-                   preferred_element_type=jnp.float32).astype(dt)
+    o = causal_attention(q, k, v)
     return _adapted(o.reshape(B, T, -1), lp, ad, "wo", scale)
 
 

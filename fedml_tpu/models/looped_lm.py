@@ -40,11 +40,20 @@ How it is built for a chip:
 * every layer application is a ``jax.checkpoint`` that saves nothing
   but its input: backward keeps ``n_passes x n_layers`` residual
   streams ([B, T, d] in the compute dtype) and recomputes the layer's
-  forward — its four norms, seven matrix products, the attention scores
-  and the softmax — once.  That is a third more matrix work than the
-  count that omits recomputation, for activations that stay at tens of
-  megabytes per application instead of a gigabyte (the [B, H, T, T]
-  scores and the two [B, T, d_ff] gate products of every application).
+  forward — its four norms, seven matrix products and the attention
+  core — once.  That is a third more matrix work than the count that
+  omits recomputation, for activations that stay at tens of megabytes
+  per application (the two [B, T, d_ff] gate products would be the
+  largest).
+* the attention core, ``softmax_causal(q k^T / sqrt(head_dim)) v``, is
+  ``ops/attention.py::causal_attention``: in a program lowered for a TPU,
+  with T a multiple of 128 and heads of 64 or 128, fused kernels that
+  hold the [B, H, T, T] scores one tile at a time in fast memory — they
+  never reach HBM, forward, recomputed or backward, and the tiles above
+  the diagonal are not computed; the backward pass keeps the output and
+  the rows' log-sum-exp ([B, H, T] float32) in place of the scores.
+  Anywhere else (a CPU, a small test shape) it is the einsum, mask,
+  softmax, einsum that used to stand here.
 * matrix products run in the parameters' dtype (the trainer casts them
   to its ``train_dtype``) with float32 accumulation; norms, rotary
   angles, softmax, the gate and the returned logits are float32.
@@ -56,6 +65,7 @@ import jax
 import jax.numpy as jnp
 
 from fedml_tpu.obs import scopes
+from fedml_tpu.ops.attention import causal_attention
 
 _LAYER_NORMS = ("attn_norm", "attn_post_norm", "mlp_norm", "mlp_post_norm")
 
@@ -101,14 +111,7 @@ def decoder_layer(h, lp, cos, sin, n_heads: int, eps: float):
         heads = lambda w: _dot(a, w).astype(dt).reshape(B, T, n_heads, -1)
         q, k, v = heads(lp["wq"]), heads(lp["wk"]), heads(lp["wv"])
         q, k = apply_rotary(q, cos, sin), apply_rotary(k, cos, sin)
-        s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
-                       preferred_element_type=jnp.float32)
-        s = s * (q.shape[-1] ** -0.5)
-        causal = jnp.tril(jnp.ones((T, T), bool))
-        s = jnp.where(causal[None, None], s, jnp.finfo(jnp.float32).min)
-        w = jax.nn.softmax(s, axis=-1).astype(dt)
-        o = jnp.einsum("bhqk,bkhd->bqhd", w, v,
-                       preferred_element_type=jnp.float32).astype(dt)
+        o = causal_attention(q, k, v)
         o = _dot(o.reshape(B, T, -1), lp["wo"]).astype(dt)
         h = h + rms_norm(o, lp["attn_post_norm"], eps)
     with jax.named_scope(scopes.FED_MLP):

@@ -1,0 +1,362 @@
+"""Causal softmax attention — the one place the repo spells
+``softmax_causal(q k^T / sqrt(hd)) v``.
+
+``causal_attention(q, k, v)`` is the core of both language models'
+attention (`models/looped_lm.py::decoder_layer`: 16 heads of 128 at
+T = 1,024; `models/lfm2_moe.py::gqa_attention`: 32 query / 8 key-value
+heads of 64 at T = 2,048); projections, norms and rotary stay with the
+models.  Two bodies, one result:
+
+* **the plain path** — einsum, mask, ``jax.nn.softmax``, einsum: the
+  numerical spec, and what a program lowered for anything but a TPU (or a
+  shape the kernels do not take) runs.  It writes the float32
+  ``[B, H, T, T]`` scores to memory, reads them back several times, and
+  computes the full ``T x T`` products.
+* **the fused path** — a Pallas TPU forward and backward
+  (``jax.custom_vjp``) in which the scores exist one ``tile x tile`` block
+  at a time in fast memory and never reach HBM.  Forward: for each query
+  block, an online softmax over the key blocks up to the diagonal (the
+  blocks above it are not visited, the diagonal one is masked), returning
+  ``o`` and the rows' log-sum-exp.  Backward: for each key block, the
+  query blocks from the diagonal down; ``p`` is recomputed from ``q``,
+  ``k`` and the log-sum-exp, then ``dv += p^T do``, ``dp = do v^T``,
+  ``ds = p (dp - delta)`` with ``delta = rowsum(o do)``, ``dk += ds^T q``,
+  ``dq += ds k``.  Grouped-query attention reads key/value head
+  ``h // group`` through the block index map; ``dk`` / ``dv`` come out per
+  query head and are summed over the group in float32.
+
+**Precision is the plain path's**: operands in the compute dtype, every
+product accumulated in float32; scores, max, exp, sum and the running
+accumulator in float32; ``p`` (and ``ds``) cast to the compute dtype only
+as operands of ``p v``, ``p^T do``, ``ds^T q`` and ``ds k``.  No bfloat16
+exp, no approximated reciprocal, no dropped row.  (``dp`` stays float32
+here where the plain path's autodiff rounds it to the compute dtype.)
+
+**Which body runs is read off the program, not configured**: the fused
+path where the program is LOWERED for a TPU (``jax.lax.platform_dependent``:
+a compile for a described chip from a CPU process sees the kernels), ``T``
+is a multiple of 128, the head size is 64 or 128 and the operands are
+bfloat16 or float32; the plain path otherwise.  The tile is the largest
+of 512, 256, 128 that divides ``T``.  Heads as wide as the lanes (128) are
+read where they lie in ``[B, T, H, hd]``; narrower ones are brought
+heads-first around the kernels.  The decision is counted at trace time in
+``ops_kernel_path_total{op="causal_attention", path=...}`` (both bodies of
+an eligible shape are traced, so ``path="pallas"`` says what a TPU
+lowering takes), and a shape that does not qualify is named in a warning.
+"""
+from __future__ import annotations
+
+import functools
+import logging
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from fedml_tpu import obs
+from fedml_tpu.obs import scopes
+
+log = logging.getLogger(__name__)
+
+_MASK = float(jnp.finfo(jnp.float32).min)
+_NT = (((1,), (1,)), ((), ()))       # a [m, d] . b [n, d] -> [m, n]
+_NN = (((1,), (0,)), ((), ()))       # a [m, d] . b [d, n] -> [m, n]
+_TN = (((0,), (0,)), ((), ()))       # a [d, m] . b [d, n] -> [m, n]
+
+
+# -- the plain path -----------------------------------------------------------
+
+def _plain(q, k, v):
+    """einsum, mask, softmax, einsum, as both models spelled it before
+    they shared it: query heads grouped over their key/value head, and the
+    ungrouped products where every head has its own (the same mathematics;
+    XLA:CPU rounds the two spellings' gradients differently, and each
+    model's CPU results stay what they were to the bit)."""
+    B, T, H, hd = q.shape
+    dt, n_kv = q.dtype, k.shape[2]
+    grouped = n_kv != H
+    if grouped:
+        q = q.reshape(B, T, n_kv, H // n_kv, hd)
+    s = jnp.einsum("btgrd,bsgd->bgrts" if grouped else "bqhd,bkhd->bhqk",
+                   q, k, preferred_element_type=jnp.float32) * (hd ** -0.5)
+    causal = jnp.tril(jnp.ones((T, T), bool))
+    s = jnp.where(causal, s, _MASK)
+    w = jax.nn.softmax(s, axis=-1).astype(dt)
+    o = jnp.einsum("bgrts,bsgd->btgrd" if grouped else "bhqk,bkhd->bqhd",
+                   w, v, preferred_element_type=jnp.float32).astype(dt)
+    return o.reshape(B, T, H, hd)
+
+
+# -- the fused path -----------------------------------------------------------
+
+def _tile(T: int):
+    """The rows a kernel instance owns (queries forward, keys backward),
+    and the columns of the other axis it takes at a time — the scores in
+    flight are ``[tile, tile]``: the largest of 512, 256, 128 that divides
+    T, or None where none does.  (On the chip, of nine pairings of tile and
+    columns at T = 2,048 with heads of 64, 512 x 512 was the fastest, and
+    the smaller the scores in flight the slower: PERF.md section 6, PR 35.)"""
+    return next((b for b in (512, 256, 128) if T % b == 0), None)
+
+
+def _fits(q, k, v) -> bool:
+    """The kernels' requirement on shapes and dtype (module docstring)."""
+    return (q.ndim == 4 and k.shape == v.shape and q.dtype == k.dtype == v.dtype
+            and q.dtype in (jnp.bfloat16, jnp.float32)
+            and q.shape[-1] in (64, 128) and _tile(q.shape[1]) is not None
+            and q.shape[2] % k.shape[2] == 0)
+
+
+def _dot(a, b, dims):
+    """A product on the MXU, accumulated in float32.  The precision is
+    spelled out so that an ambient ``jax.default_matmul_precision`` cannot
+    change the kernel: bfloat16 products are exact in one pass, float32
+    operands take every pass."""
+    precision = (jax.lax.Precision.HIGHEST if a.dtype == jnp.float32
+                 else jax.lax.Precision.DEFAULT)
+    return jax.lax.dot_general(a, b, dims, precision=precision,
+                               preferred_element_type=jnp.float32)
+
+
+def _causal(s, queries_axis: int):
+    """The tile of scores that the diagonal crosses (queries and keys from
+    the same position on): key position <= query position, or the plain
+    path's mask value."""
+    qpos = jax.lax.broadcasted_iota(jnp.int32, s.shape, queries_axis)
+    kpos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - queries_axis)
+    return jnp.where(kpos <= qpos, s, _MASK)
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale):
+    """One tile of queries [tile, hd] against the keys [T, hd] of its head,
+    a tile of keys at a time up to the diagonal; scores are [tile
+    (queries), tile (keys)]."""
+    i = pl.program_id(2)
+    q = q_ref[...]
+    tile, hd = q.shape
+
+    def step(j, carry, diagonal=False):
+        m, l, acc = carry
+        rows = pl.ds(pl.multiple_of(j * tile, tile), tile)
+        k, v = k_ref[rows, :], v_ref[rows, :]
+        s = _dot(q, k, _NT) * scale
+        if diagonal:
+            s = _causal(s, queries_axis=0)
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new)
+        l = alpha * l + jnp.sum(p, axis=1, keepdims=True)
+        acc = alpha * acc + _dot(p.astype(v.dtype), v, _NN)
+        return m_new, l, acc
+
+    carry = (jnp.full((tile, 1), -jnp.inf, jnp.float32),
+             jnp.zeros((tile, 1), jnp.float32),
+             jnp.zeros((tile, hd), jnp.float32))
+    carry = jax.lax.fori_loop(0, i, step, carry)
+    m, l, acc = step(i, carry, diagonal=True)
+    o_ref[...] = (acc / l).astype(o_ref.dtype)
+    # the rows' statistics leave as one lane-major row [1, tile]
+    lse_ref[...] = jnp.broadcast_to(m + jnp.log(l), (tile, 128)).T[:1]
+
+
+def _bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
+                dq_ref, dk_ref, dv_ref, *, scale):
+    """One tile of keys [tile, hd] against the queries [T, hd] of one head,
+    a tile of queries at a time from the diagonal down; scores are
+    transposed, [tile (keys), tile (queries)], so the rows' statistics
+    broadcast along sublanes.  ``dq`` [T, hd] stays in fast memory across
+    the head's key tiles."""
+    j = pl.program_id(2)
+    k, v = k_ref[...], v_ref[...]
+    tile, dt = k.shape[0], k.dtype
+
+    @pl.when(j == 0)
+    def _():
+        dq_ref[...] = jnp.zeros_like(dq_ref)
+
+    def step(i, carry, diagonal=False):
+        dk, dv = carry
+        rows = pl.ds(pl.multiple_of(i * tile, tile), tile)
+        q, do = q_ref[rows, :], do_ref[rows, :]
+        s = _dot(k, q, _NT) * scale
+        if diagonal:
+            s = _causal(s, queries_axis=1)
+        p = jnp.exp(s - lse_ref[i])
+        dv = dv + _dot(p.astype(dt), do, _NN)
+        dp = _dot(v, do, _NT)
+        ds = (p * (dp - delta_ref[i]) * scale).astype(dt)
+        dk = dk + _dot(ds, q, _NN)
+        dq_ref[rows, :] += _dot(ds, k, _TN)
+        return dk, dv
+
+    carry = step(j, (jnp.zeros(k.shape, jnp.float32),) * 2, diagonal=True)
+    dk, dv = jax.lax.fori_loop(j + 1, q_ref.shape[0] // tile, step, carry)
+    dk_ref[...] = dk
+    dv_ref[...] = dv
+
+
+# How the kernels reach one head of a [B, T, H, hd] operand.  A head as wide
+# as the lanes (hd a multiple of 128) is read IN PLACE: columns h of
+# [B, T, H * hd], a free reshape, cut out by the block itself.  A narrower
+# head cannot be cut out of the lanes by a block, so those operands are
+# brought heads-first, [B, H, T, hd]: transposes, which XLA folds into their
+# neighbours where it can.  The kernels see [rows, hd] either way.  (Measured
+# in `ouro2p6b`, heads of 128, against heads-first for every head size: 66 ms
+# of copies a round less, most of it back inside the matrix products that
+# had absorbed the relayouts, + 0.15 % rounds a second; PERF.md section 6,
+# PR 35.)
+
+def _kernel_layout(a):
+    B, T, H, hd = a.shape
+    return a.reshape(B, T, H * hd) if hd % 128 == 0 else jnp.swapaxes(a, 1, 2)
+
+
+def _model_layout(a, H: int):
+    """`_kernel_layout`'s inverse, for a result of H heads."""
+    if a.ndim == 3:
+        return a.reshape(*a.shape[:2], H, -1)
+    return jnp.swapaxes(a, 1, 2)
+
+
+def _block(rows: int, hd: int, row, head=lambda h: h):
+    """``rows`` positions of one head in the kernels' layout: block
+    ``row(i)`` of the positions, head ``head(h)``, at grid point (b, h, i)."""
+    if hd % 128 == 0:
+        return pl.BlockSpec((None, rows, hd),
+                            lambda b, h, i: (b, row(i), head(h)))
+    return pl.BlockSpec((None, None, rows, hd),
+                        lambda b, h, i: (b, head(h), row(i), 0))
+
+
+def _struct(like, shape, dtype):
+    """A result's shape with the operands' varying mesh axes: under
+    ``shard_map(check_vma=True)`` jax refuses a ``pallas_call`` whose
+    results do not say over which axes they vary."""
+    return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
+
+
+def _params(interpret):
+    """How the call is run: Mosaic with the grid's semantics (batch and
+    heads independent, the blocks of a head in order), or an interpreter
+    (the CPU tests'; it takes no compiler parameters)."""
+    if interpret:
+        return dict(interpret=interpret)
+    return dict(compiler_params=pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary")))
+
+
+def _fused_output_lse(q, k, v, interpret):
+    """o [B, T, H, hd] and the rows' log-sum-exp [B, H, T] in float32."""
+    B, T, H, hd = q.shape
+    group, tile = H // k.shape[2], _tile(T)
+    own = _block(tile, hd, row=lambda i: i)
+    whole_kv = _block(T, hd, row=lambda i: 0, head=lambda h: h // group)
+    stats = pl.BlockSpec((None, None, None, 1, tile),
+                         lambda b, h, i: (b, h, i, 0, 0))
+    q, k, v = map(_kernel_layout, (q, k, v))
+    o, lse = pl.pallas_call(
+        functools.partial(_fwd_kernel, scale=hd ** -0.5),
+        grid=(B, H, T // tile), in_specs=[own, whole_kv, whole_kv],
+        out_specs=[own, stats],
+        out_shape=[_struct(q, q.shape, q.dtype),
+                   _struct(q, (B, H, T // tile, 1, tile), jnp.float32)],
+        **_params(interpret))(q, k, v)
+    return _model_layout(o, H), lse.reshape(B, H, T)
+
+
+def _fused_grads(q, k, v, o, lse, do, interpret):
+    """(dq, dk, dv) in the operands' shapes and dtype."""
+    B, T, H, hd = q.shape
+    n_kv, tile = k.shape[2], _tile(T)
+    group = H // n_kv
+    delta = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)
+    # one lane-major row [1, tile] of statistics for each block of queries
+    by_block = lambda a: a.reshape(B, H, T // tile, 1, tile)
+    stats = pl.BlockSpec((None, None, T // tile, 1, tile),
+                         lambda b, h, j: (b, h, 0, 0, 0))
+    whole = _block(T, hd, row=lambda j: 0)
+    own = _block(tile, hd, row=lambda j: j)
+    own_kv = _block(tile, hd, row=lambda j: j, head=lambda h: h // group)
+    q, k, v, do = map(_kernel_layout, (q, k, v, do))
+    per_head = _struct(q, q.shape, jnp.float32)
+    grads = pl.pallas_call(
+        functools.partial(_bwd_kernel, scale=hd ** -0.5),
+        grid=(B, H, T // tile),
+        in_specs=[whole, whole, stats, stats, own_kv, own_kv],
+        out_specs=[whole, own, own], out_shape=[per_head] * 3,
+        **_params(interpret))(q, do, by_block(lse),
+                              by_block(jnp.swapaxes(delta, 1, 2)), k, v)
+    dq, dk, dv = (_model_layout(d, H) for d in grads)
+    # the query heads of a group share their key/value head
+    over_group = lambda d: jnp.sum(d.reshape(B, T, n_kv, group, hd), axis=3)
+    return (dq.astype(q.dtype), over_group(dk).astype(k.dtype),
+            over_group(dv).astype(v.dtype))
+
+
+def _plain_output_lse(q, k, v):
+    """The plain path keeps no statistics: its backward pass is its own
+    transposition.  The zeros stand in for them, made from ``q`` so that
+    they vary over the mesh axes the kernel's would."""
+    return _plain(q, k, v), 0.0 * jnp.swapaxes(q[..., 0], 1, 2).astype(jnp.float32)
+
+
+def _plain_grads(q, k, v, o, lse, do):
+    return jax.vjp(_plain, q, k, v)[1](do)
+
+
+def _lowered(interpret, fused, plain, *args):
+    """``fused`` where the program is lowered for a TPU, ``plain`` for any
+    other platform; ``interpret`` (the CPU tests') runs the kernels in
+    Pallas interpret mode whatever the platform."""
+    if interpret:
+        return fused(*args, interpret)
+    return jax.lax.platform_dependent(
+        *args, tpu=lambda *a: fused(*a, False), default=plain)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _attention(q, k, v, interpret=False):
+    """A shape the kernels take, on [B, T, H, hd] operands.  The platform
+    is chosen inside each of the three rules, so no transformation ever
+    differentiates through the choice (a differentiated switch would carry
+    the plain branch's [B, H, T, T] residuals in both)."""
+    return _attention_fwd(q, k, v, interpret)[0]
+
+
+def _attention_fwd(q, k, v, interpret):
+    o, lse = _lowered(interpret, _fused_output_lse, _plain_output_lse, q, k, v)
+    return o, (q, k, v, o, lse)
+
+
+def _attention_bwd(interpret, res, do):
+    # the backward kernel is attention's too: the benchmark's labels read
+    # the scope, forward and backward alike (obs/scopes.py)
+    with jax.named_scope(scopes.FED_ATTENTION):
+        return _lowered(interpret, _fused_grads, _plain_grads, *res, do)
+
+
+_attention.defvjp(_attention_fwd, _attention_bwd)
+
+
+# -- the choice ---------------------------------------------------------------
+
+def causal_attention(q, k, v):
+    """``softmax_causal(q k^T / sqrt(hd)) v``: q [B, T, H, hd], k and v
+    [B, T, H_kv, hd] with ``H % H_kv == 0`` (query head h reads key/value
+    head ``h // (H / H_kv)``) -> [B, T, H, hd] in q's dtype.
+
+    Operands in their dtype, every product accumulated in float32, the
+    softmax (scores, max, exp, sum) in float32 on both paths.  The fused
+    kernels run where the program is lowered for a TPU and the shape fits
+    (module docstring); no option selects a path."""
+    fused = _fits(q, k, v)
+    obs.counter("ops_kernel_path_total", op="causal_attention",
+                path="pallas" if fused else "reference").inc()
+    if fused:
+        return _attention(q, k, v)
+    if jax.default_backend() == "tpu":      # the log line only, as group_norm
+        log.warning("causal_attention: q %s %s, k %s does not fit the fused "
+                    "kernels; using the plain path", tuple(q.shape),
+                    q.dtype.name, tuple(k.shape))
+    return _plain(q, k, v)

@@ -1,0 +1,239 @@
+"""`ops/attention.py::causal_attention` — the fused kernels (Pallas
+interpret mode, small aligned shapes) against the plain path, under the
+transformations the engine applies to them, and the rule that picks a path.
+
+What only a chip can show — Mosaic-compiled kernels at the cells' shapes —
+is `tests/test_tpu_compile.py::test_causal_attention_compiles` (compiles)
+and `chip_smoke.py` phase (c) (values)."""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import Mesh, PartitionSpec as P
+
+from fedml_tpu import obs
+from fedml_tpu.models import create_model, lfm2_moe, looped_lm
+from fedml_tpu.ops import attention
+from fedml_tpu.ops.attention import causal_attention
+
+T = 384          # three tiles of 128: an unmasked loop and a diagonal
+
+
+def _fused(q, k, v):
+    return attention._attention(q, k, v, True)
+
+
+def _operands(shape_q, n_kv, dtype, seed=0):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 4)
+    kv = shape_q[:-2] + (n_kv, shape_q[-1])
+    q, k, v, w = (jax.random.normal(key, s, jnp.float32) for key, s in
+                  zip(keys, (shape_q, kv, kv, shape_q)))
+    return q.astype(dtype), k.astype(dtype), v.astype(dtype), w
+
+
+def _out_and_grads(fn, q, k, v, w):
+    """[o, dq, dk, dv] in float32 of loss = sum(o * w)."""
+    def loss(q, k, v):
+        o = fn(q, k, v)
+        return jnp.sum(o.astype(jnp.float32) * w), o
+    grads, o = jax.jit(jax.grad(loss, (0, 1, 2), has_aux=True))(q, k, v)
+    return [np.asarray(a, np.float32) for a in (o,) + grads]
+
+
+def _rel(got, want):
+    return [float(np.max(np.abs(a - b)) / np.max(np.abs(b)))
+            for a, b in zip(got, want)]
+
+
+def _distance(got, want):
+    return [float(np.linalg.norm(a - b) / np.linalg.norm(b))
+            for a, b in zip(got, want)]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("hd", [64, 128])
+@pytest.mark.parametrize("heads", [(2, 2), (4, 1)], ids=["mha", "gqa4"])
+def test_fused_matches_plain(heads, hd, dtype):
+    """Output and the three gradients.  float32: to 1e-5 of the largest
+    value.  bfloat16: no farther (relative l2 distance: the largest single
+    error is one rounding of the result on either path) from a float32
+    oracle than 1.5 x the plain bfloat16 path is — the softmax stays
+    float32 and every product accumulates in float32 on both, they differ
+    in where they round (on the CPU the plain path's autodiff keeps ds
+    float32 as an operand, which a TPU's one-pass product does not)."""
+    H, n_kv = heads
+    q, k, v, w = _operands((2, T, H, hd), n_kv, dtype)
+    got = _out_and_grads(_fused, q, k, v, w)
+    plain = _out_and_grads(attention._plain, q, k, v, w)
+    if dtype == jnp.float32:
+        assert max(_rel(got, plain)) <= 1e-5, _rel(got, plain)
+        return
+    oracle = _out_and_grads(attention._plain, *(
+        a.astype(jnp.float32) for a in (q, k, v)), w)
+    for f, p in zip(_distance(got, oracle), _distance(plain, oracle)):
+        assert f <= 1.5 * p, (_distance(got, oracle), _distance(plain, oracle))
+
+
+@pytest.fixture(scope="module", params=[64, 128], ids=["hd64", "hd128"])
+def clients(request):
+    """Two clients' operands [C, B, T, H, hd] and the plain gradients, for
+    heads brought heads-first (64) and heads read in place (128)."""
+    q, k, v, w = _operands((2, 1, 256, 4, request.param), 2, jnp.float32)
+    loss = lambda fn: lambda q, k, v, w: jnp.sum(jnp.sin(fn(q, k, v)) * w)
+    grad = lambda fn: jax.grad(loss(fn), (0, 1, 2))
+    want = jax.jit(jax.vmap(grad(attention._plain)))(q, k, v, w)
+    return (q, k, v, w), grad, want
+
+
+def _close(got, want):
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, atol=1e-5 * float(jnp.max(jnp.abs(b))))
+
+
+def test_under_vmap_over_clients(clients):
+    args, grad, want = clients
+    _close(jax.jit(jax.vmap(grad(_fused)))(*args), want)
+
+
+def test_under_checkpoint(clients):
+    args, grad, want = clients
+    _close(jax.jit(jax.vmap(grad(jax.checkpoint(_fused))))(*args), want)
+
+
+def test_under_shard_map_with_check_vma(clients):
+    """The engine's `shard_map(check_vma=True)` (what refused megablox in
+    PR 34: a `pallas_call` result without a `vma`), over a vmap over
+    clients, on a two-device mesh: the kernels run (the TPU interpreter:
+    the plain one re-binds the kernel's untyped equations under the mesh),
+    and the public function lowers for a TPU with both kernels in it."""
+    args, grad, want = clients
+    mesh = Mesh(np.array(jax.devices()[:2]), ("clients",))
+    spec = (P("clients"),) * 4
+
+    def sharded(fn):
+        return jax.jit(jax.shard_map(jax.vmap(grad(fn)), mesh=mesh,
+                                     in_specs=spec, out_specs=spec[:3]))
+
+    interpreted = lambda q, k, v: attention._attention(
+        q, k, v, pltpu.InterpretParams())
+    _close(sharded(interpreted)(*args), want)
+    engine_like = sharded(jax.checkpoint(causal_attention))
+    for a, b in zip(engine_like(*args), want):          # CPU: the plain path
+        np.testing.assert_array_equal(a, b)
+    lowered = engine_like.trace(*args).lower(lowering_platforms=("tpu",))
+    assert lowered.as_text().count("tpu_custom_call") >= 2
+
+
+def test_causality_is_bitwise():
+    """Changing token t leaves every output before t as it was, to the bit."""
+    q, k, v, _ = _operands((1, T, 2, 64), 1, jnp.float32)
+    t = 200                                       # inside the second tile
+    bump = lambda a: a.at[:, t].add(1.0)
+    before = jax.jit(_fused)(q, k, v)
+    after = jax.jit(_fused)(bump(q), bump(k), bump(v))
+    np.testing.assert_array_equal(before[:, :t], after[:, :t])
+    assert not np.array_equal(before[:, t:], after[:, t:])
+
+
+def _paths():
+    return {path: obs.counter("ops_kernel_path_total", op="causal_attention",
+                              path=path).value
+            for path in ("pallas", "reference")}
+
+
+@pytest.mark.parametrize("shape, n_kv, dtype", [
+    ((1, 200, 2, 64), 2, jnp.float32),            # T not a multiple of 128
+    ((1, 256, 2, 32), 2, jnp.float32),            # a head size of 32
+    ((1, 256, 2, 64), 2, jnp.float16),            # a dtype of neither kind
+], ids=["T200", "hd32", "f16"])
+def test_a_shape_that_does_not_fit_takes_the_plain_path(shape, n_kv, dtype):
+    q, k, v, _ = _operands(shape, n_kv, dtype)
+    before = _paths()
+    lowered = jax.jit(causal_attention).lower(q, k, v)
+    after = _paths()
+    assert after["reference"] == before["reference"] + 1
+    assert after["pallas"] == before["pallas"]
+    assert "platform_index" not in str(jax.make_jaxpr(causal_attention)(q, k, v))
+    np.testing.assert_array_equal(lowered.compile()(q, k, v),
+                                  jax.jit(attention._plain)(q, k, v))
+
+
+def test_a_shape_that_fits_is_counted_and_its_cpu_lowering_is_the_plain_path():
+    q, k, v, w = _operands((1, 256, 4, 64), 2, jnp.bfloat16)
+    before = _paths()
+    lowered = jax.jit(causal_attention).lower(q, k, v)
+    after = _paths()
+    assert after["pallas"] == before["pallas"] + 1
+    assert after["reference"] == before["reference"]
+    assert "custom_call" not in lowered.as_text()
+    for a, b in zip(_out_and_grads(causal_attention, q, k, v, w),
+                    _out_and_grads(attention._plain, q, k, v, w)):
+        np.testing.assert_array_equal(a, b)
+    # the same trace lowered for a TPU holds the kernel
+    tpu = jax.jit(causal_attention).trace(q, k, v).lower(
+        lowering_platforms=("tpu",))
+    assert "tpu_custom_call" in tpu.as_text()
+
+
+# -- the refactor changed nothing: the parent's bodies, written out ----------
+
+def _parent_looped(q, k, v):
+    """`decoder_layer`'s core before ISSUE 35."""
+    T, dt = q.shape[1], q.dtype
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                   preferred_element_type=jnp.float32)
+    s = s * (q.shape[-1] ** -0.5)
+    causal = jnp.tril(jnp.ones((T, T), bool))
+    s = jnp.where(causal[None, None], s, jnp.finfo(jnp.float32).min)
+    w = jax.nn.softmax(s, axis=-1).astype(dt)
+    return jnp.einsum("bhqk,bkhd->bqhd", w, v,
+                      preferred_element_type=jnp.float32).astype(dt)
+
+
+def _parent_gqa(q, k, v):
+    """`gqa_attention`'s core before ISSUE 35."""
+    B, T, n_heads, _ = q.shape
+    n_kv_heads, dt = k.shape[2], q.dtype
+    q = q.reshape(B, T, n_kv_heads, n_heads // n_kv_heads, -1)
+    s = jnp.einsum("btgrd,bsgd->bgrts", q, k,
+                   preferred_element_type=jnp.float32) * (q.shape[-1] ** -0.5)
+    causal = jnp.tril(jnp.ones((T, T), bool))
+    s = jnp.where(causal, s, jnp.finfo(jnp.float32).min)
+    w = jax.nn.softmax(s, axis=-1).astype(dt)
+    o = jnp.einsum("bgrts,bsgd->btgrd", w, v,
+                   preferred_element_type=jnp.float32).astype(dt)
+    return o.reshape(B, T, n_heads, -1)
+
+
+@pytest.mark.parametrize("name, module, parent, kwargs", [
+    ("looped_lm", looped_lm, _parent_looped, {}),
+    ("lfm2_moe", lfm2_moe, _parent_gqa, {"lora_rank": 2}),
+])
+def test_models_are_bitwise_what_they_were(monkeypatch, name, module, parent,
+                                           kwargs):
+    """Logits and parameter gradients of both models at a tiny size, with
+    `causal_attention` against the parent's einsum-mask-softmax-einsum in
+    its place."""
+    model = create_model(name, output_dim=50, **kwargs)
+    x = jax.random.randint(jax.random.PRNGKey(1), (2, 12), 0, 50)
+    variables = model.init(jax.random.PRNGKey(0), x)
+    if name == "lfm2_moe":       # B = 0 would hide the adapters' gradients
+        variables = jax.tree.map(
+            lambda a: a + 0.01 if a.dtype == jnp.float32 else a, variables)
+
+    def run():
+        def loss(params):
+            logits = model.apply({"params": params}, x)
+            return jnp.mean(jnp.square(logits)), logits
+        return jax.jit(jax.value_and_grad(loss, has_aux=True))(
+            variables["params"])
+
+    (_, logits), grads = run()
+    monkeypatch.setattr(module, "causal_attention", parent)
+    (_, want_logits), want_grads = run()
+    np.testing.assert_array_equal(logits, want_logits)
+    assert float(jnp.max(jnp.abs(logits))) > 0
+    for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(want_grads)):
+        np.testing.assert_array_equal(a, b)

@@ -20,7 +20,8 @@ import chip_smoke  # noqa: E402
 TINY = chip_smoke.Sizes(
     model="lr", n_clients=8, samples_per_client=16, batch_size=8,
     image_hw=8, warmup_rounds=1, timed_rounds=2, oracle_clients=4,
-    agg_clients=4, gn_shapes=((8, 4, 4, 16),), platform="cpu")
+    agg_clients=4, gn_shapes=((8, 4, 4, 16),),
+    attn_shapes=((1, 128, 2, 1, 64),), platform="cpu")
 
 
 def _lines(capsys) -> list:
@@ -83,7 +84,7 @@ def test_phase_kernels_tiny_interpret_mode(capsys):
     lines = _lines(capsys)
     ops = [l["op"] for l in lines]
     assert ops == ["weighted_mean_pallas", "robust_weighted_mean_pallas",
-                   "group_norm", "group_norm"]
+                   "group_norm", "group_norm", "causal_attention"]
     assert not any(l["compiled"] for l in lines)   # no Mosaic on the CPU
 
 

@@ -307,3 +307,36 @@ def test_lstm_sequence_vjp_keeps_the_scopes():
     assert seen["forward", "dot", "dot_general"] == 1
     assert seen["backward", "dot", "dot_general"] == 3
     assert seen["backward", "reduce", "reduce_sum"] >= 1
+
+
+def test_fused_attention_kernels_carry_the_attention_scope():
+    """`ops/attention.py::causal_attention` in a tiny looped model (T = 128,
+    heads of 64: a shape the kernels take), lowered for the TPU platform:
+    the forward kernel, its rematerialised twin and the backward kernel —
+    a `custom_vjp` rule, which opens the scope itself — all carry
+    `fed_attention` in their name, and `label_of` reads `attention`: the
+    labels still partition `round_busy_ms` (`attention_ms` /
+    `gqa_attention_ms` read the scope, not a kernel's name)."""
+    import jax.numpy as jnp
+    from fedml_tpu.models import create_model
+    model = create_model("looped_lm", output_dim=50, d_model=128, n_heads=2,
+                         head_dim=64, d_ff=128, n_layers=1, n_passes=2)
+    x = jnp.zeros((1, 128), jnp.int32)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), x))["params"]
+
+    def loss(p, x):
+        with jax.named_scope(scopes.FED_FORWARD):
+            return jnp.mean(model.apply({"params": p}, x))
+
+    text = jax.jit(jax.grad(loss)).trace(params, x).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
+    names = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    kernels = [names[m.group(1)] for m in re.finditer(
+        r"@tpu_custom_call.*loc\((#loc\d+)\)$", text, re.M)]
+    assert len(kernels) == 3, kernels
+    assert sum("rematted_computation" in k for k in kernels) == 1
+    for k in kernels:
+        assert scopes.FED_ATTENTION in k and k.endswith("pallas_call"), k
+        assert scopes.label_of("jit(f)/transpose(jvp(fed_forward))/" + k) \
+            == "attention"

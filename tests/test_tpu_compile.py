@@ -15,6 +15,17 @@ carries and where its batch loop ends) and of `ouro_2p6b` (0.51 B parameters, wi
 reference check runs), and the documented C = 128 size limit of the
 fused robust aggregation.
 
+The fused causal attention (`ops/attention.py`, ISSUE 35) is compiled at
+both language-model cells' shapes, and with `-m slow` both cells' rounds
+are shown to hold its kernels and no float32 buffer the size of a step's
+scores.  The four cells without attention are not its to move: their
+real-shape round programs (`_resident_round` of `xdev10of4000`,
+`xdev50of342k`, `silo128of1024`, `silo128of4096x4`), compiled here on the
+parent `43bd4f9` and on PR 35's tree, are the same text line for line —
+49,913 / 3,514 / 125,859 / 130,049 lines, 0 differ outside this file's
+own line numbers in the source table (builder, CPU compile rehearsal,
+PR 35; as PRs 29 and 34 showed theirs).
+
 A compile that passes is not a chip run: nothing executes, so these
 tests say nothing about results or times.  Skipped where the topology
 cannot be described.
@@ -36,6 +47,9 @@ from jax.sharding import SingleDeviceSharding
 from fedml_tpu.ops import aggregate, groupnorm
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+import chip_smoke  # noqa: E402  (its sizes are the chip's; imports no jax)
+
 RESNET18_N = 11_173_962                         # ResNet-18-GN, 10 classes
 N_PADDED = RESNET18_N + (-RESNET18_N) % aggregate.TILE
 
@@ -140,6 +154,39 @@ def test_groupnorm_large_stage_compiles(topo, shape, case):
     case(topo, shape, jnp.float32)
 
 
+# -- fused causal attention at the two language-model cells' shapes ---------
+
+# (B, T, H, H_kv, head size): a local step of ouro2p6b.silo4of256t1024 and
+# a chunk's local step of lfm2moe24b.lora4of256t2048, as phase (c) runs them
+ATTENTION = list(chip_smoke.Sizes.attn_shapes)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32-highest"])
+@pytest.mark.parametrize("shape", ATTENTION, ids=str)
+def test_causal_attention_compiles(topo, shape, dtype):
+    """`ops.attention.causal_attention`, lowered from this CPU process for
+    the described chip, holds the forward and the backward kernel (the
+    path is chosen by the platform the program is lowered FOR) and no
+    float32 `[B, H, T, T]` buffer.  float32 operands take the kernels too,
+    at matmul precision "highest" as the benchmark's reference check traces
+    its float32 twin: Mosaic accepts them (XLA:TPU's grouped product did
+    not, PR 34)."""
+    from fedml_tpu.ops.attention import causal_attention
+    B, T, H, n_kv, hd = shape
+
+    def grads(q, k, v):
+        return jax.grad(lambda *a: jnp.sum(
+            causal_attention(*a).astype(jnp.float32) ** 2), (0, 1, 2))(q, k, v)
+
+    with jax.default_matmul_precision(
+            "highest" if dtype == jnp.float32 else "default"):
+        c = _compile(topo, grads, ((B, T, H, hd), dtype),
+                     ((B, T, n_kv, hd), dtype), ((B, T, n_kv, hd), dtype))
+    _assert_kernels(c, 2)
+    assert not re.search(rf"f32\[[\d,]*{T},{T}\]", c.as_text())
+
+
 # -- the documented size limit --------------------------------------------
 
 @pytest.mark.slow
@@ -166,8 +213,6 @@ def _headline_round(topo, n_devices: int):
     """chip_smoke.py's headline engine over a mesh of described chips,
     its streaming round lowered from shape structs (128 clients x 13
     batches x 32, ResNet-18-GN, bf16 compute, chunk 2, unroll 8)."""
-    sys.path.insert(0, REPO)
-    import chip_smoke
     sz = chip_smoke.Sizes()
     from fedml_tpu.parallel.mesh import (client_sharding, make_mesh,
                                          replicated_sharding,
@@ -214,7 +259,6 @@ def test_headline_round_compiles_for_four_chips(topo):
 # -- the resident round of a benchmark cell ---------------------------------
 
 def _bench_files(config: str, traffic: str):
-    sys.path.insert(0, REPO)
     from fedbench.harness import manifest
     return tuple(manifest.load_json(os.path.join(
         manifest.BENCH_DIR, kind, name + ".json"))
@@ -386,12 +430,37 @@ def test_ouro_round_and_its_float32_twin_fit_one_chip(topo, monkeypatch):
                   hlo_instructions(compiled.as_text())
                   if re.search(r"f32\[\d{9,}\]", result)]
     assert not whole_tree, whole_tree
+    _assert_fused_attention(compiled.as_text(), ATTENTION[0])
     real = build.make_engine
     monkeypatch.setattr(build, "make_engine", lambda *a, **k: real(
         *a, **{**k, "train_dtype": "float32", "local_dtype": None}))
     with jax.default_matmul_precision("highest"):
         twin = _resident_round(topo, config, dict(traffic, population=4, cohort=4))
     assert needs(twin) < 15.75 * 2 ** 30, twin.memory_analysis()
+    _assert_fused_attention(twin.as_text(), ATTENTION[0])
+
+
+def _assert_fused_attention(text: str, shape):
+    """The round program holds the attention kernels, forward and backward,
+    labelled `attention`, and no float32 buffer labelled so is as large as
+    a local step's scores (B x H x T x T elements of `shape`, one of
+    ATTENTION): they never reach HBM.  (By label and size, not by shape:
+    in `lfm2_24b_a2b` T = hidden = 2048 and the head's logits have as many
+    elements as a chunk's scores.)"""
+    B, T, H, _, _ = shape
+    scores = B * H * T * T
+    from fedml_tpu.obs import programs
+    from parallel_case import hlo_instructions
+    smap = programs.scope_map_of_hlo_text(text)
+    kernels = [name for name, _, opcode, rest in hlo_instructions(text)
+               if opcode == "custom-call" and "tpu_custom_call" in rest
+               and smap[name] == "attention"]
+    assert len(kernels) >= 2, kernels
+    large = [(name, result) for name, result, _, _ in hlo_instructions(text)
+             if smap.get(name) == "attention"
+             and (m := re.match(r"f32\[([\d,]+)\]", result))
+             and np.prod([int(d) for d in m.group(1).split(",")]) >= scores]
+    assert not large, large
 
 
 @pytest.mark.slow
@@ -443,7 +512,11 @@ def test_lfm2_adapter_round_and_its_float32_twin_fit_one_chip(topo, monkeypatch)
                 + mem.generated_code_size_in_bytes)
 
     engine, variables, compiled = dispatched(traffic)
-    assert needs(compiled) < 13.5e9, compiled.memory_analysis()
+    # 15.08e9 at the file's chunk 4 (11.6e9 at chunk 1, where this bound
+    # was first written as 13.5e9: the pool grows with the chunk, the
+    # traffic file's `chunk_why`); the fused attention left it where it was
+    # (the head's float32 logits, not the scores, are the peak)
+    assert needs(compiled) < 15.75 * 2 ** 30, compiled.memory_analysis()
     trained = engine.trainer.trained_variables(variables)
     n_trained = sum(int(np.prod(a.shape)) for a in jax.tree.leaves(trained))
     assert n_trained == config["widths"]["parameters_trained"]
@@ -460,7 +533,9 @@ def test_lfm2_adapter_round_and_its_float32_twin_fit_one_chip(topo, monkeypatch)
         assert not re.search(rf"f32\[{dims}\]", text), shape
         assert not re.search(rf"bf16\[\d+,{dims}\]", text), shape
     assert text.count("ragged-dot") > 0            # XLA:TPU's grouped product
+    _assert_fused_attention(text, ATTENTION[1])
     with jax.default_matmul_precision("highest"):
         _, _, twin = dispatched(dict(traffic, population=4, cohort=4),
                                 train_dtype="float32", local_dtype=None)
     assert needs(twin) < 15.75 * 2 ** 30, twin.memory_analysis()
+    _assert_fused_attention(twin.as_text(), ATTENTION[1])
