@@ -21,19 +21,18 @@ PERF.md stage table).  This registry makes them STANDING artifacts:
   (fedml_tpu/obs/__init__.py) reads it to attribute backend-compile
   counts/seconds per family instead of one global pair (fallback label
   ``unattributed``), so a recompile storm names its culprit;
-* an HLO flop/byte census joins in: either live (``enable_census()``
-  — one extra AOT lower+compile per family on its first dispatch,
-  reading ``compiled.cost_analysis()``; default OFF so the hot paths
-  and tier-1 pay nothing) or from a ``tools/hlo_copy_audit.py --out``
-  artifact (``load_census()``), giving per-family and whole-run
+* an HLO flop/byte census joins in from a ``tools/hlo_copy_audit.py
+  --out`` artifact (``load_census()``) or a caller's own numbers
+  (``ProgramFamily.attach_census``), giving per-family and whole-run
   FLOP/bytes-moved totals (no utilization: dispatch wall is HOST time
   of an asynchronous enqueue, so the "MFU" this module once derived
   from it measured nothing — the device trace's ``round_roofline`` is
   that number, PERF.md §3);
-* ``scope_map()`` names the scopes of the compiled program: HLO
-  instruction name -> ``fed_*`` scope label (obs/scopes.py), which is
-  what lets a reader split a device trace whose events carry bare HLO
-  names by layer;
+* ``scope_map()`` and ``phase_map()`` name the scopes and the phases of
+  the compiled program: HLO instruction name -> ``fed_*`` scope label /
+  forward, recompute, backward or other (obs/scopes.py), which is what
+  lets a reader split a device trace whose events carry bare HLO names
+  by layer and by pass;
 * every family maps to a canonical timeline stage
   (obs/timeline.py PROGRAM_FAMILY_STAGES), so the profile table groups
   into the same taxonomy as the round critical path.
@@ -53,12 +52,9 @@ from typing import Any, Optional
 from fedml_tpu.obs import scopes
 from fedml_tpu.obs.metrics import quantile_from_cumulative
 
-ENV_CENSUS = "FEDML_OBS_CENSUS"
-
 _lock = threading.Lock()
 _families: dict[str, "ProgramFamily"] = {}
 _tls = threading.local()
-_census_enabled: Optional[bool] = None      # None = resolve env lazily
 
 
 def _stage_of(family: str) -> str:
@@ -144,18 +140,6 @@ def reset() -> None:
 
 # -- census ------------------------------------------------------------------
 
-def enable_census(on: bool = True) -> None:
-    global _census_enabled
-    _census_enabled = bool(on)
-
-
-def census_enabled() -> bool:
-    global _census_enabled
-    if _census_enabled is None:
-        _census_enabled = os.environ.get(ENV_CENSUS, "") not in ("", "0")
-    return _census_enabled
-
-
 def cost_analysis_of(compiled) -> tuple[Optional[float], Optional[float]]:
     """(flops, bytes_accessed) from a jax Compiled's cost analysis —
     handles the dict and the per-partition-list shapes across jax
@@ -232,15 +216,13 @@ def peak_flops() -> float:
 
 class InstrumentedProgram:
     """Transparent wrapper around one jitted program: counts + times
-    each dispatch (and opens the ``program.dispatch`` span around it),
-    marks the thread's current family for compile attribution, and
-    (census mode) runs a one-time AOT cost analysis.  `lower` and every
-    other attribute delegate to the wrapped jit, so AOT consumers
-    (hlo_copy_audit's ``fn.lower(*args).compile()``) see the real
-    thing."""
+    each dispatch (and opens the ``program.dispatch`` span around it)
+    and marks the thread's current family for compile attribution.
+    `lower` and every other attribute delegate to the wrapped jit, so
+    AOT consumers (hlo_copy_audit's ``fn.lower(*args).compile()``) see
+    the real thing."""
 
-    __slots__ = ("_fn", "_family", "_census_tried", "_signature",
-                 "_scope_map", "_on_result")
+    __slots__ = ("_fn", "_family", "_signature", "_maps", "_on_result")
 
     def __init__(self, fn, family: ProgramFamily, on_result=None):
         self._fn = fn
@@ -248,9 +230,8 @@ class InstrumentedProgram:
         # called with what a dispatch returned, before the caller sees it
         # (the engine keeps the round's counters: no device sync here)
         self._on_result = on_result
-        self._census_tried = False
         self._signature = None      # abstract (args, kwargs), 1st dispatch
-        self._scope_map = None
+        self._maps = None           # (scope map, phase map), on demand
 
     @property
     def inner(self):
@@ -262,9 +243,6 @@ class InstrumentedProgram:
 
     def __call__(self, *args, **kwargs):
         fam = self._family
-        if (not self._census_tried and fam.flops_per_dispatch is None
-                and census_enabled()):
-            self._try_census(args, kwargs)
         if self._signature is None:
             self._signature = _abstract_signature(args, kwargs)
         prev = getattr(_tls, "family", None)
@@ -286,22 +264,42 @@ class InstrumentedProgram:
     def scope_map(self) -> Optional[dict]:
         """{HLO instruction name -> scope label} of the compiled program
         (labels: obs/scopes.py LABELS; the labelling rule:
-        ``scope_map_of_hlo_text``), or None before the first dispatch /
+        ``maps_of_hlo_text``), or None before the first dispatch /
         for a callable that cannot be lowered.
 
-        Nothing is computed until this is called: the first dispatch
-        only remembered the abstract signature (shape, dtype, sharding);
-        here it is lowered and compiled again (the persistent compile
-        cache has the executable) and the optimized module's text is
-        walked once.  The cache keys on the module WITHOUT its metadata,
-        so the executable it returns may carry another build's names (the
-        same program before it had scopes): where the text lacks a scope
-        that the lowering has, it is compiled once more past the cache.
-        Instruction names do not depend on metadata, so either text names
-        the ops of the executable that ran.  Every instruction of every
-        computation is listed (fused bodies too); a device trace names
-        only the ones that ran as ops of their own."""
-        if self._scope_map is None:
+        Nothing is computed until this or ``phase_map()`` is called: the
+        first dispatch only remembered the abstract signature (shape,
+        dtype, sharding); here it is lowered and compiled again (the
+        persistent compile cache has the executable) and the optimized
+        module's text is walked once, for both maps.  The cache keys on
+        the module WITHOUT its metadata, so the executable it returns may
+        carry another build's names (the same program before it had
+        scopes): where the text lacks a scope that the lowering has, it
+        is compiled once more past the cache.  Instruction names do not
+        depend on metadata, so either text names the ops of the
+        executable that ran.  Every instruction of every computation is
+        listed (fused bodies too); a device trace names only the ones
+        that ran as ops of their own."""
+        maps = self._both_maps()
+        return None if maps is None else maps[0]
+
+    def phase_map(self) -> Optional[dict]:
+        """{HLO instruction name -> phase} of the same executable
+        (phases: obs/scopes.py PHASES, the rule ``scopes.phase_of``), from
+        the same compile and the same walk as ``scope_map()``: asking for
+        both costs one of each, in either order.
+
+        A fusion has its root's phase, as it has its root's scope: a
+        recomputed elementwise op that XLA fuses into a backward consumer
+        is booked ``backward``.  So ``recompute`` is exact for matrix
+        products, custom calls and fusions rooted in them, and a floor
+        for elementwise work (``maps_of_hlo_text`` has the rule for an
+        instruction without a traced name)."""
+        maps = self._both_maps()
+        return None if maps is None else maps[1]
+
+    def _both_maps(self):
+        if self._maps is None:
             if self._signature is None or not hasattr(self._fn, "lower"):
                 return None
             args, kwargs = self._signature
@@ -312,27 +310,8 @@ class InstrumentedProgram:
                 traced = lowered.as_text(debug_info=True)
                 if any(s in traced for s in missing):
                     text = _compile_past_the_cache(lowered).as_text()
-            self._scope_map = scope_map_of_hlo_text(text)
-        return self._scope_map
-
-    def _try_census(self, args, kwargs) -> None:
-        """One-time AOT lower+compile with the live call's args (shapes
-        only are read — donation happens at execution, so the caller's
-        buffers are untouched).  Census mode is opt-in: this pays one
-        extra compile per family, amortized by the persistent compile
-        cache."""
-        self._census_tried = True
-        fn = self._fn
-        if not hasattr(fn, "lower"):
-            return
-        try:
-            compiled = fn.lower(*args, **kwargs).compile()
-        except Exception:
-            return
-        flops, nbytes = cost_analysis_of(compiled)
-        if flops is not None or nbytes is not None:
-            self._family.attach_census(flops=flops, bytes_accessed=nbytes,
-                                       source="live")
+            self._maps = maps_of_hlo_text(text)
+        return self._maps
 
     def lower(self, *args, **kwargs):
         return self._fn.lower(*args, **kwargs)
@@ -386,25 +365,52 @@ _INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = ")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _OPERAND = re.compile(r"%([\w.\-]+)")
 _LOOP_COMPUTATION = re.compile(r"(?:body|condition)=%?([\w.\-]+)")
+_COMPILER_KERNEL = 'custom_call_target="tpu_custom_call"'
 
 
 def scope_map_of_hlo_text(text: str) -> dict:
     """{instruction name -> scope label} from an optimized HLO module's
-    text (see InstrumentedProgram.scope_map).
+    text: the first of ``maps_of_hlo_text``."""
+    return maps_of_hlo_text(text)[0]
+
+
+def maps_of_hlo_text(text: str) -> tuple:
+    """({instruction name -> scope label}, {instruction name -> phase})
+    from one walk of an optimized HLO module's text (see
+    InstrumentedProgram.scope_map / .phase_map).
 
     An instruction whose ``op_name`` is a traced op's name stack
-    (``jit(...)/...``) is labelled by its innermost ``fed_*`` component
-    (``scopes.label_of``).  A fusion carries the ``op_name`` of its root:
-    that is the granularity — a fusion that merged ops of two scopes is
-    booked whole to its root's.  One without (no metadata, or
-    only an argument's name) was put there by the compiler — an async
-    copy-start/-done or slice into faster memory, a relayout copy of an
-    argument, the tuple plumbing of a while: it takes the label of the
-    nearest labelled instruction that consumes it — the layer it moves
-    data for — else of the nearest that produces its operands, else of
-    the ``while`` whose body it sits in (a carry that is only copied
-    through), else ``unscoped``."""
-    own, operands, users, comp_of, loop_of = {}, {}, {}, {}, {}
+    (``jit(...)/...``) is labelled by it: the innermost ``fed_*``
+    component (``scopes.label_of``) and the pass the outermost
+    ``fed_forward`` runs in (``scopes.phase_of``).  A fusion carries the
+    ``op_name`` of its root: that is the granularity — a fusion that
+    merged ops of two scopes, or of two phases, is booked whole to its
+    root's.  One without (no metadata, or only an argument's name) was put
+    there by the compiler — an async copy-start/-done or slice into
+    faster memory, a relayout copy of an argument, the tuple plumbing of a
+    while: it reads the name of the nearest named instruction that
+    consumes it — the layer and the pass it moves data for — else of the
+    nearest that produces its operands, else of the ``while`` whose body
+    it sits in (a carry that is only copied through), else it is
+    ``unscoped`` / ``other``.
+
+    One kind of nameless instruction is work, not data movement: a kernel
+    XLA:TPU builds itself from an op of the program and names itself (the
+    grouped product: ``jax.lax.ragged_dot`` becomes a ``tpu_custom_call``
+    called ``ragged-dot-none``).  The SCOPES follow the rule above, as
+    they did before there were phases.  For the PHASES such a kernel
+    first reads the name of its nearest named producer, and then counts
+    as named for the instructions around it.  Values only flow forward ->
+    recompute -> backward, so a nameless op runs no earlier than its
+    producers and no later than its consumers: a re-run grouped product
+    takes rows gathered in the re-run and feeds the hand-written backward
+    rule — its consumer would call it, and the copy of the experts made
+    for it, ``backward``.  The same pass un-names what carries the name of
+    a ``jax.checkpoint`` call itself (``…/remat2``: the barrier in front
+    of the re-run and of the layer's backward pass, and the copies the
+    compiler hangs on it — the experts' weights again): it is the
+    checkpoint's plumbing, and has the phase of what it feeds."""
+    own, operands, users, comp_of, loop_of, kernels = {}, {}, {}, {}, {}, set()
     comp = None
     for line in text.splitlines():
         m = _INSTRUCTION.match(line)
@@ -414,43 +420,62 @@ def scope_map_of_hlo_text(text: str) -> dict:
             continue
         name = m.group(1)
         op = _OP_NAME.search(line, m.end())
-        own[name] = (scopes.label_of(op.group(1))
+        own[name] = (op.group(1)
                      if op and op.group(1).startswith("jit(") else None)
         operands[name] = _OPERAND.findall(line, m.end())
         comp_of[name] = comp
         if " while(" in line:
             for c in _LOOP_COMPUTATION.findall(line, m.end()):
                 loop_of[c] = name
+        if own[name] is None and _COMPILER_KERNEL in line:
+            kernels.add(name)
     for name, ops in operands.items():
         # computations named by calls=/body= are not instructions
         operands[name] = ops = [o for o in ops if o in own]
         for o in ops:
             users.setdefault(o, []).append(name)
 
-    def nearest(name, edges):
+    def nearest(named, name, edges):
         seen, frontier = {name}, [name]
         while frontier:
             nxt = []
             for n in frontier:
                 for e in edges.get(n, ()):
-                    if own[e] is not None:
-                        return own[e]
+                    if named[e] is not None:
+                        return named[e]
                     if e not in seen:
                         seen.add(e)
                         nxt.append(e)
             frontier = nxt
         return None
 
-    labels = {name: (label if label is not None else
-                     nearest(name, users) or nearest(name, operands))
-              for name, label in own.items()}
+    def names_read(named):
+        """The op_name each instruction reads: its own, or a neighbour's."""
+        read = {name: (op if op is not None else nearest(named, name, users)
+                       or nearest(named, name, operands))
+                for name, op in named.items()}
 
-    def resolved(name):
-        while name is not None and labels[name] is None:
-            name = loop_of.get(comp_of[name])
-        return scopes.UNSCOPED if name is None else labels[name]
+        def resolved(name):
+            while name is not None and read[name] is None:
+                name = loop_of.get(comp_of[name])
+            return "" if name is None else read[name]
 
-    return {name: resolved(name) for name in labels}
+        return {name: resolved(name) for name in read}
+
+    def labelled(read, label_of):
+        label = {op: label_of(op) for op in set(read.values())}
+        return {name: label[op] for name, op in read.items()}
+
+    read = names_read(own)
+    scope_map = labelled(read, scopes.label_of)
+    call = "/" + scopes.REMAT_CALL
+    barriers = [name for name, op in own.items() if op and op.endswith(call)]
+    if kernels or barriers:
+        named = {**own, **dict.fromkeys(barriers)}
+        for name in kernels:
+            named[name] = nearest(named, name, operands) or read[name]
+        read = names_read(named)
+    return scope_map, labelled(read, scopes.phase_of)
 
 
 def instrument(family: str, fn, on_result=None) -> InstrumentedProgram:

@@ -48,6 +48,27 @@ backward and rematerialised alike, so in a model that has them
 residual stream between blocks, the final norm); a model without them
 reads as before.
 
+**Phases** are the second coordinate of the same name stack: which pass
+of ``value_and_grad`` over ``fed_forward`` an op belongs to, whatever its
+scope.  ``phase_of`` reads it from what jax itself writes into the stack
+(read on jax 0.9.0; no scope of the program's is involved):
+
+    forward     the part that holds fed_forward is   jvp(fed_forward)/…
+                not wrapped in ``transpose(``
+    backward    it is, and no later part of the      transpose(jvp(fed_forward))/…
+                stack is ``rematted_computation``    /checkpoint/fed_mlp/…
+    recompute   it is, and a later part is: what     transpose(jvp(fed_forward))/…
+                ``jax.checkpoint`` runs again        /checkpoint/rematted_computation
+                inside the backward pass             /fed_mlp/…
+    other       the stack holds no fed_forward:
+                take, optimizer, aggregate, server
+                update, chunk plumbing, unscoped
+
+A ``custom_vjp``'s rules are traced under the pass that calls them (the
+forward rule under ``jvp(…)`` or, re-run, under ``rematted_computation``;
+the backward rule under ``transpose(…)``), so hand-written backward
+passes need nothing of their own.
+
 **Spans** are ``obs.span`` names: host intervals that land in the
 ``SpanTracer`` when ``obs.configure()`` ran and, always, in the
 profiler's own trace (``/host:CPU`` of the ``.xplane.pb``) when a
@@ -72,6 +93,10 @@ FED_MOE_EXPERTS = "fed_moe_experts"
 
 UNSCOPED = "unscoped"
 BACKWARD = "backward"
+FORWARD, RECOMPUTE, OTHER = "forward", "recompute", "other"
+PHASES = (FORWARD, RECOMPUTE, BACKWARD, OTHER)
+REMATTED = "rematted_computation"     # jax.checkpoint's name for its re-run
+REMAT_CALL = "remat2"       # its primitive: the name of the call itself
 LABEL_OF_SCOPE = {
     FED_TAKE: "take",
     FED_LOCAL_TRAIN: "local_other",
@@ -122,3 +147,19 @@ def label_of(op_name: str) -> str:
                 return BACKWARD
             return LABEL_OF_SCOPE[m.group()]
     return UNSCOPED
+
+
+def phase_of(op_name: str) -> str:
+    """The phase of one HLO instruction from its ``op_name`` (one of
+    ``PHASES``; the rule: module docstring).  Like ``label_of`` it looks
+    inside each "/"-separated part, so the wrappers around a part
+    (``vmap(…)`` over a chunk's clients) and the parts between
+    (``shard_map``, ``while/body/closed_call``, ``custom_vjp`` frames) do
+    not matter."""
+    parts = op_name.split("/")
+    for i, part in enumerate(parts):
+        if FED_FORWARD in part:
+            if "transpose(" not in part:
+                return FORWARD
+            return RECOMPUTE if REMATTED in parts[i + 1:] else BACKWARD
+    return OTHER

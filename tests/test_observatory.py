@@ -246,37 +246,36 @@ def test_programs_compile_attribution(clean_obs):
 
 
 def test_programs_census_and_mfu(clean_obs):
-    """Census mode reads the compiled program's cost analysis once and
-    report() turns dispatch counts into FLOP/byte totals (the 64x64
-    matmul's flops are exactly 2·64^3 on this backend).  The "MFU" this
-    report once derived (census FLOPs over HOST dispatch wall) is gone:
-    no field, no gauge."""
+    """A family's census — here the compiled program's own cost analysis,
+    attached by hand — and report() turns dispatch counts into FLOP/byte
+    totals (the 64x64 matmul's flops are exactly 2·64^3 on this backend).
+    The "MFU" this report once derived (census FLOPs over HOST dispatch
+    wall) is gone: no field, no gauge."""
     import jax
-    programs.enable_census(True)
-    try:
-        prog = programs.instrument("fedavg_resident",
-                                   jax.jit(lambda x: x @ x))
-        a = np.zeros((64, 64), np.float32)
-        snap = programs.snapshot()
-        for _ in range(4):
-            prog(a)
-        rep = programs.report(snap)
-        row = next(r for r in rep["families"]
-                   if r["family"] == "fedavg_resident")
-        assert row["flops_per_dispatch"] == 2 * 64 ** 3
-        assert row["flops_total"] == 4 * 2 * 64 ** 3
-        assert row["bytes_per_dispatch"] > 0
-        assert row["stage"] == "train"
-        assert rep["total"]["flops_total"] == row["flops_total"]
-        assert obs.gauge("program_bytes_moved_total",
-                         family="fedavg_resident").value \
-            == row["bytes_total"]
-        assert "mfu" not in row and "mfu" not in rep["total"]
-        assert not any(m.name == "program_mfu"
-                       for m in obs.registry().metrics())
-        assert "MFU" not in programs.format_table(rep)
-    finally:
-        programs.enable_census(False)
+    fn = jax.jit(lambda x: x @ x)
+    prog = programs.instrument("fedavg_resident", fn)
+    a = np.zeros((64, 64), np.float32)
+    flops, nbytes = programs.cost_analysis_of(fn.lower(a).compile())
+    programs.register("fedavg_resident").attach_census(
+        flops=flops, bytes_accessed=nbytes)
+    snap = programs.snapshot()
+    for _ in range(4):
+        prog(a)
+    rep = programs.report(snap)
+    row = next(r for r in rep["families"]
+               if r["family"] == "fedavg_resident")
+    assert row["flops_per_dispatch"] == 2 * 64 ** 3
+    assert row["flops_total"] == 4 * 2 * 64 ** 3
+    assert row["bytes_per_dispatch"] > 0
+    assert row["stage"] == "train"
+    assert rep["total"]["flops_total"] == row["flops_total"]
+    assert obs.gauge("program_bytes_moved_total",
+                     family="fedavg_resident").value \
+        == row["bytes_total"]
+    assert "mfu" not in row and "mfu" not in rep["total"]
+    assert not any(m.name == "program_mfu"
+                   for m in obs.registry().metrics())
+    assert "MFU" not in programs.format_table(rep)
 
 
 def test_programs_census_from_audit_artifact(clean_obs):

@@ -66,7 +66,7 @@ def _trainer_keywords() -> int:
 @pytest.mark.parametrize("surface, count, ceiling", [
     pytest.param(name, count, ceiling, id=name) for name, count, ceiling in (
         ("cli_flags", _cli_flags, 152),
-        ("fedml_env_names", lambda: len(_env_names()), 16),
+        ("fedml_env_names", lambda: len(_env_names()), 15),
         ("mesh_engine_keywords", _engine_keywords, 10),
         ("client_trainer_keywords", _trainer_keywords, 13))])
 def test_option_count_does_not_grow(surface, count, ceiling):

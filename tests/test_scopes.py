@@ -6,7 +6,10 @@
 * a CPU ``jax.profiler`` trace of two tiny rounds holds every program span of
   the hot path in ``/host:CPU`` with its identifier (``round``; ``family`` for
   ``program.dispatch``), a prefetched upload carrying the round it is FOR;
-* ``label_of`` / ``scope_map_of_hlo_text`` on hand-made inputs.
+* ``label_of`` / ``phase_of`` / ``scope_map_of_hlo_text`` on hand-made inputs;
+* ``phase_map()`` beside it: on tiny language-model rounds every instruction
+  has a phase, what ``jax.checkpoint`` re-runs reads ``recompute`` under each
+  checkpointed scope and nowhere else, and both maps come from one compile.
 
 The copy census of the touched families is pinned where it always was
 (tests/test_hlo_copy_audit.py, exact ceilings): the scopes change metadata.
@@ -239,6 +242,42 @@ ENTRY %main (p: f32[8]) -> f32[8] {
                     "tuple.6": "unscoped"}
 
 
+def test_a_kernel_the_compiler_named_itself_has_its_producers_phase():
+    """XLA:TPU turns `jax.lax.ragged_dot` into a `tpu_custom_call` named
+    `ragged-dot-none`: no traced name.  The re-run grouped product of a
+    checkpointed expert layer takes rows gathered in the re-run and feeds the
+    backward rule: its scope is its consumer's, as every nameless
+    instruction's, its phase its producer's — `recompute`, not `backward` —
+    and the data moved for it is moved for the re-run, the copy that carries
+    the name of the checkpoint's own call included."""
+    step = "jit(r)/fed_local_train/while/body/transpose(jvp(fed_forward))/M"
+    rerun = step + "/checkpoint/rematted_computation/fed_moe_router/gather"
+    rule = step + "/checkpoint/fed_moe_experts/mul"
+    text = f"""HloModule m
+ENTRY %main (p: f32[8]) -> f32[8] {{
+  %p = f32[8]{{0}} parameter(0), metadata={{op_name="stack['x']"}}
+  %fusion.1 = f32[8]{{0}} fusion(%p), kind=kLoop, calls=%fc, metadata={{op_name="{rerun}"}}
+  %copy.2 = f32[8]{{0}} copy(%fusion.1)
+  %w = f32[8]{{0}} parameter(1), metadata={{op_name="variables['w1']"}}
+  %copy.7 = f32[8]{{0}} copy(%w), metadata={{op_name="{step}/jvp(fed_forward)/M/remat2"}}
+  %ragged-dot-none = f32[8]{{0}} custom-call(%copy.2, %copy.7), custom_call_target="tpu_custom_call", metadata={{op_name="ragged-dot-none"}}
+  %copy.3 = f32[8]{{0}} copy(%ragged-dot-none)
+  ROOT %fusion.4 = f32[8]{{0}} fusion(%copy.3), kind=kLoop, calls=%fd, metadata={{op_name="{rule}"}}
+}}
+"""
+    smap, pmap = programs.maps_of_hlo_text(text)
+    assert smap == {"p": "moe_router", "fusion.1": "moe_router",
+                    "copy.2": "moe_experts", "ragged-dot-none": "moe_experts",
+                    "copy.3": "moe_experts", "fusion.4": "moe_experts",
+                    # the copy of the experts hung on the checkpoint's barrier
+                    # keeps the scope of its own name, as before
+                    "w": "forward", "copy.7": "forward"}
+    assert pmap == {"p": "recompute", "fusion.1": "recompute",
+                    "copy.2": "recompute", "ragged-dot-none": "recompute",
+                    "copy.3": "backward", "fusion.4": "backward",
+                    "w": "recompute", "copy.7": "recompute"}
+
+
 def test_scope_map_sees_past_a_cached_executable_without_the_scopes(tmp_path):
     """The persistent cache keys on the module without metadata: a program
     that gained scopes loads the executable its scope-less twin left there,
@@ -340,3 +379,207 @@ def test_fused_attention_kernels_carry_the_attention_scope():
         assert scopes.FED_ATTENTION in k and k.endswith("pallas_call"), k
         assert scopes.label_of("jit(f)/transpose(jvp(fed_forward))/" + k) \
             == "attention"
+
+
+# -- phases: the second coordinate of the same name stack --------------------
+
+_STEP = "jit(r)/shard_map/fed_local_train/while/body/closed_call/"
+
+
+@pytest.mark.parametrize("op_name,phase", [
+    # plain
+    ("jit(r)/fed_local_train/while/body/jvp(fed_forward)/conv", "forward"),
+    ("jit(r)/fed_local_train/while/body/transpose(jvp(fed_forward))/mul",
+     "backward"),
+    ("jit(r)/fed_local_train/while/body/transpose(jvp(fed_forward))/while/body"
+     "/checkpoint/rematted_computation/fed_mlp/dot_general", "recompute"),
+    # under the vmap over a chunk's clients
+    (_STEP + "vmap(jvp(fed_forward))/fed_lm_head/dot_general", "forward"),
+    (_STEP + "vmap(transpose(jvp(fed_forward)))/checkpoint/fed_mlp/mul",
+     "backward"),
+    (_STEP + "vmap(transpose(jvp(fed_forward)))/checkpoint"
+     "/rematted_computation/fed_attention/exp", "recompute"),
+    # under the scans' while/body/closed_call, the engine's and the model's
+    (_STEP + "vmap()/while/body/closed_call/jvp(fed_forward)/LoopedDecoderLM"
+     "/while/body/closed_call/fed_attention/div", "forward"),
+    (_STEP + "vmap()/while/body/closed_call/transpose(jvp(fed_forward))"
+     "/LoopedDecoderLM/while/body/closed_call/checkpoint/fed_mlp/mul",
+     "backward"),
+    (_STEP + "vmap()/while/body/closed_call/transpose(jvp(fed_forward))"
+     "/LoopedDecoderLM/while/body/closed_call/checkpoint"
+     "/rematted_computation/fed_mlp/...a,ab->...b/dot_general", "recompute"),
+    # a checkpoint outside a scan: jax writes the stack the layer was traced
+    # under after the one it runs under; the OUTER fed_forward is the pass
+    (_STEP + "transpose(jvp(fed_forward))/Lfm2MoeLM/jvp(fed_forward)/Lfm2MoeLM"
+     "/checkpoint/Lfm2MoeLM._layer/reshape", "backward"),
+    (_STEP + "transpose(jvp(fed_forward))/Lfm2MoeLM/jvp(fed_forward)/Lfm2MoeLM"
+     "/checkpoint/rematted_computation/Lfm2MoeLM._layer/fed_moe_router/sort",
+     "recompute"),
+    # inside a custom_vjp: the backward rule, and the forward rule re-run
+    (_STEP + "transpose(jvp(fed_forward))/Lfm2MoeLM/jvp(fed_forward)/Lfm2MoeLM"
+     "/checkpoint/Lfm2MoeLM._layer/fed_moe_experts/custom_vjp_call"
+     "/ragged_dot_general", "backward"),
+    ("jit(r)/transpose(jvp(fed_forward))/checkpoint/fed_attention"
+     "/pallas_call", "backward"),
+    ("jit(r)/transpose(jvp(fed_forward))/checkpoint/rematted_computation"
+     "/fed_attention/custom_vjp_call/pallas_call", "recompute"),
+    # outside fed_forward
+    ("jit(_mesh_round)/fed_take/jit(_take)/gather", "other"),
+    (_STEP + "vmap()/while/body/closed_call/fed_optimizer/sub", "other"),
+    (_STEP + "fed_aggregate/dot_general", "other"),
+    ("jit(r)/fed_server_update/add", "other"),
+    ("jit(r)/shard_map/random_split", "other"),
+    ("", "other"),
+    # a loop-invariant op jax hoists out of a differentiated scan keeps the
+    # stack of the scan's body alone: no fed_forward, so no phase
+    (_STEP + "vmap()/while/body/closed_call/fed_attention/jit(_where)"
+     "/broadcast_in_dim", "other"),
+])
+def test_phase_is_read_from_the_outermost_fed_forward(op_name, phase):
+    assert scopes.phase_of(op_name) == phase and phase in scopes.PHASES
+
+
+LFM2_SMALL = dict(d_model=64, n_heads=4, n_kv_heads=2, head_dim=16, d_ff=96,
+                  d_expert=32, n_experts=8, experts_per_token=2,
+                  layers=[0, 2, 3], num_dense_layers=1, lora_rank=4,
+                  lora_alpha=8.0)                  # tests/test_lfm2_moe.py
+OURO_SMALL = dict(d_model=64, n_heads=16, head_dim=4, d_ff=96, n_layers=2,
+                  n_passes=2)                      # tests/fedbench/tiny
+MODELS = {
+    # model, keywords, the scopes its jax.checkpoint wraps
+    "looped_lm": ("looped_lm", OURO_SMALL, {"attention", "mlp"}),
+    "lfm2_moe": ("lfm2_moe", LFM2_SMALL, {"attention", "mlp", "short_conv",
+                                          "moe_router", "moe_experts"}),
+    "looped_lm_unrolled": ("looped_lm", dict(OURO_SMALL, unrolled=True),
+                           set()),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _lm_round(case: str):
+    """(checkpointed scopes, round_fn after one dispatch, its optimized
+    text): four clients on two shards, a chunk of two under the vmap."""
+    from parallel_case import _token_setup
+    model, kw, checkpointed = MODELS[case]
+    trainer, data, cfg = _token_setup("stackoverflow_nwp", model, kw, True)
+    eng = MeshFedAvgEngine(trainer, data, cfg, mesh=make_mesh(2), chunk=2)
+    eng.run(rounds=1)
+    args, kwargs = eng.round_fn._signature
+    text = eng.round_fn.lower(*args, **kwargs).compile().as_text()
+    return checkpointed, eng.round_fn, text
+
+
+@pytest.fixture(params=list(MODELS))
+def lm_round(request):
+    return _lm_round(request.param)
+
+
+def test_every_instruction_has_a_phase_and_the_product_refines_the_scopes(
+        lm_round):
+    """One walk gives both maps: they name the same instructions, the
+    scope map is what it was before there were phases, and an instruction
+    with a traced name has the phase of that name — but the checkpoint's
+    own call (`…/remat2`, the barrier in front of the re-run), which has
+    the phase of what it feeds."""
+    _, round_fn, text = lm_round
+    smap, pmap = round_fn.scope_map(), round_fn.phase_map()
+    assert pmap is round_fn.phase_map()                      # computed once
+    assert set(pmap) == set(smap) and set(pmap.values()) <= set(scopes.PHASES)
+    assert (smap, pmap) == programs.maps_of_hlo_text(text)
+    assert smap == programs.scope_map_of_hlo_text(text)
+    from parallel_case import hlo_instructions
+    for inst, _, _, rest in hlo_instructions(text):
+        op = re.search(r'op_name="(jit\([^"]*)"', rest)
+        if op:
+            assert smap[inst] == scopes.label_of(op.group(1))
+            if not op.group(1).endswith("/" + scopes.REMAT_CALL):
+                assert pmap[inst] == scopes.phase_of(op.group(1))
+    # what lies outside fed_forward has no phase, and the reverse, but for
+    # what jax hoists out of a differentiated scan (loop invariants of the
+    # model's body: masks, rotary tables)
+    outside = {"take", "local_other", "optimizer", "aggregate",
+               "server_update", "unscoped"}
+    for name, phase in pmap.items():
+        if smap[name] in outside:
+            assert phase == "other", (name, smap[name], phase)
+    hoisted = [n for n, ph in pmap.items()
+               if ph == "other" and smap[n] not in outside]
+    assert len(hoisted) <= 0.01 * len(pmap), len(hoisted)
+
+
+def test_what_checkpoint_reruns_reads_recompute_under_each_of_its_scopes(
+        lm_round):
+    checkpointed, round_fn, text = lm_round
+    smap, pmap = round_fn.scope_map(), round_fn.phase_map()
+    from parallel_case import hlo_instructions
+    marked = 0
+    for inst, _, _, rest in hlo_instructions(text):
+        op = re.search(r'op_name="(jit\([^"]*)"', rest)
+        if op and scopes.REMATTED in op.group(1).split("/"):
+            assert pmap[inst] == "recompute", (inst, op.group(1))
+            marked += 1
+    assert (marked > 0) == bool(checkpointed)
+    seen = collections.defaultdict(set)
+    for name, phase in pmap.items():
+        seen[smap[name]].add(phase)
+    for scope in checkpointed:
+        assert {"forward", "recompute", "backward"} <= seen[scope], scope
+    # the head, the embedding and the residual stream lie outside the
+    # checkpoint (its own barrier, which no inner scope claims, is the
+    # re-run's where it feeds the re-run); a model without one recomputes
+    # nothing
+    recomputing = {sc for sc, phases in seen.items() if "recompute" in phases}
+    assert recomputing - {"forward", "backward"} == checkpointed
+    assert bool(recomputing) == bool(checkpointed)
+    assert seen["lm_head"] == {"forward", "backward"}
+    assert "backward" in seen["forward"] | seen["backward"]
+
+
+class _CountingLower:
+    """A jitted function that counts how often it is lowered."""
+
+    def __init__(self, fn):
+        self.fn, self.lowers = fn, 0
+
+    def lower(self, *args, **kwargs):
+        self.lowers += 1
+        return self.fn.lower(*args, **kwargs)
+
+
+def test_resnet_round_has_no_recompute_and_both_maps_cost_one_compile():
+    """A model without inner scopes or checkpoint: `forward` / `backward` /
+    `other` only, the `forward` phase is the `forward` label, instruction
+    for instruction; and `phase_map()` after `scope_map()` compiles nothing
+    (nor the other way round: one lowering serves both)."""
+    from fedml_tpu.core.trainer import ClientTrainer
+    from fedml_tpu.data.loaders import load_data
+    from fedml_tpu.models import create_model
+    cfg = _mnist_like_cfg(model="resnet18_gn", dataset="cifar10",
+                          client_num_in_total=8, client_num_per_round=4,
+                          batch_size=4)
+    data = load_data("cifar10", client_num_in_total=8, batch_size=4,
+                     synthetic_scale=0.002, seed=0)
+    trainer = ClientTrainer(create_model(
+        "resnet18_gn", data.class_num, num_filters=8,
+        stage_sizes=[1, 1, 1, 1]), lr=0.1)
+    eng = MeshFedAvgEngine(trainer, data, cfg, mesh=make_mesh(2), chunk=2)
+    assert eng.round_fn.phase_map() is None          # nothing dispatched yet
+    eng.run(rounds=1)
+    from fedbench.harness.device import CompileCounter
+    compiles = CompileCounter()
+    jax.jit(lambda x: x * 3 + 1)(1.0)
+    assert compiles.count >= 1                       # the counter counts
+    smap = eng.round_fn.scope_map()
+    after_first = compiles.count
+    pmap = eng.round_fn.phase_map()
+    assert compiles.count == after_first
+    assert set(pmap.values()) == {"forward", "backward", "other"}
+    for phase in ("forward", "backward"):
+        assert {n for n, p in pmap.items() if p == phase} \
+            == {n for n, lb in smap.items() if lb == phase}
+    # the other order, on a wrapper that has not been asked yet
+    spy = _CountingLower(eng.round_fn.inner)
+    fresh = programs.instrument(eng.program_family, spy)
+    fresh._signature = eng.round_fn._signature
+    assert fresh.phase_map() == pmap and spy.lowers == 1
+    assert fresh.scope_map() == smap and spy.lowers == 1

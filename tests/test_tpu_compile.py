@@ -24,7 +24,15 @@ real-shape round programs (`_resident_round` of `xdev10of4000`,
 parent `43bd4f9` and on PR 35's tree, are the same text line for line —
 49,913 / 3,514 / 125,859 / 130,049 lines, 0 differ outside this file's
 own line numbers in the source table (builder, CPU compile rehearsal,
-PR 35; as PRs 29 and 34 showed theirs).
+PR 35; as PRs 29 and 34 showed theirs).  PR 37 (the phase map: nothing
+under `parallel/`, `core/` or `models/` changed) compiled all six cells'
+rounds on the parent `2ba3380` and on its tree: 49,913 / 3,514 / 5,227 /
+16,937 / 125,859 / 130,049 lines — 41,457 / 2,756 / 3,811 / 13,078 /
+104,773 / 109,140 instructions for `xdev10of4000` / `xdev50of342k` /
+`silo4of256t1024` / `lora4of256t2048` / `silo128of1024` /
+`silo128of4096x4` — and 0 differ but the checkout's path in the source
+table and in the attention kernels' serialized bodies (builder, CPU
+compile rehearsal, PR 37).
 
 A compile that passes is not a chip run: nothing executes, so these
 tests say nothing about results or times.  Skipped where the topology
