@@ -52,6 +52,13 @@ def create_model(model_name: str, output_dim: int, input_dim: int | None = None,
         from fedml_tpu.models.lfm2_moe import Lfm2MoeLM
         kw = {k: tuple(v) if isinstance(v, list) else v for k, v in kw.items()}
         return Lfm2MoeLM(vocab_size=output_dim, **kw)
+    if name == "deepseek_v2":
+        # multi-head latent attention + group-limited sparse experts beside
+        # shared ones, adapters over a frozen base (DeepSeek-V2's family,
+        # models/deepseek_v2.py); every width is a keyword
+        from fedml_tpu.models.deepseek_v2 import DeepSeekV2LM
+        kw = {k: tuple(v) if isinstance(v, list) else v for k, v in kw.items()}
+        return DeepSeekV2LM(vocab_size=output_dim, **kw)
     if name in ("resnet18_gn", "resnet18"):
         return ResNet18GN(num_classes=output_dim, **kw)
     if name == "resnet56":

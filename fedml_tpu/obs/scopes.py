@@ -39,10 +39,19 @@ copy census of the touched families exactly).  ``label_of`` turns one
                         the un-sort / combine
     fed_moe_experts     the grouped products over the    moe_experts
                         experts held, and their gate
+    fed_mla_latent      latent attention's low-rank      mla_latent
+                        side: the query and key/value
+                        compressions, their norms, the
+                        expansions to heads, rotary,
+                        their adapters (the core and
+                        the output projection stay
+                        fed_attention's)
+    fed_shared_expert   the gated MLP every token of an  shared_expert
+                        expert layer visits
 
 An op under several scopes belongs to the innermost one (a forward op
 is inside fed_local_train too); an op under none is ``unscoped``.
-The last six sit inside fed_forward and claim their ops forward,
+The last eight sit inside fed_forward and claim their ops forward,
 backward and rematerialised alike, so in a model that has them
 ``forward`` / ``backward`` read what lies outside them (embedding,
 residual stream between blocks, the final norm); a model without them
@@ -90,6 +99,8 @@ FED_LM_HEAD = "fed_lm_head"
 FED_SHORT_CONV = "fed_short_conv"
 FED_MOE_ROUTER = "fed_moe_router"
 FED_MOE_EXPERTS = "fed_moe_experts"
+FED_MLA_LATENT = "fed_mla_latent"
+FED_SHARED_EXPERT = "fed_shared_expert"
 
 UNSCOPED = "unscoped"
 BACKWARD = "backward"
@@ -110,6 +121,8 @@ LABEL_OF_SCOPE = {
     FED_SHORT_CONV: "short_conv",
     FED_MOE_ROUTER: "moe_router",
     FED_MOE_EXPERTS: "moe_experts",
+    FED_MLA_LATENT: "mla_latent",
+    FED_SHARED_EXPERT: "shared_expert",
 }
 LABELS = tuple(LABEL_OF_SCOPE.values()) + (BACKWARD, UNSCOPED)
 

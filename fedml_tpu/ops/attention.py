@@ -1,11 +1,15 @@
 """Causal softmax attention — the one place the repo spells
 ``softmax_causal(q k^T / sqrt(hd)) v``.
 
-``causal_attention(q, k, v)`` is the core of both language models'
+``causal_attention(q, k, v)`` is the core of the language models'
 attention (`models/looped_lm.py::decoder_layer`: 16 heads of 128 at
 T = 1,024; `models/lfm2_moe.py::gqa_attention`: 32 query / 8 key-value
-heads of 64 at T = 2,048); projections, norms and rotary stay with the
-models.  Two bodies, one result:
+heads of 64 at T = 2,048; `models/deepseek_v2.py::latent_attention`: 128
+heads at T = 4,096 in the TWO-PART form, ``rope=(q_rope, k_rope)`` and a
+given ``scale`` - scores ``(q k^T + q_rope k_rope^T) * scale`` with 128-wide
+content parts, 64-wide rotary parts and ONE rotary key head that every
+query head reads); projections, norms and rotary stay with the models.
+Two bodies, one result:
 
 * **the plain path** — einsum, mask, ``jax.nn.softmax``, einsum: the
   numerical spec, and what a program lowered for anything but a TPU (or a
@@ -35,8 +39,8 @@ here where the plain path's autodiff rounds it to the compute dtype.)
 **Which body runs is read off the program, not configured**: the fused
 path where the program is LOWERED for a TPU (``jax.lax.platform_dependent``:
 a compile for a described chip from a CPU process sees the kernels), ``T``
-is a multiple of 128, the head size is 64 or 128 and the operands are
-bfloat16 or float32; the plain path otherwise.  The tile is the largest
+is a multiple of 128, the head size (and a rotary part's) is 64 or 128 and
+the operands are bfloat16 or float32; the plain path otherwise.  The tile is the largest
 of 512, 256, 128 that divides ``T``.  Heads as wide as the lanes (128) are
 read where they lie in ``[B, T, H, hd]``; narrower ones are brought
 heads-first around the kernels.  The decision is counted at trace time in
@@ -79,7 +83,7 @@ _TN = (((0,), (0,)), ((), ()))       # a [d, m] . b [d, n] -> [m, n]
 
 # -- the plain path -----------------------------------------------------------
 
-def _plain(q, k, v):
+def _plain(q, k, v, *rope, scale=None):
     """einsum, mask, softmax, einsum, as both models spelled it before
     they shared it: query heads grouped over their key/value head, and the
     ungrouped products where every head has its own (the same mathematics;
@@ -87,17 +91,36 @@ def _plain(q, k, v):
     model's CPU results stay what they were to the bit)."""
     B, T, H, hd = q.shape
     dt, n_kv = q.dtype, k.shape[2]
+    scale = hd ** -0.5 if scale is None else scale
+    if rope:
+        return _plain_two_part(q, k, v, *rope, scale)
     grouped = n_kv != H
     if grouped:
         q = q.reshape(B, T, n_kv, H // n_kv, hd)
     s = jnp.einsum("btgrd,bsgd->bgrts" if grouped else "bqhd,bkhd->bhqk",
-                   q, k, preferred_element_type=jnp.float32) * (hd ** -0.5)
+                   q, k, preferred_element_type=jnp.float32) * scale
     causal = jnp.tril(jnp.ones((T, T), bool))
     s = jnp.where(causal, s, _MASK)
     w = jax.nn.softmax(s, axis=-1).astype(dt)
     o = jnp.einsum("bgrts,bsgd->btgrd" if grouped else "bhqk,bkhd->bqhd",
                    w, v, preferred_element_type=jnp.float32).astype(dt)
     return o.reshape(B, T, H, hd)
+
+
+def _plain_two_part(q, k, v, q_rope, k_rope, scale):
+    """Scores that are the sum of two products (latent attention:
+    ``q . k + q_rope . k_rope``, the rotary key of one head read by many
+    query heads), any head sizes: every key head is repeated for the query
+    heads it serves, which only a small shape can afford."""
+    H, dt = q.shape[2], q.dtype
+    wide = lambda a: jnp.repeat(a, H // a.shape[2], axis=2)
+    scores = lambda a, b: jnp.einsum("bqhd,bkhd->bhqk", a, wide(b),
+                                     preferred_element_type=jnp.float32)
+    s = (scores(q, k) + scores(q_rope, k_rope)) * scale
+    s = jnp.where(jnp.tril(jnp.ones(s.shape[-2:], bool)), s, _MASK)
+    w = jax.nn.softmax(s, axis=-1).astype(dt)
+    return jnp.einsum("bhqk,bkhd->bqhd", w, wide(v),
+                      preferred_element_type=jnp.float32).astype(dt)
 
 
 # -- the fused path -----------------------------------------------------------
@@ -112,12 +135,18 @@ def _tile(T: int):
     return next((b for b in (512, 256, 128) if T % b == 0), None)
 
 
-def _fits(q, k, v) -> bool:
+def _fits(q, k, v, *rope) -> bool:
     """The kernels' requirement on shapes and dtype (module docstring)."""
-    return (q.ndim == 4 and k.shape == v.shape and q.dtype == k.dtype == v.dtype
+    heads = lambda a, b: (a.ndim == b.ndim == 4 and a.shape[-1] == b.shape[-1]
+                          and a.shape[:2] == b.shape[:2] == q.shape[:2]
+                          and a.shape[-1] in (64, 128)
+                          and q.shape[2] == a.shape[2]
+                          and a.shape[2] % b.shape[2] == 0
+                          and a.dtype == b.dtype == q.dtype)
+    return (heads(q, k) and k.shape == v.shape and v.dtype == q.dtype
             and q.dtype in (jnp.bfloat16, jnp.float32)
-            and q.shape[-1] in (64, 128) and _tile(q.shape[1]) is not None
-            and q.shape[2] % k.shape[2] == 0)
+            and _tile(q.shape[1]) is not None
+            and (not rope or heads(*rope)))
 
 
 def _dot(a, b, dims):
@@ -140,19 +169,29 @@ def _causal(s, queries_axis: int):
     return jnp.where(kpos <= qpos, s, _MASK)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale):
+def _fwd_kernel(*refs, scale, two_part=False):
     """One tile of queries [tile, hd] against the keys [T, hd] of its head,
     a tile of keys at a time up to the diagonal; scores are [tile
-    (queries), tile (keys)]."""
+    (queries), tile (keys)].  ``two_part``: a second pair of operands,
+    the queries' [tile, r] and the keys' [T, r] rotary parts, whose
+    product is added to the scores."""
+    q_ref, k_ref, v_ref = refs[:3]
+    o_ref, lse_ref = refs[-2:]
     i = pl.program_id(2)
     q = q_ref[...]
     tile, hd = q.shape
+    if two_part:
+        qr_ref, kr_ref = refs[3:5]
+        qr = qr_ref[...]
 
     def step(j, carry, diagonal=False):
         m, l, acc = carry
         rows = pl.ds(pl.multiple_of(j * tile, tile), tile)
         k, v = k_ref[rows, :], v_ref[rows, :]
-        s = _dot(q, k, _NT) * scale
+        s = _dot(q, k, _NT)
+        if two_part:
+            s = s + _dot(qr, kr_ref[rows, :], _NT)
+        s = s * scale
         if diagonal:
             s = _causal(s, queries_axis=0)
         m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
@@ -172,26 +211,39 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale):
     lse_ref[...] = jnp.broadcast_to(m + jnp.log(l), (tile, 128)).T[:1]
 
 
-def _bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
-                dq_ref, dk_ref, dv_ref, *, scale):
+def _bwd_kernel(*refs, scale, two_part=False):
     """One tile of keys [tile, hd] against the queries [T, hd] of one head,
     a tile of queries at a time from the diagonal down; scores are
     transposed, [tile (keys), tile (queries)], so the rows' statistics
     broadcast along sublanes.  ``dq`` [T, hd] stays in fast memory across
-    the head's key tiles."""
+    the head's key tiles.  ``two_part``: the rotary parts of the queries
+    [T, r] and of the key tile [tile, r] follow the six operands, their
+    gradients the three results."""
+    q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref = refs[:6]
+    dq_ref, dk_ref, dv_ref = refs[-5:-2] if two_part else refs[-3:]
     j = pl.program_id(2)
     k, v = k_ref[...], v_ref[...]
     tile, dt = k.shape[0], k.dtype
+    if two_part:
+        qr_ref, kr_ref = refs[6:8]
+        dqr_ref, dkr_ref = refs[-2:]
+        kr = kr_ref[...]
 
     @pl.when(j == 0)
     def _():
         dq_ref[...] = jnp.zeros_like(dq_ref)
+        if two_part:
+            dqr_ref[...] = jnp.zeros_like(dqr_ref)
 
     def step(i, carry, diagonal=False):
-        dk, dv = carry
+        dk, dv = carry[:2]
         rows = pl.ds(pl.multiple_of(i * tile, tile), tile)
         q, do = q_ref[rows, :], do_ref[rows, :]
-        s = _dot(k, q, _NT) * scale
+        s = _dot(k, q, _NT)
+        if two_part:
+            qr = qr_ref[rows, :]
+            s = s + _dot(kr, qr, _NT)
+        s = s * scale
         if diagonal:
             s = _causal(s, queries_axis=1)
         p = jnp.exp(s - lse_ref[i])
@@ -200,12 +252,20 @@ def _bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
         ds = (p * (dp - delta_ref[i]) * scale).astype(dt)
         dk = dk + _dot(ds, q, _NN)
         dq_ref[rows, :] += _dot(ds, k, _TN)
-        return dk, dv
+        if not two_part:
+            return dk, dv
+        dqr_ref[rows, :] += _dot(ds, kr, _TN)
+        return dk, dv, carry[2] + _dot(ds, qr, _NN)
 
-    carry = step(j, (jnp.zeros(k.shape, jnp.float32),) * 2, diagonal=True)
-    dk, dv = jax.lax.fori_loop(j + 1, q_ref.shape[0] // tile, step, carry)
-    dk_ref[...] = dk
-    dv_ref[...] = dv
+    carry = (jnp.zeros(k.shape, jnp.float32),) * 2
+    if two_part:
+        carry += (jnp.zeros(kr.shape, jnp.float32),)
+    carry = step(j, carry, diagonal=True)
+    carry = jax.lax.fori_loop(j + 1, q_ref.shape[0] // tile, step, carry)
+    dk_ref[...] = carry[0]
+    dv_ref[...] = carry[1]
+    if two_part:
+        dkr_ref[...] = carry[2]
 
 
 # How the kernels reach one head of a [B, T, H, hd] operand.  A head as wide
@@ -248,73 +308,116 @@ def _struct(like, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
 
 
-def _params(interpret):
+# a kernel's blocks, double-buffered, and its tiles of scores may take this
+# much fast memory before the call asks for more than Mosaic's default
+# (16 MiB on a v5e; every shape of before latent attention stays under it)
+_VMEM_DEFAULT = 12 * 2 ** 20
+
+
+def _params(interpret, blocks=(), tile=0):
     """How the call is run: Mosaic with the grid's semantics (batch and
     heads independent, the blocks of a head in order), or an interpreter
-    (the CPU tests'; it takes no compiler parameters)."""
+    (the CPU tests'; it takes no compiler parameters).  ``blocks`` are the
+    (rows, columns, dtype) a grid point holds: where they and six float32
+    ``tile x tile`` intermediates pass `_VMEM_DEFAULT` (whole-sequence
+    blocks at T = 4,096 with a rotary part), the call states its need."""
     if interpret:
         return dict(interpret=interpret)
+    need = (2 * sum(r * c * jnp.dtype(d).itemsize for r, c, d in blocks)
+            + 6 * 4 * tile * tile)
+    limit = {} if need <= _VMEM_DEFAULT else {
+        "vmem_limit_bytes": min(2 * need, 96 * 2 ** 20)}
     return dict(compiler_params=pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary")))
+        dimension_semantics=("parallel", "parallel", "arbitrary"), **limit))
 
 
-def _fused_output_lse(q, k, v, interpret):
+def _fused_output_lse(q, k, v, *rope, scale, interpret):
     """o [B, T, H, hd] and the rows' log-sum-exp [B, H, T] in float32."""
     B, T, H, hd = q.shape
-    group, tile = H // k.shape[2], _tile(T)
-    own = _block(tile, hd, row=lambda i: i)
-    whole_kv = _block(T, hd, row=lambda i: 0, head=lambda h: h // group)
+    tile = _tile(T)
+    own = lambda a: _block(tile, a.shape[-1], row=lambda i: i)
+    whole_kv = lambda a: _block(T, a.shape[-1], row=lambda i: 0,
+                                head=_shared(H, a))
     stats = pl.BlockSpec((None, None, None, 1, tile),
                          lambda b, h, i: (b, h, i, 0, 0))
-    q, k, v = map(_kernel_layout, (q, k, v))
+    specs = [own(q), whole_kv(k), whole_kv(v)]
+    blocks = [(tile, hd, q.dtype)] * 2 + [(T, hd, q.dtype)] * 2
+    if rope:
+        specs += [own(rope[0]), whole_kv(rope[1])]
+        blocks += [(tile + T, rope[0].shape[-1], q.dtype)]
+    q, k, v, *rope = map(_kernel_layout, (q, k, v) + rope)
     o, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, scale=hd ** -0.5),
-        grid=(B, H, T // tile), in_specs=[own, whole_kv, whole_kv],
-        out_specs=[own, stats],
+        functools.partial(_fwd_kernel, scale=scale, two_part=bool(rope)),
+        grid=(B, H, T // tile), in_specs=specs,
+        out_specs=[specs[0], stats],
         out_shape=[_struct(q, q.shape, q.dtype),
                    _struct(q, (B, H, T // tile, 1, tile), jnp.float32)],
-        **_params(interpret))(q, k, v)
+        **_params(interpret, blocks, tile))(q, k, v, *rope)
     return _model_layout(o, H), lse.reshape(B, H, T)
 
 
-def _fused_grads(q, k, v, o, lse, do, interpret):
-    """(dq, dk, dv) in the operands' shapes and dtype."""
+def _shared(H: int, kv):
+    """The key/value head of ``kv`` [B, T, H_kv, hd] that query head h
+    of H reads."""
+    group = H // kv.shape[2]
+    return lambda h: h // group
+
+
+def _fused_grads(q, k, v, o, lse, do, *rope, scale, interpret):
+    """(dq, dk, dv[, dq_rope, dk_rope]) in the operands' shapes and dtype."""
     B, T, H, hd = q.shape
-    n_kv, tile = k.shape[2], _tile(T)
-    group = H // n_kv
+    tile = _tile(T)
     delta = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)
     # one lane-major row [1, tile] of statistics for each block of queries
     by_block = lambda a: a.reshape(B, H, T // tile, 1, tile)
     stats = pl.BlockSpec((None, None, T // tile, 1, tile),
                          lambda b, h, j: (b, h, 0, 0, 0))
-    whole = _block(T, hd, row=lambda j: 0)
-    own = _block(tile, hd, row=lambda j: j)
-    own_kv = _block(tile, hd, row=lambda j: j, head=lambda h: h // group)
-    q, k, v, do = map(_kernel_layout, (q, k, v, do))
-    per_head = _struct(q, q.shape, jnp.float32)
+    whole = lambda a: _block(T, a.shape[-1], row=lambda j: 0)
+    own = lambda a: _block(tile, a.shape[-1], row=lambda j: j)
+    own_kv = lambda a: _block(tile, a.shape[-1], row=lambda j: j,
+                              head=_shared(H, a))
+    in_specs = [whole(q), whole(q), stats, stats, own_kv(k), own_kv(v)]
+    out_specs = [whole(q), own(q), own(q)]
+    blocks = ([(T, hd, q.dtype)] * 2 + [(T, hd, jnp.float32)]
+              + [(tile, hd, q.dtype)] * 2 + [(tile, hd, jnp.float32)] * 2)
+    if rope:
+        in_specs += [whole(rope[0]), own_kv(rope[1])]
+        out_specs += [whole(rope[0]), own(rope[0])]
+        r = rope[0].shape[-1]
+        blocks += [(T + tile, r, q.dtype), (T + tile, r, jnp.float32)]
+    operands = (q, k, v) + rope
+    q, k, v, do, *ropes = map(_kernel_layout, (q, k, v, do) + rope)
+    per_head = [_struct(q, q.shape, jnp.float32)] * 3 + [
+        _struct(q, a.shape, jnp.float32) for a in ropes[:1] * 2]
     grads = pl.pallas_call(
-        functools.partial(_bwd_kernel, scale=hd ** -0.5),
-        grid=(B, H, T // tile),
-        in_specs=[whole, whole, stats, stats, own_kv, own_kv],
-        out_specs=[whole, own, own], out_shape=[per_head] * 3,
-        **_params(interpret))(q, do, by_block(lse),
-                              by_block(jnp.swapaxes(delta, 1, 2)), k, v)
-    dq, dk, dv = (_model_layout(d, H) for d in grads)
+        functools.partial(_bwd_kernel, scale=scale, two_part=bool(rope)),
+        grid=(B, H, T // tile), in_specs=in_specs,
+        out_specs=out_specs, out_shape=per_head,
+        **_params(interpret, blocks, tile))(
+            q, do, by_block(lse), by_block(jnp.swapaxes(delta, 1, 2)), k, v,
+            *ropes)
+    dq, dk, dv, *dropes = (_model_layout(d, H) for d in grads)
     # the query heads of a group share their key/value head
-    over_group = lambda d: jnp.sum(d.reshape(B, T, n_kv, group, hd), axis=3)
-    return (dq.astype(q.dtype), over_group(dk).astype(k.dtype),
-            over_group(dv).astype(v.dtype))
+    over_group = lambda d, a: jnp.sum(
+        d.reshape(B, T, a.shape[2], H // a.shape[2], a.shape[-1]),
+        axis=3).astype(a.dtype)
+    grads = (dq.astype(q.dtype), over_group(dk, operands[1]),
+             over_group(dv, operands[2]))
+    if rope:
+        grads += (dropes[0].astype(q.dtype), over_group(dropes[1], rope[1]))
+    return grads
 
 
-def _plain_output_lse(q, k, v):
+def _plain_output_lse(q, k, v, *rope, scale):
     """The plain path keeps no statistics: its backward pass is its own
     transposition.  The zeros stand in for them, made from ``q`` so that
     they vary over the mesh axes the kernel's would."""
-    return _plain(q, k, v), 0.0 * jnp.swapaxes(q[..., 0], 1, 2).astype(jnp.float32)
+    return (_plain(q, k, v, *rope, scale=scale),
+            0.0 * jnp.swapaxes(q[..., 0], 1, 2).astype(jnp.float32))
 
 
-def _plain_grads(q, k, v, o, lse, do):
-    return jax.vjp(_plain, q, k, v)[1](do)
+def _plain_grads(q, k, v, o, lse, do, *rope, scale):
+    return jax.vjp(functools.partial(_plain, scale=scale), q, k, v, *rope)[1](do)
 
 
 def _lowered(interpret, fused, plain, *args):
@@ -322,33 +425,43 @@ def _lowered(interpret, fused, plain, *args):
     other platform; ``interpret`` (the CPU tests') runs the kernels in
     Pallas interpret mode whatever the platform."""
     if interpret:
-        return fused(*args, interpret)
+        return fused(*args, interpret=interpret)
     return jax.lax.platform_dependent(
-        *args, tpu=lambda *a: fused(*a, False), default=plain)
+        *args, tpu=lambda *a: fused(*a, interpret=False), default=plain)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _attention(q, k, v, interpret=False):
-    """A shape the kernels take, on [B, T, H, hd] operands.  The platform
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 5))
+def _attention(q, k, v, interpret=False, rope=(), scale=None):
+    """A shape the kernels take, on [B, T, H, hd] operands (``rope``: the
+    rotary (queries, keys) of latent attention, or nothing).  The platform
     is chosen inside each of the three rules, so no transformation ever
     differentiates through the choice (a differentiated switch would carry
     the plain branch's [B, H, T, T] residuals in both)."""
-    return _attention_fwd(q, k, v, interpret)[0]
+    return _attention_fwd(q, k, v, interpret, rope, scale)[0]
 
 
-def _attention_fwd(q, k, v, interpret):
-    o, lse = _lowered(interpret, _fused_output_lse, _plain_output_lse, q, k, v)
+def _attention_fwd(q, k, v, interpret, rope, scale):
+    scale = q.shape[-1] ** -0.5 if scale is None else scale
+    o, lse = _lowered(interpret,
+                      functools.partial(_fused_output_lse, scale=scale),
+                      functools.partial(_plain_output_lse, scale=scale),
+                      q, k, v, *rope)
     # named outside the platform switch: a checkpoint policy that lists
     # SAVED_NAMES keeps them, and this rule is not run again (module docstring)
     o, lse = map(checkpoint_name, (o, lse), SAVED_NAMES)
-    return o, (q, k, v, o, lse)
+    return o, (q, k, v, o, lse, *rope)
 
 
-def _attention_bwd(interpret, res, do):
+def _attention_bwd(interpret, scale, res, do):
+    scale = res[0].shape[-1] ** -0.5 if scale is None else scale
     # the backward kernel is attention's too: the benchmark's labels read
     # the scope, forward and backward alike (obs/scopes.py)
     with jax.named_scope(scopes.FED_ATTENTION):
-        return _lowered(interpret, _fused_grads, _plain_grads, *res, do)
+        grads = _lowered(interpret,
+                         functools.partial(_fused_grads, scale=scale),
+                         functools.partial(_plain_grads, scale=scale),
+                         *res[:5], do, *res[5:])
+    return (*grads[:3], tuple(grads[3:]))
 
 
 _attention.defvjp(_attention_fwd, _attention_bwd)
@@ -356,22 +469,30 @@ _attention.defvjp(_attention_fwd, _attention_bwd)
 
 # -- the choice ---------------------------------------------------------------
 
-def causal_attention(q, k, v):
-    """``softmax_causal(q k^T / sqrt(hd)) v``: q [B, T, H, hd], k and v
+def causal_attention(q, k, v, *, rope=(), scale=None):
+    """``softmax_causal(q k^T * scale) v``: q [B, T, H, hd], k and v
     [B, T, H_kv, hd] with ``H % H_kv == 0`` (query head h reads key/value
-    head ``h // (H / H_kv)``) -> [B, T, H, hd] in q's dtype.
+    head ``h // (H / H_kv)``) -> [B, T, H, hd] in q's dtype; ``scale`` is
+    ``hd ** -0.5`` unless given.
+
+    ``rope`` = (q_rope [B, T, H, r], k_rope [B, T, H_r, r]) adds a second
+    product to the scores, ``(q k^T + q_rope k_rope^T) * scale`` — latent
+    attention's decoupled rotary key, one head (``H_r`` = 1) read by every
+    query head and never repeated in memory.
 
     Operands in their dtype, every product accumulated in float32, the
     softmax (scores, max, exp, sum) in float32 on both paths.  The fused
     kernels run where the program is lowered for a TPU and the shape fits
     (module docstring); no option selects a path."""
-    fused = _fits(q, k, v)
+    rope = tuple(rope)
+    fused = _fits(q, k, v, *rope)
     obs.counter("ops_kernel_path_total", op="causal_attention",
                 path="pallas" if fused else "reference").inc()
     if fused:
-        return _attention(q, k, v)
+        return _attention(q, k, v, False, rope, scale)
     if jax.default_backend() == "tpu":      # the log line only, as group_norm
-        log.warning("causal_attention: q %s %s, k %s does not fit the fused "
+        log.warning("causal_attention: q %s %s, k %s%s does not fit the fused "
                     "kernels; using the plain path", tuple(q.shape),
-                    q.dtype.name, tuple(k.shape))
-    return _plain(q, k, v)
+                    q.dtype.name, tuple(k.shape),
+                    "".join(f", rope {tuple(a.shape)}" for a in rope))
+    return _plain(q, k, v, *rope, scale=scale)

@@ -20,24 +20,32 @@ from fedml_tpu.ops.attention import causal_attention
 T = 384          # three tiles of 128: an unmasked loop and a diagonal
 
 
-def _fused(q, k, v):
-    return attention._attention(q, k, v, True)
+def _fused(q, k, v, *rope, scale=None):
+    return attention._attention(q, k, v, True, rope, scale)
 
 
-def _operands(shape_q, n_kv, dtype, seed=0):
-    keys = jax.random.split(jax.random.PRNGKey(seed), 4)
+def _operands(shape_q, n_kv, dtype, seed=0, rope=None):
+    """q, k, v and the weights of the loss; ``rope`` = (r, H_r) adds the
+    rotary parts q_rope [.., H, r] and k_rope [.., H_r, r] of latent
+    attention before the weights."""
+    keys = jax.random.split(jax.random.PRNGKey(seed), 6)
     kv = shape_q[:-2] + (n_kv, shape_q[-1])
-    q, k, v, w = (jax.random.normal(key, s, jnp.float32) for key, s in
-                  zip(keys, (shape_q, kv, kv, shape_q)))
-    return q.astype(dtype), k.astype(dtype), v.astype(dtype), w
+    shapes = [shape_q, kv, kv]
+    if rope:
+        shapes += [shape_q[:-1] + (rope[0],), shape_q[:-2] + (rope[1], rope[0])]
+    ops = [jax.random.normal(key, s, jnp.float32).astype(dtype)
+           for key, s in zip(keys, shapes)]
+    return (*ops, jax.random.normal(keys[5], shape_q, jnp.float32))
 
 
-def _out_and_grads(fn, q, k, v, w):
-    """[o, dq, dk, dv] in float32 of loss = sum(o * w)."""
-    def loss(q, k, v):
-        o = fn(q, k, v)
+def _out_and_grads(fn, *operands, **kw):
+    """[o, dq, dk, dv(, dq_rope, dk_rope)] in float32 of loss = sum(o * w)."""
+    *ops, w = operands
+
+    def loss(*ops):
+        o = fn(*ops, **kw)
         return jnp.sum(o.astype(jnp.float32) * w), o
-    grads, o = jax.jit(jax.grad(loss, (0, 1, 2), has_aux=True))(q, k, v)
+    grads, o = jax.jit(jax.grad(loss, tuple(range(len(ops))), has_aux=True))(*ops)
     return [np.asarray(a, np.float32) for a in (o,) + grads]
 
 
@@ -51,29 +59,56 @@ def _distance(got, want):
             for a, b in zip(got, want)]
 
 
+# (query heads, key/value heads), head size, (rotary size, rotary key heads),
+# scale: the two models' shapes, and latent attention's - keys 128 + 64 wide
+# against values of 128, ONE rotary key head for all, a scale that is given
+MLA_SCALE = 192 ** -0.5 * 1.2608 ** 2
+SHAPES = [pytest.param(heads, hd, None, None, id=f"{name}-{hd}")
+          for hd in (64, 128) for name, heads in (("mha", (2, 2)), ("gqa4", (4, 1)))]
+SHAPES.append(pytest.param((4, 4), 128, (64, 1), MLA_SCALE, id="mla-128+64"))
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
-@pytest.mark.parametrize("hd", [64, 128])
-@pytest.mark.parametrize("heads", [(2, 2), (4, 1)], ids=["mha", "gqa4"])
-def test_fused_matches_plain(heads, hd, dtype):
-    """Output and the three gradients.  float32: to 1e-5 of the largest
-    value.  bfloat16: no farther (relative l2 distance: the largest single
-    error is one rounding of the result on either path) from a float32
-    oracle than 1.5 x the plain bfloat16 path is — the softmax stays
-    float32 and every product accumulates in float32 on both, they differ
-    in where they round (on the CPU the plain path's autodiff keeps ds
-    float32 as an operand, which a TPU's one-pass product does not)."""
+@pytest.mark.parametrize("heads, hd, rope, scale", SHAPES)
+def test_fused_matches_plain(heads, hd, rope, scale, dtype):
+    """Output and the three gradients (five with a rotary part).  float32:
+    to 1e-5 of the largest value.  bfloat16: no farther (relative l2
+    distance: the largest single error is one rounding of the result on
+    either path) from a float32 oracle than 1.5 x the plain bfloat16 path
+    is — the softmax stays float32 and every product accumulates in float32
+    on both, they differ in where they round (on the CPU the plain path's
+    autodiff keeps ds float32 as an operand, which a TPU's one-pass product
+    does not)."""
     H, n_kv = heads
-    q, k, v, w = _operands((2, T, H, hd), n_kv, dtype)
-    got = _out_and_grads(_fused, q, k, v, w)
-    plain = _out_and_grads(attention._plain, q, k, v, w)
+    operands = _operands((2, T, H, hd), n_kv, dtype, rope=rope)
+    got = _out_and_grads(_fused, *operands, scale=scale)
+    plain = _out_and_grads(attention._plain, *operands, scale=scale)
+    assert len(got) == (6 if rope else 4)
     if dtype == jnp.float32:
         assert max(_rel(got, plain)) <= 1e-5, _rel(got, plain)
         return
     oracle = _out_and_grads(attention._plain, *(
-        a.astype(jnp.float32) for a in (q, k, v)), w)
+        a.astype(jnp.float32) for a in operands), scale=scale)
     for f, p in zip(_distance(got, oracle), _distance(plain, oracle)):
         assert f <= 1.5 * p, (_distance(got, oracle), _distance(plain, oracle))
+
+
+def test_the_two_part_plain_path_is_attention_over_concatenated_keys():
+    """The numerical spec of the two-operand form: the scores of q | q_rope
+    against k | k_rope (the rotary key repeated for every head), any three
+    head sizes, the scale as given."""
+    q, k, v, q_rope, k_rope, _ = _operands((2, 24, 4, 16), 4, jnp.float32,
+                                           rope=(8, 1))
+    v = v[..., :12]                                # values narrower than keys
+    got = causal_attention(q, k, v, rope=(q_rope, k_rope), scale=0.37)
+    wide_q = jnp.concatenate([q, q_rope], axis=-1)
+    wide_k = jnp.concatenate([k, jnp.repeat(k_rope, 4, axis=2)], axis=-1)
+    s = jnp.einsum("bqhd,bkhd->bhqk", wide_q, wide_k) * 0.37
+    s = jnp.where(jnp.tril(jnp.ones((24, 24), bool)), s, -jnp.inf)
+    want = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v)
+    assert got.shape == (2, 24, 4, 12)
+    np.testing.assert_allclose(got, want, atol=1e-5)
 
 
 @pytest.fixture(scope="module", params=[64, 128], ids=["hd64", "hd128"])
@@ -100,6 +135,20 @@ def test_under_vmap_over_clients(clients):
 def test_under_checkpoint(clients):
     args, grad, want = clients
     _close(jax.jit(jax.vmap(grad(jax.checkpoint(_fused))))(*args), want)
+
+
+def test_latent_attention_under_vmap_and_checkpoint():
+    """The two-part form as the engine runs it: a vmap over a chunk's clients
+    of a checkpointed layer, all five gradients."""
+    *ops, w = _operands((2, 1, 256, 4, 128), 4, jnp.float32, rope=(64, 1))
+
+    def grad(fn):
+        loss = lambda *a: jnp.sum(jnp.sin(fn(*a[:5])) * a[5])
+        return jax.jit(jax.vmap(jax.grad(loss, (0, 1, 2, 3, 4))))
+
+    fused = lambda q, k, v, *rope: attention._attention(q, k, v, True, rope, 0.11)
+    plain = lambda *a: attention._plain(*a, scale=0.11)
+    _close(grad(jax.checkpoint(fused))(*ops, w), grad(plain)(*ops, w))
 
 
 def test_under_shard_map_with_check_vma(clients):

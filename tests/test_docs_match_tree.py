@@ -31,7 +31,7 @@ SUFFIXES = (".py", ".md", ".json", ".jsonl", ".sh", ".toml", ".cpp")
 # named by the documents and no part of a checkout: FedML's own README,
 # and what a run leaves in its output directory
 NOT_OURS = ("benchmark/README.md", "program_trace.json", "scope_map.json",
-            "phase_trace.json", "phase_map.json",
+            "phase_trace.json", "phase_map.json", "kernel_trace.json",
             "clock_offsets.json", "critical_path.json", "merged.chrome.json")
 TOP_LEVEL = {name for name in os.listdir(REPO)
              if os.path.isdir(os.path.join(REPO, name))}

@@ -204,6 +204,38 @@ def test_causal_attention_compiles(topo, shape, dtype):
     assert not re.search(rf"f32\[[\d,]*{T},{T}\]", c.as_text())
 
 
+# a local step of deepseekv2.lora4of256t4096: (B, T, H, content | value head
+# size, rotary size) - keys 128 + 64 wide, ONE rotary key head for all 128
+LATENT_ATTENTION = (1, 4096, 128, 128, 64)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32-highest"])
+def test_latent_attention_compiles(topo, dtype):
+    """The two-part form of `causal_attention` at DeepSeek-V2's shapes, as the
+    cell's round and its float32 twin run it: both kernels, the whole-sequence
+    blocks of T = 4,096 with the rotary operands beside them inside the fast
+    memory the call asks for (Mosaic's default refuses them), no float32
+    `[.., T, T]` buffer and no rotary key repeated for the heads."""
+    from fedml_tpu.ops.attention import causal_attention
+    B, T, H, hd, r = LATENT_ATTENTION
+
+    def grads(q, k, v, q_rope, k_rope):
+        return jax.grad(lambda *a: jnp.sum(causal_attention(
+            *a[:3], rope=a[3:], scale=0.18).astype(jnp.float32) ** 2),
+            (0, 1, 2, 3, 4))(q, k, v, q_rope, k_rope)
+
+    with jax.default_matmul_precision(
+            "highest" if dtype == jnp.float32 else "default"):
+        c = _compile(topo, grads, *[((B, T, H, hd), dtype)] * 3,
+                     ((B, T, H, r), dtype), ((B, T, 1, r), dtype))
+    _assert_kernels(c, 2)
+    text = c.as_text()
+    assert "vmem_limit_bytes" in text or "scoped_memory" in text
+    assert not re.search(rf"f32\[[\d,]*{T},{T}\]", text)
+    assert not re.search(rf"\[{B},{T},{H},{r}\].*broadcast", text)
+
+
 # -- the documented size limit --------------------------------------------
 
 @pytest.mark.slow
@@ -513,6 +545,45 @@ def _assert_fused_attention(text: str, shape):
     assert not large, large
 
 
+def _dispatched(topo, config, traffic, **engine_kw):
+    """(engine, variables' shapes, the compiled round) of a resident cell as
+    the engine dispatches it on the first described chip: variables donated,
+    the population's stack as shapes."""
+    from fedbench.harness import build
+    from fedml_tpu.parallel.mesh import (client_sharding, make_mesh,
+                                         replicated_sharding,
+                                         stack_leaf_sharding)
+    population, k = int(traffic["population"]), int(traffic["cohort"])
+    host = dict(traffic, population=4)
+    data = build.make_data(host, seed=0)
+    engine = build.make_engine(config, host, data, seed=0, **engine_kw)
+    mesh = engine.mesh = make_mesh(devices=topo.devices[:1])
+    rep, csh = replicated_sharding(mesh), client_sharding(mesh)
+    stack = {
+        name: jax.ShapeDtypeStruct((population,) + v.shape[1:], v.dtype,
+                                   sharding=stack_leaf_sharding(mesh, v))
+        for name, v in engine._cast_stack_x(dict(engine._host_shards())).items()}
+    variables = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rep),
+        jax.eval_shape(engine.init_variables))
+    compiled = engine.round_fn.inner.lower(
+        variables, (), stack,
+        jax.ShapeDtypeStruct((population,), jnp.float32, sharding=csh),
+        jax.ShapeDtypeStruct((k,), jnp.int32, sharding=rep),
+        jax.ShapeDtypeStruct((k,), jnp.float32, sharding=rep),
+        jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=rep)).compile()
+    return engine, variables, compiled
+
+
+def _needs_with_the_base_aliased(compiled, config) -> int:
+    """Bytes the round needs on the chip; the frozen leaves come back in the
+    buffers they came in."""
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes > 2 * config["widths"]["parameters_held"] - 1e6
+    return (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.generated_code_size_in_bytes)
+
+
 @pytest.mark.slow
 def test_lfm2_adapter_round_and_its_float32_twin_fit_one_chip(topo, monkeypatch):
     """`lfm2moe24b.lora4of256t2048`'s resident round (a 5.4 GB frozen bfloat16
@@ -525,41 +596,10 @@ def test_lfm2_adapter_round_and_its_float32_twin_fit_one_chip(topo, monkeypatch)
     base is read as it is stored: the bfloat16 round holds no float32 buffer
     of a frozen matrix's shape, and no buffer of one behind a client axis;
     what it folds and averages is the adapters."""
-    from fedbench.harness import build
     from fedml_tpu.parallel.engine import flatten_carry_f32
-    from fedml_tpu.parallel.mesh import (client_sharding, make_mesh,
-                                         replicated_sharding,
-                                         stack_leaf_sharding)
     config, traffic = _bench_files("lfm2_24b_a2b", "lora4of256t2048")
-
-    def dispatched(traffic, **engine_kw):
-        population, k = int(traffic["population"]), int(traffic["cohort"])
-        host = dict(traffic, population=4)
-        data = build.make_data(host, seed=0)
-        engine = build.make_engine(config, host, data, seed=0, **engine_kw)
-        mesh = engine.mesh = make_mesh(devices=topo.devices[:1])
-        rep, csh = replicated_sharding(mesh), client_sharding(mesh)
-        stack = {
-            name: jax.ShapeDtypeStruct((population,) + v.shape[1:], v.dtype,
-                                       sharding=stack_leaf_sharding(mesh, v))
-            for name, v in engine._cast_stack_x(dict(engine._host_shards())).items()}
-        variables = jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rep),
-            jax.eval_shape(engine.init_variables))
-        compiled = engine.round_fn.inner.lower(
-            variables, (), stack,
-            jax.ShapeDtypeStruct((population,), jnp.float32, sharding=csh),
-            jax.ShapeDtypeStruct((k,), jnp.int32, sharding=rep),
-            jax.ShapeDtypeStruct((k,), jnp.float32, sharding=rep),
-            jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=rep)).compile()
-        return engine, variables, compiled
-
-    def needs(compiled):
-        mem = compiled.memory_analysis()
-        # the frozen leaves come back in the buffers they came in
-        assert mem.alias_size_in_bytes > 2 * config["widths"]["parameters_held"] - 1e6
-        return (mem.argument_size_in_bytes + mem.temp_size_in_bytes
-                + mem.generated_code_size_in_bytes)
+    dispatched = lambda traffic, **kw: _dispatched(topo, config, traffic, **kw)
+    needs = lambda compiled: _needs_with_the_base_aliased(compiled, config)
 
     engine, variables, compiled = dispatched(traffic)
     # 15.08e9 at the file's chunk 4 (11.6e9 at chunk 1, where this bound
@@ -589,3 +629,47 @@ def test_lfm2_adapter_round_and_its_float32_twin_fit_one_chip(topo, monkeypatch)
                                 train_dtype="float32", local_dtype=None)
     assert needs(twin) < 15.75 * 2 ** 30, twin.memory_analysis()
     _assert_fused_attention(twin.as_text(), ATTENTION[1])
+
+
+@pytest.mark.slow
+def test_deepseek_v2_adapter_round_and_its_float32_twin_fit_one_chip(topo):
+    """`deepseekv2.lora4of256t4096`'s resident round (a 6.3 GB frozen bfloat16
+    base - 5 layers, one routing group of 20 experts a layer, an eighth of
+    the vocabulary - under 7,459,840 adapter parameters, chunk from the file)
+    and the float32 twin that the reference check runs, compiled as the engine
+    dispatches them, fit one chip: the test that sizes the cut
+    (fedbench/configs/deepseek_v2.json, "cut": at chunk 2 the bfloat16 round
+    needs 16.1 GiB of 15.75).  The round takes the fused attention - no
+    buffer as large as a step's [128, T, T] scores - and XLA:TPU's grouped
+    product for the held experts; the base is read as it is stored, and what
+    the round folds is the adapters."""
+    from fedml_tpu.parallel.engine import flatten_carry_f32
+    config, traffic = _bench_files("deepseek_v2", "lora4of256t4096")
+    B, T, H, _, _ = LATENT_ATTENTION
+    engine, variables, compiled = _dispatched(topo, config, traffic)
+    needs = _needs_with_the_base_aliased(compiled, config)
+    # 14.78e9 at chunk 1 (the rehearsal, PR 39): base 6.32e9, the compiler's
+    # relayout copy of the held experts 3.77e9, a step's activations the rest
+    assert needs < 15.3e9, compiled.memory_analysis()
+    trained = engine.trainer.trained_variables(variables)
+    n_trained = sum(int(np.prod(a.shape)) for a in jax.tree.leaves(trained))
+    assert n_trained == config["widths"]["parameters_trained"]
+    assert flatten_carry_f32(engine._zero_sums(variables)[0])[0].shape == (n_trained,)
+    frozen = engine.trainer.split_frozen(variables["params"])[1]
+    text = compiled.as_text()
+    # the expert stacks: shapes no activation shares
+    shapes = {a.shape for a in jax.tree.leaves(frozen) if len(a.shape) == 3}
+    assert shapes == {(20, 5120, 1536), (20, 1536, 5120)}
+    for shape in shapes:
+        dims = ",".join(map(str, shape))
+        assert not re.search(rf"f32\[{dims}\]", text), shape
+        assert not re.search(rf"bf16\[\d+,{dims}\]", text), shape
+    assert text.count("ragged-dot") > 0            # XLA:TPU's grouped product
+    _assert_fused_attention(text, (B, T, H, H, 128))
+    with jax.default_matmul_precision("highest"):
+        _, _, twin = _dispatched(topo, config, dict(traffic, population=4, cohort=4),
+                                 train_dtype="float32", local_dtype=None)
+    # 15.96e9 of 16.91e9
+    assert _needs_with_the_base_aliased(twin, config) < 15.75 * 2 ** 30, \
+        twin.memory_analysis()
+    _assert_fused_attention(twin.as_text(), (B, T, H, H, 128))
