@@ -51,8 +51,30 @@ imported from there, not copied):
   the published expert parallelism): the router scores all ``n_experts``,
   slots of absent experts sort behind the held ones' and add nothing; the
   shared experts are a dense gated MLP on every token.
-* every layer is a ``jax.checkpoint`` that saves its input only; the layers
-  are unrolled (each has its own leaves).
+* every layer is a ``jax.checkpoint``; the layers are unrolled (each has its
+  own leaves), so a kept value is ONE buffer that the forward pass writes
+  anyway and the backward pass reads in place — no scan's stack (what made
+  kept products cost what they save in `models/looped_lm.py`).  **Where the
+  stream is 16 bits wide** a layer keeps, beside its input, what
+  ``KEPT_NAMES`` lists: the attention kernel's output and log-sum-exp (named
+  in ``ops/attention.py``) and ``W_o``'s adapted output (``attn_out``, named
+  in ``latent_attention``), each in the dtype the backward pass reads it in,
+  so no value changes.  The backward pass then re-runs the latent side, the
+  router, the experts and the MLPs — the backward kernel needs q, k, v again
+  — and neither the forward kernel nor the output projection with its
+  adapter and the heads-first transposes in front of the kernel.  At
+  DeepSeek-V2's widths on 4,096 bfloat16 tokens (``kept_bytes``) that is
+  134.2 MB of ``o``, 2.1 MB of log-sum-exp and 41.9 MB of ``attn_out`` a
+  layer, 178,257,920 B, beside 41.9 MB of input: 1.10 GB for the five layers
+  of a local step.  What bounds the set is the chip's memory: the kernel's
+  three 134 MB operands a layer would not fit, and the MXU redoes their
+  products in ≈ 1.5 ms.  **A float32 stream** (the twin the benchmark's
+  reference check runs, which has under 1 GB to spare on a chip) keeps a
+  layer's input alone, as every stream did before.  The stream's width is
+  the whole rule, no option — `looped_lm`'s rule, for the same reason; the
+  choice is counted at trace time in
+  ``remat_policy_total{model="deepseek_v2", saved=...}`` beside the bytes a
+  local step keeps (``remat_saved_bytes``).
 * the router's decisions are counted as in lfm2_moe: tokens routed to every
   (expert layer, expert) of a step, held or not
   (``counters/moe_expert_tokens``).
@@ -67,12 +89,22 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
+from fedml_tpu import obs
 from fedml_tpu.models.lfm2_moe import (_adapted, _Groups, _Leaves,
                                        expert_product, gated_mlp)
 from fedml_tpu.models.looped_lm import _dot, apply_rotary, rms_norm
 from fedml_tpu.obs import scopes
-from fedml_tpu.ops.attention import causal_attention
+from fedml_tpu.ops.attention import SAVED_NAMES, causal_attention
+
+# what a layer's checkpoint keeps beside its input where the stream is 16
+# bits wide (module docstring): ``W_o``'s adapted output, as
+# `latent_attention` names it, and the attention kernel's output and
+# log-sum-exp
+KEPT_NAMES = ("attn_out",) + SAVED_NAMES
+# made once: a jaxpr prints its checkpoint's policy by identity
+_KEEP = jax.checkpoint_policies.save_only_these_names(*KEPT_NAMES)
 
 
 def yarn_mscale(factor: float, mscale: float) -> float:
@@ -102,7 +134,8 @@ def yarn_tables(seq_len: int, dim: int, theta: float, factor: float,
 def latent_attention(h, lp, ad, scale, eps, cos, sin, n_heads: int,
                      nope: int, v_dim: int, softmax_scale: float):
     """Multi-head latent attention on h [B, T, d]; the scopes: the latent
-    side here, the core and the output projection ``fed_attention``."""
+    side here, the core and the output projection ``fed_attention``.  The
+    projection's output is named for the layer's checkpoint (``KEPT_NAMES``)."""
     B, T, _ = h.shape
     with jax.named_scope(scopes.FED_MLA_LATENT):
         a = rms_norm(h, lp["in_norm"], eps)
@@ -120,7 +153,15 @@ def latent_attention(h, lp, ad, scale, eps, cos, sin, n_heads: int,
     with jax.named_scope(scopes.FED_ATTENTION):
         o = causal_attention(q_nope, k_nope, v, rope=(q_rope, k_rope),
                              scale=softmax_scale)
-        return adapted(o.reshape(B, T, -1), "wo")
+        return checkpoint_name(adapted(o.reshape(B, T, -1), "wo"), "attn_out")
+
+
+def kept_bytes(h, n_heads: int, v_dim: int) -> int:
+    """Bytes of ``KEPT_NAMES``' values for ONE layer on the stream h
+    [B, T, d]: the attention output and ``W_o``'s in h's dtype, the rows'
+    log-sum-exp in float32."""
+    tokens, d = h.size // h.shape[-1], h.shape[-1]
+    return tokens * ((n_heads * v_dim + d) * h.dtype.itemsize + 4 * n_heads)
 
 
 def route_grouped(f, router, k: int, n_group: int, topk_group: int,
@@ -289,8 +330,15 @@ class DeepSeekV2LM(nn.Module):
             self.rope_beta_fast, self.rope_beta_slow, self.rope_original))
         h = embed[x.astype(jnp.int32)].astype(dt)
         counts = []
+        attention = h.dtype.itemsize <= 2
+        obs.counter("remat_policy_total", model="deepseek_v2",
+                    saved="attention" if attention else "input_only").inc()
+        kept = kept_bytes(h, self.n_heads, self.v_dim) if attention else 0
+        obs.gauge("remat_saved_bytes", model="deepseek_v2").set(
+            len(self.held_layers) * (h.size * h.dtype.itemsize + kept))
         for i in self.held_layers:
-            layer = jax.checkpoint(functools.partial(self._layer, i))
+            layer = jax.checkpoint(functools.partial(self._layer, i),
+                                   policy=_KEEP if attention else None)
             h, c = layer(h, base[i], lora[f"layer_{i}"], cos, sin)
             if c is not None:
                 counts.append(c)

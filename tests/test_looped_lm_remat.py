@@ -54,11 +54,11 @@ def _stacks(loss, params, lead):
     return found
 
 
-def _rematted(loss, *args):
+def _rematted(loss, *args, visit=None):
     """Primitive names of the gradient's equations that `jax.checkpoint`
     runs again inside the backward pass, with everything beneath them (a
     sub-jaxpr's name stacks are relative to its equation's), and the names
-    `checkpoint_name` gave values there."""
+    `checkpoint_name` gave values there; `visit` sees each such equation."""
     again, names = [], set()
 
     def walk(jaxpr, inside):
@@ -67,6 +67,8 @@ def _rematted(loss, *args):
                 e.source_info.name_stack)
             if rerun:
                 again.append(e.primitive.name)
+                if visit is not None:
+                    visit(e)
                 names.update({e.params["name"]} if e.primitive.name == "name"
                              else ())
             for v in e.params.values():

@@ -41,7 +41,16 @@ instructions) and in the float32 twin of `silo4of256t1024` (4,890 lines,
 of `ops/attention.py` and `models/looped_lm.py` in the source table and
 the 3 serialized kernel bodies that embed them; the bfloat16 round of
 `silo4of256t1024` went from 3,811 instructions and 10.73e9 B to 9,358 and
-11.59e9 (builder, CPU compile rehearsal, PR 38).
+11.59e9 (builder, CPU compile rehearsal, PR 38).  PR 40 (what
+`deepseek_v2`'s checkpoint keeps: `models/deepseek_v2.py` alone, which no
+other cell imports) compiled `silo4of256t1024` and `lora4of256t2048` on the
+parent `a47c3af` and on its tree: 11,996 and 15,791 lines with op metadata,
+the source table and the kernels' serialized bodies taken out, 8 and 43
+kernels, the same arguments / temporaries / code bytes, 0 lines differ; the
+float32 twin of `lora4of256t4096` is the parent's too (17,536 instructions,
+15,955,036,672 B on both trees), and its bfloat16 round went from 14.78e9 B
+with 5 attention kernels and 89 matrix products in the re-run to 15.09e9
+with none and 79 (builder, CPU compile rehearsal, PR 40).
 
 A compile that passes is not a chip run: nothing executes, so these
 tests say nothing about results or times.  Skipped where the topology
@@ -631,6 +640,21 @@ def test_lfm2_adapter_round_and_its_float32_twin_fit_one_chip(topo, monkeypatch)
     _assert_fused_attention(twin.as_text(), ATTENTION[1])
 
 
+def _attention_kernels(text: str) -> dict:
+    """{phase: how many} of the kernels labelled `attention` in a round's
+    text (the layers are unrolled and the local steps are loops: one
+    instruction a layer is one execution a layer-step)."""
+    from fedml_tpu.obs import programs
+    from parallel_case import hlo_instructions
+    smap, phases = programs.maps_of_hlo_text(text)
+    found = {}
+    for name, _, opcode, rest in hlo_instructions(text):
+        if (opcode == "custom-call" and "tpu_custom_call" in rest
+                and smap[name] == "attention"):
+            found[phases[name]] = found.get(phases[name], 0) + 1
+    return found
+
+
 @pytest.mark.slow
 def test_deepseek_v2_adapter_round_and_its_float32_twin_fit_one_chip(topo):
     """`deepseekv2.lora4of256t4096`'s resident round (a 6.3 GB frozen bfloat16
@@ -642,15 +666,21 @@ def test_deepseek_v2_adapter_round_and_its_float32_twin_fit_one_chip(topo):
     needs 16.1 GiB of 15.75).  The round takes the fused attention - no
     buffer as large as a step's [128, T, T] scores - and XLA:TPU's grouped
     product for the held experts; the base is read as it is stored, and what
-    the round folds is the adapters."""
+    the round folds is the adapters.  The bfloat16 round's layers keep the
+    attention kernel's (o, lse) and W_o's output (ISSUE 40): one forward
+    kernel a layer-step where the bare checkpoint ran two; the float32 twin
+    keeps a layer's input alone and is the parent's program."""
     from fedml_tpu.parallel.engine import flatten_carry_f32
+    from parallel_case import hlo_instructions
     config, traffic = _bench_files("deepseek_v2", "lora4of256t4096")
     B, T, H, _, _ = LATENT_ATTENTION
     engine, variables, compiled = _dispatched(topo, config, traffic)
     needs = _needs_with_the_base_aliased(compiled, config)
-    # 14.78e9 at chunk 1 (the rehearsal, PR 39): base 6.32e9, the compiler's
-    # relayout copy of the held experts 3.77e9, a step's activations the rest
-    assert needs < 15.3e9, compiled.memory_analysis()
+    # 15.09e9 at chunk 1 (the rehearsal, PR 40) + 0.2e9: base 6.32e9, the
+    # compiler's relayout copy of the held experts 3.77e9, a step's
+    # activations the rest; 14.78e9 before the five layers kept 0.89e9 of
+    # named values a step (PR 39); the chip gives 16.91e9
+    assert needs < 15.29e9, compiled.memory_analysis()
     trained = engine.trainer.trained_variables(variables)
     n_trained = sum(int(np.prod(a.shape)) for a in jax.tree.leaves(trained))
     assert n_trained == config["widths"]["parameters_trained"]
@@ -666,10 +696,24 @@ def test_deepseek_v2_adapter_round_and_its_float32_twin_fit_one_chip(topo):
         assert not re.search(rf"bf16\[\d+,{dims}\]", text), shape
     assert text.count("ragged-dot") > 0            # XLA:TPU's grouped product
     _assert_fused_attention(text, (B, T, H, H, 128))
+    # a layer's forward kernel runs once: the parent's text held 5 more, in
+    # phase `recompute`, and re-ran 89 matrix products where this one re-runs
+    # 79 - W_o and its adapter's B are kept (the rank-wide `o A` is not: the
+    # gradient of B reads it); the 8 kernels still re-run are the expert
+    # layers' grouped products (2 of 3 a layer)
+    assert _attention_kernels(text) == {"forward": 5, "backward": 5}
+    assert _rerun_work(text) == {"convolution": 79, "custom-call": 8}
     with jax.default_matmul_precision("highest"):
         _, _, twin = _dispatched(topo, config, dict(traffic, population=4, cohort=4),
                                  train_dtype="float32", local_dtype=None)
-    # 15.96e9 of 16.91e9
-    assert _needs_with_the_base_aliased(twin, config) < 15.75 * 2 ** 30, \
+    # 15.96e9 of 16.91e9, the parent's need to the byte, in the parent's
+    # 17,536 instructions (the rehearsal on a47c3af and on PR 40's tree): a
+    # float32 stream keeps a layer's input alone, and every kernel and every
+    # product of a layer runs again
+    assert _needs_with_the_base_aliased(twin, config) == 15_955_036_672, \
         twin.memory_analysis()
-    _assert_fused_attention(twin.as_text(), (B, T, H, H, 128))
+    text = twin.as_text()
+    _assert_fused_attention(text, (B, T, H, H, 128))
+    assert _attention_kernels(text) == {"forward": 5, "recompute": 5, "backward": 5}
+    assert _rerun_work(text) == {"convolution": 89, "custom-call": 13}
+    assert sum(1 for _ in hlo_instructions(text)) == 17536
