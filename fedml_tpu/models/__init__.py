@@ -59,6 +59,13 @@ def create_model(model_name: str, output_dim: int, input_dim: int | None = None,
         from fedml_tpu.models.deepseek_v2 import DeepSeekV2LM
         kw = {k: tuple(v) if isinstance(v, list) else v for k, v in kw.items()}
         return DeepSeekV2LM(vocab_size=output_dim, **kw)
+    if name == "cohere2_moe":
+        # parallel blocks of sliding-window / full attention and sigmoid-routed
+        # experts beside averaged shared ones, adapters over a frozen base
+        # (Command A+'s family, models/cohere2_moe.py); every width is a keyword
+        from fedml_tpu.models.cohere2_moe import Cohere2MoeLM
+        kw = {k: tuple(v) if isinstance(v, list) else v for k, v in kw.items()}
+        return Cohere2MoeLM(vocab_size=output_dim, **kw)
     if name in ("resnet18_gn", "resnet18"):
         return ResNet18GN(num_classes=output_dim, **kw)
     if name == "resnet56":

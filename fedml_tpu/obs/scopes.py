@@ -48,10 +48,23 @@ copy census of the touched families exactly).  ``label_of`` turns one
                         fed_attention's)
     fed_shared_expert   the gated MLP every token of an  shared_expert
                         expert layer visits
+    fed_window_attention  a sliding-window layer's       window_attention
+                        attention in a model that mixes
+                        window and full layers (the
+                        parallel block's one norm,
+                        projections, rotary, the banded
+                        core, output projection)
+    fed_full_attention  the same of a full layer of      full_attention
+                        such a model (no positional
+                        term)
 
 An op under several scopes belongs to the innermost one (a forward op
-is inside fed_local_train too); an op under none is ``unscoped``.
-The last eight sit inside fed_forward and claim their ops forward,
+is inside fed_local_train too); an op under none is ``unscoped``.  One
+exception: ``fed_attention`` inside ``fed_window_attention`` or
+``fed_full_attention`` yields to it - the fused attention's backward rule
+opens ``fed_attention`` itself (ops/attention.py), whichever kind of
+layer called it.
+The last ten sit inside fed_forward and claim their ops forward,
 backward and rematerialised alike, so in a model that has them
 ``forward`` / ``backward`` read what lies outside them (embedding,
 residual stream between blocks, the final norm); a model without them
@@ -101,6 +114,8 @@ FED_MOE_ROUTER = "fed_moe_router"
 FED_MOE_EXPERTS = "fed_moe_experts"
 FED_MLA_LATENT = "fed_mla_latent"
 FED_SHARED_EXPERT = "fed_shared_expert"
+FED_WINDOW_ATTENTION = "fed_window_attention"
+FED_FULL_ATTENTION = "fed_full_attention"
 
 UNSCOPED = "unscoped"
 BACKWARD = "backward"
@@ -123,7 +138,12 @@ LABEL_OF_SCOPE = {
     FED_MOE_EXPERTS: "moe_experts",
     FED_MLA_LATENT: "mla_latent",
     FED_SHARED_EXPERT: "shared_expert",
+    FED_WINDOW_ATTENTION: "window_attention",
+    FED_FULL_ATTENTION: "full_attention",
 }
+# the kinds of attention layer a model may tell apart: an enclosing one of
+# these claims what ``fed_attention`` inside it holds
+_ATTENTION_KINDS = (FED_WINDOW_ATTENTION, FED_FULL_ATTENTION)
 LABELS = tuple(LABEL_OF_SCOPE.values()) + (BACKWARD, UNSCOPED)
 
 # **Counters**: a model may count what its forward pass did in a step
@@ -153,13 +173,19 @@ def label_of(op_name: str) -> str:
     """The label of one HLO instruction from its ``op_name``: the
     innermost ``fed_*`` component of the "/"-separated name stack
     (``fed_forward`` wrapped in ``transpose(`` is the backward pass)."""
+    generic = False
     for part in reversed(op_name.split("/")):
         m = _SCOPE.search(part)
         if m:
+            if m.group() == FED_ATTENTION:
+                generic = True        # a kind of attention outside it wins
+                continue
+            if generic and m.group() not in _ATTENTION_KINDS:
+                break
             if m.group() == FED_FORWARD and "transpose(" in part:
                 return BACKWARD
             return LABEL_OF_SCOPE[m.group()]
-    return UNSCOPED
+    return LABEL_OF_SCOPE[FED_ATTENTION] if generic else UNSCOPED
 
 
 def phase_of(op_name: str) -> str:

@@ -8,7 +8,10 @@ heads of 64 at T = 2,048; `models/deepseek_v2.py::latent_attention`: 128
 heads at T = 4,096 in the TWO-PART form, ``rope=(q_rope, k_rope)`` and a
 given ``scale`` - scores ``(q k^T + q_rope k_rope^T) * scale`` with 128-wide
 content parts, 64-wide rotary parts and ONE rotary key head that every
-query head reads); projections, norms and rotary stay with the models.
+query head reads; `models/cohere2_moe.py::attention`: 128 query / 8
+key-value heads of 128 at T = 8,192, its sliding layers under ``window`` =
+4,096 - key j visible to query i iff ``0 <= i - j < window`` - and its full
+layers under none); projections, norms and rotary stay with the models.
 Two bodies, one result:
 
 * **the plain path** — einsum, mask, ``jax.nn.softmax``, einsum: the
@@ -27,7 +30,17 @@ Two bodies, one result:
   ``ds = p (dp - delta)`` with ``delta = rowsum(o do)``, ``dk += ds^T q``,
   ``dq += ds k``.  Grouped-query attention reads key/value head
   ``h // group`` through the block index map; ``dk`` / ``dv`` come out per
-  query head and are summed over the group in float32.
+  query head and are summed over the group in float32.  **Under a window**
+  the same kernels visit only the blocks inside the band (`_band`): forward
+  from the first key block a query block reaches, backward to the last
+  query block that reaches the key block; the blocks the band's far edge
+  crosses are masked on that side as the diagonal one is on its own, the
+  blocks between are whole, and the blocks outside are not read - loop
+  bounds, as above the diagonal.  The blocks a windowed call visits and the
+  causal blocks in all are counted at trace time in
+  ``attention_band_blocks_total{blocks="visited" | "causal"}``
+  (`band_blocks`).  Without a window the kernels are what they were before
+  they took one: the same jaxpr, equation for equation.
 
 **Precision is the plain path's**: operands in the compute dtype, every
 product accumulated in float32; scores, max, exp, sum and the running
@@ -83,7 +96,14 @@ _TN = (((0,), (0,)), ((), ()))       # a [d, m] . b [d, n] -> [m, n]
 
 # -- the plain path -----------------------------------------------------------
 
-def _plain(q, k, v, *rope, scale=None):
+def _visible(T: int, window=None):
+    """[T (queries), T (keys)] bool: key j <= query i and, under a sliding
+    window, ``i - j < window``."""
+    causal = jnp.tril(jnp.ones((T, T), bool))
+    return causal if window is None else jnp.triu(causal, 1 - window)
+
+
+def _plain(q, k, v, *rope, scale=None, window=None):
     """einsum, mask, softmax, einsum, as both models spelled it before
     they shared it: query heads grouped over their key/value head, and the
     ungrouped products where every head has its own (the same mathematics;
@@ -93,21 +113,20 @@ def _plain(q, k, v, *rope, scale=None):
     dt, n_kv = q.dtype, k.shape[2]
     scale = hd ** -0.5 if scale is None else scale
     if rope:
-        return _plain_two_part(q, k, v, *rope, scale)
+        return _plain_two_part(q, k, v, *rope, scale, window)
     grouped = n_kv != H
     if grouped:
         q = q.reshape(B, T, n_kv, H // n_kv, hd)
     s = jnp.einsum("btgrd,bsgd->bgrts" if grouped else "bqhd,bkhd->bhqk",
                    q, k, preferred_element_type=jnp.float32) * scale
-    causal = jnp.tril(jnp.ones((T, T), bool))
-    s = jnp.where(causal, s, _MASK)
+    s = jnp.where(_visible(T, window), s, _MASK)
     w = jax.nn.softmax(s, axis=-1).astype(dt)
     o = jnp.einsum("bgrts,bsgd->btgrd" if grouped else "bhqk,bkhd->bqhd",
                    w, v, preferred_element_type=jnp.float32).astype(dt)
     return o.reshape(B, T, H, hd)
 
 
-def _plain_two_part(q, k, v, q_rope, k_rope, scale):
+def _plain_two_part(q, k, v, q_rope, k_rope, scale, window=None):
     """Scores that are the sum of two products (latent attention:
     ``q . k + q_rope . k_rope``, the rotary key of one head read by many
     query heads), any head sizes: every key head is repeated for the query
@@ -117,7 +136,7 @@ def _plain_two_part(q, k, v, q_rope, k_rope, scale):
     scores = lambda a, b: jnp.einsum("bqhd,bkhd->bhqk", a, wide(b),
                                      preferred_element_type=jnp.float32)
     s = (scores(q, k) + scores(q_rope, k_rope)) * scale
-    s = jnp.where(jnp.tril(jnp.ones(s.shape[-2:], bool)), s, _MASK)
+    s = jnp.where(_visible(s.shape[-1], window), s, _MASK)
     w = jax.nn.softmax(s, axis=-1).astype(dt)
     return jnp.einsum("bhqk,bkhd->bqhd", w, wide(v),
                       preferred_element_type=jnp.float32).astype(dt)
@@ -169,10 +188,39 @@ def _causal(s, queries_axis: int):
     return jnp.where(kpos <= qpos, s, _MASK)
 
 
-def _fwd_kernel(*refs, scale, two_part=False):
+def _inside(s, queries_axis: int, ahead, window: int):
+    """A tile of scores that the far edge of the band may cross, its
+    queries ``ahead`` positions after its keys: query position - key
+    position < ``window``, or the plain path's mask value."""
+    qpos = jax.lax.broadcasted_iota(jnp.int32, s.shape, queries_axis)
+    kpos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - queries_axis)
+    return jnp.where(qpos - kpos + ahead < window, s, _MASK)
+
+
+def _band(tile: int, window: int):
+    """(reach, whole): a block of queries sees the key blocks at block
+    distances 0 .. ``reach`` behind it; those at distances 1 .. ``whole`` - 1
+    lie wholly inside the band, those from ``whole`` on (and the diagonal one
+    itself where the window is narrower than a tile, ``whole`` = 0) hold a
+    pair ``window`` or more apart and are masked."""
+    return (window + tile - 2) // tile, window // tile
+
+
+def band_blocks(T: int, window=None):
+    """(visited, causal): the ``tile x tile`` blocks of scores the kernels
+    compute for one head of T positions under ``window``, and the blocks on
+    and below the diagonal."""
+    tile = _tile(T)
+    n = T // tile
+    reach = n if window is None else _band(tile, window)[0]
+    return sum(min(i, reach) + 1 for i in range(n)), n * (n + 1) // 2
+
+
+def _fwd_kernel(*refs, scale, two_part=False, window=None):
     """One tile of queries [tile, hd] against the keys [T, hd] of its head,
-    a tile of keys at a time up to the diagonal; scores are [tile
-    (queries), tile (keys)].  ``two_part``: a second pair of operands,
+    a tile of keys at a time up to the diagonal - from the start, or under a
+    ``window`` from the first block inside the band (`_band`); scores are
+    [tile (queries), tile (keys)].  ``two_part``: a second pair of operands,
     the queries' [tile, r] and the keys' [T, r] rotary parts, whose
     product is added to the scores."""
     q_ref, k_ref, v_ref = refs[:3]
@@ -184,7 +232,7 @@ def _fwd_kernel(*refs, scale, two_part=False):
         qr_ref, kr_ref = refs[3:5]
         qr = qr_ref[...]
 
-    def step(j, carry, diagonal=False):
+    def step(j, carry, diagonal=False, edge=False):
         m, l, acc = carry
         rows = pl.ds(pl.multiple_of(j * tile, tile), tile)
         k, v = k_ref[rows, :], v_ref[rows, :]
@@ -194,6 +242,11 @@ def _fwd_kernel(*refs, scale, two_part=False):
         s = s * scale
         if diagonal:
             s = _causal(s, queries_axis=0)
+        if edge:
+            # a row may lose every key of this block: its statistics then
+            # hold the mask value until a later block, which every row has
+            # (its own position, at the latest), rescales them to nothing
+            s = _inside(s, 0, (i - j) * tile, window)
         m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m - m_new)
         p = jnp.exp(s - m_new)
@@ -204,16 +257,26 @@ def _fwd_kernel(*refs, scale, two_part=False):
     carry = (jnp.full((tile, 1), -jnp.inf, jnp.float32),
              jnp.zeros((tile, 1), jnp.float32),
              jnp.zeros((tile, hd), jnp.float32))
-    carry = jax.lax.fori_loop(0, i, step, carry)
-    m, l, acc = step(i, carry, diagonal=True)
+    if window is None:
+        carry = jax.lax.fori_loop(0, i, step, carry)
+        m, l, acc = step(i, carry, diagonal=True)
+    else:
+        reach, whole = _band(tile, window)
+        first = jnp.maximum(i - reach, 0)
+        masked = jnp.clip(i - whole + 1, first, i)
+        carry = jax.lax.fori_loop(
+            first, masked, functools.partial(step, edge=True), carry)
+        carry = jax.lax.fori_loop(masked, i, step, carry)
+        m, l, acc = step(i, carry, diagonal=True, edge=whole == 0)
     o_ref[...] = (acc / l).astype(o_ref.dtype)
     # the rows' statistics leave as one lane-major row [1, tile]
     lse_ref[...] = jnp.broadcast_to(m + jnp.log(l), (tile, 128)).T[:1]
 
 
-def _bwd_kernel(*refs, scale, two_part=False):
+def _bwd_kernel(*refs, scale, two_part=False, window=None):
     """One tile of keys [tile, hd] against the queries [T, hd] of one head,
-    a tile of queries at a time from the diagonal down; scores are
+    a tile of queries at a time from the diagonal down - to the end, or under
+    a ``window`` to the last block inside the band; scores are
     transposed, [tile (keys), tile (queries)], so the rows' statistics
     broadcast along sublanes.  ``dq`` [T, hd] stays in fast memory across
     the head's key tiles.  ``two_part``: the rotary parts of the queries
@@ -235,7 +298,7 @@ def _bwd_kernel(*refs, scale, two_part=False):
         if two_part:
             dqr_ref[...] = jnp.zeros_like(dqr_ref)
 
-    def step(i, carry, diagonal=False):
+    def step(i, carry, diagonal=False, edge=False):
         dk, dv = carry[:2]
         rows = pl.ds(pl.multiple_of(i * tile, tile), tile)
         q, do = q_ref[rows, :], do_ref[rows, :]
@@ -246,6 +309,8 @@ def _bwd_kernel(*refs, scale, two_part=False):
         s = s * scale
         if diagonal:
             s = _causal(s, queries_axis=1)
+        if edge:
+            s = _inside(s, 1, (i - j) * tile, window)
         p = jnp.exp(s - lse_ref[i])
         dv = dv + _dot(p.astype(dt), do, _NN)
         dp = _dot(v, do, _NT)
@@ -260,8 +325,18 @@ def _bwd_kernel(*refs, scale, two_part=False):
     carry = (jnp.zeros(k.shape, jnp.float32),) * 2
     if two_part:
         carry += (jnp.zeros(kr.shape, jnp.float32),)
-    carry = step(j, carry, diagonal=True)
-    carry = jax.lax.fori_loop(j + 1, q_ref.shape[0] // tile, step, carry)
+    n = q_ref.shape[0] // tile
+    if window is None:
+        carry = step(j, carry, diagonal=True)
+        carry = jax.lax.fori_loop(j + 1, n, step, carry)
+    else:
+        reach, whole = _band(tile, window)
+        end = jnp.minimum(j + reach + 1, n)
+        masked = jnp.clip(j + whole, j + 1, end)
+        carry = step(j, carry, diagonal=True, edge=whole == 0)
+        carry = jax.lax.fori_loop(j + 1, masked, step, carry)
+        carry = jax.lax.fori_loop(
+            masked, end, functools.partial(step, edge=True), carry)
     dk_ref[...] = carry[0]
     dv_ref[...] = carry[1]
     if two_part:
@@ -331,7 +406,7 @@ def _params(interpret, blocks=(), tile=0):
         dimension_semantics=("parallel", "parallel", "arbitrary"), **limit))
 
 
-def _fused_output_lse(q, k, v, *rope, scale, interpret):
+def _fused_output_lse(q, k, v, *rope, scale, window, interpret):
     """o [B, T, H, hd] and the rows' log-sum-exp [B, H, T] in float32."""
     B, T, H, hd = q.shape
     tile = _tile(T)
@@ -347,7 +422,8 @@ def _fused_output_lse(q, k, v, *rope, scale, interpret):
         blocks += [(tile + T, rope[0].shape[-1], q.dtype)]
     q, k, v, *rope = map(_kernel_layout, (q, k, v) + rope)
     o, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, scale=scale, two_part=bool(rope)),
+        functools.partial(_fwd_kernel, scale=scale, two_part=bool(rope),
+                          window=window),
         grid=(B, H, T // tile), in_specs=specs,
         out_specs=[specs[0], stats],
         out_shape=[_struct(q, q.shape, q.dtype),
@@ -363,7 +439,7 @@ def _shared(H: int, kv):
     return lambda h: h // group
 
 
-def _fused_grads(q, k, v, o, lse, do, *rope, scale, interpret):
+def _fused_grads(q, k, v, o, lse, do, *rope, scale, window, interpret):
     """(dq, dk, dv[, dq_rope, dk_rope]) in the operands' shapes and dtype."""
     B, T, H, hd = q.shape
     tile = _tile(T)
@@ -390,7 +466,8 @@ def _fused_grads(q, k, v, o, lse, do, *rope, scale, interpret):
     per_head = [_struct(q, q.shape, jnp.float32)] * 3 + [
         _struct(q, a.shape, jnp.float32) for a in ropes[:1] * 2]
     grads = pl.pallas_call(
-        functools.partial(_bwd_kernel, scale=scale, two_part=bool(rope)),
+        functools.partial(_bwd_kernel, scale=scale, two_part=bool(rope),
+                          window=window),
         grid=(B, H, T // tile), in_specs=in_specs,
         out_specs=out_specs, out_shape=per_head,
         **_params(interpret, blocks, tile))(
@@ -408,16 +485,17 @@ def _fused_grads(q, k, v, o, lse, do, *rope, scale, interpret):
     return grads
 
 
-def _plain_output_lse(q, k, v, *rope, scale):
+def _plain_output_lse(q, k, v, *rope, scale, window):
     """The plain path keeps no statistics: its backward pass is its own
     transposition.  The zeros stand in for them, made from ``q`` so that
     they vary over the mesh axes the kernel's would."""
-    return (_plain(q, k, v, *rope, scale=scale),
+    return (_plain(q, k, v, *rope, scale=scale, window=window),
             0.0 * jnp.swapaxes(q[..., 0], 1, 2).astype(jnp.float32))
 
 
-def _plain_grads(q, k, v, o, lse, do, *rope, scale):
-    return jax.vjp(functools.partial(_plain, scale=scale), q, k, v, *rope)[1](do)
+def _plain_grads(q, k, v, o, lse, do, *rope, scale, window):
+    return jax.vjp(functools.partial(_plain, scale=scale, window=window),
+                   q, k, v, *rope)[1](do)
 
 
 def _lowered(interpret, fused, plain, *args):
@@ -430,21 +508,23 @@ def _lowered(interpret, fused, plain, *args):
         *args, tpu=lambda *a: fused(*a, interpret=False), default=plain)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 5))
-def _attention(q, k, v, interpret=False, rope=(), scale=None):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 5, 6))
+def _attention(q, k, v, interpret=False, rope=(), scale=None, window=None):
     """A shape the kernels take, on [B, T, H, hd] operands (``rope``: the
-    rotary (queries, keys) of latent attention, or nothing).  The platform
+    rotary (queries, keys) of latent attention, or nothing; ``window``: a
+    sliding window narrower than T, or None).  The platform
     is chosen inside each of the three rules, so no transformation ever
     differentiates through the choice (a differentiated switch would carry
     the plain branch's [B, H, T, T] residuals in both)."""
-    return _attention_fwd(q, k, v, interpret, rope, scale)[0]
+    return _attention_fwd(q, k, v, interpret, rope, scale, window)[0]
 
 
-def _attention_fwd(q, k, v, interpret, rope, scale):
+def _attention_fwd(q, k, v, interpret, rope, scale, window):
     scale = q.shape[-1] ** -0.5 if scale is None else scale
+    how = dict(scale=scale, window=window)
     o, lse = _lowered(interpret,
-                      functools.partial(_fused_output_lse, scale=scale),
-                      functools.partial(_plain_output_lse, scale=scale),
+                      functools.partial(_fused_output_lse, **how),
+                      functools.partial(_plain_output_lse, **how),
                       q, k, v, *rope)
     # named outside the platform switch: a checkpoint policy that lists
     # SAVED_NAMES keeps them, and this rule is not run again (module docstring)
@@ -452,14 +532,15 @@ def _attention_fwd(q, k, v, interpret, rope, scale):
     return o, (q, k, v, o, lse, *rope)
 
 
-def _attention_bwd(interpret, scale, res, do):
+def _attention_bwd(interpret, scale, window, res, do):
     scale = res[0].shape[-1] ** -0.5 if scale is None else scale
+    how = dict(scale=scale, window=window)
     # the backward kernel is attention's too: the benchmark's labels read
     # the scope, forward and backward alike (obs/scopes.py)
     with jax.named_scope(scopes.FED_ATTENTION):
         grads = _lowered(interpret,
-                         functools.partial(_fused_grads, scale=scale),
-                         functools.partial(_plain_grads, scale=scale),
+                         functools.partial(_fused_grads, **how),
+                         functools.partial(_plain_grads, **how),
                          *res[:5], do, *res[5:])
     return (*grads[:3], tuple(grads[3:]))
 
@@ -469,11 +550,16 @@ _attention.defvjp(_attention_fwd, _attention_bwd)
 
 # -- the choice ---------------------------------------------------------------
 
-def causal_attention(q, k, v, *, rope=(), scale=None):
+def causal_attention(q, k, v, *, window=None, rope=(), scale=None):
     """``softmax_causal(q k^T * scale) v``: q [B, T, H, hd], k and v
     [B, T, H_kv, hd] with ``H % H_kv == 0`` (query head h reads key/value
     head ``h // (H / H_kv)``) -> [B, T, H, hd] in q's dtype; ``scale`` is
     ``hd ** -0.5`` unless given.
+
+    ``window`` (a model's ``sliding_window``, an int >= 1) narrows what a
+    query sees to the last ``window`` positions, its own included: key j is
+    visible to query i iff ``0 <= i - j < window``.  A window that reaches
+    the whole sequence (``window >= T``) is no window.
 
     ``rope`` = (q_rope [B, T, H, r], k_rope [B, T, H_r, r]) adds a second
     product to the scores, ``(q k^T + q_rope k_rope^T) * scale`` — latent
@@ -485,14 +571,23 @@ def causal_attention(q, k, v, *, rope=(), scale=None):
     kernels run where the program is lowered for a TPU and the shape fits
     (module docstring); no option selects a path."""
     rope = tuple(rope)
+    if window is not None and window >= q.shape[1]:
+        window = None
     fused = _fits(q, k, v, *rope)
     obs.counter("ops_kernel_path_total", op="causal_attention",
                 path="pallas" if fused else "reference").inc()
     if fused:
-        return _attention(q, k, v, False, rope, scale)
+        if window is not None:
+            # what share of the causal half the band's calls run, by blocks
+            # and heads, at trace time as the path above
+            for blocks, n in zip(("visited", "causal"),
+                                 band_blocks(q.shape[1], window)):
+                obs.counter("attention_band_blocks_total", blocks=blocks).inc(
+                    n * q.shape[0] * q.shape[2])
+        return _attention(q, k, v, False, rope, scale, window)
     if jax.default_backend() == "tpu":      # the log line only, as group_norm
         log.warning("causal_attention: q %s %s, k %s%s does not fit the fused "
                     "kernels; using the plain path", tuple(q.shape),
                     q.dtype.name, tuple(k.shape),
                     "".join(f", rope {tuple(a.shape)}" for a in rope))
-    return _plain(q, k, v, *rope, scale=scale)
+    return _plain(q, k, v, *rope, scale=scale, window=window)

@@ -94,6 +94,135 @@ def test_fused_matches_plain(heads, hd, rope, scale, dtype):
         assert f <= 1.5 * p, (_distance(got, oracle), _distance(plain, oracle))
 
 
+# a sliding window against T = 384 in tiles of 128: narrower than a tile (the
+# diagonal tile holds the band's far edge too), exactly a tile, not a multiple
+# of the tile (the edge crosses two tiles of a row), spanning several tiles,
+# and as long as the sequence (no key is out of reach)
+WINDOWS = [5, 128, 200, 300, T]
+BAND_SHAPES = [pytest.param((4, 1), 64, None, None, id="gqa4-64"),
+               pytest.param((16, 1), 128, None, None, id="gqa16-128"),
+               pytest.param((4, 4), 128, (64, 1), MLA_SCALE, id="mla-128+64")]
+
+
+def _fused_w(window):
+    return lambda q, k, v, *rope, scale=None: attention._attention(
+        q, k, v, True, rope, scale, window)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("window", WINDOWS)
+@pytest.mark.parametrize("heads, hd, rope, scale", BAND_SHAPES)
+def test_fused_band_matches_plain(heads, hd, rope, scale, window, dtype):
+    """`test_fused_matches_plain` under a sliding window: output and every
+    gradient, the same tolerances - the plain path's mask (``0 <= i - j <
+    window``) is the spec, at a group of 4, a group of 16 and with a rotary
+    part."""
+    H, n_kv = heads
+    operands = _operands((1, T, H, hd), n_kv, dtype, rope=rope)
+    got = _out_and_grads(_fused_w(window), *operands, scale=scale)
+    plain = _out_and_grads(attention._plain, *operands, scale=scale, window=window)
+    assert len(got) == (6 if rope else 4)
+    if dtype == jnp.float32:
+        assert max(_rel(got, plain)) <= 1e-5, _rel(got, plain)
+        return
+    oracle = _out_and_grads(attention._plain, *(
+        a.astype(jnp.float32) for a in operands), scale=scale, window=window)
+    for f, p in zip(_distance(got, oracle), _distance(plain, oracle)):
+        assert f <= 1.5 * p, (_distance(got, oracle), _distance(plain, oracle))
+
+
+def test_the_plain_window_is_the_last_window_positions():
+    """The numerical spec of the mask: key j is visible to query i iff
+    0 <= i - j < window - the key at distance ``window`` is out."""
+    q, k, v, _ = _operands((1, 24, 2, 16), 2, jnp.float32)
+    got = causal_attention(q, k, v, window=7)
+    i, j = np.arange(24)[:, None], np.arange(24)[None, :]
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * 16 ** -0.5
+    s = jnp.where((i - j >= 0) & (i - j < 7), s, -jnp.inf)
+    want = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v)
+    np.testing.assert_allclose(got, want, atol=1e-5)
+    # position 7 does not see key 0; with window 8 it does
+    bumped = causal_attention(q, k.at[:, 0].add(1.0), v, window=7)
+    np.testing.assert_array_equal(got[:, 7:], bumped[:, 7:])
+    assert not np.array_equal(got[:, 6], bumped[:, 6])
+    assert not np.array_equal(causal_attention(q, k, v, window=8)[:, 7],
+                              causal_attention(q, k.at[:, 0].add(1.0), v, window=8)[:, 7])
+
+
+@pytest.mark.parametrize("T_, window, visited, causal", [
+    (8192, 4096, 108, 136),        # cmdaplus.lora4of256long: tiles of 512
+    (384, 5, 5, 6), (384, 128, 5, 6), (384, 129, 5, 6), (384, 130, 6, 6),
+    (384, 300, 6, 6), (1024, 512, 3, 3), (2048, 512, 7, 10), (2048, 513, 7, 10),
+    (2048, 514, 9, 10), (384, None, 6, 6)])
+def test_band_blocks_counts_the_tiles_inside_the_band(T_, window, visited, causal):
+    assert attention.band_blocks(T_, window) == (visited, causal)
+    if window is None:
+        return
+    tile = attention._tile(T_)
+    i, j = np.arange(T_)[:, None], np.arange(T_)[None, :]
+    mask = ((i - j >= 0) & (i - j < window)).reshape(
+        T_ // tile, tile, T_ // tile, tile).any(axis=(1, 3))
+    assert int(mask.sum()) == visited          # exactly the tiles that hold a pair
+
+
+def test_blocks_outside_the_band_are_not_visited():
+    """Keys and values of a block that lies wholly outside a query block's
+    band are never read: made NaN, they reach no output of the rows whose
+    band excludes them (a kernel that computed and masked them would turn
+    ``0 x NaN`` into NaN, as the plain path does), and the windowed call
+    counts the tiles it visits."""
+    q, k, v, w = _operands((1, T, 2, 128), 1, jnp.float32)
+    window = 100                                  # reach: one tile behind
+    poison = lambda a: a.at[:, :128].set(jnp.nan)  # tile 0
+    got = _out_and_grads(_fused_w(window), q, poison(k), poison(v), w)
+    clean = _out_and_grads(_fused_w(window), q, k, v, w)
+    rows = slice(256, T)                          # query tile 2 sees tiles 1, 2
+    np.testing.assert_array_equal(got[0][:, rows], clean[0][:, rows])
+    np.testing.assert_array_equal(got[1][:, rows], clean[1][:, rows])      # dq
+    assert np.isnan(got[0][:, :128]).all()        # tile 0's own rows do read it
+    plain = np.asarray(attention._plain(q, poison(k), poison(v), window=window))
+    assert np.isnan(plain[:, rows]).all()
+    count = lambda blocks: obs.counter("attention_band_blocks_total",
+                                       blocks=blocks).value
+    before = count("visited"), count("causal"), _paths()["pallas"]
+    jax.make_jaxpr(lambda *a: causal_attention(*a, window=window))(q, k, v)
+    assert count("visited") - before[0] == 5 * 2      # tiles x batch x heads
+    assert count("causal") - before[1] == 6 * 2
+    assert _paths()["pallas"] == before[2] + 1        # the path counts this shape too
+
+
+def test_a_window_that_reaches_the_whole_sequence_is_no_window():
+    """``window >= T`` traces the program ``window=None`` traces, kernels
+    and all, and counts no band."""
+    q, k, v, _ = _operands((1, 256, 4, 64), 2, jnp.bfloat16)
+    count = lambda: obs.counter("attention_band_blocks_total", blocks="causal").value
+    before = count()
+    text = lambda **kw: str(jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(
+        causal_attention(*a, **kw).astype(jnp.float32)), (0, 1, 2)))(q, k, v))
+    assert text(window=256) == text(window=4096) == text()
+    assert text(window=255) != text()
+    assert count() == before + 1 * 4 * 1          # the one windowed call, T = one tile
+
+
+def test_band_under_vmap_and_checkpoint(clients):
+    """The windowed kernels as the engine runs them: a vmap over a chunk's
+    clients of a checkpointed layer."""
+    (q, k, v, w), _, _ = clients                  # T = 256: one tile, window < tile
+    loss = lambda fn: lambda q, k, v, w: jnp.sum(jnp.sin(fn(q, k, v)) * w)
+    grad = lambda fn: jax.jit(jax.vmap(jax.grad(loss(fn), (0, 1, 2))))
+    plain = lambda q, k, v: attention._plain(q, k, v, window=77)
+    _close(grad(jax.checkpoint(_fused_w(77)))(q, k, v, w), grad(plain)(q, k, v, w))
+
+
+def test_band_over_several_tiles_under_vmap_and_checkpoint():
+    q, k, v, w = _operands((2, 1, T, 2, 128), 1, jnp.float32)     # three tiles of 128
+    loss = lambda fn: lambda q, k, v, w: jnp.sum(jnp.sin(fn(q, k, v)) * w)
+    grad = lambda fn: jax.jit(jax.vmap(jax.grad(loss(fn), (0, 1, 2))))
+    plain = lambda q, k, v: attention._plain(q, k, v, window=200)
+    _close(grad(jax.checkpoint(_fused_w(200)))(q, k, v, w), grad(plain)(q, k, v, w))
+
+
 def test_the_two_part_plain_path_is_attention_over_concatenated_keys():
     """The numerical spec of the two-operand form: the scores of q | q_rope
     against k | k_rope (the rotary key repeated for every head), any three

@@ -245,6 +245,39 @@ def test_latent_attention_compiles(topo, dtype):
     assert not re.search(rf"\[{B},{T},{H},{r}\].*broadcast", text)
 
 
+# a local step of cmdaplus.lora4of256long: (B, T, H, H_kv, head size, window) -
+# 16 query heads over each key-value head, 8,192 positions under a window of
+# 4,096 (the sliding layers) and under none (the full layer)
+BAND_ATTENTION = (1, 8192, 128, 8, 128, 4096)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32-highest"])
+@pytest.mark.parametrize("window", [BAND_ATTENTION[-1], None],
+                         ids=["window4096", "full"])
+def test_band_attention_compiles(topo, window, dtype):
+    """`causal_attention(window=...)` at Command A+'s shapes, as the cell's round
+    and its float32 twin run it: both kernels with their whole-sequence blocks
+    of T = 8,192 inside the fast memory the call asks for, a group of 16 through
+    the block index map, and no float32 `[.., T, T]` buffer (34 GB at these
+    shapes: the plain path could not run at all)."""
+    from fedml_tpu.ops.attention import causal_attention
+    B, T, H, n_kv, hd, _ = BAND_ATTENTION
+
+    def grads(q, k, v):
+        return jax.grad(lambda *a: jnp.sum(causal_attention(
+            *a, window=window).astype(jnp.float32) ** 2), (0, 1, 2))(q, k, v)
+
+    with jax.default_matmul_precision(
+            "highest" if dtype == jnp.float32 else "default"):
+        c = _compile(topo, grads, ((B, T, H, hd), dtype),
+                     ((B, T, n_kv, hd), dtype), ((B, T, n_kv, hd), dtype))
+    _assert_kernels(c, 2)
+    text = c.as_text()
+    assert "vmem_limit_bytes" in text or "scoped_memory" in text
+    assert not re.search(rf"f32\[[\d,]*{T},{T}\]", text)
+
+
 # -- the documented size limit --------------------------------------------
 
 @pytest.mark.slow
@@ -717,3 +750,80 @@ def test_deepseek_v2_adapter_round_and_its_float32_twin_fit_one_chip(topo):
     assert _attention_kernels(text) == {"forward": 5, "recompute": 5, "backward": 5}
     assert _rerun_work(text) == {"convolution": 89, "custom-call": 13}
     assert sum(1 for _ in hlo_instructions(text)) == 17536
+
+
+def _kernels_by_label(text: str) -> dict:
+    """{(label, phase): how many} of the Pallas / grouped-product kernels in a
+    round's text whose label is one of the two kinds of attention layer."""
+    from fedml_tpu.obs import programs
+    from parallel_case import hlo_instructions
+    smap, phases = programs.maps_of_hlo_text(text)
+    found = {}
+    for name, _, opcode, rest in hlo_instructions(text):
+        if (opcode == "custom-call" and "tpu_custom_call" in rest
+                and smap[name].endswith("_attention")):
+            key = (smap[name], phases[name])
+            found[key] = found.get(key, 0) + 1
+    return found
+
+
+@pytest.mark.slow
+def test_command_a_plus_adapter_round_and_its_float32_twin_fit_one_chip(topo):
+    """`cmdaplus.lora4of256long`'s resident round (a 6.2 GB frozen bfloat16 base -
+    one period of the layer pattern, 8 of 128 experts a layer, an eighth of the
+    tied embedding - under 3,276,800 adapter parameters, chunk 1, 8,192 tokens
+    a step) and the float32 twin that the reference check runs, compiled as the
+    engine dispatches them, fit one chip: the test that sizes the cut and
+    admits the sequence length (fedbench/configs/command_a_plus.json, "cut").
+    Both take the fused attention for both kinds of layer - three kernels under
+    `window_attention` and one under `full_attention` a pass, no buffer as large
+    as a step's [128, T, T] scores - and XLA:TPU's grouped product for the held
+    experts; the base is read as it is stored, and what the round folds is the
+    adapters.  The bfloat16 round's layers keep the kernel's (o, lse) and W_o's
+    output: no attention kernel runs again; the float32 twin keeps a layer's
+    input alone and re-runs all four."""
+    from fedml_tpu.parallel.engine import flatten_carry_f32
+    config, traffic = _bench_files("command_a_plus", "lora4of256long")
+    B, T, H, _, hd, _ = BAND_ATTENTION
+    assert traffic["dataset"]["args"]["seq_len"] == T
+    engine, variables, compiled = _dispatched(topo, config, traffic)
+    needs = _needs_with_the_base_aliased(compiled, config)
+    # 15.34e9 (the rehearsal, PR 41: arguments 6.29e9 of which the base 6.26e9
+    # comes back in the buffers it came in, temporaries 9.00e9 - 3.22e9 of them
+    # the compiler's relayout copies of the 4 x 8 held expert matrices -, code
+    # 0.05e9) + 0.2e9; the chip gives 16.91e9
+    assert needs < 15.55e9, compiled.memory_analysis()
+    trained = engine.trainer.trained_variables(variables)
+    n_trained = sum(int(np.prod(a.shape)) for a in jax.tree.leaves(trained))
+    assert n_trained == config["widths"]["parameters_trained"]
+    assert flatten_carry_f32(engine._zero_sums(variables)[0])[0].shape == (n_trained,)
+    frozen = engine.trainer.split_frozen(variables["params"])[1]
+    text = compiled.as_text()
+    shapes = {a.shape for a in jax.tree.leaves(frozen) if len(a.shape) == 3}
+    assert shapes == {(8, 4096, 4096)}
+    assert not re.search(r"f32\[8,4096,4096\]", text)
+    assert not re.search(r"bf16\[\d+,8,4096,4096\]", text)
+    assert text.count("ragged-dot") > 0            # XLA:TPU's grouped product
+
+    def no_scores(text):
+        from parallel_case import hlo_instructions
+        large = [(name, result) for name, result, _, _ in hlo_instructions(text)
+                 if (m := re.match(r"f32\[([\d,]+)\]", result))
+                 and np.prod([int(d) for d in m.group(1).split(",")]) >= B * H * T * T]
+        assert not large, large
+
+    no_scores(text)
+    assert _kernels_by_label(text) == {
+        ("window_attention", "forward"): 3, ("window_attention", "backward"): 3,
+        ("full_attention", "forward"): 1, ("full_attention", "backward"): 1}
+    with jax.default_matmul_precision("highest"):
+        _, _, twin = _dispatched(topo, config, dict(traffic, population=4, cohort=4),
+                                 train_dtype="float32", local_dtype=None)
+    # 16.29e9 of 16.91e9 (the rehearsal, PR 41): what admits T = 8,192
+    assert _needs_with_the_base_aliased(twin, config) < 16.5e9, twin.memory_analysis()
+    text = twin.as_text()
+    no_scores(text)
+    assert _kernels_by_label(text) == {
+        ("window_attention", "forward"): 3, ("window_attention", "recompute"): 3,
+        ("window_attention", "backward"): 3, ("full_attention", "forward"): 1,
+        ("full_attention", "recompute"): 1, ("full_attention", "backward"): 1}
