@@ -43,6 +43,12 @@ models/looped_lm.py is imported from there, not copied):
   hands it ``window``, and in a program lowered for a TPU the fused kernels
   then visit only the key blocks inside the band; a full layer hands it
   nothing and runs the kernels every other model runs.
+* **a sliding layer's rotary is ``ops/rotary.py::rotate_half``**: with heads
+  as wide as the lanes it is, in a program lowered for a TPU, one elementwise
+  kernel pass over q and over k where they lie (`apply_rotary`, which the
+  other models call, cost 11-18 ms a pass at [8192, 128, 128] where the
+  kernel takes 0.8: PERF.md section 5, PR 42); the result and the rounding
+  are `apply_rotary`'s.
 * **the expert layer is lfm2_moe's dropless grouped product**, told which
   experts it holds (``held`` = (first, past-last): the router scores all
   ``n_experts``, slots of absent experts sort behind the held ones' and add
@@ -81,9 +87,10 @@ from jax.ad_checkpoint import checkpoint_name
 from fedml_tpu import obs
 from fedml_tpu.models.lfm2_moe import (_adapted, _Groups, _Leaves,
                                        expert_product, gated_mlp)
-from fedml_tpu.models.looped_lm import _dot, apply_rotary, rotary_tables
+from fedml_tpu.models.looped_lm import _dot, rotary_tables
 from fedml_tpu.obs import scopes
 from fedml_tpu.ops.attention import SAVED_NAMES, causal_attention
+from fedml_tpu.ops.rotary import rotate_half
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 _SCOPE_OF = {SLIDING: scopes.FED_WINDOW_ATTENTION,
@@ -114,7 +121,7 @@ def attention(a, lp, ad, scale, cos, sin, n_heads: int, n_kv_heads: int,
     heads = lambda name, n: _adapted(a, lp, ad, name, scale).reshape(B, T, n, -1)
     q, k, v = heads("wq", n_heads), heads("wk", n_kv_heads), heads("wv", n_kv_heads)
     if window is not None:
-        q, k = apply_rotary(q, cos, sin), apply_rotary(k, cos, sin)
+        q, k = rotate_half(q, cos, sin), rotate_half(k, cos, sin)
     o = causal_attention(q, k, v, window=window)
     return checkpoint_name(_adapted(o.reshape(B, T, -1), lp, ad, "wo", scale),
                            "attn_out")

@@ -93,6 +93,7 @@ from jax.ad_checkpoint import checkpoint_name
 from fedml_tpu import obs
 from fedml_tpu.obs import scopes
 from fedml_tpu.ops.attention import SAVED_NAMES, causal_attention
+from fedml_tpu.ops.rotary import apply_rotary
 
 _LAYER_NORMS = ("attn_norm", "attn_post_norm", "mlp_norm", "mlp_post_norm")
 
@@ -121,14 +122,6 @@ def rotary_tables(seq_len: int, head_dim: int, theta: float):
     ang = jnp.arange(seq_len, dtype=jnp.float32)[:, None] * inv[None, :]
     ang = jnp.concatenate([ang, ang], axis=-1)
     return jnp.cos(ang), jnp.sin(ang)
-
-
-def apply_rotary(x, cos, sin):
-    """x [B, T, H, hd] -> rotated, same dtype; the rotation in float32."""
-    x32 = x.astype(jnp.float32)
-    half = x.shape[-1] // 2
-    rot = jnp.concatenate([-x32[..., half:], x32[..., :half]], axis=-1)
-    return (x32 * cos[:, None, :] + rot * sin[:, None, :]).astype(x.dtype)
 
 
 def _dot(x, w):

@@ -16,6 +16,10 @@ numerical spec and its fallback.
   forward and backward in which the ``[B, H, T, T]`` float32 scores never
   reach HBM, chosen where the program is lowered for a TPU and the shape
   fits; no option selects it.
+* ``rotary`` — ``apply_rotary``, the language models' rotate-half rotary
+  embedding, and ``rotate_half``, the same result as one elementwise kernel
+  pass for heads as wide as the lanes (models/cohere2_moe.py), chosen as the
+  attention kernels are.
 
 Each op counts the path it took at trace time in
 ``ops_kernel_path_total{op, path}``.
@@ -25,6 +29,8 @@ from fedml_tpu.ops.aggregate import (flatten_stacked_tree,
                                      unflatten_to_tree,
                                      weighted_mean_pallas)
 from fedml_tpu.ops.attention import causal_attention
+from fedml_tpu.ops.rotary import rotate_half
 
 __all__ = ["weighted_mean_pallas", "robust_weighted_mean_pallas",
-           "flatten_stacked_tree", "unflatten_to_tree", "causal_attention"]
+           "flatten_stacked_tree", "unflatten_to_tree", "causal_attention",
+           "rotate_half"]

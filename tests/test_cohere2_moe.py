@@ -313,14 +313,21 @@ def test_round_has_no_branch_on_the_model_and_folds_the_adapters_alone():
         assert "cohere" not in text and "command_a" not in text
 
 
+def _lane_wide():
+    """(model, its parameters' shapes, tokens) at heads as wide as the lanes
+    and a sequence of two windows: shapes the TPU kernels take."""
+    model = create_model("cohere2_moe", 128, **{
+        **SMALL, "head_dim": 128, "n_heads": 4, "sliding_window": 128})
+    x = jnp.zeros((1, 256), jnp.int32)
+    return model, jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), x))["params"], x
+
+
 def test_the_round_programs_scopes_tell_the_two_kinds_of_layer_apart():
     """Lowered for a TPU, the model's forward and backward kernels carry the
     scope of their kind of layer - the backward rule's own ``fed_attention``
     yields to it - and `label_of` reads both labels."""
-    model = create_model("cohere2_moe", 128, **{
-        **SMALL, "head_dim": 128, "n_heads": 4, "sliding_window": 128})
-    x = jnp.zeros((1, 256), jnp.int32)
-    params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), x))["params"]
+    model, params, x = _lane_wide()
     params = jax.tree.map(lambda a: jnp.zeros(a.shape, jnp.bfloat16), params)
 
     def loss(lora):
@@ -331,8 +338,70 @@ def test_the_round_programs_scopes_tell_the_two_kinds_of_layer_apart():
         lowering_platforms=("tpu",)).as_text(debug_info=True)
     import re
     names = [m.group(1) for m in re.finditer(r'loc\("([^"]*pallas_call[^"]*)"', text)]
-    labels = [scopes.label_of(n) for n in names]
+    rotary = [n for n in names if "rotate_half" in n]
+    labels = [scopes.label_of(n) for n in names if n not in rotary]
     # a location is printed once, whatever the number of layers that share it:
     # a forward and a backward kernel of each kind
     assert sorted(labels) == ["full_attention"] * 2 + ["window_attention"] * 2
     assert any("fed_attention" in n for n in names)       # the rule's own scope is there
+    # the rotary's passes are the sliding layers' alone, and one of each pass:
+    # the layer's checkpoint keeps the attention kernel's output and re-runs
+    # the rotary in front of it
+    assert {scopes.label_of(n) for n in rotary} == {"window_attention"}
+    assert {scopes.phase_of(n) for n in rotary} == {
+        scopes.FORWARD, scopes.RECOMPUTE, scopes.BACKWARD}
+
+
+def test_a_sliding_layer_holds_the_rotary_op_and_a_full_one_does_not():
+    """`attention` at heads as wide as the lanes: under a window q and k go
+    through `ops.rotary.rotate_half` (two calls, counted, the kernel in the
+    jaxpr's TPU branch), under none the jaxpr does not know the op."""
+    from fedml_tpu import obs
+    _, params, _ = _lane_wide()
+    a = jax.ShapeDtypeStruct((1, 256, 64), jnp.bfloat16)
+    tables = rotary_tables(256, 128, 5e4)
+    fused = obs.counter("ops_kernel_path_total", op="rotate_half", path="pallas")
+
+    def jaxpr(window):
+        return str(jax.make_jaxpr(lambda a, lp, ad: cohere2_moe.attention(
+            a, lp, ad, 2.0, *tables, 4, 2, window))(
+                a, params["layer_0"], params["lora"]["layer_0"]))
+
+    before = fused.value
+    assert jaxpr(128).count("name=rotate_half") == 2
+    assert fused.value == before + 2
+    assert "rotate_half" not in jaxpr(None)
+    assert fused.value == before + 2
+
+
+def _parent_rotary(x, cos, sin):
+    """`looped_lm.apply_rotary` before ISSUE 42, written out."""
+    x32 = x.astype(jnp.float32)
+    half = x.shape[-1] // 2
+    rot = jnp.concatenate([-x32[..., half:], x32[..., :half]], axis=-1)
+    return (x32 * cos[:, None, :] + rot * sin[:, None, :]).astype(x.dtype)
+
+
+@pytest.mark.parametrize("name, kwargs", [
+    ("looped_lm", {}), ("lfm2_moe", {"lora_rank": 2}), ("deepseek_v2", {})])
+def test_the_other_models_trace_the_jaxpr_they_traced_before(monkeypatch, name,
+                                                             kwargs):
+    """The three older language models call `apply_rotary`, which moved to
+    `ops/rotary.py` as the op's plain body: the jaxpr of their loss and
+    gradients is what it is with the parent's function in its place, and holds
+    no rotary kernel."""
+    import importlib
+    module = importlib.import_module("fedml_tpu.models." + name)
+    model = create_model(name, output_dim=50, **kwargs)
+    x = jax.random.randint(jax.random.PRNGKey(1), (2, 12), 0, 50)
+    params = model.init(jax.random.PRNGKey(0), x)["params"]
+
+    def trace():
+        def loss(params):
+            return jnp.mean(jnp.square(model.apply({"params": params}, x)))
+        return str(jax.make_jaxpr(jax.value_and_grad(loss))(params))
+
+    got = trace()
+    monkeypatch.setattr(module, "apply_rotary", _parent_rotary)
+    assert got == trace()
+    assert "rotate_half" not in got and "concatenate" in got

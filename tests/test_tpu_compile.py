@@ -50,7 +50,14 @@ kernels, the same arguments / temporaries / code bytes, 0 lines differ; the
 float32 twin of `lora4of256t4096` is the parent's too (17,536 instructions,
 15,955,036,672 B on both trees), and its bfloat16 round went from 14.78e9 B
 with 5 attention kernels and 89 matrix products in the re-run to 15.09e9
-with none and 79 (builder, CPU compile rehearsal, PR 40).
+with none and 79 (builder, CPU compile rehearsal, PR 40).  PR 42 (the
+rotary of `cohere2_moe`'s sliding layers as a kernel, `ops/rotary.py`;
+`apply_rotary` moved there from `looped_lm` as its plain body) compiled
+`silo4of256t1024`, `lora4of256t2048` and `lora4of256t4096` on the parent
+`2b9b05b` and on its tree: 11,113 / 14,734 / 22,910 lines (9,359 / 12,632 /
+19,196 instructions) without op metadata, the source table and the kernels'
+serialized bodies, 14,865,773,568 / 15,083,182,592 / 15,091,395,072 B on
+both trees, 0 lines differ (builder, CPU compile rehearsal, PR 42).
 
 A compile that passes is not a chip run: nothing executes, so these
 tests say nothing about results or times.  Skipped where the topology
@@ -276,6 +283,34 @@ def test_band_attention_compiles(topo, window, dtype):
     text = c.as_text()
     assert "vmem_limit_bytes" in text or "scoped_memory" in text
     assert not re.search(rf"f32\[[\d,]*{T},{T}\]", text)
+
+
+# the rotary of that step's sliding layers: q and k of (B, T, ., head size)
+ROTARY = [(1, 8192, 128, 128), (1, 8192, 8, 128)]
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("shape", ROTARY, ids=str)
+def test_rotate_half_compiles(topo, shape, dtype):
+    """`ops.rotary.rotate_half` and its gradient at Command A+'s q and k, as
+    the cell's round and its float32 twin run them: Mosaic accepts the blocks
+    and the lane roll, both passes are kernels, and a bfloat16 operand leaves
+    no float32 buffer of its size (the plain path's intermediates: 537 MB for
+    q)."""
+    from fedml_tpu.models.looped_lm import rotary_tables
+    from fedml_tpu.ops.rotary import rotate_half
+    B, T, H, hd = shape
+
+    def out_and_grad(x, dy):
+        cos, sin = rotary_tables(T, hd, 5e4)
+        y, transpose = jax.vjp(lambda x: rotate_half(x, cos, sin), x)
+        return y, transpose(dy)[0]
+
+    c = _compile(topo, out_and_grad, (shape, dtype), (shape, dtype))
+    _assert_kernels(c, 2)
+    if dtype == jnp.bfloat16:
+        assert not re.search(rf"f32\[{B},{T},({H},{hd}|{H * hd})\]", c.as_text())
 
 
 # -- the documented size limit --------------------------------------------
@@ -777,22 +812,25 @@ def test_command_a_plus_adapter_round_and_its_float32_twin_fit_one_chip(topo):
     admits the sequence length (fedbench/configs/command_a_plus.json, "cut").
     Both take the fused attention for both kinds of layer - three kernels under
     `window_attention` and one under `full_attention` a pass, no buffer as large
-    as a step's [128, T, T] scores - and XLA:TPU's grouped product for the held
-    experts; the base is read as it is stored, and what the round folds is the
-    adapters.  The bfloat16 round's layers keep the kernel's (o, lse) and W_o's
-    output: no attention kernel runs again; the float32 twin keeps a layer's
-    input alone and re-runs all four."""
+    as a step's [128, T, T] scores -, the rotary kernel for q and for k of the
+    three sliding layers (`ops/rotary.py`: six more under `window_attention` a
+    pass) and XLA:TPU's grouped product for the held experts; the base is read
+    as it is stored, and what the round folds is the adapters.  The bfloat16
+    round's layers keep the kernel's (o, lse) and W_o's output: no attention
+    kernel runs again, the rotary in front of it does; the float32 twin keeps a
+    layer's input alone and re-runs all ten."""
     from fedml_tpu.parallel.engine import flatten_carry_f32
     config, traffic = _bench_files("command_a_plus", "lora4of256long")
     B, T, H, _, hd, _ = BAND_ATTENTION
     assert traffic["dataset"]["args"]["seq_len"] == T
     engine, variables, compiled = _dispatched(topo, config, traffic)
     needs = _needs_with_the_base_aliased(compiled, config)
-    # 15.34e9 (the rehearsal, PR 41: arguments 6.29e9 of which the base 6.26e9
-    # comes back in the buffers it came in, temporaries 9.00e9 - 3.22e9 of them
+    # 15.14e9 (the rehearsal, PR 42: arguments 6.29e9 of which the base 6.26e9
+    # comes back in the buffers it came in, temporaries 8.80e9 - 3.22e9 of them
     # the compiler's relayout copies of the 4 x 8 held expert matrices -, code
-    # 0.05e9) + 0.2e9; the chip gives 16.91e9
-    assert needs < 15.55e9, compiled.memory_analysis()
+    # 0.04e9; 15.34e9 with the rotary as plain XLA ops, PR 41) + 0.2e9; the
+    # chip gives 16.91e9
+    assert needs < 15.35e9, compiled.memory_analysis()
     trained = engine.trainer.trained_variables(variables)
     n_trained = sum(int(np.prod(a.shape)) for a in jax.tree.leaves(trained))
     assert n_trained == config["widths"]["parameters_trained"]
@@ -814,16 +852,18 @@ def test_command_a_plus_adapter_round_and_its_float32_twin_fit_one_chip(topo):
 
     no_scores(text)
     assert _kernels_by_label(text) == {
-        ("window_attention", "forward"): 3, ("window_attention", "backward"): 3,
+        ("window_attention", "forward"): 9, ("window_attention", "recompute"): 6,
+        ("window_attention", "backward"): 9,
         ("full_attention", "forward"): 1, ("full_attention", "backward"): 1}
     with jax.default_matmul_precision("highest"):
         _, _, twin = _dispatched(topo, config, dict(traffic, population=4, cohort=4),
                                  train_dtype="float32", local_dtype=None)
-    # 16.29e9 of 16.91e9 (the rehearsal, PR 41): what admits T = 8,192
+    # 16.28e9 of 16.91e9 (the rehearsal, PR 42; 16.29e9 at PR 41): what admits
+    # T = 8,192
     assert _needs_with_the_base_aliased(twin, config) < 16.5e9, twin.memory_analysis()
     text = twin.as_text()
     no_scores(text)
     assert _kernels_by_label(text) == {
-        ("window_attention", "forward"): 3, ("window_attention", "recompute"): 3,
-        ("window_attention", "backward"): 3, ("full_attention", "forward"): 1,
+        ("window_attention", "forward"): 9, ("window_attention", "recompute"): 9,
+        ("window_attention", "backward"): 9, ("full_attention", "forward"): 1,
         ("full_attention", "recompute"): 1, ("full_attention", "backward"): 1}
