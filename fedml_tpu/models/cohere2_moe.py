@@ -51,8 +51,10 @@ models/looped_lm.py is imported from there, not copied):
   are `apply_rotary`'s.
 * **the expert layer is lfm2_moe's dropless grouped product**, told which
   experts it holds (``held`` = (first, past-last): the router scores all
-  ``n_experts``, slots of absent experts sort behind the held ones' and add
-  nothing).  The shared experts are stored side by side — ``s1``, ``s3``
+  ``n_experts``, slots of absent experts sort behind the held ones' and are
+  not touched — the product gathers, multiplies and combines the held
+  experts' rows alone, in row blocks up to the last held slot).  The shared
+  experts are stored side by side — ``s1``, ``s3``
   [d, n_shared x width], ``s2`` [n_shared x width, d] — so their sum is ONE
   gated MLP, and the average is that times ``1 / n_shared``.
 * every layer is a ``jax.checkpoint``; the layers are unrolled.  **Where the
@@ -72,7 +74,9 @@ models/looped_lm.py is imported from there, not copied):
   projections, rotary, core and output projection; ``fed_moe_router``,
   ``fed_moe_experts``, ``fed_shared_expert`` and ``fed_lm_head`` the rest.
 * the router's decisions are counted as in lfm2_moe: tokens routed to every
-  (layer, expert) of a step, held or not (``counters/moe_expert_tokens``).
+  (layer, expert) of a step, held or not (``counters/moe_expert_tokens``),
+  and the rows the grouped products ran over beside the slots routed
+  (``counters/moe_slot_rows``).
 """
 from __future__ import annotations
 
@@ -86,7 +90,8 @@ from jax.ad_checkpoint import checkpoint_name
 
 from fedml_tpu import obs
 from fedml_tpu.models.lfm2_moe import (_adapted, _Groups, _Leaves,
-                                       expert_product, gated_mlp)
+                                       counter_shapes, float_counters,
+                                       gated_mlp, held_share, sow_counters)
 from fedml_tpu.models.looped_lm import _dot, rotary_tables
 from fedml_tpu.obs import scopes
 from fedml_tpu.ops.attention import SAVED_NAMES, causal_attention
@@ -145,20 +150,17 @@ def route_sigmoid(f, router, k: int):
 
 
 def moe_layer(a, lp, k: int, n_shared: int, held):
-    """(m, tokens routed to every expert [n_experts]) of one expert layer
-    for a [..., d]; ``lp``: router, the experts HELD (``held`` = (first,
-    past-last)) and the shared experts side by side, averaged."""
-    n_experts = lp["router"].shape[-1]
+    """(m, the layer's counters: `lfm2_moe.held_share`) of one expert
+    layer for a [..., d]; ``lp``: router, the experts HELD (``held`` =
+    (first, past-last)) and the shared experts side by side, averaged."""
     first, last = held
     rows = a.reshape((-1, a.shape[-1]))
     with jax.named_scope(scopes.FED_MOE_ROUTER):
         sel, gate = route_sigmoid(rows, lp["router"], k)
-        counts = jnp.bincount(sel.reshape(-1), length=n_experts)
-    m = expert_product(first, last - first)(
-        rows, sel, gate, lp["w1"], lp["w3"], lp["w2"])
+    m, counts = held_share(rows, sel, gate, lp, first, last - first)
     with jax.named_scope(scopes.FED_SHARED_EXPERT):
         m = m + gated_mlp(rows, lp["s1"], lp["s3"], lp["s2"]) / n_shared
-    return m.reshape(a.shape), counts.astype(jnp.float32)
+    return m.reshape(a.shape), float_counters(counts)
 
 
 def block(h, lp, ad, cos, sin, *, kind: str, window: int, n_heads: int,
@@ -212,8 +214,7 @@ class Cohere2MoeLM(nn.Module):
 
     @property
     def counters(self) -> dict:
-        return {scopes.MOE_EXPERT_TOKENS: (len(self.held_layers),
-                                           self.n_experts)}
+        return counter_shapes(len(self.held_layers), self.n_experts)
 
     def _specs(self):
         """(base, adapter) leaf specs of a layer: every layer has the same."""
@@ -272,11 +273,7 @@ class Cohere2MoeLM(nn.Module):
                                    policy=_KEEP if attention_kept else None)
             h, c = layer(h, base[i], lora[f"layer_{i}"], cos, sin)
             counts.append(c)
-        if (not self.is_initializing()
-                and self.is_mutable_collection(scopes.COUNTERS)):
-            self.sow(scopes.COUNTERS, scopes.MOE_EXPERT_TOKENS,
-                     jnp.stack(counts), init_fn=lambda: 0.0,
-                     reduce_fn=lambda a, b: a + b)
+        sow_counters(self, counts)
         with jax.named_scope(scopes.FED_LM_HEAD):
             s = layer_norm(h, out_norm, self.norm_eps)
             logits = jnp.einsum("...d,vd->...v", s, embed.astype(dt),

@@ -49,8 +49,10 @@ imported from there, not copied):
 * **the expert layer is lfm2_moe's dropless grouped product**, told which
   experts it holds (``held`` = (first, how many): ONE routing group under
   the published expert parallelism): the router scores all ``n_experts``,
-  slots of absent experts sort behind the held ones' and add nothing; the
-  shared experts are a dense gated MLP on every token.
+  slots of absent experts sort behind the held ones' and are not touched —
+  the product gathers, multiplies and combines the held experts' rows
+  alone, in row blocks up to the last held slot; the shared experts are a
+  dense gated MLP on every token.
 * every layer is a ``jax.checkpoint``; the layers are unrolled (each has its
   own leaves), so a kept value is ONE buffer that the forward pass writes
   anyway and the backward pass reads in place — no scan's stack (what made
@@ -77,7 +79,8 @@ imported from there, not copied):
   local step keeps (``remat_saved_bytes``).
 * the router's decisions are counted as in lfm2_moe: tokens routed to every
   (expert layer, expert) of a step, held or not
-  (``counters/moe_expert_tokens``).
+  (``counters/moe_expert_tokens``), and the rows the grouped products ran
+  over beside the slots routed (``counters/moe_slot_rows``).
 """
 from __future__ import annotations
 
@@ -93,7 +96,8 @@ from jax.ad_checkpoint import checkpoint_name
 
 from fedml_tpu import obs
 from fedml_tpu.models.lfm2_moe import (_adapted, _Groups, _Leaves,
-                                       expert_product, gated_mlp)
+                                       counter_shapes, float_counters,
+                                       gated_mlp, held_share, sow_counters)
 from fedml_tpu.models.looped_lm import _dot, apply_rotary, rms_norm
 from fedml_tpu.obs import scopes
 from fedml_tpu.ops.attention import SAVED_NAMES, causal_attention
@@ -183,19 +187,17 @@ def route_grouped(f, router, k: int, n_group: int, topk_group: int,
 
 def moe_layer(f, lp, k: int, n_group: int, topk_group: int, scaling: float,
               held):
-    """(m, tokens routed to every expert [n_experts]) of one expert layer
-    for f [..., d]; ``lp``: router, the experts HELD (``held`` = (first, how
-    many)) and the shared experts as one gated MLP."""
-    n_experts = lp["router"].shape[-1]
+    """(m, the layer's counters: `lfm2_moe.held_share`) of one expert
+    layer for f [..., d]; ``lp``: router, the experts HELD (``held`` =
+    (first, how many)) and the shared experts as one gated MLP."""
     rows = f.reshape((-1, f.shape[-1]))
     with jax.named_scope(scopes.FED_MOE_ROUTER):
         sel, gate = route_grouped(rows, lp["router"], k, n_group, topk_group,
                                   scaling)
-        counts = jnp.bincount(sel.reshape(-1), length=n_experts)
-    m = expert_product(*held)(rows, sel, gate, lp["w1"], lp["w3"], lp["w2"])
+    m, counts = held_share(rows, sel, gate, lp, *held)
     with jax.named_scope(scopes.FED_SHARED_EXPERT):
         m = m + gated_mlp(rows, lp["s1"], lp["s3"], lp["s2"])
-    return m.reshape(f.shape), counts.astype(jnp.float32)
+    return m.reshape(f.shape), float_counters(counts)
 
 
 class DeepSeekV2LM(nn.Module):
@@ -253,8 +255,7 @@ class DeepSeekV2LM(nn.Module):
 
     @property
     def counters(self) -> dict:
-        return {scopes.MOE_EXPERT_TOKENS: (len(self.expert_layers),
-                                           self.n_experts)}
+        return counter_shapes(len(self.expert_layers), self.n_experts)
 
     @property
     def softmax_scale(self) -> float:
@@ -342,11 +343,7 @@ class DeepSeekV2LM(nn.Module):
             h, c = layer(h, base[i], lora[f"layer_{i}"], cos, sin)
             if c is not None:
                 counts.append(c)
-        if (counts and not self.is_initializing()
-                and self.is_mutable_collection(scopes.COUNTERS)):
-            self.sow(scopes.COUNTERS, scopes.MOE_EXPERT_TOKENS,
-                     jnp.stack(counts), init_fn=lambda: 0.0,
-                     reduce_fn=lambda a, b: a + b)
+        sow_counters(self, counts)
         with jax.named_scope(scopes.FED_LM_HEAD):
             s = rms_norm(h, out_norm, self.norm_eps)
             return _dot(s, head.astype(dt))

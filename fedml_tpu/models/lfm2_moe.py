@@ -47,7 +47,12 @@ How it is built for a chip:
   un-sorted and combined.  No capacity, no dropped slot, no loop over
   masks.  ``held_experts`` is a range of expert ids: routing is over all
   ``n_experts``, and the part of ``m`` the held experts give is what goes
-  on (a slot of an absent expert adds nothing).
+  on.  **A layer that holds a share of its experts touches only their
+  slots**: the sort puts them first, and gather, products and combine run
+  in row blocks up to the last held slot — a loop whose bound is the load,
+  so no slot is dropped and none of an absent expert is read
+  (`_held_share_rules`); a layer that holds them all has nothing to skip
+  and runs over every slot at once.
 * **A chunk of clients shares one read of the experts**: under the engine's
   ``vmap`` over clients the product merges the clients' tokens before it
   sorts them (``custom_vmap``), instead of running once per client; its
@@ -62,7 +67,9 @@ How it is built for a chip:
   The per-head norms, rotary and the adapted projections stay here.
 * the router's decisions are counted: tokens routed to every (expert layer,
   expert) of a step, sown as ``counters/moe_expert_tokens`` where the
-  caller asks for that collection (obs/scopes.py).
+  caller asks for that collection (obs/scopes.py), and beside them the rows
+  the grouped products ran over and the slots routed
+  (``counters/moe_slot_rows``: equal where every expert is held).
 """
 from __future__ import annotations
 
@@ -72,6 +79,7 @@ from typing import Any, Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.custom_batching import custom_vmap
 
 from fedml_tpu.models.looped_lm import (_dot, apply_rotary, rms_norm,
@@ -127,13 +135,19 @@ def route(f, router, bias, k: int, scaling: float):
     return sel, g / (jnp.sum(g, axis=-1, keepdims=True) + 1e-6) * scaling
 
 
+def _keys(sel, first: int, n_held: int):
+    """Every slot's held expert, counted from ``first``; ``n_held`` for a
+    slot of an expert that is not held: [S] integers."""
+    key = sel.reshape(-1) - first
+    return jnp.where((key >= 0) & (key < n_held), key, n_held)
+
+
 def _slots(sel, first: int, n_held: int):
     """The k x N token slots in the order the grouped product wants them:
     (order [S] — slot ids, those of held experts first, by expert;
     sizes [n_held] — slots of each held expert; valid [S] — sorted slot
     belongs to a held expert)."""
-    key = sel.reshape(-1) - first
-    key = jnp.where((key >= 0) & (key < n_held), key, n_held)
+    key = _keys(sel, first, n_held)
     order = jnp.argsort(key, stable=True)
     sizes = jnp.bincount(key, length=n_held + 1)[:n_held].astype(jnp.int32)
     return order, sizes, key[order] < n_held
@@ -181,14 +195,140 @@ def _merged(fn, n_mapped: int):
     return fn
 
 
+# a block's rows over what a uniform router sends the held experts
+# (`block_rows`; PERF.md section 6, PR 43 has the chip readings behind it)
+_BLOCK_SHARE = 1.5
+
+
+def block_rows(n_slots: int, n_held: int, n_experts: int) -> int:
+    """R, the rows of one block of the held-share product: the multiple of
+    512 nearest ``_BLOCK_SHARE`` x the uniform router's expectation
+    ``n_slots x n_held / n_experts``, at least 512 — a function of shapes."""
+    expected = n_slots * n_held / n_experts
+    return 512 * max(1, int(_BLOCK_SHARE * expected / 512 + 0.5))
+
+
+def _blocks(sel, first: int, n_held: int, n_experts: int):
+    """What the held-share product's block loop runs over: (order — `_slots`'
+    order padded with slot 0 to whole blocks of R; starts, ends [n_held] —
+    each held expert's interval of sorted positions; n_valid — the held
+    experts' slots, which are the first n_valid sorted ones; R)."""
+    order, sizes, _ = _slots(sel, first, n_held)
+    R = block_rows(order.size, n_held, n_experts)
+    ends = jnp.cumsum(sizes)
+    return jnp.pad(order, (0, -order.size % R)), ends - sizes, ends, ends[-1], R
+
+
+def _block(b, order, starts, ends, n_valid, R: int, k: int):
+    """Block b of the sorted slots: (slot ids [R]; their tokens [R]; live
+    [R] — the row is a held expert's slot; the held experts' group sizes
+    inside the block [n_held])."""
+    lo = b * R
+    slot = jax.lax.dynamic_slice(order, (lo,), (R,))
+    live = lo + jnp.arange(R, dtype=n_valid.dtype) < n_valid
+    sizes = jnp.clip(ends, lo, lo + R) - jnp.clip(starts, lo, lo + R)
+    return slot, slot // k, live, sizes
+
+
+def _add_rows(m, token, rows):
+    """m [N, d] with rows [R, d] added at their tokens (float32).  The
+    scatter-add runs with d read as d / w rows of w, w the largest power of
+    two that divides d, at most 4,096: XLA:TPU's row scatter keeps its rate
+    on such rows (1.5 ms for 6,144 rows of 4,096) and loses it on others
+    (8.0 ms for 4,608 rows of 5,120, 1.6 as 5 x 1,024: PERF.md section 6,
+    PR 43)."""
+    N, d = m.shape
+    w = min(d & -d, 4096)
+    at = (token[:, None] * (d // w) + jnp.arange(d // w, dtype=token.dtype))
+    return m.reshape((-1, w)).at[at.reshape(-1)].add(
+        rows.reshape((-1, w))).reshape((N, d))
+
+
+def _held_share_rules(first: int, n_held: int, n_experts: int):
+    """`expert_product`'s forward and backward rules for a layer that holds
+    fewer experts than it routes over: both touch the sorted slots up to
+    the last held one only, R = `block_rows` rows at a time — the block's
+    tokens gathered, the grouped products on [R, ...], the block's rows
+    added into the result at their tokens (float32, cast once).  The loop
+    runs ``ceil(n_valid / R)`` blocks, a traced bound: if every token
+    chose held experts that is all S slots.  No slot is dropped, there is
+    no second branch, no float array leads with S, and nothing crosses
+    from forward to backward: the backward block re-makes both
+    pre-activations from the rows it gathers anyway."""
+
+    def forward(f, sel, gate, w1, w3, w2):
+        k = sel.shape[-1]
+        with jax.named_scope(scopes.FED_MOE_ROUTER):
+            order, starts, ends, n_valid, R = _blocks(sel, first, n_held,
+                                                      n_experts)
+
+        def block(b, m):
+            with jax.named_scope(scopes.FED_MOE_ROUTER):
+                slot, token, live, sizes = _block(b, order, starts, ends,
+                                                  n_valid, R, k)
+                xs = f[token]
+            with jax.named_scope(scopes.FED_MOE_EXPERTS):
+                a1, a3 = _grouped(xs, w1, sizes), _grouped(xs, w3, sizes)
+                y = _grouped((jax.nn.silu(a1) * a3).astype(f.dtype), w2, sizes)
+            with jax.named_scope(scopes.FED_MOE_ROUTER):
+                gs = gate.reshape(-1)[slot][:, None]
+                return _add_rows(m, token, jnp.where(live[:, None], y * gs, 0.0))
+
+        m = jax.lax.fori_loop(0, -(-n_valid // R), block,
+                              jnp.zeros_like(f, jnp.float32))
+        return (m.astype(f.dtype),)
+
+    def backward(f, sel, gate, dm, w1, w3, w2):
+        k, dt = sel.shape[-1], f.dtype
+        with jax.named_scope(scopes.FED_MOE_ROUTER):
+            order, starts, ends, n_valid, R = _blocks(sel, first, n_held,
+                                                      n_experts)
+
+        def block(b, carry):
+            df, dgate = carry
+            with jax.named_scope(scopes.FED_MOE_ROUTER):
+                slot, token, live, sizes = _block(b, order, starts, ends,
+                                                  n_valid, R, k)
+                xs, dms = f[token], dm[token].astype(dt)
+                gs = gate.reshape(-1)[slot][:, None]
+            with jax.named_scope(scopes.FED_MOE_EXPERTS):
+                a1, a3 = _grouped(xs, w1, sizes), _grouped(xs, w3, sizes)
+                back = lambda d, w: jnp.where(
+                    live[:, None], _grouped_t(d.astype(dt), w, sizes), 0.0)
+                u = back(dms, w2)
+                sig = jax.nn.sigmoid(a1)
+                act = a1 * sig
+                dg = jnp.sum(jnp.where(
+                    live[:, None],
+                    (act * a3).astype(dt).astype(jnp.float32) * u, 0.0), axis=-1)
+                dh = gs * u
+                dxs = (back(dh * a3 * sig * (1.0 + a1 * (1.0 - sig)), w1)
+                       + back(dh * act, w3))
+            with jax.named_scope(scopes.FED_MOE_ROUTER):
+                return _add_rows(df, token, dxs), dgate.at[slot].add(dg)
+
+        df, dgate = jax.lax.fori_loop(
+            0, -(-n_valid // R), block,
+            (jnp.zeros_like(f, jnp.float32),
+             jnp.zeros_like(gate, jnp.float32).reshape(-1)))
+        return df.astype(dt), dgate.reshape(sel.shape)
+
+    return forward, backward
+
+
 @functools.lru_cache(maxsize=None)
-def expert_product(first: int, n_held: int):
+def expert_product(first: int, n_held: int, n_experts: int):
     """``m = product(f, sel, gate, w1, w3, w2)``: the held experts' share
     of an expert layer's output for tokens f [N, d] routed to ``sel`` with
-    weights ``gate`` ([N, k]); w1, w3 [n_held, d, width], w2 [n_held, width,
-    d].  Differentiable in f and gate; the weights are read, not trained.
-    Sorted-slot residuals (both pre-activations, the order) cross from
-    the forward to the backward pass in the merged layout of `_merged`."""
+    weights ``gate`` ([N, k]) over ``n_experts``; w1, w3 [n_held, d, width],
+    w2 [n_held, width, d].  Differentiable in f and gate; the weights are
+    read, not trained.  **Where every expert is held** the product runs
+    over all S = k x N sorted slots at once, and sorted-slot residuals (both
+    pre-activations, the order) cross from the forward to the backward
+    pass in the merged layout of `_merged`; **where a share is held** it
+    runs over the held experts' slots alone (`_held_share_rules`).  What
+    tells them apart is what the layer observes — every slot is valid, so
+    there is nothing to skip — and no setting."""
 
     def forward(f, sel, gate, w1, w3, w2):
         k = sel.shape[-1]
@@ -227,38 +367,101 @@ def expert_product(first: int, n_held: int):
             df = jnp.sum(_unsort(dxs, order, k), axis=1).astype(dt)
             return df, _unsort(dgate, order, k)
 
-    forward_m, backward_m = _merged(forward, 3), _merged(backward, 7)
+    n_kept = 3               # a1, a3, order: what `forward` returns beside m
+    if n_held < n_experts:
+        forward, backward = _held_share_rules(first, n_held, n_experts)
+        n_kept = 0
+    # mapped: f, sel, gate (and, backward, what was kept and dm)
+    forward_m, backward_m = _merged(forward, 3), _merged(backward, 4 + n_kept)
 
     @jax.custom_vjp
     def product(f, sel, gate, w1, w3, w2):
         return forward_m(f, sel, gate, w1, w3, w2)[0]
 
     def fwd(f, sel, gate, w1, w3, w2):
-        m, a1, a3, order = forward_m(f, sel, gate, w1, w3, w2)
-        return m, (f, sel, gate, a1, a3, order, w1, w3, w2)
+        m, *kept = forward_m(f, sel, gate, w1, w3, w2)
+        return m, (f, sel, gate, *kept, w1, w3, w2)
 
     def bwd(res, dm):
-        f, sel, gate, a1, a3, order, w1, w3, w2 = res
-        df, dgate = backward_m(f, sel, gate, a1, a3, order, dm, w1, w3, w2)
-        return df, None, dgate.astype(gate.dtype), None, None, None
+        *rows, w1, w3, w2 = res
+        df, dgate = backward_m(*rows, dm, w1, w3, w2)
+        return df, None, dgate.astype(rows[2].dtype), None, None, None
 
     product.defvjp(fwd, bwd)
     return product
 
 
-def moe_layer(f, lp, k: int, scaling: float, held=None):
-    """(m, tokens routed to every expert [n_experts]) of one expert layer
-    for f [..., d]; ``lp``: router, expert_bias and the experts HELD
-    (``held`` = (first, past-last) expert id; None = all)."""
+@functools.lru_cache(maxsize=None)
+def _rows_run(first: int, n_held: int, n_experts: int):
+    """``(share,) = rows(sel)``: the rows the held-share product's blocks
+    run for the tokens routed to ``sel`` [N, k], as whole numbers spread
+    over the N tokens (float32 [N]; their sum is the rows) — merged over a
+    chunk's clients as the product is, so the sum over clients is what
+    the one merged product ran."""
+
+    def rows(sel):
+        n = sel.shape[0]
+        n_valid = jnp.sum(_keys(sel, first, n_held) < n_held)
+        R = block_rows(sel.size, n_held, n_experts)
+        ran = -(-n_valid // R) * R
+        return ((ran // n + (jnp.arange(n) < ran % n)).astype(jnp.float32),)
+
+    return _merged(rows, 1)
+
+
+def held_share(rows, sel, gate, lp, first: int, n_held: int):
+    """(m, the layer's counters) for tokens ``rows`` [N, d] routed to
+    ``sel`` with weights ``gate``: the share of the layer's output that the
+    held experts ``lp["w1"], lp["w3"], lp["w2"]`` give (`expert_product`);
+    tokens routed to every expert [n_experts], held or not, and (rows the
+    grouped products ran over, slots routed) — equal where every expert is
+    held."""
     n_experts = lp["router"].shape[-1]
-    first, last = held if held is not None else (0, n_experts)
+    with jax.named_scope(scopes.FED_MOE_ROUTER):
+        tokens = jnp.bincount(sel.reshape(-1), length=n_experts)
+    m = expert_product(first, n_held, n_experts)(
+        rows, sel, gate, lp["w1"], lp["w3"], lp["w2"])
+    if n_held == n_experts:
+        slot_rows = np.full((2,), sel.size, np.float32)
+    else:
+        with jax.named_scope(scopes.FED_MOE_ROUTER):
+            ran = jnp.sum(_rows_run(first, n_held, n_experts)(sel)[0])
+            slot_rows = jnp.stack([ran, jnp.float32(sel.size)])
+    return m, {scopes.MOE_EXPERT_TOKENS: tokens, scopes.MOE_SLOT_ROWS: slot_rows}
+
+
+def moe_layer(f, lp, k: int, scaling: float, held=None):
+    """(m, the layer's counters: `held_share`) of one expert layer for f
+    [..., d]; ``lp``: router, expert_bias and the experts HELD (``held`` =
+    (first, past-last) expert id; None = all)."""
+    first, last = held if held is not None else (0, lp["router"].shape[-1])
     rows = f.reshape((-1, f.shape[-1]))
     with jax.named_scope(scopes.FED_MOE_ROUTER):
         sel, gate = route(rows, lp["router"], lp["expert_bias"], k, scaling)
-        counts = jnp.bincount(sel.reshape(-1), length=n_experts)
-    m = expert_product(first, last - first)(
-        rows, sel, gate, lp["w1"], lp["w3"], lp["w2"])
-    return m.reshape(f.shape), counts.astype(jnp.float32)
+    m, counts = held_share(rows, sel, gate, lp, first, last - first)
+    return m.reshape(f.shape), float_counters(counts)
+
+
+def float_counters(counts: dict) -> dict:
+    """A layer's counters as the trainer sums them: float32."""
+    return {name: c.astype(jnp.float32) for name, c in counts.items()}
+
+
+def counter_shapes(n_expert_layers: int, n_experts: int) -> dict:
+    """A model's ``counters`` for `sow_counters`: {name: shape}."""
+    return {scopes.MOE_EXPERT_TOKENS: (n_expert_layers, n_experts),
+            scopes.MOE_SLOT_ROWS: (n_expert_layers, 2)}
+
+
+def sow_counters(module, counts: list) -> None:
+    """Sow the expert layers' counters (a `moe_layer`'s second result, one
+    a layer), stacked by layer, where the caller asks for the collection."""
+    if (counts and not module.is_initializing()
+            and module.is_mutable_collection(scopes.COUNTERS)):
+        for name in counts[0]:
+            module.sow(scopes.COUNTERS, name,
+                       jnp.stack([c[name] for c in counts]),
+                       init_fn=lambda: 0.0, reduce_fn=lambda a, b: a + b)
 
 
 class _Leaves(nn.Module):
@@ -321,8 +524,7 @@ class Lfm2MoeLM(nn.Module):
 
     @property
     def counters(self) -> dict:
-        return {scopes.MOE_EXPERT_TOKENS: (len(self.expert_layers),
-                                           self.n_experts)}
+        return counter_shapes(len(self.expert_layers), self.n_experts)
 
     def _specs(self, i: int):
         """(base, adapter) leaf specs of layer i."""
@@ -397,11 +599,7 @@ class Lfm2MoeLM(nn.Module):
             h, c = layer(h, base[i], lora[f"layer_{i}"], cos, sin)
             if c is not None:
                 counts.append(c)
-        if (counts and not self.is_initializing()
-                and self.is_mutable_collection(scopes.COUNTERS)):
-            self.sow(scopes.COUNTERS, scopes.MOE_EXPERT_TOKENS,
-                     jnp.stack(counts), init_fn=lambda: 0.0,
-                     reduce_fn=lambda a, b: a + b)
+        sow_counters(self, counts)
         with jax.named_scope(scopes.FED_LM_HEAD):
             s = rms_norm(h, out_norm, self.norm_eps)
             return jnp.einsum("...d,vd->...v", s, embed.astype(dt),

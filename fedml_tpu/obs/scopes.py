@@ -153,9 +153,19 @@ LABELS = tuple(LABEL_OF_SCOPE.values()) + (BACKWARD, UNSCOPED)
 # metrics under their names (core/trainer.py, parallel/engine.py)
 COUNTERS = "counters"
 MOE_EXPERT_TOKENS = "moe_expert_tokens"    # [expert layers, experts]
-# the obs counter that takes a program counter's total when it is read
-# (utils/profiling.py::TransferOverlapStats.program_counters)
-METRIC_OF_COUNTER = {MOE_EXPERT_TOKENS: "moe_expert_tokens_total"}
+# [expert layers, 2]: (rows the grouped expert products ran over, slots
+# routed) — how far a layer that holds a share of its experts skips the
+# slots of the others (models/lfm2_moe.py::held_share)
+MOE_SLOT_ROWS = "moe_slot_rows"
+# the obs counters that take a program counter's totals when it is read
+# (utils/profiling.py::TransferOverlapStats.program_counters): (metric, the
+# labels of one counter for each entry of the LAST axis) — one unlabelled
+# counter takes the whole array's sum
+METRIC_OF_COUNTER = {
+    MOE_EXPERT_TOKENS: ("moe_expert_tokens_total", ({},)),
+    MOE_SLOT_ROWS: ("moe_slot_rows_total",
+                    ({"rows": "run"}, {"rows": "routed"})),
+}
 
 SPAN_SAMPLE = "round.sample"
 SPAN_ARGS_PUT = "round.args_put"

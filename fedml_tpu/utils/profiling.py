@@ -142,8 +142,8 @@ class TransferOverlapStats:
         # the programs' own device arrays, kept as they come and summed
         # when somebody reads them
         self._m_program_counters = {
-            name: obs.counter(metric)
-            for name, metric in scopes.METRIC_OF_COUNTER.items()}
+            name: [obs.counter(metric, **labels) for labels in each]
+            for name, (metric, each) in scopes.METRIC_OF_COUNTER.items()}
         self.reset()
 
     def reset(self) -> None:
@@ -205,8 +205,11 @@ class TransferOverlapStats:
             value = np.asarray(value, np.float64)
             with self._lock:
                 self._counted[name] = self._counted.get(name, 0.0) + value
-            if name in self._m_program_counters:
-                self._m_program_counters[name].inc(float(value.sum()))
+            metrics = self._m_program_counters.get(name)
+            if metrics:                   # one for each entry of the last axis
+                totals = value.reshape((-1, len(metrics))).sum(axis=0)
+                for metric, total in zip(metrics, totals):
+                    metric.inc(float(total))
 
     @property
     def h2d_bytes(self) -> int:

@@ -140,7 +140,9 @@ def test_the_sixteen_shares_and_the_shared_experts_once_are_the_uncut_layer():
     lp = _expert_layer(rs)
     ref = reference.resolve(REF_NAME)
     f = jnp.asarray(rs.randn(2, 12, 16), jnp.float32)
-    layer = lambda lp, held: cohere2_moe.moe_layer(f, lp, 8, 4, held)
+    def layer(lp, held):
+        m, c = cohere2_moe.moe_layer(f, lp, 8, 4, held)
+        return m, c[scopes.MOE_EXPERT_TOKENS]
     whole, counts = layer(lp, (0, 32))
     shared = lfm2_moe.gated_mlp(f, lp["s1"], lp["s3"], lp["s2"]) / 4
     np.testing.assert_allclose(

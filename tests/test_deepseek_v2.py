@@ -125,7 +125,9 @@ def test_the_shares_of_the_eight_groups_add_up_to_the_whole_layer():
     lp = _expert_layer(rs)
     ref = reference.resolve(REF_NAME)
     f = jnp.asarray(rs.randn(2, 12, 16), jnp.float32)
-    layer = lambda lp, held: deepseek_v2.moe_layer(f, lp, 6, 8, 3, 16.0, held)
+    def layer(lp, held):
+        m, c = deepseek_v2.moe_layer(f, lp, 6, 8, 3, 16.0, held)
+        return m, c[scopes.MOE_EXPERT_TOKENS]
     whole, counts = layer(lp, (0, 32))
     shared = lfm2_moe.gated_mlp(f, lp["s1"], lp["s3"], lp["s2"])
     routed = []

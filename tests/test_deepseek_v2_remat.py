@@ -113,7 +113,11 @@ def test_a_16_bit_stream_keeps_the_layer_inputs_and_the_named_values():
     assert weights.count(W_O_A) == LAYERS
     assert weights.count((D, WIDTHS["q_rank"])) == LAYERS          # W_qa
     assert weights.count((WIDTHS["kv_rank"], H * 2 * V_DIM)) == LAYERS  # W_kvb
-    assert {"rsqrt", "mul", "add", "logistic", "sort"} <= set(again)
+    assert {"rsqrt", "mul", "add", "logistic", "top_k"} <= set(again)
+    # a layer that holds a share of its experts re-runs the router's scores
+    # and selection, and nothing of the product: the backward blocks sort the
+    # slots and re-make the pre-activations of the rows they gather (PR 43)
+    assert not {"sort", "while", "ragged_dot_general"} & set(again), again
 
 
 def test_a_float32_stream_keeps_the_layer_inputs_alone():

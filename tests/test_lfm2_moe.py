@@ -103,7 +103,7 @@ def test_grouped_product_equals_a_dense_loop_over_experts(routing):
         assert not (sel == 7).any()
     sel = jnp.asarray(sel, jnp.int32)
     gate = jnp.asarray(rs.rand(n, k), jnp.float32)
-    product = lfm2_moe.expert_product(0, E)
+    product = lfm2_moe.expert_product(0, E, E)
     got = product(f, sel, gate, lp["w1"], lp["w3"], lp["w2"])
     np.testing.assert_allclose(got, _dense_loop(f, sel, gate, lp), atol=1e-5)
     # and its hand-written backward pass is the dense loop's gradient
@@ -131,7 +131,7 @@ def test_a_chunk_of_clients_is_one_merged_product_with_the_same_values():
     sel = jnp.asarray(np.stack([np.stack([rs.permutation(8)[:k] for _ in range(n)])
                                 for _ in range(c)]), jnp.int32)
     gate = jnp.asarray(rs.rand(c, n, k), jnp.float32)
-    product = lfm2_moe.expert_product(0, 8)
+    product = lfm2_moe.expert_product(0, 8, 8)
     loss = lambda f, s, g: jnp.sum(jax.checkpoint(product)(
         f, s, g, lp["w1"], lp["w3"], lp["w2"]) ** 2)
     mapped = jax.jit(jax.vmap(jax.value_and_grad(loss, (0, 2))))
@@ -155,11 +155,14 @@ def test_the_shares_of_eight_ranges_of_experts_add_up_to_the_whole_layer():
     rs = np.random.RandomState(4)
     lp = _expert_layer(rs, n_experts=64)
     f = jnp.asarray(rs.randn(2, 12, 16), jnp.float32)
-    whole, counts = lfm2_moe.moe_layer(f, lp, 4, 1.0)
+    def layer(lp, held=None):
+        m, c = lfm2_moe.moe_layer(f, lp, 4, 1.0, held=held)
+        return m, c[scopes.MOE_EXPERT_TOKENS]
+    whole, counts = layer(lp)
     parts = []
     for first in range(0, 64, 8):
         share = dict(lp, **{w: lp[w][first:first + 8] for w in ("w1", "w3", "w2")})
-        m, c = lfm2_moe.moe_layer(f, share, 4, 1.0, held=(first, first + 8))
+        m, c = layer(share, (first, first + 8))
         np.testing.assert_array_equal(c, counts)
         parts.append(m)
         ref = reference.resolve("lfm2_24b_a2b")
