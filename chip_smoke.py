@@ -27,11 +27,11 @@ Default (one chip), in order:
                  centralized GD step on the pooled 256 samples, computed
                  by a plain jax.grad step that shares no code with the
                  engine (tests/test_fedavg.py is the CPU twin).
-  (c) kernels    the pallas kernels COMPILED by Mosaic (not interpreted)
-                 at the ResNet-18 row, each against its jax.numpy
-                 reference; the fused causal attention at both
-                 language-model cells' shapes, output and gradients in
-                 bfloat16, against a float32 oracle beside the plain path.
+  (c) kernels    the pallas kernels COMPILED by Mosaic (not interpreted),
+                 output and gradients in bfloat16: the fused causal
+                 attention at two language-model cells' shapes, against a
+                 float32 oracle beside the plain path; the rotary kernel at
+                 Command A+'s q and k, against its plain body.
   (d) cli        fedml_tpu.cli.main([...]) in-process: argument parsing
                  -> engine -> history.jsonl on the device.
 
@@ -65,11 +65,12 @@ class Sizes:
     warmup_rounds: int = 2
     timed_rounds: int = 3
     oracle_clients: int = 8
-    agg_clients: int = 8
-    gn_shapes: tuple = ((32, 32, 32, 64), (32, 4, 4, 512))
     # (B, T, H, H_kv, head size): a step of ouro2p6b.silo4of256t1024 and a
     # chunk's step of lfm2moe24b.lora4of256t2048
     attn_shapes: tuple = ((2, 1024, 16, 16, 128), (4, 2048, 32, 8, 64))
+    # (B, T, H, head size): q and k of a sliding layer of
+    # cmdaplus.lora4of256long
+    rotary_shapes: tuple = ((1, 8192, 128, 128), (1, 8192, 8, 128))
     platform: str = "tpu"        # where every result must live
 
 
@@ -324,94 +325,20 @@ def _lowered_has_kernel(fn, *args) -> bool:
     return "tpu_custom_call" in jax.jit(fn).lower(*args).as_text()
 
 
+def _worst_in_ulps(got, want) -> float:
+    """max |got - want| in units of `want`'s last bfloat16 place (2^-7 of
+    its power of two; 1e-6 where it is smaller than that)."""
+    import jax.numpy as jnp
+    a, b = got.astype(jnp.float32), want.astype(jnp.float32)
+    ulp = 2.0 ** (jnp.floor(jnp.log2(jnp.maximum(jnp.abs(b), 1e-30))) - 7)
+    return float(jnp.max(jnp.abs(a - b) / jnp.maximum(ulp, 1e-6)))
+
+
 def phase_kernels(sz: Sizes, seed: int) -> None:
     import jax
     import jax.numpy as jnp
 
-    from fedml_tpu.core.pytree import tree_weighted_mean
-    from fedml_tpu.core.robust import norm_diff_clip
-    from fedml_tpu.models import create_model
-    from fedml_tpu.ops import (robust_weighted_mean_pallas,
-                               weighted_mean_pallas)
-    from fedml_tpu.ops.groupnorm import _gn_reference, group_norm
-    compiled = sz.platform == "tpu"      # else pallas interpret mode (CPU)
-
-    # aggregation at the model's own row: what FedAvgEngine(pallas_agg=True)
-    # and FedAvgRobustEngine see for an agg_clients-wide cohort
-    C = sz.agg_clients
-    model = create_model(sz.model, output_dim=10)
-    shapes = jax.eval_shape(
-        lambda: model.init(jax.random.PRNGKey(0), jnp.zeros(
-            (1, sz.image_hw, sz.image_hw, 3)), train=False))["params"]
-    leaves, treedef = jax.tree.flatten(shapes)
-    keys = jax.random.split(jax.random.PRNGKey(seed), len(leaves) + 1)
-    stacked = jax.tree.unflatten(treedef, [
-        jax.random.normal(k, (C,) + l.shape, jnp.float32)
-        for k, l in zip(keys, leaves)])
-    g = jax.tree.map(lambda a: 0.5 * a[0], stacked)
-    w = jax.random.uniform(keys[-1], (C,), jnp.float32, 0.5, 1.5)
-    n = sum(l.size for l in leaves)
-    tau = 0.8 * math.sqrt(n)   # ‖x_0 − g‖ ≈ 0.5√n passes, the rest (≈ 1.1√n) clip
-
-    def ref_robust(s, w, g):
-        return tree_weighted_mean(
-            jax.vmap(lambda p: norm_diff_clip(p, g, tau))(s), w)
-
-    for name, fused, ref, args, tol in (
-            ("weighted_mean_pallas", weighted_mean_pallas,
-             tree_weighted_mean, (stacked, w), 1e-5),
-            ("robust_weighted_mean_pallas",
-             lambda s, w, g: robust_weighted_mean_pallas(s, w, g, tau),
-             ref_robust, (stacked, w, g), 1e-5)):
-        kernel = _lowered_has_kernel(fused, *args)
-        diff = _max_abs_diff(jax.jit(fused)(*args), jax.jit(ref)(*args))
-        emit("kernel", op=name, clients=C, n=n, compiled=kernel,
-             max_abs_diff=diff, tolerance=tol)
-        assert kernel == compiled, f"{name}: kernel path taken = {kernel}"
-        assert diff <= tol, f"{name}: {diff} > {tol}"
-
-    # fused GroupNorm forward + grad, tools/tpu_smoke.py's tolerances:
-    # forward 1e-3, gradients 5e-2 absolute.  The large-mean input is
-    # there for the two-pass variance (a one-pass E[x²]−μ² cancels
-    # catastrophically), and its forward holds the same 1e-3.  Its
-    # gradients cannot meet an ABSOLUTE 5e-2 in any f32 implementation:
-    # at mean/σ ≈ 3.5e3, f32 keeps x̂ to ~4e-4, and dγ/dβ are sums of
-    # 32 k such terms of magnitude ~3e4 (the first chip run measured
-    # 8e-4 relative on dx, 8e-5 on dγ).  They are held to 5e-3 of the
-    # reference's own magnitude — still far below what a cancelled
-    # variance produces.
-    G, rs = 8, np.random.RandomState(seed)
-    for shape in sz.gn_shapes:
-        for shift in (0.0, 1000.0):
-            xs = jnp.asarray(rs.rand(*shape) + shift, jnp.float32)
-            gamma = jnp.asarray(rs.rand(shape[-1]), jnp.float32)
-            beta = jnp.asarray(rs.rand(shape[-1]), jnp.float32)
-
-            def fused(x, g_, b):
-                return jnp.sum(jnp.sin(group_norm(x, g_, b, G)))
-
-            def ref(x, g_, b):
-                return jnp.sum(jnp.sin(_gn_reference(x, g_, b, G, 1e-5)))
-
-            kernel = _lowered_has_kernel(jax.grad(fused, (0, 1, 2)),
-                                         xs, gamma, beta)
-            fwd = float(jnp.max(jnp.abs(
-                group_norm(xs, gamma, beta, G)
-                - _gn_reference(xs, gamma, beta, G, 1e-5))))
-            got = jax.jit(jax.grad(fused, (0, 1, 2)))(xs, gamma, beta)
-            want = jax.jit(jax.grad(ref, (0, 1, 2)))(xs, gamma, beta)
-            grad_abs = [float(jnp.max(jnp.abs(a - b)))
-                        for a, b in zip(got, want)]
-            grad_rel = [d / float(jnp.max(jnp.abs(b)))
-                        for d, b in zip(grad_abs, want)]
-            grads_ok = (max(grad_rel) < 5e-3 if shift
-                        else max(grad_abs) < 5e-2)
-            emit("kernel", op="group_norm", shape=list(shape), shift=shift,
-                 compiled=kernel, fwd_max_abs_diff=fwd, fwd_tolerance=1e-3,
-                 grad_max_abs_diff=grad_abs, grad_max_rel_diff=grad_rel,
-                 grad_tolerance="rel 5e-3" if shift else "abs 5e-2")
-            assert kernel == compiled, f"group_norm {shape}: {kernel}"
-            assert fwd < 1e-3 and grads_ok, (shape, shift, fwd, grad_abs)
+    compiled = sz.platform == "tpu"      # else the ops' plain paths (CPU)
 
     # fused causal attention (ops/attention.py) at the two language-model
     # cells' shapes, bfloat16: output and the three gradients of the fused
@@ -458,6 +385,34 @@ def phase_kernels(sz: Sizes, seed: int) -> None:
              plain_max_o_dq_dk_dv=worst["plain"], tolerance="l2 1.5 x plain")
         assert kernel == compiled, f"causal_attention: kernel path = {kernel}"
         assert all(f <= 1.5 * p_ for f, p_ in zip(l2["fused"], l2["plain"])), l2
+
+    # the rotary kernel (ops/rotary.py) at the q and the k of a sliding
+    # layer of cmdaplus.lora4of256long, bfloat16: output and gradient against
+    # the plain body `apply_rotary`.  Both rotate in float32 and round once:
+    # they may be one bfloat16 place apart (the two may contract a * b + c
+    # differently), as tests/test_rotary_op.py holds them in interpret mode.
+    from fedml_tpu.models.looped_lm import rotary_tables
+    from fedml_tpu.ops.rotary import apply_rotary, rotate_half
+    for shape in sz.rotary_shapes:
+        kx, kw = jax.random.split(jax.random.PRNGKey(seed))
+        x = jax.random.normal(kx, shape, jnp.float32).astype(jnp.bfloat16)
+        w = jax.random.normal(kw, shape, jnp.float32).astype(jnp.bfloat16)
+        cos, sin = rotary_tables(shape[1], shape[-1], 5e4)
+
+        def out_and_grad(fn):
+            def run(x, w):
+                y, transpose = jax.vjp(lambda x: fn(x, cos, sin), x)
+                return y, transpose(w)[0]
+            return run
+
+        kernel = _lowered_has_kernel(out_and_grad(rotate_half), x, w)
+        ulps = [_worst_in_ulps(a, b) for a, b in zip(
+            jax.jit(out_and_grad(rotate_half))(x, w),
+            jax.jit(out_and_grad(apply_rotary))(x, w))]
+        emit("kernel", op="rotate_half", shape=list(shape), dtype="bfloat16",
+             compiled=kernel, max_bf16_ulps_y_dx=ulps, tolerance="1 ulp")
+        assert kernel == compiled, f"rotate_half: kernel path = {kernel}"
+        assert max(ulps) <= 1.0, ulps
 
 
 # -- (d) -------------------------------------------------------------------

@@ -42,18 +42,10 @@ class FedAvgEngine:
     """Standalone-simulation FedAvg (single device or vmap cohort)."""
 
     def __init__(self, trainer: ClientTrainer, data: FederatedData,
-                 cfg: FedConfig, donate: bool = True,
-                 pallas_agg: bool = False):
+                 cfg: FedConfig, donate: bool = True):
         self.trainer = trainer
         self.data = data
         self.cfg = cfg
-        # opt-in fused aggregation kernel (fedml_tpu/ops); the default XLA
-        # tree-mean is already fused well — the kernel wins when the whole
-        # stack is flattened anyway (robust pipeline) or on very many leaves.
-        # It flattens the cohort to one f32 [C, N] matrix (+ a pad copy):
-        # at the ResNet-18 row (N = 11.2 M) C = 8-10 fits one v5e chip and
-        # C = 128 does not (ops/aggregate.py "Size limit")
-        self.pallas_agg = pallas_agg
         self.donate = donate
         self.sampler = ClientSampler.for_data(data, cfg)
         # donate BOTH the variables and the server state (FedOpt's adam
@@ -81,9 +73,6 @@ class FedAvgEngine:
         """Sample-weighted mean over ALL variable collections (params and
         batch_stats alike), matching the reference's iteration over every
         state_dict key (FedAVGAggregator.py:74-81)."""
-        if self.pallas_agg:
-            from fedml_tpu.ops import weighted_mean_pallas
-            return weighted_mean_pallas(stacked_variables, weights), server_state
         return tree_weighted_mean(stacked_variables, weights), server_state
 
     # ---- one federated round, fully jitted -------------------------------

@@ -49,14 +49,9 @@ class FedAvgRobustEngine(FedAvgEngine):
         params = stacked_variables["params"]
         g = global_variables["params"]
         if self.defense == "norm_clip":
-            if self.pallas_agg:
-                from fedml_tpu.ops import robust_weighted_mean_pallas
-                new_params = robust_weighted_mean_pallas(
-                    params, weights, g, self.cfg.norm_bound)
-            else:
-                clipped = jax.vmap(
-                    lambda p: norm_diff_clip(p, g, self.cfg.norm_bound))(params)
-                new_params = tree_weighted_mean(clipped, weights)
+            clipped = jax.vmap(
+                lambda p: norm_diff_clip(p, g, self.cfg.norm_bound))(params)
+            new_params = tree_weighted_mean(clipped, weights)
             if self.cfg.stddev > 0:
                 new_params = add_weak_dp_noise(new_params, rng, self.cfg.stddev)
         elif self.defense == "krum":

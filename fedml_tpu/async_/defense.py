@@ -12,8 +12,8 @@ scheduler's arrival handler): every uplink row passes, in order,
     2. norm-bound clip    — the update delta (row − global) is clipped
                             to ``norm_bound`` through THE shared
                             clip definition (core/robust.clip_row ==
-                            norm_diff_clip's factor == the pallas
-                            clip-agg's), so a boosted model-replacement
+                            norm_diff_clip's factor), so a boosted
+                            model-replacement
                             contributes at most a clean-sized step;
     3. anomaly screen     — robust z-score of the delta norm against an
                             exponentially-weighted running reference

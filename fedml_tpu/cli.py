@@ -291,8 +291,8 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--defense_norm_bound", type=float, default=None,
                    help="admission clip: client update deltas are "
                         "norm-clipped to this bound at the insert path "
-                        "(the ONE clip definition norm_diff_clip/the "
-                        "pallas clip-agg share)")
+                        "(the ONE clip definition norm_diff_clip "
+                        "shares)")
     p.add_argument("--defense_screen", action="store_true",
                    help="arm the z-score + cosine anomaly screen "
                         "against a running reference of accepted "

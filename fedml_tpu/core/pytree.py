@@ -85,15 +85,13 @@ def clip_scale(sq_norm, max_norm):
     """THE norm-clip factor:  min(1, τ/‖·‖)  from a SQUARED norm, with
     the 1e-24 floor inside the sqrt guarding the zero-update case.
 
-    One definition, three call sites (the ISSUE-9 dedupe): the pytree
-    clip below (→ core/robust.norm_diff_clip), the pallas clip-agg's
-    host-side factor (ops/aggregate.robust_weighted_mean_pallas), and
-    the flat-row admission/DP clip (core/robust.clip_row →
-    async_/defense.py).  They reduce their squared norms differently
-    (tree-sum vs tile-accumulated vs flat dot), so the cross-pin in
-    tests/test_robustness.py holds on the FACTOR given equal sq_norm —
-    routing all three through here is what keeps the DP-FedAvg clip
-    and the admission clip from drifting."""
+    One definition, two call sites (the ISSUE-9 dedupe): the pytree
+    clip below (→ core/robust.norm_diff_clip) and the flat-row
+    admission/DP clip (core/robust.clip_row → async_/defense.py).  They
+    reduce their squared norms differently (tree-sum vs flat dot), so
+    the cross-pin in tests/test_robustness.py holds on the FACTOR given
+    equal sq_norm — routing both through here is what keeps the
+    DP-FedAvg clip and the admission clip from drifting."""
     norm = jnp.sqrt(jnp.maximum(jnp.asarray(sq_norm, jnp.float32), 1e-24))
     return jnp.minimum(1.0, max_norm / norm)
 

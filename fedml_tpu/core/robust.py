@@ -32,8 +32,8 @@ def norm_diff_clip(local_params: Pytree, global_params: Pytree,
                    norm_bound: float) -> Pytree:
     """Clip the update (w_local - w_global) to `norm_bound` and re-apply:
     returns w_global + clip(w_local - w_global).  The clip factor is the
-    ONE shared definition (core/pytree.clip_scale) — the pallas fused
-    clip-agg and the flat-row admission/DP clip use the same one."""
+    ONE shared definition (core/pytree.clip_scale) — the flat-row
+    admission/DP clip uses the same one."""
     diff = tree_sub(local_params, global_params)
     return tree_add(global_params, tree_clip_by_norm(diff, norm_bound))
 

@@ -13,34 +13,17 @@ from functools import partial
 from typing import Sequence
 
 import flax.linen as nn
-import jax
 import jax.numpy as jnp
-
-
-class FusionBarrierGroupNorm(nn.GroupNorm):
-    """GroupNorm that sees its input through `lax.optimization_barrier`:
-    semantically the identity, but it stops XLA from output-fusing the
-    producing convolution with the GN statistics reduces — the dominant
-    cost category of the north-star bench trace (PERF.md round-2b).
-    Opt-in via ResNet18GN(norm_fusion_barrier=True); measured slower on
-    the v5e (PERF.md §6 "Before PR 22"), so no cell turns it on."""
-
-    @nn.compact
-    def __call__(self, x):
-        return super().__call__(jax.lax.optimization_barrier(x))
 
 
 class BasicBlockGN(nn.Module):
     filters: int
     strides: int = 1
     groups: int = 2
-    norm_fusion_barrier: bool = False
 
     @nn.compact
     def __call__(self, x, train: bool = False):
-        gn = (FusionBarrierGroupNorm if self.norm_fusion_barrier
-              else nn.GroupNorm)
-        norm = partial(gn, num_groups=self.groups)
+        norm = partial(nn.GroupNorm, num_groups=self.groups)
         residual = x
         y = nn.Conv(self.filters, (3, 3), strides=(self.strides, self.strides),
                     padding="SAME", use_bias=False)(x)
@@ -61,20 +44,16 @@ class ResNet18GN(nn.Module):
     stage_sizes: Sequence[int] = (2, 2, 2, 2)
     num_filters: int = 64
     groups: int = 2
-    norm_fusion_barrier: bool = False
 
     @nn.compact
     def __call__(self, x, train: bool = False):
-        gn = (FusionBarrierGroupNorm if self.norm_fusion_barrier
-              else nn.GroupNorm)
         x = nn.Conv(self.num_filters, (3, 3), padding="SAME", use_bias=False)(x)
-        x = gn(num_groups=self.groups)(x)
+        x = nn.GroupNorm(num_groups=self.groups)(x)
         x = nn.relu(x)
         for i, n_blocks in enumerate(self.stage_sizes):
             for j in range(n_blocks):
                 strides = 2 if i > 0 and j == 0 else 1
                 x = BasicBlockGN(self.num_filters * (2 ** i), strides,
-                                 self.groups,
-                                 self.norm_fusion_barrier)(x, train)
+                                 self.groups)(x, train)
         x = jnp.mean(x, axis=(1, 2))
         return nn.Dense(self.num_classes)(x)

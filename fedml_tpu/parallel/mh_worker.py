@@ -74,8 +74,10 @@ DEFAULTS = {
 
 
 def _setup_jax(cfg: dict) -> None:
-    """Platform/device-count/compile-cache config — BEFORE any jax
-    backend init (the init_multihost contract).  The multi-process tier
+    """Platform/device-count config — BEFORE any jax backend init (the
+    init_multihost contract).  No persistent compile cache in a rank: one
+    rank loading an entry while its peers compile skews them, and a skewed
+    round can hang (ROADMAP D10).  The multi-process tier
     is host-level and CPU-only today: spawn_cluster_report hands every
     rank JAX_PLATFORMS=cpu explicitly (a parent may hold the chip); the
     setdefault only covers a worker started by hand."""
@@ -85,8 +87,6 @@ def _setup_jax(cfg: dict) -> None:
             os.environ.get("XLA_FLAGS", "")
             + f" --xla_force_host_platform_device_count="
               f"{cfg['local_devices']}")
-    from fedml_tpu.utils import compile_cache
-    compile_cache.configure(min_compile_time_secs=0.5)
 
 
 def build_case(cfg: dict):

@@ -6,9 +6,11 @@ variable itself — this module then sets no directory in code);
 otherwise the cache lives at `<checkout>/.jax_cache`, a fixed path next
 to the package.  Never a temp name, a pid or a timestamp.
 
-Callers: `fedml_tpu.cli.main`, `chip_smoke.py`,
-`parallel/mh_worker.py`, the jax-running scripts under `tools/`, and
-`tests/conftest.py` plus the multihost test workers.
+Callers: `fedml_tpu.cli.main`, `fedbench/run.py`, `chip_smoke.py`, the
+jax-running scripts under `tools/`, and `tests/conftest.py`.  Not the ranks
+of a multi-process cluster (`parallel/mh_worker.py`, the tests' multihost
+workers): a rank that loads an entry while its peers compile falls out of
+step with them.
 """
 from __future__ import annotations
 
@@ -26,7 +28,7 @@ def configure(min_compile_time_secs: Optional[float] = None) -> str:
 
     `min_compile_time_secs` lowers JAX's persist threshold (default
     1 s) for callers that recompile many sub-second programs — the test
-    suite and the spawned multihost workers."""
+    suite."""
     import jax
     if min_compile_time_secs is not None:
         jax.config.update("jax_persistent_cache_min_compile_time_secs",

@@ -14,9 +14,9 @@ from fedml_tpu.utils import compile_cache  # noqa: E402  (after env vars)
 
 # Persistent compilation cache: the suite compiles hundreds of XLA programs
 # (mesh round programs dominate wall-clock); repeat runs hit the disk cache
-# instead of recompiling.  ONE location for the whole test universe — the
-# multihost workers (fresh subprocesses) call the same helper and land in
-# the same directory.  0.1 s threshold: the suite compiles many hundreds of
+# instead of recompiling.  ONE location for the whole test universe, but for
+# the multihost workers: ranks of one cluster keep no persistent cache (a
+# rank that loads while its peers compile falls out of step).  0.1 s threshold: the suite compiles many hundreds of
 # 0.1-0.5 s programs; caching them too trades ~ms of disk lookup for their
 # compile CPU.
 compile_cache.configure(min_compile_time_secs=0.1)

@@ -27,11 +27,8 @@ os.environ["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={ndev}"
 
 import jax  # noqa: E402
 
-# same persistent compile cache as conftest.py — the workers are fresh
-# processes and would otherwise recompile every round program every run
-from fedml_tpu.utils import compile_cache  # noqa: E402
-
-compile_cache.configure(min_compile_time_secs=0.5)
+# no persistent compile cache in a rank: one rank loading an entry while
+# its peers compile skews them, and a skewed round can hang (ROADMAP D10)
 # no explicit gloo config here: on current jaxlib the option already
 # defaults to "gloo"; init_multihost's fallback covers builds where it
 # doesn't (that branch is a no-op in this CI)

@@ -1,9 +1,10 @@
 """chip_smoke.py's plumbing, on the CPU: the no-fallback contract (no
 TPU => non-zero exit and no `"ok": true` line) and every phase function
-driven at a tiny size — pallas kernels in interpret mode, the `--chips
-4` phase on four of the virtual CPU devices.  What only the chip can
-show (Mosaic-compiled kernels, the real widths, times) is the chip
-run's job; tests/test_tpu_compile.py compiles the kernels for it."""
+driven at a tiny size — the ops' plain paths (nothing is lowered for a
+TPU here), the `--chips 4` phase on four of the virtual CPU devices.
+What only the chip can show (Mosaic-compiled kernels, the real widths,
+times) is the chip run's job; tests/test_tpu_compile.py compiles the
+kernels for it."""
 import json
 import os
 import shutil
@@ -20,8 +21,8 @@ import chip_smoke  # noqa: E402
 TINY = chip_smoke.Sizes(
     model="lr", n_clients=8, samples_per_client=16, batch_size=8,
     image_hw=8, warmup_rounds=1, timed_rounds=2, oracle_clients=4,
-    agg_clients=4, gn_shapes=((8, 4, 4, 16),),
-    attn_shapes=((1, 128, 2, 1, 64),), platform="cpu")
+    attn_shapes=((1, 128, 2, 1, 64),), rotary_shapes=((1, 128, 2, 128),),
+    platform="cpu")
 
 
 def _lines(capsys) -> list:
@@ -82,18 +83,20 @@ def test_phase_oracle_tiny(capsys):
 def test_phase_kernels_tiny_interpret_mode(capsys):
     chip_smoke.phase_kernels(TINY, seed=0)
     lines = _lines(capsys)
-    ops = [l["op"] for l in lines]
-    assert ops == ["weighted_mean_pallas", "robust_weighted_mean_pallas",
-                   "group_norm", "group_norm", "causal_attention"]
+    assert [l["op"] for l in lines] == ["causal_attention", "rotate_half"]
     assert not any(l["compiled"] for l in lines)   # no Mosaic on the CPU
+    assert lines[1]["max_bf16_ulps_y_dx"] == [0.0, 0.0]   # the plain body
 
 
-def test_phase_kernels_demands_the_compiled_path_on_tpu():
+@pytest.mark.parametrize("op, others", [
+    ("causal_attention", "rotary_shapes"), ("rotate_half", "attn_shapes")])
+def test_phase_kernels_demands_the_compiled_path_on_tpu(op, others):
     """On a TPU the kernel path is asserted, never assumed: a run that
-    claims the platform but lowers no tpu_custom_call fails."""
-    with pytest.raises(AssertionError, match="kernel path taken"):
-        chip_smoke.phase_kernels(
-            chip_smoke.dataclasses.replace(TINY, platform="tpu"), seed=0)
+    claims the platform but lowers no tpu_custom_call fails, at either op
+    (the other one's shapes taken out, so that this one is reached)."""
+    with pytest.raises(AssertionError, match=f"{op}: kernel path"):
+        chip_smoke.phase_kernels(chip_smoke.dataclasses.replace(
+            TINY, platform="tpu", **{others: ()}), seed=0)
 
 
 def test_phase_cli_round_trip(capsys):

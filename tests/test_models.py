@@ -90,23 +90,3 @@ def test_efficientnet_variant_scaling():
 def test_factory_rejects_unknown():
     with pytest.raises(ValueError):
         create_model("no_such_model", 10)
-
-
-def test_resnet18_gn_fusion_barrier_is_identity():
-    """norm_fusion_barrier only changes XLA fusion decisions, never math:
-    same rng init must give identical params (module structure apart from
-    the GN class name is unchanged) and identical logits."""
-    import numpy as np
-    x = jnp.asarray(np.random.RandomState(0).rand(2, 32, 32, 3),
-                    jnp.float32)
-    plain = create_model("resnet18_gn", 10)
-    barrier = create_model("resnet18_gn", 10, norm_fusion_barrier=True)
-    vp = plain.init(jax.random.PRNGKey(0), x, train=False)
-    vb = barrier.init(jax.random.PRNGKey(0), x, train=False)
-    for a, b in zip(jax.tree.leaves(vp), jax.tree.leaves(vb)):
-        assert a.shape == b.shape
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    lp = plain.apply(vp, x, train=False)
-    lb = barrier.apply(vb, x, train=False)
-    np.testing.assert_allclose(np.asarray(lp), np.asarray(lb),
-                               rtol=1e-6, atol=1e-6)

@@ -936,9 +936,12 @@ def test_elastic_kill_respawn_bitwise_pin(tmp_path):
     (md5-over-leaf-bytes) to the clean same-partition run — FedAvg
     resident AND streaming, on every rank including the rejoiner.
     round_sleep_s paces the run so the respawn (a fresh jax boot)
-    rejoins deterministically inside the first (streaming) run."""
+    rejoins deterministically inside the first (streaming) run: the
+    four rounds after the kill hold it open for 12 s, and a rank boots
+    in 4-5 s on an idle box (at 0.9 s a round the window was 5 s, and
+    the rejoin landed in the resident run every other time)."""
     cfg = {**MH_ELASTIC_CLEAN, "die_rank": 1,
-           "die_at_round": 0, "round_sleep_s": 0.9,
+           "die_at_round": 0, "round_sleep_s": 3.0,
            "round_sleep_mode": "streaming",
            "hb_timeout_s": 1.5, "channel_timeout_s": 60}
     cleanb, r0b = _run_launcher(1, MH_ELASTIC_CLEAN, tmp_path)

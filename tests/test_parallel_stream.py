@@ -20,11 +20,26 @@ from fedml_tpu.utils.config import FedConfig
 from parallel_case import _mnist_like_cfg, _setup, run_donate_pair
 
 
+def _buffers(a):
+    """The addresses of a live array's buffers, one a shard; none where the
+    array was deleted after `jax.live_arrays()` listed it."""
+    try:
+        return tuple(s.data.unsafe_buffer_pointer()
+                     for s in a.addressable_shards)
+    except RuntimeError:
+        return ()
+
+
 def _live_bytes():
-    """Total bytes across all live device arrays — the one accounting
-    every memory-bound test in this file shares."""
-    return sum(int(np.prod(a.shape)) * a.dtype.itemsize
-               for a in jax.live_arrays())
+    """Total bytes across all live device arrays, each buffer once — the
+    one accounting every memory-bound test in this file shares.
+    `np.asarray` of a sharded array leaves single-device views of its
+    shards alive until the array is deleted, and the prefetch thread can
+    sample in between: a view is its array's buffer, not more memory."""
+    held = [(a.nbytes, _buffers(a)) for a in jax.live_arrays()]
+    shards = {p for _, ptrs in held if len(ptrs) > 1 for p in ptrs}
+    return sum(n for n, ptrs in held
+               if len(ptrs) > 1 or ptrs and ptrs[0] not in shards)
 
 
 def _spy_live_bytes(obj, attr, peaks):

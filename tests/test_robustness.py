@@ -17,7 +17,7 @@ Anchors, in order of importance:
   designated attack and never an honest update (the false-positive
   gate).
 * One norm-clip definition: core/pytree.clip_scale is the factor for
-  norm_diff_clip, the pallas clip-agg AND the flat-row clip — pinned
+  norm_diff_clip AND the flat-row clip — pinned
   bitwise on equal inputs, so DP-FedAvg and admission clipping cannot
   drift.
 * Quality bands: attacked-undefended degrades below the clean band
@@ -64,7 +64,7 @@ def _assert_trees_bitwise(a, b):
 
 class TestOneClipDefinition:
     def test_clip_scale_is_the_shared_factor_bitwise(self):
-        """All three clip call sites reduce to core/pytree.clip_scale:
+        """Both clip call sites reduce to core/pytree.clip_scale:
         fed the SAME squared norm, the factors are bit-identical (they
         are literally one function), and each path's end-to-end clip
         agrees with factor * input."""

@@ -5,15 +5,15 @@ chip that is described, not attached (`topologies.get_topology_desc`,
 the `on-chip-measurement` guide §2).  Interpret mode cannot show what
 Mosaic refuses — a slice not aligned to the tiling, too much VMEM, a
 program that does not fit 16 GB of HBM — so the pallas kernels of
-`chip_smoke.py` phase (c) are compiled here at their real widths, and
-`-m slow` adds the (32,32,32,64) GroupNorm pair, the whole headline
+`chip_smoke.py` phase (c) are compiled here at their real widths, beside
+the server's aggregation at the ResNet-18 row (plain XLA: it holds no
+kernel, and 128 clients fit one chip), and `-m slow` adds the whole headline
 round program on one and on four described chips, the resident round of
 the benchmark's `xdev10of4000` cell (10 of 4,000 clients: the take reads
 the cohort, not the stack), of `so_nwp_lstm` at its published 342,477
 clients (and, at the cell's traffic, what the LSTM's backward time loop
 carries and where its batch loop ends) and of `ouro_2p6b` (0.51 B parameters, with the float32 twin the
-reference check runs), and the documented C = 128 size limit of the
-fused robust aggregation.
+reference check runs).
 
 The fused causal attention (`ops/attention.py`, ISSUE 35) is compiled at
 both language-model cells' shapes, and with `-m slow` both cells' rounds
@@ -77,14 +77,11 @@ from jax.experimental import topologies
 from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
 
-from fedml_tpu.ops import aggregate, groupnorm
-
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 import chip_smoke  # noqa: E402  (its sizes are the chip's; imports no jax)
 
 RESNET18_N = 11_173_962                         # ResNet-18-GN, 10 classes
-N_PADDED = RESNET18_N + (-RESNET18_N) % aggregate.TILE
 
 
 @pytest.fixture(scope="module")
@@ -117,74 +114,51 @@ def _assert_kernels(compiled, n: int):
     assert compiled.as_text().count("tpu_custom_call") >= n
 
 
-# -- aggregation: what FedAvgEngine(pallas_agg=True) / FedAvgRobustEngine
-#    see for an 8- or 10-client cohort of ResNet-18 rows -------------------
+# -- the server's aggregation at the ResNet-18 row ---------------------------
 
-@pytest.mark.parametrize("C", [8, 10])
-def test_wmean_flat_compiles(topo, C):
-    c = _compile(topo, lambda f, w: aggregate._wmean_flat(f, w, False),
-                 ((C, N_PADDED), jnp.float32), ((C,), jnp.float32))
-    _assert_kernels(c, 1)
-
-
-@pytest.mark.parametrize("C", [8, 10])
-def test_robust_passes_compile(topo, C):
-    """Both robust passes (per-client ‖x−g‖², then the clipped
-    reduction) on a single-leaf tree already at the padded width."""
-    def f(flat, w, g):
-        return aggregate.robust_weighted_mean_pallas(
-            {"w": flat}, w, {"w": g}, 5.0, interpret=False)
-    c = _compile(topo, f, ((C, N_PADDED), jnp.float32),
-                 ((C,), jnp.float32), ((N_PADDED,), jnp.float32))
-    _assert_kernels(c, 2)
-
-
-# -- fused GroupNorm at the ResNet-18 CIFAR stage shapes -------------------
-
-GN_FAST = [(32, 8, 8, 256), (32, 4, 4, 512)]
-GN_SLOW = [(32, 32, 32, 64)]
-G = 8              # group_norm's default (the model's own GroupNorm uses 2)
+@pytest.fixture(scope="module")
+def aggregates():
+    """`aggregate` of FedAvgEngine (`tree_weighted_mean`) and of
+    FedAvgRobustEngine under its default defense (`vmap(norm_diff_clip)`,
+    then `tree_weighted_mean`), and ResNet-18-GN's parameter shapes."""
+    from fedml_tpu.algorithms import FedAvgEngine, FedAvgRobustEngine
+    from fedml_tpu.core.trainer import ClientTrainer
+    from fedml_tpu.models import create_model
+    from fedml_tpu.utils.config import FedConfig
+    from tests.test_fednas import tiny_data
+    cfg = FedConfig(client_num_in_total=2, client_num_per_round=2,
+                    comm_round=1, batch_size=2)
+    trainer, data = ClientTrainer(create_model("lr", 10)), tiny_data()
+    engines = {"mean": FedAvgEngine(trainer, data, cfg),
+               "clip_then_mean": FedAvgRobustEngine(trainer, data, cfg)}
+    params = jax.eval_shape(lambda: create_model("resnet18_gn", 10).init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 32, 32, 3)), train=False))
+    assert sum(a.size for a in jax.tree.leaves(params)) == RESNET18_N
+    return engines, params
 
 
-def _gn_fwd_case(topo, shape, dtype):
-    assert groupnorm._kernel_supports(shape, G)
-    Cc = shape[-1]
-    c = _compile(
-        topo, lambda x, g, b: groupnorm._pallas_fwd(x, g, b, G, 1e-5),
-        (shape, dtype), ((Cc,), jnp.float32), ((Cc,), jnp.float32))
-    _assert_kernels(c, 1)
-
-
-def _gn_dx_case(topo, shape, dtype):
-    Cc, N = shape[-1], shape[0]
-    c = _compile(
-        topo,
-        lambda x, dy, g, m, r: groupnorm._pallas_dx(x, dy, g, m, r, G, 1e-5),
-        (shape, dtype), (shape, dtype), ((Cc,), jnp.float32),
-        ((N, G), jnp.float32), ((N, G), jnp.float32))
-    _assert_kernels(c, 1)
-
-
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
-                         ids=["f32", "bf16"])
-@pytest.mark.parametrize("shape", GN_FAST, ids=str)
-def test_groupnorm_fwd_compiles(topo, shape, dtype):
-    _gn_fwd_case(topo, shape, dtype)
-
-
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
-                         ids=["f32", "bf16"])
-@pytest.mark.parametrize("shape", GN_FAST, ids=str)
-def test_groupnorm_dx_compiles(topo, shape, dtype):
-    _gn_dx_case(topo, shape, dtype)
-
-
-@pytest.mark.slow
-@pytest.mark.parametrize("case", [_gn_fwd_case, _gn_dx_case],
-                         ids=["fwd", "dx"])
-@pytest.mark.parametrize("shape", GN_SLOW, ids=str)
-def test_groupnorm_large_stage_compiles(topo, shape, case):
-    case(topo, shape, jnp.float32)
+@pytest.mark.parametrize("C", [8, 10, 128])
+@pytest.mark.parametrize("engine", ["mean", "clip_then_mean"])
+def test_aggregation_compiles_and_fits_one_chip(topo, aggregates, engine, C):
+    """The aggregation of a C-client cohort of ResNet-18 rows, as the
+    single-device engines trace it: XLA's own fusions (no kernel call), and
+    at C = 128 - 5.72 GB of stacked float32 arguments, the size at which the
+    Pallas clip-aggregate this replaced was refused for its `[C, N]` matrix
+    and pad copy - the program fits one chip."""
+    engines, params = aggregates
+    chip = SingleDeviceSharding(topo.devices[0])
+    struct = lambda lead: jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        lead + a.shape, a.dtype, sharding=chip), params)
+    weights = jax.ShapeDtypeStruct((C,), jnp.float32, sharding=chip)
+    rng = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=chip)
+    c = jax.jit(lambda s, w, g, r: engines[engine].aggregate(
+        s, w, g, (), r)[0]).lower(
+            struct((C,)), weights, struct(()), rng).compile()
+    assert "tpu_custom_call" not in c.as_text()
+    mem = c.memory_analysis()
+    assert mem.argument_size_in_bytes >= C * RESNET18_N * 4
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.generated_code_size_in_bytes) < 15.75 * 2 ** 30, mem
 
 
 # -- fused causal attention at the two language-model cells' shapes ---------
@@ -311,26 +285,6 @@ def test_rotate_half_compiles(topo, shape, dtype):
     _assert_kernels(c, 2)
     if dtype == jnp.bfloat16:
         assert not re.search(rf"f32\[{B},{T},({H},{hd}|{H * hd})\]", c.as_text())
-
-
-# -- the documented size limit --------------------------------------------
-
-@pytest.mark.slow
-def test_robust_c128_exceeds_one_chip(topo):
-    """C = 128 ResNet-18 rows do not fit one v5e chip through the fused
-    robust op (ops/aggregate.py "Size limit"): the compiler refuses."""
-    params = {"a": ((3, 3, 64, 64), jnp.float32),
-              "b": ((RESNET18_N - 3 * 3 * 64 * 64,), jnp.float32)}
-    chip = SingleDeviceSharding(topo.devices[0])
-    stacked = {k: jax.ShapeDtypeStruct((128,) + s, d, sharding=chip)
-               for k, (s, d) in params.items()}
-    g = {k: jax.ShapeDtypeStruct(s, d, sharding=chip)
-         for k, (s, d) in params.items()}
-    w = jax.ShapeDtypeStruct((128,), jnp.float32, sharding=chip)
-    f = jax.jit(lambda s, w, g: aggregate.robust_weighted_mean_pallas(
-        s, w, g, 5.0, interpret=False))
-    with pytest.raises(Exception, match="RESOURCE_EXHAUSTED"):
-        f.lower(stacked, w, g).compile()
 
 
 # -- the whole headline round program --------------------------------------
