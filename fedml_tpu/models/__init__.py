@@ -66,6 +66,14 @@ def create_model(model_name: str, output_dim: int, input_dim: int | None = None,
         from fedml_tpu.models.cohere2_moe import Cohere2MoeLM
         kw = {k: tuple(v) if isinstance(v, list) else v for k, v in kw.items()}
         return Cohere2MoeLM(vocab_size=output_dim, **kw)
+    if name == "xing4":
+        # four residual streams mixed by manifold-constrained hyper-connections
+        # around latent attention and bias-selected sigmoid-routed experts,
+        # adapters over a frozen base (Xing4.0's family, models/xing4.py);
+        # every width is a keyword
+        from fedml_tpu.models.xing4 import Xing4LM
+        kw = {k: tuple(v) if isinstance(v, list) else v for k, v in kw.items()}
+        return Xing4LM(vocab_size=output_dim, **kw)
     if name in ("resnet18_gn", "resnet18"):
         return ResNet18GN(num_classes=output_dim, **kw)
     if name == "resnet56":

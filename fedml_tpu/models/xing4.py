@@ -1,0 +1,407 @@
+"""Xing4.0 — a decoder LM whose residual path is ``hc_mult`` streams mixed by
+manifold-constrained hyper-connections, around multi-head latent attention
+and bias-selected sigmoid-routed sparse experts beside a shared one, with
+low-rank adapters over a frozen base.
+
+The family of Xing4.0-29B-A4B (``model_type`` "xing4_0"): no layer is
+``h + f(h)``.  A token carries ``n`` streams; each sublayer (attention, then
+the dense MLP of the first ``first_dense`` layers or the experts of every
+later one) reads a per-token mixture of them and writes back through a
+per-token doubly-stochastic ``n x n`` matrix, made by ``sinkhorn_iters``
+alternating normalisations (mHC, arXiv:2512.24880, on hyper-connections,
+arXiv:2409.19606).  Every width, the number of streams and of iterations, the
+layers and the experts held here and the adapter rank are constructor
+arguments; a benchmark configuration carries a published model's.
+
+    streams:  X_0 = (E[x], ..., E[x])                X_l in R^{n x C} a token
+    hyper-connection around a sublayer F (own maps each):
+      z       = vec(X) * rsqrt(mean(vec(X)^2) + eps)          float32, no weight
+      Ht_pre  = a_pre  (z Phi_pre)  + b_pre          Phi_pre, Phi_post [nC, n]
+      Ht_post = a_post (z Phi_post) + b_post         Phi_res [nC, n^2]
+      Ht_res  = a_res  mat(z Phi_res) + b_res        a_* scalars
+      H_pre   = sigmoid(Ht_pre);  H_post = 2 sigmoid(Ht_post)
+      H_res   = Sinkhorn(Ht_res):  M = exp(clip(Ht_res, clamp_min, clamp_max))
+                ``sinkhorn_iters`` times:  M = M / (colsum(M) + hc_eps)
+                                           M = M / (rowsum(M) + hc_eps)
+      u       = sum_i H_pre[i] X[i]                  the sublayer's input
+      X'[i]   = sum_j H_res[i, j] X[j] + H_post[i] F(u)
+    F_attn(u) = `deepseek_v2.latent_attention` (its own input norm; the
+                softmax scale carries YaRN's mscale^2 as there)
+    F_mlp(u)  = gated_mlp(RMSNorm_post(u))           layers < first_dense
+    F_moe(u)  = f = RMSNorm_post(u);  `lfm2_moe.route`: s = sigmoid(f W_r)
+                float32 over all experts, sel = top_k(s + b_e) (b_e selects
+                only), g = s[sel] / (sum s[sel] + 1e-6) * routed_scaling_factor
+                sum_{e in sel, e held} g_e expert_e(f)  +  shared(f)
+    model:    logits = RMSNorm_out(sum_i X_L[i]) W_head        (untied)
+    adapter:  y = x W + (alpha / r) (x A) B   on W_qa, W_qb, W_kva, W_kvb,
+              W_o of every held layer;  A ~ N(0, 1 / d_in), B = 0
+
+``__call__`` returns float32 logits [B, T, vocab] — the trainer's contract.
+
+How it is built for a chip (what it shares with models/deepseek_v2.py and
+models/lfm2_moe.py is imported from there, not copied):
+
+* the base — the hyper-connection maps ``Phi``, ``b``, ``a`` included — is
+  frozen and stored in ``base_dtype``; only the adapters train.
+* **the streams of a token lie side by side in the lanes**: X is
+  ``[B, T, n C]``, stream i the columns ``i C .. (i + 1) C`` (a ``[B, T, n,
+  C]`` array would put ``n`` = 4 on the sublanes, which an (8, 128) or
+  (16, 128) tile pads 2-4 x).  The ``n (n + 2)``-wide projection then reads X
+  once for all three maps, as it lies; the RMS is a scalar a token, so it
+  scales the projection's result and ``z`` is never written.
+* **the Sinkhorn loop keeps tokens in the minor dimension**: the
+  ``[B, T, n (n + 2)]`` projection is turned once to ``[n (n + 2), B, T]``,
+  the ``2 x sinkhorn_iters`` normalisations are elementwise passes over full
+  lanes, unrolled, and the three maps are turned back to ``[B, T, .]`` for
+  the mixing, where a coefficient is one value a token across the lanes.
+* the mixing is elementwise in float32 on lane slices of X, one result cast
+  to the stream's dtype; the attention core, the expert product and the
+  router are the other models' (`causal_attention` in its two-part form,
+  `held_share`, `route`).
+* every layer is a ``jax.checkpoint``, layers unrolled.  **Where the stream
+  is 16 bits wide** it keeps, beside its input X, ``KEPT_NAMES``:
+  `deepseek_v2.KEPT_NAMES` (the attention kernel's output and log-sum-exp,
+  ``W_o``'s adapted output) **and the second sublayer's output**
+  (``mlp_out``).  A hyper-connection's backward pass reads ``F(u)`` itself
+  (``dH_post[i] = dX'[i] . F(u)``), so a sublayer's output is either kept or
+  re-made: ``attn_out`` is the first sublayer's, and without ``mlp_out`` the
+  whole expert product (four grouped-product kernels a layer), the shared
+  expert's and the dense MLP's last product ran again for it.  Measured on
+  the v5e at Xing4.0-29B-A4B's widths on 8,192 bfloat16 tokens, ten layers
+  (PERF.md section 6, PR 45): 7.80 s a round with it, 8.33 with
+  `deepseek_v2.KEPT_NAMES` alone, 9.00 keeping the input alone; 58.7 MB a
+  layer named, 3.3 GB more in the compiler's count of the round (15.08e9 B
+  of 16.91e9).  **A float32 stream** (the twin the benchmark's reference
+  check runs) keeps a layer's input alone.  The stream's width is the whole
+  rule, no option; counted in ``remat_policy_total{model="xing4"}`` /
+  ``remat_saved_bytes``.
+* scopes (obs/scopes.py): ``fed_hc_maps`` holds the RMS, the projection, the
+  sigmoids and the Sinkhorn loop, ``fed_hc_mix`` the read ``u`` and the
+  write ``X'``; the sublayers keep the labels they have in the other models.
+* counters: the router's, as in lfm2_moe, and ``hc_sinkhorn_err``
+  ``[held layers, 2 sublayers, 2]`` — a step's largest ``|rowsum(H_res) - 1|``
+  and ``|colsum(H_res) - 1|`` over its tokens after the last iteration (the
+  last pass normalises rows, so the first reads rounding).
+
+Initial values of the maps (the catalog row gives none): ``Phi`` ~
+N(0, ``init_std``), ``a`` = `HC_GATE`, ``b_pre`` = -ln(n - 1) (sigmoid = 1 /
+n), ``b_post`` = 0 (2 sigmoid = 1), ``b_res`` = `HC_RES_DIAG` x I — a fresh
+model reads H_pre near 1 / n, H_post near 1 and H_res near the identity, and
+the per-token part still moves every map.
+"""
+from __future__ import annotations
+
+import math
+from typing import Any, Optional
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.ad_checkpoint import checkpoint_name
+
+from fedml_tpu import obs
+from fedml_tpu.models import deepseek_v2
+from fedml_tpu.models.deepseek_v2 import (latent_attention, yarn_mscale,
+                                          yarn_tables)
+from fedml_tpu.models.lfm2_moe import (_Groups, _Leaves, counter_shapes,
+                                       float_counters, gated_mlp, held_share,
+                                       route, sow_counters)
+from fedml_tpu.models.looped_lm import _dot, rms_norm
+from fedml_tpu.obs import scopes
+
+# what a layer's checkpoint keeps beside its input where the stream is 16
+# bits wide (module docstring): deepseek_v2's set - the attention kernel's
+# output and log-sum-exp, ``W_o``'s adapted output (the first sublayer's F(u))
+# - and the second sublayer's F(u), as `block` names it
+KEPT_NAMES = deepseek_v2.KEPT_NAMES + ("mlp_out",)
+# made once: a jaxpr prints its checkpoint's policy by identity
+_KEEP = jax.checkpoint_policies.save_only_these_names(*KEPT_NAMES)
+# the maps' initial values (module docstring)
+HC_GATE = 0.1
+HC_RES_DIAG = 4.0
+SUBLAYERS = ("attn", "mlp")
+
+
+def sinkhorn(ht_res, iters: int, hc_eps: float, clamp):
+    """Ht_res [n, n, ...] (row, column, then anything) -> M of the same
+    shape: exp of the clamped entries, then ``iters`` times columns
+    normalised, then rows."""
+    m = jnp.exp(jnp.clip(ht_res, *clamp))
+    for _ in range(iters):
+        m = m / (jnp.sum(m, axis=0, keepdims=True) + hc_eps)
+        m = m / (jnp.sum(m, axis=1, keepdims=True) + hc_eps)
+    return m
+
+
+def hc_maps(X, hp, n: int, norm_eps: float, iters: int, hc_eps: float, clamp):
+    """The three maps of one hyper-connection for the streams X [B, T, n C]:
+    (H_pre [B, T, n], H_post [B, T, n], H_res [B, T, n n] — entry
+    ``i n + j`` is row i, column j — all float32; err [2]: the largest
+    |rowsum(H_res) - 1| and |colsum(H_res) - 1| over the tokens)."""
+    with jax.named_scope(scopes.FED_HC_MAPS):
+        x32 = X.astype(jnp.float32)
+        r = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + norm_eps)
+        f32 = lambda name: hp[name].astype(jnp.float32)
+        gate = f32("a")[np.repeat(np.arange(3), [n, n, n * n])]
+        ht = gate * (_dot(X, hp["phi"].astype(X.dtype)) * r) + f32("b")
+        ht = jnp.moveaxis(ht, -1, 0)                     # tokens to the lanes
+        pre, post = jax.nn.sigmoid(ht[:n]), 2.0 * jax.nn.sigmoid(ht[n:2 * n])
+        res = sinkhorn(ht[2 * n:].reshape((n, n) + ht.shape[1:]), iters,
+                       hc_eps, clamp)
+        off = lambda axis: jnp.max(jnp.abs(jnp.sum(res, axis=axis) - 1.0))
+        err = jax.lax.stop_gradient(jnp.stack([off(1), off(0)]))
+        back = lambda a: jnp.moveaxis(a.reshape((-1,) + ht.shape[1:]), 0, -1)
+        return back(pre), back(post), back(res), err
+
+
+def _streams(X, n: int):
+    C = X.shape[-1] // n
+    return [X[..., i * C:(i + 1) * C] for i in range(n)]
+
+
+def hc_read(X, pre):
+    """u = sum_i H_pre[i] X[i]: [B, T, C] in X's dtype."""
+    n = pre.shape[-1]
+    with jax.named_scope(scopes.FED_HC_MIX):
+        u = sum(pre[..., i:i + 1] * x.astype(jnp.float32)
+                for i, x in enumerate(_streams(X, n)))
+        return u.astype(X.dtype)
+
+
+def hc_write(X, y, post, res):
+    """X'[i] = sum_j H_res[i, j] X[j] + H_post[i] y: [B, T, n C]."""
+    n = post.shape[-1]
+    with jax.named_scope(scopes.FED_HC_MIX):
+        xs = [x.astype(jnp.float32) for x in _streams(X, n)]
+        y32 = y.astype(jnp.float32)
+        rows = [post[..., i:i + 1] * y32
+                + sum(res[..., i * n + j:i * n + j + 1] * xs[j]
+                      for j in range(n)) for i in range(n)]
+        return jnp.concatenate(rows, axis=-1).astype(X.dtype)
+
+
+def hyper_connected(X, hp, F, *, n: int, norm_eps: float, iters: int,
+                    hc_eps: float, clamp):
+    """(X', F's second result, err): the sublayer ``F(u) -> (y, aux)`` under
+    its hyper-connection ``hp`` = {phi, b, a} on the streams X."""
+    pre, post, res, err = hc_maps(X, hp, n, norm_eps, iters, hc_eps, clamp)
+    y, aux = F(hc_read(X, pre))
+    return hc_write(X, y, post, res), aux, err
+
+
+def moe_layer(f, lp, k: int, scaling: float, held):
+    """(m, the layer's counters: `lfm2_moe.held_share`) of one expert layer
+    for f [..., d]; ``lp``: router, expert_bias, the experts HELD (``held`` =
+    (first, how many)) and the shared expert(s) as one gated MLP."""
+    rows = f.reshape((-1, f.shape[-1]))
+    with jax.named_scope(scopes.FED_MOE_ROUTER):
+        sel, gate = route(rows, lp["router"], lp["expert_bias"], k, scaling)
+    m, counts = held_share(rows, sel, gate, lp, *held)
+    with jax.named_scope(scopes.FED_SHARED_EXPERT):
+        m = m + gated_mlp(rows, lp["s1"], lp["s3"], lp["s2"])
+    return m.reshape(f.shape), float_counters(counts)
+
+
+def block(X, lp, ad, cos, sin, *, hc: dict, n_heads: int, nope: int,
+          v_dim: int, softmax_scale: float, adapter_scale: float, eps: float,
+          experts_per_token: int, scaling: float, held):
+    """One layer on the streams X [B, T, n C] -> (X', the expert layer's
+    counters or None, the two sublayers' Sinkhorn errors [2, 2]); ``hc``:
+    `hyper_connected`'s keywords.  A layer whose leaves hold a router
+    carries experts, any other the dense MLP."""
+    maps = lambda s: {k: lp[f"hc_{s}_{k}"] for k in ("phi", "b", "a")}
+
+    def attention(u):
+        return latent_attention(u, lp, ad, adapter_scale, eps, cos, sin,
+                                n_heads, nope, v_dim, softmax_scale), None
+
+    def mlp(u):
+        if "router" in lp:
+            with jax.named_scope(scopes.FED_MOE_ROUTER):
+                f = rms_norm(u, lp["post_norm"], eps)
+            y, counts = moe_layer(f, lp, experts_per_token, scaling, held)
+        else:
+            with jax.named_scope(scopes.FED_MLP):
+                f = rms_norm(u, lp["post_norm"], eps)
+                y, counts = gated_mlp(f, lp["w1"], lp["w3"], lp["w2"]), None
+        return checkpoint_name(y, "mlp_out"), counts      # `KEPT_NAMES`
+
+    X, _, err_a = hyper_connected(X, maps("attn"), attention, **hc)
+    X, counts, err_m = hyper_connected(X, maps("mlp"), mlp, **hc)
+    return X, counts, jnp.stack([err_a, err_m])
+
+
+class Xing4LM(nn.Module):
+    """tokens [B, T] int -> float32 logits [B, T, vocab]."""
+    vocab_size: int
+    d_model: int = 64
+    n_streams: int = 4                    # hc_mult
+    sinkhorn_iters: int = 20              # hc_sinkhorn_iters
+    hc_eps: float = 1e-6
+    res_clamp: tuple = (-30.0, 30.0)      # mhc_h_res_clamp_min / _max
+    n_heads: int = 4
+    q_rank: int = 24                      # q_lora_rank
+    kv_rank: int = 16                     # kv_lora_rank
+    nope_dim: int = 16                    # qk_nope_head_dim
+    rope_dim: int = 8                     # qk_rope_head_dim
+    v_dim: int = 12                       # v_head_dim
+    d_ff: int = 96                        # the dense layers' MLP width
+    d_expert: int = 32
+    n_experts: int = 16
+    experts_per_token: int = 4
+    n_shared: int = 1
+    n_layers: int = 3
+    first_dense: int = 1                  # first_k_dense_replace
+    layers: Optional[tuple] = None        # ids of the layers held; None = all
+    held: Optional[tuple] = None          # (first expert, how many); None = all
+    rope_theta: float = 1e4
+    rope_factor: float = 64.0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_original: int = 4096
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 1.0
+    norm_eps: float = 1e-6
+    routed_scaling_factor: float = 2.0
+    lora_rank: int = 4
+    lora_alpha: float = 8.0
+    init_std: float = 0.02
+    base_dtype: Any = jnp.bfloat16
+
+    # what local training updates, as path prefixes under ``params``;
+    # every other leaf is frozen (core/trainer.py reads both names)
+    trainable = ("lora",)
+    loss_scope = scopes.FED_LM_HEAD
+
+    @property
+    def held_layers(self) -> tuple:
+        return (tuple(range(self.n_layers)) if self.layers is None
+                else tuple(self.layers))
+
+    @property
+    def expert_layers(self) -> tuple:
+        return tuple(i for i in self.held_layers if i >= self.first_dense)
+
+    @property
+    def held_experts(self) -> tuple:
+        return (0, self.n_experts) if self.held is None else tuple(self.held)
+
+    @property
+    def counters(self) -> dict:
+        return {**counter_shapes(len(self.expert_layers), self.n_experts),
+                scopes.HC_SINKHORN_ERR: (len(self.held_layers),
+                                         len(SUBLAYERS), 2)}
+
+    @property
+    def softmax_scale(self) -> float:
+        m = yarn_mscale(self.rope_factor, self.rope_mscale_all_dim)
+        return (self.nope_dim + self.rope_dim) ** -0.5 * m * m
+
+    def _hc_specs(self):
+        """The leaves of a layer's two hyper-connections."""
+        n, bt = self.n_streams, self.base_dtype
+        k = n * (n + 2)
+
+        def b_init(key, shape, dtype):
+            return jnp.concatenate([
+                jnp.full((n,), -math.log(n - 1.0)), jnp.zeros((n,)),
+                HC_RES_DIAG * jnp.eye(n).reshape(-1)]).astype(dtype)
+
+        specs = []
+        for s in SUBLAYERS:
+            specs += [(f"hc_{s}_phi", (n * self.d_model, k),
+                       nn.initializers.normal(self.init_std), bt),
+                      (f"hc_{s}_b", (k,), b_init, bt),
+                      (f"hc_{s}_a", (3,), nn.initializers.constant(HC_GATE), bt)]
+        return specs
+
+    def _specs(self, i: int):
+        """(base, adapter) leaf specs of layer i."""
+        d, H, bt = self.d_model, self.n_heads, self.base_dtype
+        normal, ones = nn.initializers.normal(self.init_std), nn.initializers.ones
+        mats = {"wq_a": (d, self.q_rank),
+                "wq_b": (self.q_rank, H * (self.nope_dim + self.rope_dim)),
+                "wkv_a": (d, self.kv_rank + self.rope_dim),
+                "wkv_b": (self.kv_rank, H * (self.nope_dim + self.v_dim)),
+                "wo": (H * self.v_dim, d)}
+        base = [(n, s, normal, bt) for n, s in mats.items()]
+        base += [(n, (w,), ones, bt) for n, w in (
+            ("in_norm", d), ("q_norm", self.q_rank),
+            ("kv_norm", self.kv_rank), ("post_norm", d))]
+        base += self._hc_specs()
+        if i < self.first_dense:
+            widths = {"w1": (d, self.d_ff), "w3": (d, self.d_ff),
+                      "w2": (self.d_ff, d)}
+        else:
+            e, w = self.held_experts[1], self.d_expert
+            s = self.n_shared * w
+            widths = {"router": (d, self.n_experts),
+                      "w1": (e, d, w), "w3": (e, d, w), "w2": (e, w, d),
+                      "s1": (d, s), "s3": (d, s), "s2": (s, d)}
+            base.append(("expert_bias", (self.n_experts,),
+                         nn.initializers.zeros, bt))
+        base += [(n, s, normal, bt) for n, s in widths.items()]
+        r = self.lora_rank
+        adapters = []
+        for n, (d_in, d_out) in mats.items():
+            adapters += [
+                (n + "_a", (d_in, r), nn.initializers.normal(d_in ** -0.5), jnp.float32),
+                (n + "_b", (r, d_out), nn.initializers.zeros, jnp.float32)]
+        return tuple(base), tuple(adapters)
+
+    def _layer(self, X, lp, ad, cos, sin):
+        return block(
+            X, lp, ad, cos, sin,
+            hc=dict(n=self.n_streams, norm_eps=self.norm_eps,
+                    iters=self.sinkhorn_iters, hc_eps=self.hc_eps,
+                    clamp=tuple(self.res_clamp)),
+            n_heads=self.n_heads, nope=self.nope_dim, v_dim=self.v_dim,
+            softmax_scale=self.softmax_scale,
+            adapter_scale=self.lora_alpha / self.lora_rank, eps=self.norm_eps,
+            experts_per_token=self.experts_per_token,
+            scaling=self.routed_scaling_factor, held=self.held_experts)
+
+    @nn.compact
+    def __call__(self, x, train: bool = False):
+        normal = nn.initializers.normal(self.init_std)
+        shape = (self.vocab_size, self.d_model)
+        embed = self.param("embed", normal, shape, self.base_dtype)
+        head = self.param("head", normal, shape[::-1], self.base_dtype)
+        out_norm = self.param("out_norm", nn.initializers.ones,
+                              (self.d_model,), self.base_dtype)
+        specs = {i: self._specs(i) for i in self.held_layers}
+        base = {i: _Leaves(specs[i][0], name=f"layer_{i}")()
+                for i in self.held_layers}
+        lora = _Groups(tuple((f"layer_{i}", specs[i][1])
+                             for i in self.held_layers), name="lora")()
+        dt = jax.tree.leaves(lora)[0].dtype          # the adapters': compute
+        # cos and sin carry YaRN's mscale / mscale_all_dim (1 as published)
+        ratio = (yarn_mscale(self.rope_factor, self.rope_mscale)
+                 / yarn_mscale(self.rope_factor, self.rope_mscale_all_dim))
+        cos, sin = (ratio * t for t in yarn_tables(
+            x.shape[-1], self.rope_dim, self.rope_theta, self.rope_factor,
+            self.rope_beta_fast, self.rope_beta_slow, self.rope_original))
+        h = embed[x.astype(jnp.int32)].astype(dt)
+        X = jnp.tile(h, (1,) * (h.ndim - 1) + (self.n_streams,))
+        attention = h.dtype.itemsize <= 2
+        obs.counter("remat_policy_total", model="xing4",
+                    saved="attention" if attention else "input_only").inc()
+        # deepseek_v2's three values and ``mlp_out``, as wide as ``h``
+        kept = (deepseek_v2.kept_bytes(h, self.n_heads, self.v_dim)
+                + h.size * h.dtype.itemsize) if attention else 0
+        obs.gauge("remat_saved_bytes", model="xing4").set(
+            len(self.held_layers) * (X.size * X.dtype.itemsize + kept))
+        layer = jax.checkpoint(self._layer, policy=_KEEP if attention else None)
+        counts, errs = [], []
+        for i in self.held_layers:
+            X, c, err = layer(X, base[i], lora[f"layer_{i}"], cos, sin)
+            errs.append({scopes.HC_SINKHORN_ERR: err})
+            if c is not None:
+                counts.append(c)
+        sow_counters(self, counts)
+        sow_counters(self, errs)
+        with jax.named_scope(scopes.FED_LM_HEAD):
+            s = rms_norm(sum(x.astype(jnp.float32) for x in _streams(
+                X, self.n_streams)).astype(dt), out_norm, self.norm_eps)
+            return _dot(s, head.astype(dt))

@@ -57,6 +57,15 @@ copy census of the touched families exactly).  ``label_of`` turns one
     fed_full_attention  the same of a full layer of      full_attention
                         such a model (no positional
                         term)
+    fed_hc_maps         a hyper-connection's maps: the   hc_maps
+                        RMS over a token's streams, the
+                        projection to the three maps,
+                        the sigmoids and the Sinkhorn
+                        normalisations
+    fed_hc_mix          its mixing: the sublayer's       hc_mix
+                        input read from the streams,
+                        and the streams written back
+                        (H_res X + H_post^T y)
 
 An op under several scopes belongs to the innermost one (a forward op
 is inside fed_local_train too); an op under none is ``unscoped``.  One
@@ -64,7 +73,7 @@ exception: ``fed_attention`` inside ``fed_window_attention`` or
 ``fed_full_attention`` yields to it - the fused attention's backward rule
 opens ``fed_attention`` itself (ops/attention.py), whichever kind of
 layer called it.
-The last ten sit inside fed_forward and claim their ops forward,
+The last twelve sit inside fed_forward and claim their ops forward,
 backward and rematerialised alike, so in a model that has them
 ``forward`` / ``backward`` read what lies outside them (embedding,
 residual stream between blocks, the final norm); a model without them
@@ -116,6 +125,8 @@ FED_MLA_LATENT = "fed_mla_latent"
 FED_SHARED_EXPERT = "fed_shared_expert"
 FED_WINDOW_ATTENTION = "fed_window_attention"
 FED_FULL_ATTENTION = "fed_full_attention"
+FED_HC_MAPS = "fed_hc_maps"
+FED_HC_MIX = "fed_hc_mix"
 
 UNSCOPED = "unscoped"
 BACKWARD = "backward"
@@ -140,6 +151,8 @@ LABEL_OF_SCOPE = {
     FED_SHARED_EXPERT: "shared_expert",
     FED_WINDOW_ATTENTION: "window_attention",
     FED_FULL_ATTENTION: "full_attention",
+    FED_HC_MAPS: "hc_maps",
+    FED_HC_MIX: "hc_mix",
 }
 # the kinds of attention layer a model may tell apart: an enclosing one of
 # these claims what ``fed_attention`` inside it holds
@@ -157,6 +170,12 @@ MOE_EXPERT_TOKENS = "moe_expert_tokens"    # [expert layers, experts]
 # routed) — how far a layer that holds a share of its experts skips the
 # slots of the others (models/lfm2_moe.py::held_share)
 MOE_SLOT_ROWS = "moe_slot_rows"
+# [held layers, 2 sublayers, 2]: a step's largest |rowsum(H_res) - 1| and
+# |colsum(H_res) - 1| over its tokens after the last Sinkhorn iteration
+# (models/xing4.py).  Summed over steps like the others, so a reader divides
+# by the steps it counted; no obs counter takes it (a sum of maxima is no
+# total)
+HC_SINKHORN_ERR = "hc_sinkhorn_err"
 # the obs counters that take a program counter's totals when it is read
 # (utils/profiling.py::TransferOverlapStats.program_counters): (metric, the
 # labels of one counter for each entry of the LAST axis) — one unlabelled

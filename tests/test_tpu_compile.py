@@ -821,3 +821,76 @@ def test_command_a_plus_adapter_round_and_its_float32_twin_fit_one_chip(topo):
         ("window_attention", "forward"): 9, ("window_attention", "recompute"): 9,
         ("window_attention", "backward"): 9, ("full_attention", "forward"): 1,
         ("full_attention", "recompute"): 1, ("full_attention", "backward"): 1}
+
+
+def _labelled(text: str, labels) -> dict:
+    """{label: how many instructions} of a round's text for ``labels``."""
+    from fedml_tpu.obs import programs
+    from parallel_case import hlo_instructions
+    smap = programs.scope_map_of_hlo_text(text)
+    found = dict.fromkeys(labels, 0)
+    for name, _, _, _ in hlo_instructions(text):
+        if smap.get(name) in found:
+            found[smap[name]] += 1
+    return found
+
+
+@pytest.mark.slow
+def test_xing4_adapter_round_and_its_float32_twin_fit_one_chip(topo):
+    """`xing4.lora4of256long`'s resident round (a 4.45 GB frozen bfloat16 base -
+    layers 0-9 of 40: both leading dense layers and eight expert layers, 16 of
+    64 experts a layer, a quarter of the vocabulary - under 5,089,280 adapter
+    parameters, chunk 1, 8,192 tokens a step on four streams) and the float32
+    twin that the reference check runs, compiled as the engine dispatches them,
+    fit one chip at the depth the file states: the test that sizes the cut
+    (fedbench/configs/xing4_0_29b_a4b.json, "cut").  Both take the fused
+    two-part attention - no buffer as large as a step's [32, T, T] scores -,
+    XLA:TPU's grouped product for the held experts, and carry ops under both
+    hyper-connection labels; the base is read as it is stored, and what the
+    round folds is the adapters.  The bfloat16 round's layers keep the kernel's
+    (o, lse), W_o's output and the second sublayer's output
+    (`models/xing4.py::KEPT_NAMES`): no attention kernel and no expert product
+    runs again - with `deepseek_v2.KEPT_NAMES` alone the re-run held 32
+    grouped-product kernels and 10 more matrix products, because a
+    hyper-connection's backward pass reads the sublayer's output itself (for
+    dH_post), at 11.82e9 B -; the float32 twin keeps a layer's input alone and
+    re-runs both."""
+    from fedml_tpu.parallel.engine import flatten_carry_f32
+    config, traffic = _bench_files("xing4_0_29b_a4b", "lora4of256long")
+    T, H = config["widths"]["sequence_length"], config["widths"]["num_attention_heads"]
+    n_layers = len(config["held_layers"])
+    assert traffic["dataset"]["args"]["seq_len"] == T == 8192
+    engine, variables, compiled = _dispatched(topo, config, traffic)
+    needs = _needs_with_the_base_aliased(compiled, config)
+    # 15,080,673,280 B (the rehearsal, PR 45: arguments 4.50e9 of which the base
+    # 4.47e9 comes back in the buffers it came in, temporaries 10.46e9, code
+    # 0.12e9) + 0.2e9; the chip gives 16.91e9
+    assert needs < 15.29e9, compiled.memory_analysis()
+    trained = engine.trainer.trained_variables(variables)
+    n_trained = sum(int(np.prod(a.shape)) for a in jax.tree.leaves(trained))
+    assert n_trained == config["widths"]["parameters_trained"]
+    assert flatten_carry_f32(engine._zero_sums(variables)[0])[0].shape == (n_trained,)
+    frozen = engine.trainer.split_frozen(variables["params"])[1]
+    text = compiled.as_text()
+    shapes = {a.shape for a in jax.tree.leaves(frozen) if len(a.shape) == 3}
+    assert shapes == {(16, 3584, 1024), (16, 1024, 3584)}
+    for shape in shapes:
+        dims = ",".join(map(str, shape))
+        assert not re.search(rf"f32\[{dims}\]", text), shape
+        assert not re.search(rf"bf16\[\d+,{dims}\]", text), shape
+    assert text.count("ragged-dot") > 0            # XLA:TPU's grouped product
+    _assert_fused_attention(text, (1, T, H, H, 128))
+    assert _attention_kernels(text) == {"forward": n_layers, "backward": n_layers}
+    assert _rerun_work(text) == {"convolution": 178}
+    assert all(_labelled(text, ("hc_maps", "hc_mix")).values())
+    with jax.default_matmul_precision("highest"):
+        _, _, twin = _dispatched(topo, config, dict(traffic, population=4, cohort=4),
+                                 train_dtype="float32", local_dtype=None)
+    # 14,535,794,688 B of 16.91e9 (the rehearsal, PR 45)
+    assert _needs_with_the_base_aliased(twin, config) < 14.74e9, \
+        twin.memory_analysis()
+    text = twin.as_text()
+    _assert_fused_attention(text, (1, T, H, H, 128))
+    assert _attention_kernels(text) == {"forward": n_layers, "recompute": n_layers,
+                                        "backward": n_layers}
+    assert all(_labelled(text, ("hc_maps", "hc_mix")).values())

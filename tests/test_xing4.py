@@ -1,0 +1,396 @@
+"""The Xing4.0 model (``fedml_tpu/models/xing4.py``: four residual streams
+under manifold-constrained hyper-connections around latent attention and
+bias-selected sigmoid-routed experts beside a shared one, adapters over a
+frozen base) against its plain reference
+(``fedbench/reference/xing4_0_29b_a4b.py``), on the CPU at a tiny size that
+keeps every ratio — 4 streams, 20 Sinkhorn iterations, three different head
+sizes, 2 leading dense layers, 4 experts a token, a quarter of the experts
+held, 1 shared — with seeded weights; and through ``MeshFedAvgEngine``'s
+normal round.
+
+Tolerance: model and reference are both float32 on the CPU and differ by
+summation order through a handful of layers: 1e-5 absolute on logits of
+order 1 and on adapter gradients of order 1e-1."""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from fedbench import reference
+from fedml_tpu.core.trainer import ClientTrainer
+from fedml_tpu.models import create_model, deepseek_v2, lfm2_moe, xing4
+from fedml_tpu.obs import scopes
+
+# two dense layers (0, 1) and two expert layers holding experts 4..7 of 16
+SMALL = dict(d_model=64, n_heads=4, q_rank=24, kv_rank=16, nope_dim=16,
+             rope_dim=8, v_dim=12, d_ff=96, d_expert=32, n_experts=16,
+             experts_per_token=4, n_shared=1, n_layers=8, first_dense=2,
+             layers=[0, 1, 2, 3], held=[4, 4], rope_original=16, lora_rank=4,
+             lora_alpha=8.0)
+ROPE = dict(theta=1e4, factor=64.0, beta_fast=32.0, beta_slow=1.0,
+            original=16, mscale=1.0, mscale_all_dim=1.0)
+REF = dict(n_heads=4, head_block=2, top_k=4, first_held=4, scaling=2.0,
+           alpha=8.0, rope=ROPE)
+REF_NAME = "xing4_0_29b_a4b"
+HC = dict(n=4, norm_eps=1e-6, iters=20, hc_eps=1e-6, clamp=(-30.0, 30.0))
+
+
+@pytest.fixture(scope="module")
+def case():
+    """(model, float32 params off their initial values - norms away from 1,
+    the adapters' B away from 0, the selection bias away from 0, the maps'
+    gates and biases moved -, tokens)."""
+    model = create_model("xing4", 128, **SMALL)
+    rs = np.random.RandomState(0)
+    x = rs.randint(0, 128, (3, 16)).astype(np.int32)
+    variables = model.init(jax.random.PRNGKey(0), jnp.asarray(x), train=False)
+    assert set(variables) == {"params"}          # no counter at rest
+    leaves, tree = jax.tree.flatten(variables["params"])
+    params = jax.tree.unflatten(tree, [
+        jnp.asarray(a, jnp.float32) + 0.1 * rs.randn(*a.shape).astype(np.float32)
+        for a in leaves])
+    return model, params, x
+
+
+def test_logits_match_the_reference(case):
+    model, params, x = case
+    ref = reference.resolve(REF_NAME)
+    assert ref.head_sizes(params["layer_2"], 4) == (16, 8, 12)
+    assert ref.n_streams(params) == 4
+    got = model.apply({"params": params}, x, train=True)
+    assert got.dtype == jnp.float32 and got.shape == (3, 16, 128)
+    want = ref.forward(params, x, **REF)
+    assert float(jnp.abs(want).max()) > 0.5
+    np.testing.assert_allclose(got, want, atol=1e-5)
+    # attention in blocks of heads is attention: a block of all four heads
+    np.testing.assert_allclose(
+        want, ref.forward(params, x, **{**REF, "head_block": 4}), atol=1e-5)
+    # the maps matter: without the per-token part the logits are others
+    flat = jax.tree_util.tree_map_with_path(
+        lambda path, a: 0 * a if "phi" in jax.tree_util.keystr(path) else a, params)
+    assert float(jnp.abs(ref.forward(flat, x, **REF) - want).max()) > 1e-2
+
+
+def test_loss_and_adapter_gradients_match_the_reference(case):
+    model, params, x = case
+    ref = reference.resolve(REF_NAME)
+    rs = np.random.RandomState(1)
+    y = rs.randint(0, 128, x.shape)
+    mask = np.array([1.0, 1.0, 0.0], np.float32)
+    with_lora = lambda lora: {**params, "lora": lora}
+    l_model, g_model = jax.value_and_grad(lambda q: reference.masked_ce(
+        model.apply({"params": with_lora(q)}, x, train=True), y, mask))(params["lora"])
+    l_ref, g_ref = jax.value_and_grad(lambda q: reference.masked_ce(
+        ref.forward(with_lora(q), x, **REF), y, mask))(params["lora"])
+    assert abs(float(l_model) - float(l_ref)) < 1e-5
+    flat_ref = dict(jax.tree_util.tree_flatten_with_path(g_ref)[0])
+    assert len(flat_ref) == 4 * 5 * 2             # layers x matrices x (A, B)
+    for path, g in jax.tree_util.tree_flatten_with_path(g_model)[0]:
+        np.testing.assert_allclose(g, flat_ref[path], atol=1e-5,
+                                   err_msg=jax.tree_util.keystr(path))
+        # every adapter of every layer is reached - layer 0's through the
+        # maps and the mixing of every later sublayer
+        assert np.abs(g).max() > 1e-4, jax.tree_util.keystr(path)
+
+
+def test_sinkhorn_rows_and_columns_sum_to_one_after_twenty_iterations_not_after_one():
+    """Ht_res like a seeded model's, with twice its per-token part (diagonal
+    4, normal noise of 0.5): 20 iterations leave every row within 2e-6 of 1
+    (hc_eps in the last denominator) and every column within 2e-2; one
+    iteration leaves columns over 1e-1 off.  Near the identity the loop
+    gains only about 0.87 a pass (the second singular value of the limit,
+    squared), which is why `hc_sinkhorn_err` keeps the column part.  The
+    reference's loop is the same map."""
+    rs = np.random.RandomState(2)
+    ht = jnp.asarray(4.0 * np.eye(4)[:, :, None] + 0.5 * rs.randn(4, 4, 256),
+                     jnp.float32)
+    off = lambda m, axis: float(jnp.max(jnp.abs(jnp.sum(m, axis=axis) - 1.0)))
+    m20 = xing4.sinkhorn(ht, 20, 1e-6, (-30.0, 30.0))
+    assert off(m20, 1) < 2e-6 and off(m20, 0) < 2e-2
+    m1 = xing4.sinkhorn(ht, 1, 1e-6, (-30.0, 30.0))
+    assert off(m1, 1) < 2e-6 and off(m1, 0) > 1e-1
+    assert off(xing4.sinkhorn(ht, 100, 1e-6, (-30.0, 30.0)), 0) < 1e-3
+    assert float(jnp.min(m20)) > 0
+    np.testing.assert_allclose(
+        m20, reference.resolve(REF_NAME).sinkhorn(ht), atol=1e-6)
+    # the clamp bounds the entries before the exponential
+    wild = xing4.sinkhorn(1e4 * ht, 20, 1e-6, (-30.0, 30.0))
+    assert bool(jnp.all(jnp.isfinite(wild)))
+
+
+def _fixed_maps(lp, n=4, C=64):
+    """A layer's leaves with both hyper-connections fixed at H_res = I,
+    H_pre = e_1, H_post = 1 for every token: no per-token part, the biases
+    at the clamp."""
+    b = np.concatenate([[30.0] + [-30.0] * (n - 1), np.zeros(n),
+                        (60.0 * np.eye(n) - 30.0).reshape(-1)])
+    fixed = dict(lp)
+    for s in xing4.SUBLAYERS:
+        fixed[f"hc_{s}_phi"] = jnp.zeros((n * C, n * (n + 2)), jnp.float32)
+        fixed[f"hc_{s}_b"] = jnp.asarray(b, jnp.float32)
+    return fixed
+
+
+@pytest.mark.parametrize("layer", ["layer_0", "layer_2"])
+def test_with_identity_maps_every_stream_is_the_single_stream_layer(case, layer):
+    """H_res = I, H_pre = e_1, H_post = 1 and four equal streams: every
+    stream leaves the layer as ``h + F_attn(h)`` then ``+ F_mlp(.)`` of the
+    SAME sublayers on one stream - a dense layer and an expert layer."""
+    model, params, x = case
+    lp, ad = _fixed_maps(params[layer]), params["lora"][layer]
+    rs = np.random.RandomState(3)
+    h = jnp.asarray(rs.randn(2, 16, 64), jnp.float32)
+    cos, sin = deepseek_v2.yarn_tables(16, 8, 1e4, 64.0, 32.0, 1.0, 16)
+    X, counts, err = model._layer(jnp.tile(h, (1, 1, 4)), lp, ad, cos, sin)
+    assert float(err.max()) < 2e-6
+    a = h + deepseek_v2.latent_attention(
+        h, lp, ad, 2.0, 1e-6, cos, sin, 4, 16, 12, model.softmax_scale)
+    f = xing4.rms_norm(a, lp["post_norm"], 1e-6)
+    if "router" in lp:
+        m, want_counts = xing4.moe_layer(f, lp, 4, 2.0, (4, 4))
+        np.testing.assert_array_equal(counts[scopes.MOE_EXPERT_TOKENS],
+                                      want_counts[scopes.MOE_EXPERT_TOKENS])
+    else:
+        assert counts is None
+        m = lfm2_moe.gated_mlp(f, lp["w1"], lp["w3"], lp["w2"])
+    for stream in xing4._streams(X, 4):
+        np.testing.assert_allclose(stream, a + m, atol=1e-5)
+
+
+def test_the_maps_of_a_fresh_model_are_near_their_targets():
+    """As the module says of its initial values: H_pre near 1 / n, H_post
+    near 1, H_res near the identity - and no map is the same for two tokens."""
+    model = create_model("xing4", 128, **SMALL)
+    x = jnp.arange(32).reshape(2, 16)
+    lp = model.init(jax.random.PRNGKey(0), x)["params"]["layer_0"]
+    hp = {k: lp[f"hc_attn_{k}"] for k in ("phi", "b", "a")}
+    X = jnp.asarray(np.random.RandomState(6).randn(2, 16, 256), jnp.float32)
+    pre, post, res, err = xing4.hc_maps(X, hp, **HC)
+    assert pre.shape == post.shape == (2, 16, 4) and res.shape == (2, 16, 16)
+    assert float(jnp.abs(pre - 0.25).max()) < 0.1
+    assert float(jnp.abs(post - 1.0).max()) < 0.2
+    assert float(jnp.abs(res.reshape(2, 16, 4, 4) - jnp.eye(4)).max()) < 0.1
+    assert float(jnp.std(pre[..., 0])) > 1e-3 and float(jnp.std(res[..., 5])) > 1e-4
+    assert err.shape == (2,) and float(err[0]) < 2e-6 and 0 < float(err[1]) < 1e-2
+    # the streams lie side by side: stream i is columns i C .. (i + 1) C
+    u = xing4.hc_read(X, pre)
+    np.testing.assert_allclose(
+        u, sum(pre[..., i:i + 1] * X[..., 64 * i:64 * (i + 1)] for i in range(4)),
+        atol=1e-6)
+
+
+def _expert_layer(rs, n_experts=16, d=16, width=8):
+    mk = lambda *s: jnp.asarray(rs.randn(*s), jnp.float32)
+    return {"router": mk(d, n_experts), "expert_bias": 0.5 * mk(n_experts),
+            "w1": 0.3 * mk(n_experts, d, width), "w3": 0.3 * mk(n_experts, d, width),
+            "w2": 0.3 * mk(n_experts, width, d),
+            "s1": 0.3 * mk(d, width), "s3": 0.3 * mk(d, width),
+            "s2": 0.3 * mk(width, d)}
+
+
+def test_the_four_expert_shares_and_the_shared_expert_add_up_to_the_whole_layer():
+    """The share test of the model-configs guide, section 4: with ``held`` =
+    each quarter of the experts in turn, routing over all 16, the four
+    partial results - the shared expert, which every chip computes alike,
+    counted once - add up to what the uncut layer and the uncut reference
+    give, and the routed-token count does not depend on the share."""
+    rs = np.random.RandomState(4)
+    lp = _expert_layer(rs)
+    ref = reference.resolve(REF_NAME)
+    f = jnp.asarray(rs.randn(2, 12, 16), jnp.float32)
+
+    def layer(lp, held):
+        m, c = xing4.moe_layer(f, lp, 4, 2.0, held)
+        return m, c[scopes.MOE_EXPERT_TOKENS]
+
+    whole, counts = layer(lp, (0, 16))
+    shared = lfm2_moe.gated_mlp(f, lp["s1"], lp["s3"], lp["s2"])
+    routed = []
+    for first in range(0, 16, 4):
+        share = dict(lp, **{w: lp[w][first:first + 4] for w in ("w1", "w3", "w2")})
+        m, c = layer(share, (first, 4))
+        np.testing.assert_array_equal(c, counts)
+        np.testing.assert_allclose(
+            m, ref.experts(f, share, 4, first_held=first, scaling=2.0), atol=1e-5)
+        routed.append(m - shared)
+        assert float(jnp.abs(routed[-1]).max()) > 1e-3      # every quarter is used
+    np.testing.assert_allclose(sum(routed) + shared, whole, atol=2e-5)
+    np.testing.assert_allclose(
+        whole, ref.experts(f, lp, 4, first_held=0, scaling=2.0), atol=2e-5)
+    assert float(counts.sum()) == 4 * 2 * 12          # dropless: every slot
+    # the bias selects and does not weigh: the gates of a token sum to the
+    # scaling factor whatever the bias
+    g = ref.gate_weights(f.reshape(-1, 16), lp["router"], lp["expert_bias"], 4, 2.0)
+    np.testing.assert_allclose(jnp.sum(g, axis=-1), 2.0, atol=1e-4)
+    unbiased = ref.gate_weights(f.reshape(-1, 16), lp["router"],
+                                0 * lp["expert_bias"], 4, 2.0)
+    assert float(jnp.mean((g > 0) != (unbiased > 0))) > 0.05
+
+
+def test_counters_are_the_routers_and_the_sinkhorn_error(case):
+    model, params, x = case
+    _, aux = model.apply({"params": params}, x, train=True,
+                         mutable=[scopes.COUNTERS])
+    got = {k: np.asarray(v) for k, v in aux[scopes.COUNTERS].items()}
+    assert {k: v.shape for k, v in got.items()} == model.counters == {
+        scopes.MOE_EXPERT_TOKENS: (2, 16), scopes.MOE_SLOT_ROWS: (2, 2),
+        scopes.HC_SINKHORN_ERR: (4, 2, 2)}
+    assert model.expert_layers == (2, 3) and model.held_experts == (4, 4)
+    np.testing.assert_array_equal(got[scopes.MOE_EXPERT_TOKENS].sum(axis=1),
+                                  4.0 * x.size)
+    err = got[scopes.HC_SINKHORN_ERR]
+    assert (err[..., 0] < 2e-6).all()           # rows: the last pass's
+    assert (err[..., 1] > err[..., 0]).all() and (err[..., 1] < 0.1).all()
+
+
+def test_base_is_stored_in_bfloat16_and_only_the_adapters_train(case):
+    model, _, x = case
+    v = model.init(jax.random.PRNGKey(0), jnp.asarray(x), train=False)["params"]
+    trained, frozen = ClientTrainer(model, has_time_axis=True).split_frozen(v)
+    assert set(trained) == {"lora"} and "lora" not in frozen
+    assert {a.dtype for a in jax.tree.leaves(frozen)} == {jnp.dtype(jnp.bfloat16)}
+    assert {a.dtype for a in jax.tree.leaves(trained)} == {jnp.dtype(jnp.float32)}
+    assert set(trained["lora"]["layer_1"]) == {
+        m + s for m in ("wq_a", "wq_b", "wkv_a", "wkv_b", "wo") for s in ("_a", "_b")}
+    assert v["layer_2"]["w1"].shape == (4, 64, 32)        # the held quarter
+    assert v["layer_2"]["router"].shape == (64, 16)       # scores all experts
+    assert v["layer_2"]["expert_bias"].shape == (16,)
+    assert v["layer_2"]["s1"].shape == (64, 32) and "s1" not in v["layer_1"]
+    assert v["layer_1"]["w1"].shape == (64, 96) and "router" not in v["layer_1"]
+    for s in ("attn", "mlp"):                             # both sublayers' maps
+        assert v["layer_1"][f"hc_{s}_phi"].shape == (4 * 64, 24)
+        assert v["layer_1"][f"hc_{s}_b"].shape == (24,)
+        assert v["layer_1"][f"hc_{s}_a"].shape == (3,)
+    assert v["embed"].shape == (128, 64) and v["head"].shape == (64, 128)
+
+
+def test_scopes_and_phases_of_the_new_labels():
+    """The two labels claim their ops in all three passes, and sit beside the
+    sublayers' own scopes, not around them."""
+    assert scopes.label_of("jvp(fed_forward)/checkpoint/fed_hc_maps/div") == "hc_maps"
+    back = "transpose(jvp(fed_forward))/checkpoint/rematted_computation/fed_hc_mix/mul"
+    assert scopes.label_of(back) == "hc_mix" and scopes.phase_of(back) == "recompute"
+    assert scopes.phase_of(
+        "transpose(jvp(fed_forward))/checkpoint/fed_hc_maps/div") == "backward"
+    assert {"hc_maps", "hc_mix"} <= set(scopes.LABELS)
+    model = create_model("xing4", 128, **SMALL)
+    x = jnp.zeros((1, 16), jnp.int32)
+    v = model.init(jax.random.PRNGKey(0), x)
+    text = jax.jit(lambda v: model.apply(v, x)).lower(v).as_text(debug_info=True)
+    for scope in (scopes.FED_HC_MAPS, scopes.FED_HC_MIX, scopes.FED_MLA_LATENT,
+                  scopes.FED_ATTENTION, scopes.FED_MLP, scopes.FED_MOE_ROUTER,
+                  scopes.FED_MOE_EXPERTS, scopes.FED_SHARED_EXPERT,
+                  scopes.FED_LM_HEAD):
+        assert scope in text, scope
+    assert "fed_hc_mix/fed_" not in text and "fed_hc_maps/fed_" not in text
+
+
+# -- through the mesh engine's normal round ---------------------------------
+
+def _engine(chunk=2):
+    from fedbench.harness import build
+    config = {"model": {"factory": "fedml_tpu.models.create_model",
+                        "name": "xing4", "kwargs": SMALL},
+              "trainer": {"loss": "ce", "optimizer": "sgd",
+                          "train_dtype": "bfloat16", "has_time_axis": True},
+              "engine": {"local_dtype": None, "chunk": chunk}}
+    traffic = {"dataset": {"generator": "classed_markov_tokens",
+                           "args": {"seq_len": 16, "vocab": 128, "classes": 4}},
+               "population": 6, "cohort": 4,
+               "client_sizes": {"law": "equal", "samples": 2},
+               "batch_size": 1, "epochs": 1, "lr": 0.3, "mesh_devices": 1,
+               "engine": {"class": "fedml_tpu.parallel.MeshFedAvgEngine",
+                          "args": {"streaming": False}}}
+    data = build.make_data(traffic, 3)
+    return build.make_engine(config, traffic, data, 3), build
+
+
+def test_frozen_leaves_come_back_bitwise_and_the_counters_are_exact():
+    """Two chunks of two clients scanned over one closed-over base: the
+    frozen leaves - the maps among them - come back bit for bit, every
+    adapter moves, the loss falls (from ln 128: eight rounds, because the
+    cohorts differ and only adapters train), the router's counter is 4 x
+    tokens x expert layers, and the Sinkhorn error is a sum over the 64
+    steps."""
+    from fedbench.harness import loop
+    engine, build = _engine()
+    state = loop.State(engine, build.init_variables(engine), 3)
+    before = jax.tree.map(np.asarray, state.variables["params"])
+    engine.transfer_stats.reset()
+    win = loop.run_rounds(state, 2, rounds=8)
+    assert win["failed"] == 0 and win["losses"][-1] < win["losses"][0] - 0.05
+    after = jax.tree.map(np.asarray, state.variables["params"])
+    for name in before:
+        same = jax.tree.map(np.array_equal, before[name], after[name])
+        assert all(jax.tree.leaves(same)) == (name != "lora"), name
+    moved = jax.tree.map(lambda a, b: not np.array_equal(a, b),
+                         before["lora"], after["lora"])
+    assert all(jax.tree.leaves(moved))
+    # 8 rounds x 4 clients x 2 steps x 16 tokens x 4 a token x 2 expert layers
+    counted = engine.transfer_stats.program_counters()
+    tokens = counted[scopes.MOE_EXPERT_TOKENS]
+    assert tokens.shape == (2, 16) and tokens.sum() == 8 * 4 * 2 * 16 * 4 * 2
+    err = counted[scopes.HC_SINKHORN_ERR] / (8 * 4 * 2)
+    assert err.shape == (4, 2, 2)
+    assert (err[..., 0] < 1e-5).all() and (err[..., 1] > 0).all() \
+        and (err[..., 1] < 1e-2).all()
+
+
+def test_round_has_no_branch_on_the_model_and_folds_the_adapters_alone():
+    """`create_model("xing4")` goes through the split the trainer reads off
+    the model (`trainable`), like the other adapter models: the carry is as
+    long as the adapters, and neither the trainer nor the engine knows the
+    family or its streams."""
+    from fedml_tpu.parallel.engine import flatten_carry_f32
+    engine, _ = _engine()
+    variables = jax.eval_shape(engine.init_variables)
+    trained = engine.trainer.trained_variables(variables)
+    n_adapters = sum(int(np.prod(a.shape)) for a in jax.tree.leaves(trained))
+    assert n_adapters == sum(int(np.prod(a.shape))
+                             for a in jax.tree.leaves(variables["params"]["lora"]))
+    assert flatten_carry_f32(engine._zero_sums(variables)[0])[0].shape == (n_adapters,)
+    import fedml_tpu.core.trainer as trainer_mod
+    import fedml_tpu.parallel.engine as engine_mod
+    for mod in (trainer_mod, engine_mod):
+        with open(mod.__file__) as f:
+            text = f.read().lower()
+        assert "xing" not in text and "sinkhorn" not in text
+
+
+def test_the_model_imports_what_it_shares():
+    """The attention, the router and the expert product are the other
+    models' objects, not copies."""
+    assert xing4.latent_attention is deepseek_v2.latent_attention
+    assert xing4.route is lfm2_moe.route
+    assert xing4.held_share is lfm2_moe.held_share
+    assert xing4.KEPT_NAMES == deepseek_v2.KEPT_NAMES + ("mlp_out",)
+    m = 0.1 * np.log(64.0) + 1
+    model = create_model("xing4", 16, nope_dim=128, rope_dim=64)
+    assert abs(model.softmax_scale - 192 ** -0.5 * m * m) < 1e-9
+
+
+def test_what_the_checkpoint_keeps_is_counted():
+    """A 16-bit stream keeps `KEPT_NAMES` - deepseek_v2's three values and the
+    second sublayer's output - beside the four-stream input, a float32 one its
+    input alone; both are counted at trace time, and the names are in the
+    layer's jaxpr."""
+    from fedml_tpu import obs
+    model = create_model("xing4", 128, **SMALL)
+    x = jnp.zeros((2, 16), jnp.int32)
+    v = model.init(jax.random.PRNGKey(0), x)
+    count = lambda saved: obs.counter("remat_policy_total", model="xing4",
+                                      saved=saved).value
+    before = count("attention"), count("input_only")
+    half = jax.tree_util.tree_map_with_path(
+        lambda p, a: a.astype(jnp.bfloat16) if "lora" in jax.tree_util.keystr(p)
+        else a, v)
+    jax.eval_shape(lambda v: model.apply(v, x), half)
+    stream = 2 * 16 * 4 * 64 * 2
+    kept = 2 * 16 * ((4 * 12 + 64 + 64) * 2 + 4 * 4)      # o, attn_out, mlp_out; lse
+    assert obs.gauge("remat_saved_bytes", model="xing4").value == 4 * (stream + kept)
+    jax.eval_shape(lambda v: model.apply(v, x), v)
+    assert obs.gauge("remat_saved_bytes", model="xing4").value == 4 * 2 * stream
+    assert (count("attention"), count("input_only")) == (before[0] + 1, before[1] + 1)
+    text = str(jax.make_jaxpr(lambda v: model.apply(v, x))(half))
+    for name in ("attn_out", "mlp_out"):     # the two sublayers' F(u)
+        assert f"name={name}" in text, name
