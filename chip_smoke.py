@@ -31,7 +31,9 @@ Default (one chip), in order:
                  output and gradients in bfloat16: the fused causal
                  attention at two language-model cells' shapes, against a
                  float32 oracle beside the plain path; the rotary kernel at
-                 Command A+'s q and k, against its plain body.
+                 Command A+'s q and k and at latent attention's 64-wide
+                 query parts (DeepSeek-V2's, Xing4.0's), against its plain
+                 body.
   (d) cli        fedml_tpu.cli.main([...]) in-process: argument parsing
                  -> engine -> history.jsonl on the device.
 
@@ -69,8 +71,10 @@ class Sizes:
     # chunk's step of lfm2moe24b.lora4of256t2048
     attn_shapes: tuple = ((2, 1024, 16, 16, 128), (4, 2048, 32, 8, 64))
     # (B, T, H, head size): q and k of a sliding layer of
-    # cmdaplus.lora4of256long
-    rotary_shapes: tuple = ((1, 8192, 128, 128), (1, 8192, 8, 128))
+    # cmdaplus.lora4of256long, and the queries' rotary part in latent
+    # attention of deepseekv2.lora4of256t4096 and of xing4.lora4of256long
+    rotary_shapes: tuple = ((1, 8192, 128, 128), (1, 8192, 8, 128),
+                            (1, 4096, 128, 64), (1, 8192, 32, 64))
     platform: str = "tpu"        # where every result must live
 
 
@@ -387,7 +391,8 @@ def phase_kernels(sz: Sizes, seed: int) -> None:
         assert all(f <= 1.5 * p_ for f, p_ in zip(l2["fused"], l2["plain"])), l2
 
     # the rotary kernel (ops/rotary.py) at the q and the k of a sliding
-    # layer of cmdaplus.lora4of256long, bfloat16: output and gradient against
+    # layer of cmdaplus.lora4of256long and at the two latent cells' q_rope
+    # (heads of 64, two to a row of lanes), bfloat16: output and gradient against
     # the plain body `apply_rotary`.  Both rotate in float32 and round once:
     # they may be one bfloat16 place apart (the two may contract a * b + c
     # differently), as tests/test_rotary_op.py holds them in interpret mode.

@@ -46,6 +46,16 @@ imported from there, not copied):
   — it is never repeated for the heads — and neither the ``nope + rope``
   wide queries and keys nor the [B, H, T, T] scores are ever assembled.
   The softmax scale (YaRN's ``mscale^2`` included) is handed to it.
+* **the rotary of the queries' 64-wide part is
+  ``ops/rotary.py::rotate_half``**: lowered for a TPU one elementwise kernel
+  pass over ``q_rope`` viewed ``[B, T, H * 64]``, two heads to a row of
+  lanes, in the forward pass, the checkpoint's re-run and, transposed, the
+  backward pass; its residuals are the two tables, so ``KEPT_NAMES`` is
+  untouched.  `apply_rotary`, which it replaces and falls back to (the CPU;
+  the one shared key head, 0.5 MB), turned float32 halves of 32 lanes that
+  the (8, 128) tiling pads 4 x, behind a float32 relayout of the whole
+  queries: a layer-step of this function and the core takes 67.3 ms where
+  it took 77.1 (PERF.md section 5, PR 46).
 * **the expert layer is lfm2_moe's dropless grouped product**, told which
   experts it holds (``held`` = (first, how many): ONE routing group under
   the published expert parallelism): the router scores all ``n_experts``,
@@ -98,9 +108,10 @@ from fedml_tpu import obs
 from fedml_tpu.models.lfm2_moe import (_adapted, _Groups, _Leaves,
                                        counter_shapes, float_counters,
                                        gated_mlp, held_share, sow_counters)
-from fedml_tpu.models.looped_lm import _dot, apply_rotary, rms_norm
+from fedml_tpu.models.looped_lm import _dot, rms_norm
 from fedml_tpu.obs import scopes
 from fedml_tpu.ops.attention import SAVED_NAMES, causal_attention
+from fedml_tpu.ops.rotary import rotate_half
 
 # what a layer's checkpoint keeps beside its input where the stream is 16
 # bits wide (module docstring): ``W_o``'s adapted output, as
@@ -139,7 +150,9 @@ def latent_attention(h, lp, ad, scale, eps, cos, sin, n_heads: int,
                      nope: int, v_dim: int, softmax_scale: float):
     """Multi-head latent attention on h [B, T, d]; the scopes: the latent
     side here, the core and the output projection ``fed_attention``.  The
-    projection's output is named for the layer's checkpoint (``KEPT_NAMES``)."""
+    projection's output is named for the layer's checkpoint (``KEPT_NAMES``).
+    `rotate_half` reads off the shapes which body turns what: the kernel the
+    queries' rotary part, the plain body the one key head all heads share."""
     B, T, _ = h.shape
     with jax.named_scope(scopes.FED_MLA_LATENT):
         a = rms_norm(h, lp["in_norm"], eps)
@@ -152,8 +165,8 @@ def latent_attention(h, lp, ad, scale, eps, cos, sin, n_heads: int,
         k_nope, v = jnp.split(kv.reshape(B, T, n_heads, nope + v_dim),
                               [nope], axis=-1)
         q_nope, q_rope = jnp.split(q, [nope], axis=-1)
-        q_rope = apply_rotary(q_rope, cos, sin)
-        k_rope = apply_rotary(k_rope[:, :, None, :], cos, sin)
+        q_rope = rotate_half(q_rope, cos, sin)
+        k_rope = rotate_half(k_rope[:, :, None, :], cos, sin)
     with jax.named_scope(scopes.FED_ATTENTION):
         o = causal_attention(q_nope, k_nope, v, rope=(q_rope, k_rope),
                              scale=softmax_scale)

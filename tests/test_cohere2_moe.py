@@ -384,14 +384,16 @@ def _parent_rotary(x, cos, sin):
     return (x32 * cos[:, None, :] + rot * sin[:, None, :]).astype(x.dtype)
 
 
-@pytest.mark.parametrize("name, kwargs", [
-    ("looped_lm", {}), ("lfm2_moe", {"lora_rank": 2}), ("deepseek_v2", {})])
+@pytest.mark.parametrize("name, kwargs, call", [
+    ("looped_lm", {}, "apply_rotary"), ("lfm2_moe", {"lora_rank": 2}, "apply_rotary"),
+    ("deepseek_v2", {}, "rotate_half")])
 def test_the_other_models_trace_the_jaxpr_they_traced_before(monkeypatch, name,
-                                                             kwargs):
-    """The three older language models call `apply_rotary`, which moved to
-    `ops/rotary.py` as the op's plain body: the jaxpr of their loss and
-    gradients is what it is with the parent's function in its place, and holds
-    no rotary kernel."""
+                                                             kwargs, call):
+    """Two older language models call `apply_rotary`, which moved to
+    `ops/rotary.py` as the op's plain body, and `deepseek_v2` calls
+    `rotate_half` since PR 46, which takes that body at this size (a rotary
+    part of 8): the jaxpr of their loss and gradients is what it is with the
+    parent's function in its place, and holds no rotary kernel."""
     import importlib
     module = importlib.import_module("fedml_tpu.models." + name)
     model = create_model(name, output_dim=50, **kwargs)
@@ -404,6 +406,6 @@ def test_the_other_models_trace_the_jaxpr_they_traced_before(monkeypatch, name,
         return str(jax.make_jaxpr(jax.value_and_grad(loss))(params))
 
     got = trace()
-    monkeypatch.setattr(module, "apply_rotary", _parent_rotary)
+    monkeypatch.setattr(module, call, _parent_rotary)
     assert got == trace()
     assert "rotate_half" not in got and "concatenate" in got

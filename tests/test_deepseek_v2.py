@@ -283,3 +283,127 @@ def test_round_has_no_branch_on_the_model_and_folds_the_adapters_alone():
     for mod in (trainer_mod, engine_mod):
         with open(mod.__file__) as f:
             assert "deepseek" not in f.read().lower()
+
+
+# -- the rotary of the queries' 64-wide part (ops/rotary.py, PR 46) ----------
+
+# SMALL at the published rotary width: two heads of 64 fill a row of lanes
+LANE_ROPE = dict(SMALL, n_heads=2, rope_dim=64, rope_original=128)
+
+
+IN_ALL_THREE_PASSES = {("mla_latent", phase) for phase in (
+    scopes.FORWARD, scopes.RECOMPUTE, scopes.BACKWARD)}
+
+
+def _lane_rope(name="deepseek_v2", kwargs=LANE_ROPE, T=128):
+    """(model, params as initialised, tokens [1, T]): T a multiple of 128, so
+    `rotate_half` takes the queries' rotary part."""
+    model = create_model(name, 128, **kwargs)
+    x = jax.random.randint(jax.random.PRNGKey(1), (1, T), 0, 128)
+    return model, model.init(jax.random.PRNGKey(0), x)["params"], x
+
+
+def rotary_kernels_by_label_and_phase(model, params, x):
+    """{(label, phase)} of the `rotate_half` kernels in the gradient of a
+    bfloat16 model lowered for a TPU (`tests/test_xing4.py` shares it)."""
+    import re
+    params = jax.tree.map(lambda a: jnp.zeros(a.shape, jnp.bfloat16), params)
+
+    def loss(lora):
+        with jax.named_scope(scopes.FED_FORWARD):
+            return jnp.sum(model.apply({"params": {**params, "lora": lora}}, x))
+
+    text = jax.jit(jax.grad(loss)).trace(params["lora"]).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
+    names = [m.group(1) for m in re.finditer(r'loc\("([^"]*pallas_call[^"]*)"', text)]
+    return {(scopes.label_of(n), scopes.phase_of(n)) for n in names
+            if "rotate_half" in n}
+
+
+def rotary_paths_of_one_trace(model, params, x):
+    """(pallas, reference) calls of `rotate_half` counted by one forward trace."""
+    from fedml_tpu import obs
+    paths = lambda: [obs.counter("ops_kernel_path_total", op="rotate_half",
+                                 path=path).value for path in ("pallas", "reference")]
+    before = paths()
+    jax.make_jaxpr(lambda p: model.apply({"params": p}, x))(params)
+    return tuple(b - a for a, b in zip(before, paths()))
+
+
+def test_the_rotary_of_q_rope_is_the_kernel_in_all_three_passes():
+    """Lowered for a TPU, `latent_attention` at a rotary part of 64 holds the
+    `rotate_half` kernel under the latent side's scope - forward, in the
+    checkpoint's re-run and backward (its residuals are the tables: nothing
+    new is kept) - and a trace counts one `pallas` (q_rope) and one
+    `reference` (the one shared key head, under a row of lanes) a layer."""
+    model, params, x = _lane_rope()
+    assert rotary_kernels_by_label_and_phase(model, params, x) == IN_ALL_THREE_PASSES
+    assert rotary_paths_of_one_trace(model, params, x) == (3, 3)
+    assert deepseek_v2.KEPT_NAMES == (
+        "attn_out", "causal_attention_o", "causal_attention_lse")
+
+
+def assert_the_cpu_model_is_the_parents_to_the_bit(monkeypatch, model, params, x):
+    """Logits and adapter gradients (parameters moved off their initial
+    values) with `rotate_half` in `latent_attention`, and with the parent's
+    `apply_rotary` in its place (`tests/test_xing4.py` shares it)."""
+    from fedml_tpu.ops.rotary import apply_rotary
+    rs = np.random.RandomState(0)
+    params = jax.tree.map(
+        lambda a: a + 0.1 * rs.randn(*a.shape).astype(a.dtype), params)
+
+    def loss(lora):
+        logits = model.apply({"params": {**params, "lora": lora}}, x)
+        return jnp.mean(jnp.square(logits)), logits
+
+    # a new function each time: the second trace sees the parent's call
+    both = lambda: jax.jit(jax.value_and_grad(loss, has_aux=True))(params["lora"])
+    got = both()
+    monkeypatch.setattr(deepseek_v2, "rotate_half", apply_rotary)
+    want = both()
+    assert float(jnp.abs(want[0][1]).max()) > 0.1
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_on_the_cpu_the_model_is_the_parents_to_the_bit(monkeypatch):
+    """A CPU program lowers `rotate_half` to its plain body: logits and
+    adapter gradients are what the parent's call of `apply_rotary` gives, bit
+    for bit, at the width the kernel takes on a chip."""
+    assert_the_cpu_model_is_the_parents_to_the_bit(monkeypatch, *_lane_rope())
+
+
+@pytest.mark.parametrize("name, kwargs, T", [
+    ("cohere2_moe", dict(d_model=64, n_heads=4, n_kv_heads=2, head_dim=128,
+                         d_expert=32, n_experts=8, experts_per_token=2,
+                         n_shared=1, sliding_window=128, lora_rank=2), 256),
+    ("lfm2_moe", dict(lora_rank=2), 128),
+    ("looped_lm", {}, 128)])
+def test_the_other_models_lower_to_the_text_they_lowered_to(monkeypatch, name,
+                                                            kwargs, T):
+    """The narrow form is not on the path of `cmdaplus` (heads of 128: the wide
+    form, whose jaxpr `tests/test_rotary_op.py` pins), `lfm2moe24b` or
+    `ouro2p6b` (both call `apply_rotary`): their loss and gradients lowered
+    for a TPU are the same text under the parent's shape rule, with the narrow
+    form taken away."""
+    from fedml_tpu.ops import rotary
+    model = create_model(name, output_dim=50, **kwargs)
+    x = jax.random.randint(jax.random.PRNGKey(1), (1, T), 0, 50)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16), jax.eval_shape(
+            lambda: model.init(jax.random.PRNGKey(0), x))["params"])
+
+    def lowered():
+        def loss(params):
+            return jnp.mean(jnp.square(model.apply({"params": params}, x)))
+        return jax.jit(jax.value_and_grad(loss)).trace(params).lower(
+            lowering_platforms=("tpu",)).as_text()
+
+    texts = []
+    for parents_rule in (False, True):     # one call site: a kernel's body
+        if parents_rule:                   # holds the lines it was traced from
+            monkeypatch.setattr(rotary, "_lanes_fit", lambda H, hd: hd % 128 == 0)
+            monkeypatch.delattr(rotary, "_narrow_kernel")
+        texts.append(lowered())
+    assert ("rotate_half" in texts[0]) == (name == "cohere2_moe")
+    assert texts[0] == texts[1]

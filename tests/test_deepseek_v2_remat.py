@@ -96,7 +96,8 @@ W_O, W_O_A, W_O_B = (H * V_DIM, D), (H * V_DIM, RANK), (RANK, D)
 def test_a_16_bit_stream_keeps_the_layer_inputs_and_the_named_values():
     """(a) One residual a layer for its input, `W_o`'s output, `o` and the
     log-sum-exp, each in the dtype the backward pass reads it in, and nothing
-    else of the layers'; the re-run holds no platform switch, no kernel, and
+    else of the layers'; the re-run holds no platform switch and no kernel of
+    the attention rule's, and
     neither `W_o` nor its adapter's product back to the stream - the rank-wide
     `o A` is made again (the gradient of B reads it, `lfm2_moe._adapted`
     names nothing) - and still the latent side's (the backward kernel reads
@@ -107,8 +108,15 @@ def test_a_16_bit_stream_keeps_the_layer_inputs_and_the_named_values():
         ((B, T, H, V_DIM), "bfloat16"): LAYERS,            # o
         ((B, H, T), "float32"): LAYERS}                    # lse
     again, weights = _products(loss, lora)
-    assert not {"cond", "platform_index", "pallas_call",
-                "custom_vjp_call"} & set(again), again
+    # the one platform switch a layer is the rotary's (`ops/rotary.py`, whose
+    # residuals are the tables: the latent side turns q_rope again), with its
+    # kernel in the TPU branch; the attention rule's is not there
+    kernels = []
+    _rematted(_value(loss), lora, visit=lambda e: e.primitive.name == "pallas_call"
+              and kernels.append(e.params["name"]))
+    assert kernels == ["rotate_half"] * LAYERS, kernels
+    assert again.count("platform_index") == again.count("cond") == LAYERS, again
+    assert "custom_vjp_call" not in again, again
     assert not {W_O, W_O_B} & set(weights), weights
     assert weights.count(W_O_A) == LAYERS
     assert weights.count((D, WIDTHS["q_rank"])) == LAYERS          # W_qa
@@ -149,6 +157,12 @@ def test_gradients_are_the_other_programs_to_the_bit(monkeypatch, dtype, other):
     checkpoint of before) and of the plain loop (no checkpoint at all) to the
     bit - run op by op (`jax.disable_jit`), so that each primitive is its own
     program (under `jit` XLA:CPU fuses the programs differently)."""
+    # `rotate_half`'s platform switch is ONE compiled program where a
+    # checkpoint evaluates its jaxpr and a Python branch where none does; its
+    # plain body, which a CPU program lowers to (tests/test_deepseek_v2.py
+    # holds the two equal to the bit under `jit`), is primitives either way
+    from fedml_tpu.ops.rotary import apply_rotary
+    monkeypatch.setattr(deepseek_v2, "rotate_half", apply_rotary)
     _, loss, lora = _case(DTYPES[dtype])
     with jax.disable_jit():
         got = jax.value_and_grad(_value(loss))(lora)

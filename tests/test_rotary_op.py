@@ -4,8 +4,11 @@ transpose against autodiff of the plain body, under the transformations the
 engine applies to it, and the rule that picks a path.
 
 What only a chip's compiler can show — Mosaic accepting the blocks and the
-lane roll at Command A+'s shapes — is
-`tests/test_tpu_compile.py::test_rotate_half_compiles`."""
+lane roll at Command A+'s shapes and at latent attention's 64-wide query
+parts — is `tests/test_tpu_compile.py::test_rotate_half_compiles`."""
+import os
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -20,8 +23,11 @@ from fedml_tpu.ops.rotary import apply_rotary, rotate_half
 
 THETA = 5e4
 # (B, T, H, hd): several heads a block, every head of a wide operand in one
-# block, and a head of two lane tiles (the roll crosses a tile)
-SHAPES = [(1, 256, 4, 128), (2, 128, 16, 128), (1, 256, 1, 256)]
+# block, and a head of two lane tiles (the roll crosses a tile); then the
+# narrow form (two heads of 64 to a row of 128 lanes): two rows of lanes a
+# position, sixteen (a wide operand), and one
+SHAPES = [(1, 256, 4, 128), (2, 128, 16, 128), (1, 256, 1, 256),
+          (1, 256, 4, 64), (2, 128, 32, 64), (1, 256, 2, 64)]
 DTYPES = [pytest.param(jnp.bfloat16, id="bf16"), pytest.param(jnp.float32, id="f32")]
 
 
@@ -79,21 +85,24 @@ def test_the_rotation_then_its_transpose_returns_the_input(shape):
     np.testing.assert_allclose(transpose(y)[0], x, atol=2e-6, rtol=0)
 
 
-def test_no_activation_is_kept_for_the_backward_pass():
+@pytest.mark.parametrize("shape", [SHAPES[0], SHAPES[3]], ids=str)
+def test_no_activation_is_kept_for_the_backward_pass(shape):
     """The residuals are the two tables alone."""
-    x, _, cos, sin = _operands(SHAPES[0], jnp.bfloat16)
+    x, _, cos, sin = _operands(shape, jnp.bfloat16)
     _, transpose = jax.vjp(lambda x: _fused(x, cos, sin), x)
     kept = [a.shape for a in jax.tree.leaves(transpose)
             if hasattr(a, "shape") and a.ndim]
     assert kept and all(s == cos.shape for s in kept), kept
 
 
-@pytest.fixture(scope="module")
-def clients():
+@pytest.fixture(scope="module", params=[128, 64], ids=["hd128", "hd64"])
+def clients(request):
     """Two clients' operands [C, B, T, H, hd], the tables they share, and
-    the plain body's output and gradient."""
-    x, w = _operands((2, 1, 256, 4, 128), jnp.float32)[:2]
-    cos, sin = rotary_tables(256, 128, THETA)
+    the plain body's output and gradient; heads as wide as the lanes, and
+    the narrow form's."""
+    hd = request.param
+    x, w = _operands((2, 1, 256, 4, hd), jnp.float32)[:2]
+    cos, sin = rotary_tables(256, hd, THETA)
 
     def both(fn):
         def one(x, w):
@@ -147,10 +156,12 @@ def _paths():
 
 
 @pytest.mark.parametrize("shape, dtype", [
-    ((1, 256, 4, 64), jnp.bfloat16),              # a head narrower than the lanes
+    ((1, 256, 1, 64), jnp.bfloat16),              # latent attention's shared key:
+                                                  # H * hd under a row of lanes
+    ((1, 256, 4, 96), jnp.bfloat16),              # a head that does not divide 128
     ((1, 200, 4, 128), jnp.bfloat16),             # T not a multiple of 128
     ((1, 256, 4, 128), jnp.float16),              # a dtype of neither kind
-], ids=["hd64", "T200", "f16"])
+], ids=["H1hd64", "hd96", "T200", "f16"])
 def test_a_shape_that_does_not_fit_takes_the_plain_body(shape, dtype):
     x, _, cos, sin = _operands(shape, dtype)
     before = _paths()
@@ -177,3 +188,18 @@ def test_a_shape_that_fits_is_counted_and_its_cpu_lowering_is_the_plain_body():
     tpu = jax.jit(rotate_half).trace(x, cos, sin).lower(
         lowering_platforms=("tpu",))
     assert "tpu_custom_call" in tpu.as_text()
+
+
+def test_the_wide_form_traces_to_the_program_it_was():
+    """Heads as wide as the lanes keep the program they had before the narrow
+    form came (PR 46): `jax.make_jaxpr(rotate_half)` on bfloat16
+    (1, 256, 4, 128) prints what it printed at the parent `dfda071`, equation
+    for equation, the kernel's body included (`cmdaplus.lora4of256long` runs
+    it) - `tests/data/rotate_half_wide.jaxpr.txt`, addresses and source
+    lines taken out."""
+    x, _, cos, sin = _operands(SHAPES[0], jnp.bfloat16)
+    text = re.sub(r" at 0x[0-9a-f]+", "", str(jax.make_jaxpr(rotate_half)(x, cos, sin)))
+    text = re.sub(r"[\w/.\-]+\.py:\d+", "", text)
+    with open(os.path.join(os.path.dirname(__file__), "data",
+                           "rotate_half_wide.jaxpr.txt")) as f:
+        assert text == f.read()

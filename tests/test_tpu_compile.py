@@ -57,7 +57,17 @@ rotary of `cohere2_moe`'s sliding layers as a kernel, `ops/rotary.py`;
 `2b9b05b` and on its tree: 11,113 / 14,734 / 22,910 lines (9,359 / 12,632 /
 19,196 instructions) without op metadata, the source table and the kernels'
 serialized bodies, 14,865,773,568 / 15,083,182,592 / 15,091,395,072 B on
-both trees, 0 lines differ (builder, CPU compile rehearsal, PR 42).
+both trees, 0 lines differ (builder, CPU compile rehearsal, PR 42).  PR 46
+(the narrow form of `ops/rotary.py`, called by `deepseek_v2.latent_attention`)
+compiled `lora4of256long` of `command_a_plus`, `lora4of256t2048` and
+`silo4of256t1024` on the parent `dfda071` and on its tree: 11,454 / 12,663 /
+9,358 instructions, 13,725,866,496 / 15,083,352,576 / 14,865,773,568 B on both
+trees, 0 lines differ but line numbers of `ops/rotary.py` and of this file in
+the source table; the two cells that gain the kernel went from 14,706,029,568
+to 14,333,107,200 B (`lora4of256t4096`: 15 rotary kernels, the float32 halves
+gone) and from 15,080,673,280 to 15,088,438,784 B (`xing4`: 30), their float32
+twins from 15,431,562,240 to 15,447,213,056 and from 14,535,794,688 to
+14,546,665,472 B (builder, CPU compile rehearsal, PR 46).
 
 A compile that passes is not a chip run: nothing executes, so these
 tests say nothing about results or times.  Skipped where the topology
@@ -259,19 +269,24 @@ def test_band_attention_compiles(topo, window, dtype):
     assert not re.search(rf"f32\[[\d,]*{T},{T}\]", text)
 
 
-# the rotary of that step's sliding layers: q and k of (B, T, ., head size)
-ROTARY = [(1, 8192, 128, 128), (1, 8192, 8, 128)]
+# the rotary of that step's sliding layers: q and k of (B, T, ., head size);
+# and of latent attention's 64-wide query parts in a step of
+# deepseekv2.lora4of256t4096 and of xing4.lora4of256long (the narrow form)
+ROTARY = [(1, 8192, 128, 128), (1, 8192, 8, 128),
+          (1, 4096, 128, 64), (1, 8192, 32, 64)]
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
                          ids=["bf16", "f32"])
 @pytest.mark.parametrize("shape", ROTARY, ids=str)
 def test_rotate_half_compiles(topo, shape, dtype):
-    """`ops.rotary.rotate_half` and its gradient at Command A+'s q and k, as
-    the cell's round and its float32 twin run them: Mosaic accepts the blocks
-    and the lane roll, both passes are kernels, and a bfloat16 operand leaves
-    no float32 buffer of its size (the plain path's intermediates: 537 MB for
-    q)."""
+    """`ops.rotary.rotate_half` and its gradient at Command A+'s q and k and
+    at the two latent cells' `q_rope`, as the cells' rounds and their float32
+    twins run them: Mosaic accepts the blocks and the lane rolls (by half a
+    head of 128 lanes; by 32 and by 96 of a row that holds two heads of 64,
+    and the select between them), both passes are kernels,
+    and a bfloat16 operand leaves no float32 buffer of its size (the plain
+    path's intermediates: 537 MB for Command A+'s q)."""
     from fedml_tpu.models.looped_lm import rotary_tables
     from fedml_tpu.ops.rotary import rotate_half
     B, T, H, hd = shape
@@ -284,7 +299,8 @@ def test_rotate_half_compiles(topo, shape, dtype):
     c = _compile(topo, out_and_grad, (shape, dtype), (shape, dtype))
     _assert_kernels(c, 2)
     if dtype == jnp.bfloat16:
-        assert not re.search(rf"f32\[{B},{T},({H},{hd}|{H * hd})\]", c.as_text())
+        assert not re.search(
+            rf"f32\[{B},({T},{H},{hd}|{T},{H * hd}|{H},{T},{hd})\]", c.as_text())
 
 
 # -- the whole headline round program --------------------------------------
@@ -677,6 +693,21 @@ def _attention_kernels(text: str) -> dict:
     return found
 
 
+def _rotary_kernels(text: str) -> dict:
+    """{phase: how many} of the `ops/rotary.py` kernels in a round's text, all
+    of which the latent side's label must claim."""
+    from fedml_tpu.obs import programs
+    from parallel_case import hlo_instructions
+    smap, phases = programs.maps_of_hlo_text(text)
+    found = {}
+    for name, _, opcode, rest in hlo_instructions(text):
+        if (opcode == "custom-call" and "tpu_custom_call" in rest
+                and name.startswith("rotate_half")):
+            assert smap[name] == "mla_latent", (name, smap[name])
+            found[phases[name]] = found.get(phases[name], 0) + 1
+    return found
+
+
 @pytest.mark.slow
 def test_deepseek_v2_adapter_round_and_its_float32_twin_fit_one_chip(topo):
     """`deepseekv2.lora4of256t4096`'s resident round (a 6.3 GB frozen bfloat16
@@ -697,12 +728,14 @@ def test_deepseek_v2_adapter_round_and_its_float32_twin_fit_one_chip(topo):
     B, T, H, _, _ = LATENT_ATTENTION
     engine, variables, compiled = _dispatched(topo, config, traffic)
     needs = _needs_with_the_base_aliased(compiled, config)
-    # 14.70e9 at chunk 1 (the rehearsal, PR 43) + 0.2e9: base 6.32e9, the
+    # 14.33e9 at chunk 1 (the rehearsal, PR 46: the rotary of q_rope is a
+    # kernel on bfloat16 heads and its float32 halves, 0.37e9, are gone;
+    # 14.70e9 until then, PR 43): base 6.32e9, the
     # compiler's relayout copy of the held experts 3.77e9, a step's
     # activations the rest; 15.09e9 while the expert product made [S, width]
     # float32 arrays for all 6 x 4,096 slots (PR 40), 14.78e9 before the five
     # layers kept 0.89e9 of named values a step (PR 39); the chip gives 16.91e9
-    assert needs < 14.9e9, compiled.memory_analysis()
+    assert needs < 14.55e9, compiled.memory_analysis()
     trained = engine.trainer.trained_variables(variables)
     n_trained = sum(int(np.prod(a.shape)) for a in jax.tree.leaves(trained))
     assert n_trained == config["widths"]["parameters_trained"]
@@ -721,16 +754,19 @@ def test_deepseek_v2_adapter_round_and_its_float32_twin_fit_one_chip(topo):
     # a layer's forward kernel runs once: the parent's text held 5 more, in
     # phase `recompute`, and re-ran 89 matrix products where this one re-runs
     # 79 - W_o and its adapter's B are kept (the rank-wide `o A` is not: the
-    # gradient of B reads it); no kernel runs again: the expert layers'
-    # grouped products (8 re-run until PR 43, 2 of 3 a layer) are made in the
-    # backward rule's blocks, from the rows it gathers
+    # gradient of B reads it); the one kernel that runs again is the rotary
+    # of q_rope (its residuals are the tables: the rotated queries are not
+    # kept, M9 e): the expert layers' grouped products (8 re-run until PR 43,
+    # 2 of 3 a layer) are made in the backward rule's blocks, from the rows
+    # it gathers
     assert _attention_kernels(text) == {"forward": 5, "backward": 5}
-    assert _rerun_work(text) == {"convolution": 79}
+    assert _rotary_kernels(text) == {"forward": 5, "recompute": 5, "backward": 5}
+    assert _rerun_work(text) == {"convolution": 79, "custom-call": 5}
     with jax.default_matmul_precision("highest"):
         _, _, twin = _dispatched(topo, config, dict(traffic, population=4, cohort=4),
                                  train_dtype="float32", local_dtype=None)
-    # 15.45e9 of 16.91e9 (the rehearsal, PR 43; 15.96e9 until then): a
-    # float32 stream keeps a layer's input alone, and every attention kernel
+    # 15.45e9 of 16.91e9 (the rehearsal, PR 46; 15.43e9 PR 43, 15.96e9
+    # until then): a float32 stream keeps a layer's input alone, and every attention kernel
     # and every product of a layer runs again - but the grouped ones, which
     # the backward rule's blocks make
     assert _needs_with_the_base_aliased(twin, config) < 15.65e9, \
@@ -738,7 +774,8 @@ def test_deepseek_v2_adapter_round_and_its_float32_twin_fit_one_chip(topo):
     text = twin.as_text()
     _assert_fused_attention(text, (B, T, H, H, 128))
     assert _attention_kernels(text) == {"forward": 5, "recompute": 5, "backward": 5}
-    assert _rerun_work(text) == {"convolution": 89, "custom-call": 5}
+    assert _rotary_kernels(text) == {"forward": 5, "recompute": 5, "backward": 5}
+    assert _rerun_work(text) == {"convolution": 89, "custom-call": 10}
 
 
 def _kernels_by_label(text: str) -> dict:
@@ -862,9 +899,10 @@ def test_xing4_adapter_round_and_its_float32_twin_fit_one_chip(topo):
     assert traffic["dataset"]["args"]["seq_len"] == T == 8192
     engine, variables, compiled = _dispatched(topo, config, traffic)
     needs = _needs_with_the_base_aliased(compiled, config)
-    # 15,080,673,280 B (the rehearsal, PR 45: arguments 4.50e9 of which the base
-    # 4.47e9 comes back in the buffers it came in, temporaries 10.46e9, code
-    # 0.12e9) + 0.2e9; the chip gives 16.91e9
+    # 15,088,438,784 B (the rehearsal, PR 46, the rotary of q_rope a kernel;
+    # 15,080,673,280 PR 45: arguments 4.50e9 of which the base 4.47e9 comes
+    # back in the buffers it came in, temporaries 10.46e9, code 0.12e9)
+    # + 0.2e9; the chip gives 16.91e9
     assert needs < 15.29e9, compiled.memory_analysis()
     trained = engine.trainer.trained_variables(variables)
     n_trained = sum(int(np.prod(a.shape)) for a in jax.tree.leaves(trained))
@@ -881,7 +919,10 @@ def test_xing4_adapter_round_and_its_float32_twin_fit_one_chip(topo):
     assert text.count("ragged-dot") > 0            # XLA:TPU's grouped product
     _assert_fused_attention(text, (1, T, H, H, 128))
     assert _attention_kernels(text) == {"forward": n_layers, "backward": n_layers}
-    assert _rerun_work(text) == {"convolution": 178}
+    # the re-run's one kernel a layer is the rotary of q_rope
+    assert _rotary_kernels(text) == dict.fromkeys(
+        ("forward", "recompute", "backward"), n_layers)
+    assert _rerun_work(text) == {"convolution": 178, "custom-call": n_layers}
     assert all(_labelled(text, ("hc_maps", "hc_mix")).values())
     with jax.default_matmul_precision("highest"):
         _, _, twin = _dispatched(topo, config, dict(traffic, population=4, cohort=4),
@@ -893,4 +934,6 @@ def test_xing4_adapter_round_and_its_float32_twin_fit_one_chip(topo):
     _assert_fused_attention(text, (1, T, H, H, 128))
     assert _attention_kernels(text) == {"forward": n_layers, "recompute": n_layers,
                                         "backward": n_layers}
+    assert _rotary_kernels(text) == dict.fromkeys(
+        ("forward", "recompute", "backward"), n_layers)
     assert all(_labelled(text, ("hc_maps", "hc_mix")).values())

@@ -394,3 +394,29 @@ def test_what_the_checkpoint_keeps_is_counted():
     text = str(jax.make_jaxpr(lambda v: model.apply(v, x))(half))
     for name in ("attn_out", "mlp_out"):     # the two sublayers' F(u)
         assert f"name={name}" in text, name
+
+
+# -- the rotary of the queries' 64-wide part (ops/rotary.py, PR 46) ----------
+
+def test_the_rotary_of_q_rope_is_the_kernel_in_all_three_passes():
+    """The block calls `deepseek_v2.latent_attention`, so at a rotary part of
+    64 its queries go through the `rotate_half` kernel too: under the latent
+    side's scope in all three passes of a program lowered for a TPU, one
+    `pallas` (q_rope) and one `reference` (the shared key head) for each KIND
+    of layer in a trace (the two dense layers share one traced checkpoint, the
+    two expert layers another) - and `KEPT_NAMES` is what it was."""
+    import test_deepseek_v2 as ds
+    model, params, x = ds._lane_rope("xing4", dict(
+        SMALL, n_heads=2, rope_dim=64, rope_original=128))
+    assert ds.rotary_kernels_by_label_and_phase(model, params, x) == ds.IN_ALL_THREE_PASSES
+    assert ds.rotary_paths_of_one_trace(model, params, x) == (2, 2)
+    assert xing4.KEPT_NAMES == deepseek_v2.KEPT_NAMES + ("mlp_out",)
+
+
+def test_on_the_cpu_the_model_is_the_parents_to_the_bit(monkeypatch):
+    """Logits and adapter gradients of a CPU program are what the parent's
+    call of `apply_rotary` inside `latent_attention` gives, bit for bit."""
+    import test_deepseek_v2 as ds
+    ds.assert_the_cpu_model_is_the_parents_to_the_bit(
+        monkeypatch, *ds._lane_rope("xing4", dict(
+            SMALL, n_heads=2, rope_dim=64, rope_original=128)))
