@@ -75,6 +75,9 @@ class Sizes:
     # attention of deepseekv2.lora4of256t4096 and of xing4.lora4of256long
     rotary_shapes: tuple = ((1, 8192, 128, 128), (1, 8192, 8, 128),
                             (1, 4096, 128, 64), (1, 8192, 32, 64))
+    # (B, T, n, C): the four streams of xing4.lora4of256long under one
+    # hyper-connection
+    hc_shapes: tuple = ((1, 8192, 4, 3584),)
     platform: str = "tpu"        # where every result must live
 
 
@@ -418,6 +421,68 @@ def phase_kernels(sz: Sizes, seed: int) -> None:
              compiled=kernel, max_bf16_ulps_y_dx=ulps, tolerance="1 ulp")
         assert kernel == compiled, f"rotate_half: kernel path = {kernel}"
         assert max(ulps) <= 1.0, ulps
+
+    _hc_kernels(sz, seed, compiled)
+
+
+def _hc_kernels(sz: Sizes, seed: int, compiled: bool) -> None:
+    """The four hyper-connection kernels (ops/hyper_connection.py) at the
+    streams of xing4.lora4of256long, bfloat16: one hyper-connection around
+    ``F(u) = y + u`` with the model's own maps (sigmoids, the Sinkhorn
+    loop) - the read's ``u`` and ``ht``, the write's ``X'``, and the
+    gradients of ``sum(X' w)`` with respect to the streams and to ``y``
+    (the write's backward kernel, then the read's) - from the fused path
+    and from the plain path, each against the plain path in float32.  The
+    kernels may be no farther (relative l2) from that oracle than 1.5 x the
+    plain bfloat16 path is: both sum in float32, the kernels round ``dX``
+    once where jax adds the plain path's rounded shares."""
+    import jax
+    import jax.numpy as jnp
+    from fedml_tpu.models import xing4
+    from fedml_tpu.ops import hyper_connection as hc
+
+    for B, T, n, C in sz.hc_shapes:
+        k = n * (n + 2)
+        keys = jax.random.split(jax.random.PRNGKey(seed), 5)
+        X32, y32, w = (jax.random.normal(key, (B, T, width), jnp.float32)
+                       for key, width in zip(keys, (n * C, C, n * C)))
+        phi32 = 0.02 * jax.random.normal(keys[3], (n * C, k), jnp.float32)
+        b = jnp.concatenate([jnp.full((n,), -1.1), jnp.zeros((n,)),
+                             xing4.HC_RES_DIAG * jnp.eye(n).reshape(-1)])
+        b = b + 0.3 * jax.random.normal(keys[4], (k,), jnp.float32)
+        gate = jnp.full((k,), 0.5, jnp.float32)
+
+        def both(read, write, dtype):
+            def loss(X, y):
+                u, ht, Xc = read(X, phi32.astype(dtype), gate, b, n=n,
+                                 eps=1e-6)
+                post, res, _ = xing4.hc_maps(
+                    jnp.moveaxis(ht, -1, 0), n, 20, 1e-6, (-30.0, 30.0))
+                out = write(Xc, y + u, post, res)
+                return jnp.sum(out.astype(jnp.float32) * w), (u, ht, out)
+            fn = jax.jit(jax.grad(loss, (0, 1), has_aux=True))
+            grads, outs = fn(X32.astype(dtype), y32.astype(dtype))
+            return fn, [a.astype(jnp.float32) for a in outs + grads]
+
+        plain = (lambda *a, n, eps: hc.read_plain(*a, n, eps), hc.write_plain)
+        _, oracle = both(*plain, jnp.float32)
+        fn, fused = both(hc.hc_read, hc.hc_write, jnp.bfloat16)
+        _, spec = both(*plain, jnp.bfloat16)
+        l2 = {name: [float(jnp.linalg.norm(a - o) / jnp.linalg.norm(o))
+                     for a, o in zip(got, oracle)]
+              for name, got in (("fused", fused), ("plain", spec))}
+        kernels = fn.lower(X32.astype(jnp.bfloat16),
+                           y32.astype(jnp.bfloat16)).as_text().count(
+                               "tpu_custom_call")
+        emit("kernel", op="hyper_connection", shape=[B, T, n, C],
+             dtype="bfloat16", compiled=bool(kernels), kernels=kernels,
+             fused_l2_u_ht_out_dX_dy=l2["fused"],
+             plain_l2_u_ht_out_dX_dy=l2["plain"],
+             tolerance="l2 1.5 x plain + 1e-6")
+        assert kernels == (4 if compiled else 0), (
+            f"hyper_connection: kernel path = {kernels} custom calls")
+        assert all(f <= 1.5 * p_ + 1e-6
+                   for f, p_ in zip(l2["fused"], l2["plain"])), l2
 
 
 # -- (d) -------------------------------------------------------------------

@@ -16,6 +16,12 @@ layout helper the mesh engines share.
   heads that divide them, several to a row of lanes - the 64-wide rotary
   part of latent attention's queries (``deepseekv2.lora4of256t4096``,
   ``xing4.lora4of256long``).
+* ``hyper_connection`` — ``hc_read`` / ``hc_write``, the two passes a
+  hyper-connection makes over a token's streams in front of and behind a
+  sublayer (the RMS, the maps' projection and the read ``u`` in one; the
+  write ``X'`` in the other) with backward rules of their own: four kernels
+  over blocks of whole rows, chosen as the others are.  Runs in
+  ``xing4.lora4of256long``.
 * ``aggregate`` — ``flatten_stacked_tree`` / ``unflatten_to_tree``, stacked
   client trees as one padded f32 ``[C, N]`` matrix and back: no kernel; the
   mesh engines' krum / median / trimmed-mean defenses, which no cell runs.
@@ -25,7 +31,8 @@ Each kernel counts the path it took at trace time in
 """
 from fedml_tpu.ops.aggregate import flatten_stacked_tree, unflatten_to_tree
 from fedml_tpu.ops.attention import causal_attention
+from fedml_tpu.ops.hyper_connection import hc_read, hc_write
 from fedml_tpu.ops.rotary import apply_rotary, rotate_half
 
-__all__ = ["causal_attention", "apply_rotary", "rotate_half",
-           "flatten_stacked_tree", "unflatten_to_tree"]
+__all__ = ["causal_attention", "apply_rotary", "rotate_half", "hc_read",
+           "hc_write", "flatten_stacked_tree", "unflatten_to_tree"]

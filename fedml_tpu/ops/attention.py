@@ -389,6 +389,13 @@ def _struct(like, shape, dtype):
 _VMEM_DEFAULT = 12 * 2 ** 20
 
 
+def _vmem(need: int) -> dict:
+    """Nothing where a grid point's ``need`` bytes of fast memory stay under
+    `_VMEM_DEFAULT`; the call's stated limit where they pass it."""
+    return {} if need <= _VMEM_DEFAULT else {
+        "vmem_limit_bytes": min(2 * need, 96 * 2 ** 20)}
+
+
 def _params(interpret, blocks=(), tile=0):
     """How the call is run: Mosaic with the grid's semantics (batch and
     heads independent, the blocks of a head in order), or an interpreter
@@ -400,10 +407,9 @@ def _params(interpret, blocks=(), tile=0):
         return dict(interpret=interpret)
     need = (2 * sum(r * c * jnp.dtype(d).itemsize for r, c, d in blocks)
             + 6 * 4 * tile * tile)
-    limit = {} if need <= _VMEM_DEFAULT else {
-        "vmem_limit_bytes": min(2 * need, 96 * 2 ** 20)}
     return dict(compiler_params=pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"), **limit))
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        **_vmem(need)))
 
 
 def _fused_output_lse(q, k, v, *rope, scale, window, interpret):

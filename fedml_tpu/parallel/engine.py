@@ -600,10 +600,14 @@ class MeshFedAvgEngine(FedAvgEngine):
         # bitwise, the standing pins)
         self.program_family = self._program_family_name(streaming,
                                                         stream_block)
+        # what the model asks of the compiler of this mesh's platform
+        # (`round_compiler_options`): nothing for a model that names none
+        options = self.round_compiler_options() or None
         self.round_fn = obs_programs.instrument(
             self.program_family,
             jax.jit(self._mesh_round,
-                    donate_argnums=(0, 1) if donate else ()),
+                    donate_argnums=(0, 1) if donate else (),
+                    compiler_options=options),
             on_result=self._keep_counters)
         # streaming variant: the gather happened on host; cohort arrives
         # pre-sharded [K, ...] with K = padded cohort size.  This public
@@ -613,7 +617,8 @@ class MeshFedAvgEngine(FedAvgEngine):
         self.round_fn_streaming = obs_programs.instrument(
             self.program_family,
             jax.jit(self._mesh_round_streaming,
-                    donate_argnums=(0, 1) if donate else ()))
+                    donate_argnums=(0, 1) if donate else (),
+                    compiler_options=options))
         # ...but the run() loop gathers a FRESH cohort every round
         # (_round_args), each consumed exactly once — donate it too, so
         # a retired cohort's HBM is recycled into the round instead of
@@ -623,7 +628,8 @@ class MeshFedAvgEngine(FedAvgEngine):
         self._round_fn_streaming_consume = obs_programs.instrument(
             self.program_family,
             jax.jit(self._mesh_round_streaming,
-                    donate_argnums=(0, 1, 2, 3) if donate else ()),
+                    donate_argnums=(0, 1, 2, 3) if donate else (),
+                    compiler_options=options),
             on_result=self._keep_counters)
         if streaming:
             self.round_fn = self._round_fn_streaming_consume
@@ -653,6 +659,16 @@ class MeshFedAvgEngine(FedAvgEngine):
                         donate_argnums=(0, 1, 2) if donate else (2,)))
             self.round_fn = self._round_blockstream
 
+
+    def round_compiler_options(self) -> dict:
+        """{option: value} for the compiler of the round programs: what the
+        model names for the platform of this mesh's devices
+        (``compiler_options`` = {platform: {option: value}} beside
+        ``trainable``; a compiler refuses another platform's options).
+        Empty for a model that names none: its programs and their cache
+        keys are what they were."""
+        named = getattr(self.trainer.model, "compiler_options", None) or {}
+        return dict(named.get(self.mesh.devices.flat[0].platform, {}))
 
     # jit-program family stem (ISSUE 12): subclasses override so their
     # profile rows and compile attribution name the right family in the

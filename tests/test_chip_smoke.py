@@ -22,7 +22,7 @@ TINY = chip_smoke.Sizes(
     model="lr", n_clients=8, samples_per_client=16, batch_size=8,
     image_hw=8, warmup_rounds=1, timed_rounds=2, oracle_clients=4,
     attn_shapes=((1, 128, 2, 1, 64),), rotary_shapes=((1, 128, 2, 128),),
-    platform="cpu")
+    hc_shapes=((1, 128, 4, 128),), platform="cpu")
 
 
 def _lines(capsys) -> list:
@@ -83,20 +83,29 @@ def test_phase_oracle_tiny(capsys):
 def test_phase_kernels_tiny_interpret_mode(capsys):
     chip_smoke.phase_kernels(TINY, seed=0)
     lines = _lines(capsys)
-    assert [l["op"] for l in lines] == ["causal_attention", "rotate_half"]
+    assert [l["op"] for l in lines] == ["causal_attention", "rotate_half",
+                                        "hyper_connection"]
     assert not any(l["compiled"] for l in lines)   # no Mosaic on the CPU
     assert lines[1]["max_bf16_ulps_y_dx"] == [0.0, 0.0]   # the plain body
+    # the rules' plain bodies: u, ht, X', dX, dy as near the float32 oracle
+    # as the plain path's own derivative
+    hc = lines[2]
+    assert hc["kernels"] == 0 and len(hc["fused_l2_u_ht_out_dX_dy"]) == 5
+    assert all(f <= 1.5 * p + 1e-6 for f, p in zip(
+        hc["fused_l2_u_ht_out_dX_dy"], hc["plain_l2_u_ht_out_dX_dy"]))
 
 
 @pytest.mark.parametrize("op, others", [
-    ("causal_attention", "rotary_shapes"), ("rotate_half", "attn_shapes")])
+    ("causal_attention", ("rotary_shapes", "hc_shapes")),
+    ("rotate_half", ("attn_shapes", "hc_shapes")),
+    ("hyper_connection", ("attn_shapes", "rotary_shapes"))])
 def test_phase_kernels_demands_the_compiled_path_on_tpu(op, others):
     """On a TPU the kernel path is asserted, never assumed: a run that
-    claims the platform but lowers no tpu_custom_call fails, at either op
-    (the other one's shapes taken out, so that this one is reached)."""
+    claims the platform but lowers no tpu_custom_call fails, at each op
+    (the other ones' shapes taken out, so that this one is reached)."""
     with pytest.raises(AssertionError, match=f"{op}: kernel path"):
         chip_smoke.phase_kernels(chip_smoke.dataclasses.replace(
-            TINY, platform="tpu", **{others: ()}), seed=0)
+            TINY, platform="tpu", **{k: () for k in others}), seed=0)
 
 
 def test_phase_cli_round_trip(capsys):
